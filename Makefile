@@ -70,11 +70,12 @@ bench:
 	@mkdir -p artifacts
 	python bench.py | tee artifacts/bench_last.json
 
-# regression sentinel: backfill BENCH_r*.json into the append-only
-# artifacts/bench_history.jsonl ledger, ingest artifacts/bench_last.json
-# if present, judge each metric's newest row against median+MAD of its
-# comparable history (cyclone.regress.*) — nonzero on any regression.
-# Self-test of the gate itself: `... bench_regress.py --inject-regression`
+# regression sentinel: ingest artifacts/bench_last.json (if present) into
+# the append-only artifacts/bench_history.jsonl ledger and judge each
+# metric's newest row against median+MAD of its comparable history
+# (cyclone.regress.*) — nonzero on any regression. The ledger starts
+# empty on a fresh checkout. Self-test of the gate itself, on synthetic
+# rows only: `... bench_regress.py --inject-regression`
 bench-regress:
 	python scripts/bench_regress.py --ingest artifacts/bench_last.json
 

@@ -1,0 +1,466 @@
+"""Chip smoke: dense ``LogisticRegression.fit`` end to end on the attached TPU.
+
+Run from the repository root on a machine with a TPU: ``python chip_smoke.py``.
+One process, every attached TPU device, no arguments, nothing read from the
+environment. It drives the main path through the public entry points —
+``CycloneContext`` (default ``master="tpu"``), ``generate_classification``,
+``LogisticRegression.fit`` — at full width, then a host-fed numpy → ``MLFrame``
+→ ``fit`` leg, then compiles and checks every Pallas kernel natively at small
+n. Every leg asserts WHICH path ran (platform, data dtype, Mosaic custom call,
+one in-core dispatch) and that what came out is right (finite non-increasing
+objective, agreement with the XLA twin and with a float64 reference).
+
+Any failed check raises; no TPU exits 1 with a one-line reason and prints no
+result. Standard output is two lines of JSON. The first is the summary of what
+ran (shapes, paths, objectives, agreement, compile cache); its seconds are
+observations of a smoke run (one trial, compile included where labelled), NOT
+benchmark results. The LAST line is the verdict the driver parses, and holds
+exactly ``{"ok": true, "device": {"platform", "kind", "count"}}`` with the
+device as jax reports it.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+N_ROWS = 2_000_000      # the r05 shape: 5.12 GB of bf16 X on one chip
+N_COLS = 1_280
+N_HOST_ROWS = 100_000   # host-fed leg: numpy -> MLFrame -> fit
+MAX_ITER = 25
+REG = 0.01
+
+# Pallas-vs-XLA agreement of two fits over the SAME bf16 X, both with f32
+# accumulators. The twins differ in summation order only — Kahan-compensated
+# sequential row tiles against XLA's MXU/tree reduction, ~1e-7 relative per
+# evaluation — and ten L-BFGS iterations can amplify a last-ulp difference in
+# one line-search decision. Observed on the v5e (PR 21, one chip and four):
+# loss <= 6.4e-8, coefficients <= 1.5e-5. Two orders of margin.
+LOSS_RTOL = 1e-5
+COEF_RTOL = 1e-3        # ||b_pallas - b_xla|| / ||b_xla||
+
+# kernel output vs a float64 reference over the same stored values: f32
+# accumulation over <= 4k rows (HIGHEST-precision MXU passes in the matmul
+# kernels). Observed on the v5e (PR 21): <= 2.7e-6.
+KERNEL_RTOL = 2e-5
+
+
+def check(cond: bool, what: str) -> None:
+    if not cond:
+        raise AssertionError(what)
+
+
+class CompileWatch:
+    """Counts jax's own compile events: persistent-cache hits/misses and
+    backend-compile seconds (cache retrieval included)."""
+
+    def __init__(self):
+        import jax
+        self.hits = self.misses = 0
+        self.seconds = 0.0
+        jax.monitoring.register_event_listener(self._event)
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+
+    def _event(self, event: str, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def _duration(self, event: str, secs: float, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.seconds += secs
+
+
+def cache_entries(path: str) -> int:
+    if not os.path.isdir(path):
+        return 0
+    return sum(1 for f in os.listdir(path) if not f.endswith("-atime"))
+
+
+def in_use(devices) -> list:
+    return [int(d.memory_stats()["bytes_in_use"]) for d in devices]
+
+
+def assert_on_all_devices(ds, before: list, devices) -> None:
+    import jax
+    jax.block_until_ready(ds.x)
+    check(str(ds.x.dtype) == "bfloat16",
+          f"data tier is {ds.x.dtype}, expected bfloat16")
+    check(len(ds.x.sharding.device_set) == len(devices),
+          f"X sits on {len(ds.x.sharding.device_set)} of "
+          f"{len(devices)} devices")
+    grew = [a > b for a, b in zip(in_use(devices), before)]
+    check(all(grew), f"bytes_in_use did not grow on every device: {grew}")
+
+
+def assert_fit_in_core(model, what: str) -> list:
+    s = model.summary
+    hist = [float(v) for v in s.objective_history]
+    check(not s.streamed, f"{what}: fit was re-routed out of core")
+    check(s.total_dispatches == 1,
+          f"{what}: {s.total_dispatches} dispatches — the device chunk was "
+          f"degraded or the fit left DeviceLBFGS")
+    # tol=0 still stops early when an iteration no longer moves the f32
+    # objective (|f - f_new| <= 0): fewer than MAX_ITER is legitimate
+    check(1 <= s.total_iterations <= MAX_ITER,
+          f"{what}: {s.total_iterations} iterations of {MAX_ITER}")
+    check(np.all(np.isfinite(hist)), f"{what}: non-finite objective {hist}")
+    check(all(b <= a for a, b in zip(hist, hist[1:])),
+          f"{what}: objective history increases: {hist}")
+    check(np.all(np.isfinite(np.asarray(model.coefficients))),
+          f"{what}: non-finite coefficients")
+    return hist
+
+
+def aggregation_program_text(ds, d: int) -> str:
+    """Lowered text of the tree_aggregate program a default-conf binomial
+    fit of ``ds`` inlines. The aggregator factory and the program cache
+    are both identity-keyed, so asking again after the fit returns the
+    very program the fit built — asserted by the cache not growing."""
+    import jax.numpy as jnp
+    from cycloneml_tpu.ml.optim import aggregators
+    from cycloneml_tpu.parallel import collectives
+
+    size = len(collectives._program_cache)
+    call = ds.tree_aggregate_fn(
+        aggregators.binary_logistic_pallas_scaled(d, True))
+    check(len(collectives._program_cache) == size,
+          "the fit did not build the Pallas aggregation program")
+    v = jnp.zeros(d, jnp.float32)
+    return call.compiled.__wrapped__.lower(
+        *call.arrays(), v, v, jnp.zeros(d + 1, jnp.float32)).as_text()
+
+
+def fit_pair(ctx, lr, data, what: str):
+    """The default (fused Pallas) fit cold and warm, then the same fit
+    under usePallasKernels=false on the same data; returns
+    (model, history, seconds, agreement)."""
+    from cycloneml_tpu.conf import USE_PALLAS_KERNELS
+
+    t0 = time.perf_counter()
+    model = lr.fit(data)
+    cold_s = time.perf_counter() - t0
+    hist = assert_fit_in_core(model, f"{what} (pallas, cold)")
+    t0 = time.perf_counter()
+    warm = lr.fit(data)
+    warm_s = time.perf_counter() - t0
+    assert_fit_in_core(warm, f"{what} (pallas, warm)")
+    check(np.array_equal(np.asarray(model.coefficients),
+                         np.asarray(warm.coefficients)),
+          f"{what}: the warm fit is not bit-identical to the cold one")
+
+    ctx.conf.set(USE_PALLAS_KERNELS, "false")
+    try:
+        ref = lr.fit(data)
+    finally:
+        ctx.conf.set(USE_PALLAS_KERNELS, "auto")
+    ref_hist = assert_fit_in_core(ref, f"{what} (xla)")
+    b, b_ref = (np.asarray(m.coefficients, np.float64) for m in (model, ref))
+    loss_rel = abs(hist[-1] - ref_hist[-1]) / abs(ref_hist[-1])
+    coef_rel = float(np.linalg.norm(b - b_ref) / np.linalg.norm(b_ref))
+    check(loss_rel <= LOSS_RTOL,
+          f"{what}: pallas/xla final loss differ by {loss_rel:.3e}")
+    check(coef_rel <= COEF_RTOL,
+          f"{what}: pallas/xla coefficients differ by {coef_rel:.3e}")
+    return model, hist, (cold_s, warm_s), {
+        "loss_rel": loss_rel, "coef_rel": coef_rel,
+        "xla_objective_last": ref_hist[-1]}
+
+
+def device_leg(ctx, n: int, d: int, devices) -> dict:
+    """Leg 1: device-generated data, the full-width in-core fit."""
+    import jax
+    from cycloneml_tpu.dataset.random import generate_classification
+    from cycloneml_tpu.ml.classification import LogisticRegression
+    from cycloneml_tpu.observe import costs
+
+    before = in_use(devices)
+    t0 = time.perf_counter()
+    ds = generate_classification(ctx, n, d, seed=0)
+    assert_on_all_devices(ds, before, devices)
+    gen_s = time.perf_counter() - t0
+
+    lr = LogisticRegression(maxIter=MAX_ITER, regParam=REG, tol=0.0)
+    model, hist, (cold_s, warm_s), agree = fit_pair(ctx, lr, ds, "device leg")
+
+    text = aggregation_program_text(ds, d)
+    check("tpu_custom_call" in text,
+          "no Mosaic custom call in the aggregation program: the kernel "
+          "was interpreted or replaced by the XLA aggregator")
+    check(ds.x_scale is None
+          and (ctx.fit_profile() or {}).get("fp8_fallbacks", 0) == 0,
+          "an fp8 tier or fallback on a default-conf (bf16) fit")
+
+    # the generator's shards draw from their own streams, so the dataset
+    # (and the model) depends on the device count; what is shared is the
+    # ground truth every shard labels with — recover it
+    beta = np.asarray(jax.random.normal(
+        jax.random.fold_in(jax.random.PRNGKey(0), 2 ** 31 - 1), (d,),
+        dtype="float32"), np.float64)
+    b = np.asarray(model.coefficients, np.float64)
+    cosine = float(b @ beta / np.linalg.norm(b) / np.linalg.norm(beta))
+    check(cosine > 0.95, f"fitted direction misses the ground truth: "
+                         f"cosine {cosine:.4f}")
+    s = model.summary
+    return {
+        "n": n, "d": d, "data_dtype": str(ds.x.dtype),
+        "kernel_path": "pallas:tpu_custom_call",
+        "iterations": s.total_iterations, "evals": s.total_evals,
+        "dispatches": s.total_dispatches,
+        "objective_first": hist[0], "objective_last": hist[-1],
+        "pallas_vs_xla": agree, "cosine_to_truth": cosine,
+        "generate_s": round(gen_s, 3),
+        "cold_fit_s": round(cold_s, 3), "warm_fit_s": round(warm_s, 3),
+        "device_bytes_limit": costs.device_memory_limit(ctx.conf),
+        "bytes_in_use": in_use(devices),
+    }
+
+
+def host_leg(ctx, n: int, d: int, devices) -> dict:
+    """Leg 2: numpy -> MLFrame -> fit (MeshRuntime.device_put_sharded_rows).
+    The data comes from a numpy seed, so it is the same on any device
+    count: the coefficients are the cross-machine parity probe."""
+    from cycloneml_tpu.dataset.frame import MLFrame
+    from cycloneml_tpu.ml.classification import LogisticRegression
+
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((n, d), dtype=np.float32)
+    beta = rng.standard_normal(d).astype(np.float32)
+    y = (x @ beta + rng.standard_normal(n, dtype=np.float32) > 0)
+    frame = MLFrame(ctx, {"features": x, "label": y.astype(np.float64)})
+
+    lr = LogisticRegression(maxIter=MAX_ITER, regParam=REG, tol=0.0)
+    before = in_use(devices)
+    t0 = time.perf_counter()
+    # the frame caches the dataset it places: this is the fit's own X
+    ds = frame.to_instance_dataset(lr.get("featuresCol"), lr.get("labelCol"),
+                                   None, fp8_capable=True)
+    assert_on_all_devices(ds, before, devices)
+    put_s = time.perf_counter() - t0
+    model, hist, (cold_s, warm_s), agree = fit_pair(ctx, lr, frame, "host leg")
+
+    b = np.asarray(model.coefficients, np.float64)
+    acc = float(np.mean((x @ b.astype(np.float32) + model.intercept > 0) == y))
+    check(acc > 0.9, f"host leg: training accuracy {acc:.3f}")
+    os.makedirs("chiprun_out", exist_ok=True)
+    np.save(os.path.join("chiprun_out",
+                         f"chip_smoke_host_coef_{len(devices)}dev.npy"),
+            np.append(b, model.intercept))
+    s = model.summary
+    return {
+        "n": n, "d": d, "data_dtype": str(ds.x.dtype),
+        "iterations": s.total_iterations, "evals": s.total_evals,
+        "dispatches": s.total_dispatches,
+        "objective_first": hist[0], "objective_last": hist[-1],
+        "pallas_vs_xla": agree, "train_accuracy": acc,
+        "coef_l2": float(np.linalg.norm(b)),
+        "coef_head": [float(v) for v in b[:4]],
+        "intercept": float(model.intercept),
+        "device_put_s": round(put_s, 3),
+        "cold_fit_s": round(cold_s, 3), "warm_fit_s": round(warm_s, 3),
+    }
+
+
+def rel_err(got, want) -> float:
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.max(np.abs(got - want)) / max(np.max(np.abs(want)), 1e-30))
+
+
+def kernel_matrix() -> dict:
+    """Every kernel in ops/kernels.py, compiled natively (never
+    interpreted) for every storage tier it claims, at published widths and
+    small n — including row counts no aligned tile divides — and checked
+    against a float64 reference over the same stored values."""
+    import jax
+    import jax.numpy as jnp
+    from cycloneml_tpu.dataset.instance import quantize_fp8
+    from cycloneml_tpu.ml.optim import aggregators
+    from cycloneml_tpu.ops import kernels
+
+    rng = np.random.default_rng(1)
+    out = {}
+
+    def stored(x32, tier):
+        """(device array at the tier's storage dtype, per-column scale or
+        None, the float64 values the kernel is meant to see)"""
+        if tier == "float8":
+            codes, scale = quantize_fp8(x32)[:2]
+            scale = np.asarray(scale, np.float32)
+            return (jnp.asarray(codes), scale,
+                    np.asarray(codes, np.float64) * scale)
+        xs = jnp.asarray(x32, jnp.dtype(tier))
+        return xs, None, np.asarray(xs, np.float64)
+
+    def record(name, fn, args, want: dict):
+        jitted = jax.jit(fn)
+        check("tpu_custom_call" in jitted.lower(*args).as_text(),
+              f"{name}: not lowered to Mosaic")
+        got = jitted(*args)
+        errs = {k: rel_err(got[k], v) for k, v in want.items()}
+        check(all(e <= KERNEL_RTOL for e in errs.values()),
+              f"{name}: off the float64 reference: {errs}")
+        out[name] = max(errs.values())
+
+    d = N_COLS
+    for tier in ("float32", "bfloat16", "float8"):
+        # 4096: tile 256; 4104 = 8*513 and 1000 = 8*125: no 16/32-row
+        # tile divides, the narrow tiers run (8, d) blocks or pad
+        for n in (4096, 4104, 1000):
+            x32 = rng.standard_normal((n, d), dtype=np.float32)
+            xs, scale, xv = stored(x32, tier)
+            y = (rng.random(n) > 0.5).astype(np.float32)
+            w = np.ones(n, np.float32)
+            inv_std = (1.0 + rng.random(d)).astype(np.float32)
+            mean = (0.1 * rng.standard_normal(d)).astype(np.float32)
+            coef = (0.05 * rng.standard_normal(d + 1)).astype(np.float32)
+            # float64 reference of the scaled aggregator's contract
+            xh = xv * inv_std - mean
+            m = xh @ coef[:d].astype(np.float64) + coef[d]
+            mult = w * (1.0 / (1.0 + np.exp(-m)) - y)
+            want = {"loss": np.sum(w * (np.logaddexp(0.0, m) - y * m)),
+                    "grad": np.append(xh.T @ mult, mult.sum())}
+            record(f"logistic/{tier}/n={n}",
+                   lambda x, s=scale: kernels.fused_binary_logistic_scaled(
+                       x, y, w, inv_std, mean, coef, d, True, x_scale=s),
+                   (xs,), want)
+            if n != 4096:
+                continue
+            yr = (xv @ rng.standard_normal(d) / 30.0).astype(np.float32)
+            y_pars = np.asarray([0.5, 0.2], np.float32)
+            c = coef[:d]
+            err = xh @ c.astype(np.float64) + y_pars[1] - y_pars[0] * yr
+            record(f"least_squares/{tier}/n={n}",
+                   lambda x, s=scale: kernels.fused_least_squares_scaled(
+                       x, yr, w, inv_std, mean, y_pars, c, d, x_scale=s),
+                   (xs,), {"loss": 0.5 * np.sum(w * err * err),
+                           "grad": xh.T @ (w * err)})
+            record(f"gramian/{tier}/n={n}",
+                   lambda x, s=scale: {"g": kernels.fused_gramian(
+                       x, w, x_scale=s)},
+                   (xs,), {"g": xv.T @ xv})
+
+    # stacked fits vmap the GLM kernel (labels on axis 1 of an (n, K) bf16
+    # stack, coefficients on axis 0)
+    n, k_models = 4096, 8
+    agg = aggregators.stack_scaled_aggregator(
+        aggregators.binary_logistic_pallas_scaled(d, True))
+    for tier in ("float32", "bfloat16"):
+        x32 = rng.standard_normal((n, d), dtype=np.float32)
+        xs, _, xv = stored(x32, tier)
+        ys = (rng.random((n, k_models)) > 0.5)
+        w = np.ones(n, np.float32)
+        inv_std = np.ones(d, np.float32)
+        mean = np.zeros(d, np.float32)
+        coefs = (0.05 * rng.standard_normal((k_models, d + 1))
+                 ).astype(np.float32)
+        m = xv @ coefs[:, :d].T.astype(np.float64) + coefs[:, d]
+        mult = 1.0 / (1.0 + np.exp(-m)) - ys
+        record(f"logistic_vmap_K8/{tier}/n={n}",
+               agg, (xs, jnp.asarray(ys, jnp.bfloat16), w, inv_std, mean,
+                     coefs),
+               {"loss": np.sum(np.logaddexp(0.0, m) - ys * m, axis=0),
+                "grad": np.concatenate([mult.T @ xv,
+                                        mult.sum(0)[:, None]], axis=1)})
+
+    # KMeans assignment (opt-in path; recorded for ROADMAP D3)
+    n, d_k, k = 8192, 128, 1000
+    centers = rng.standard_normal((k, d_k), dtype=np.float32)
+    for tier in ("float32", "bfloat16", "float8"):
+        x32 = (centers[rng.integers(0, k, n)]
+               + 0.05 * rng.standard_normal((n, d_k), dtype=np.float32))
+        xs, scale, xv = stored(x32, tier)
+        d2 = ((xv * xv).sum(1)[:, None] - 2.0 * xv @ centers.T.astype(
+            np.float64) + (centers.astype(np.float64) ** 2).sum(1)[None])
+        fn = jax.jit(lambda x, s=scale: kernels.fused_kmeans_assign(
+            x, centers, x_scale=s))
+        check("tpu_custom_call" in fn.lower(xs).as_text(),
+              f"kmeans/{tier}: not lowered to Mosaic")
+        best, dist = fn(xs)
+        best = np.asarray(best)
+        # near-ties may resolve either way: judge by the distance reached
+        reached = d2[np.arange(n), best]
+        check(np.all(reached <= d2.min(1) + 1e-3 * (1.0 + d2.min(1))),
+              f"kmeans/{tier}: assignments are not nearest centers")
+        out[f"kmeans_assign/{tier}/n={n},k={k},d={d_k}"] = rel_err(
+            np.asarray(dist), np.maximum(d2.min(1), 0.0))
+    return out
+
+
+def main() -> int:
+    import jax
+
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
+        print(f"chip_smoke: no TPU attached — jax's default backend is "
+              f"{devices[0].platform!r} ({len(devices)} device(s)); this "
+              f"script only runs on the chip", file=sys.stderr)
+        return 1
+    watch = CompileWatch()
+
+    from importlib.metadata import version
+
+    from cycloneml_tpu import CycloneConf, CycloneContext
+    from cycloneml_tpu import mesh as mesh_mod
+
+    t_start = time.perf_counter()
+    cache_dir = mesh_mod.compilation_cache_dir()
+    entries_before = cache_entries(cache_dir)
+    ctx = CycloneContext(
+        CycloneConf().set("cyclone.app.name", "chip-smoke")
+        # the whole iteration budget in ONE device dispatch
+        .set("cyclone.ml.lbfgs.deviceChunk", str(MAX_ITER + 8)))
+    rt = ctx.mesh_runtime
+    check(rt.platform == "tpu", f"mesh platform is {rt.platform}")
+    check(rt.n_devices == len(devices),
+          f"mesh has {rt.n_devices} of {len(devices)} devices")
+    mesh_shape = dict(zip(rt.mesh.axis_names, rt.mesh.devices.shape))
+    check(mesh_shape == {"replica": 1, "data": len(devices), "model": 1},
+          f"unexpected mesh shape {mesh_shape}")
+    check(jax.config.jax_compilation_cache_dir == cache_dir,
+          f"compile cache is at {jax.config.jax_compilation_cache_dir}, "
+          f"expected {cache_dir}")
+    print(f"chip_smoke: {len(devices)} x {devices[0].device_kind} "
+          f"({devices[0].platform}), mesh {mesh_shape}, jax {jax.__version__}",
+          file=sys.stderr)
+
+    device = device_leg(ctx, N_ROWS, N_COLS, devices)
+    print(f"chip_smoke: device leg ok {device}", file=sys.stderr)
+    host = host_leg(ctx, N_HOST_ROWS, N_COLS, devices)
+    print(f"chip_smoke: host leg ok {host}", file=sys.stderr)
+    kernels_ok = kernel_matrix()
+    print(f"chip_smoke: kernel matrix ok {kernels_ok}", file=sys.stderr)
+    ctx.stop()
+
+    verdict = {"ok": True,
+               "device": {"platform": devices[0].platform,
+                          "kind": devices[0].device_kind,
+                          "count": len(devices)}}
+    print(json.dumps({
+        **verdict,
+        "mesh": mesh_shape,
+        "versions": {"jax": jax.__version__, "jaxlib": version("jaxlib"),
+                     "libtpu": version("libtpu")},
+        "fit": device,
+        "host_fit": host,
+        "kernel_matrix_max_rel_err": kernels_ok,
+        "compile_cache": {
+            "dir": cache_dir, "entries_before": entries_before,
+            "entries_after": cache_entries(cache_dir),
+            "hits": watch.hits, "misses": watch.misses,
+            "compile_s": round(watch.seconds, 3)},
+        "wall_s": round(time.perf_counter() - t_start, 3),
+        "note": "seconds are smoke observations, not benchmark results",
+        "claim": None,
+    }))
+    # the last line carries the verdict and the device, and nothing else
+    print(json.dumps(verdict), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

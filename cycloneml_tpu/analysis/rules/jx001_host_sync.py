@@ -11,7 +11,7 @@ a) **Inside jit-reachable (traced) code**: ``float(x)`` / ``int(x)`` /
 b) **In host driver code**: pulling several scalars piecemeal out of the
    result of a compiled aggregation program (``out = run(...)`` then
    ``float(out["loss"])``, ``float(out["wsum"])``, ...). Each conversion
-   is its own blocking transfer through the dispatch relay; one
+   is its own blocking device-to-host transfer; one
    ``jax.device_get(out)`` batches them into a single round trip. Only
    flagged at >= 2 pulls — a single conversion is already minimal.
 """

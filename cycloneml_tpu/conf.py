@@ -367,11 +367,11 @@ USE_PALLAS_KERNELS = (
          "fused kernels the DEFAULT sweep on natively-lowered backends "
          "(TPU): one VMEM-resident row pass per loss/grad evaluation, "
          "narrow (bf16) blocks read at storage width with fp32 in-kernel "
-         "accumulation, ~10-16% faster end-to-end at HBM scale "
-         "(benchmarks/PALLAS_AB.md; small shapes are within relay noise "
-         "either way). Everywhere else 'auto' keeps the XLA path — the "
-         "interpreted kernels exist for tests, not speed. 'true'/'false' "
-         "force one path for every eligible estimator.")
+         "accumulation (which twin is faster at which shape is not "
+         "measured on the current machine). Everywhere else 'auto' keeps "
+         "the XLA path. 'true'/'false' force one path for every eligible "
+         "estimator; 'true' on a backend that cannot lower Mosaic raises "
+         "— the package never runs the Pallas interpreter on its own.")
     .check_value(lambda v: str(v).lower() in ("auto", "true", "false"),
                  "must be auto, true or false")
     .str_conf("auto")

@@ -71,72 +71,95 @@ def _generate(ctx, n_rows: int, n_cols: int, seed: int,
                            rt.device_put_sharded_rows(w), n_rows, n_cols)
 
 
-def generate_classification(ctx, n_rows: int, n_cols: int, seed: int = 0,
-                            noise: float = 1.0) -> InstanceDataset:
-    """Labeled synthetic binary-classification dataset, generated entirely
-    on device (the benchmark/scale-test feeder; ref RandomRDDs +
-    LogisticRegressionDataGenerator, mllib/util/LogisticRegressionDataGenerator.scala:33).
+#: f32 bytes one draw of :func:`_draw_projected` may hold at a time
+_DRAW_CHUNK_BYTES = 64 << 20
 
-    Each shard draws its feature rows from ``fold_in(seed, shard)`` and
-    labels them with a shared ground-truth weight vector drawn from
-    ``fold_in(seed, 2**31 - 1)``: ``y = 1[x·beta + noise·eps > 0]``. Zero
-    host→device transfer of X; only the (n,) labels are read back once so
-    estimators get their host label histogram for free."""
+
+def _draw_projected(key, per: int, n_cols: int, beta, xdt):
+    """One shard's ``(per, n_cols)`` standard-normal X in the data-tier
+    dtype together with its f32 projection ``x·beta``, drawn in ROW CHUNKS
+    written in place into the outputs.
+
+    The projection needs the f32 draw and the dataset keeps the narrowed
+    copy, so drawing X whole holds both at once: XLA materializes the f32
+    block (10.24 GB at 2M×1,280, beside the 5.12 GB bf16 result — measured
+    with the compiler's memory analysis for a v5e) and a 16 GB chip has no
+    room left. Chunked, the f32 draw never exceeds ``_DRAW_CHUNK_BYTES``.
+    Chunk ``i`` draws from ``fold_in(key, i)``, so the stream depends on
+    the chunk size but not on the mesh."""
     import jax
     import jax.numpy as jnp
+
+    rows = min(per, max(8, _DRAW_CHUNK_BYTES // (4 * n_cols) // 8 * 8))
+    # n_full whole chunks, then a last one of 1..rows rows (never empty)
+    n_full = (per - 1) // rows
+    tail = per - n_full * rows
+
+    def put(i, n, carry):
+        x, proj = carry
+        xc = jax.random.normal(jax.random.fold_in(key, i), (n, n_cols),
+                               dtype=jnp.float32)
+        return (jax.lax.dynamic_update_slice(x, xc.astype(xdt),
+                                             (i * rows, 0)),
+                jax.lax.dynamic_update_slice(proj, xc @ beta, (i * rows,)))
+
+    carry = (jnp.zeros((per, n_cols), xdt), jnp.zeros((per,), jnp.float32))
+    carry = jax.lax.fori_loop(
+        0, n_full, lambda i, c: put(i, rows, c), carry)
+    return put(n_full, tail, carry)
+
+
+def _generate_labelled(ctx, n_rows: int, n_cols: int, seed: int,
+                       noise: float, label: Callable) -> InstanceDataset:
+    """Shared body of the labelled generators: per shard, X from
+    ``fold_in(seed, shard)`` and ``y = label(x·beta + noise·eps)`` with a
+    ground-truth ``beta ~ N(0, 1)`` from ``fold_in(seed, 2**31 - 1)`` shared
+    by every shard. Zero host→device transfer of X; only the (n,) labels
+    are read back once, so estimators get their host label histogram (an
+    (n,) readback, not (n, d)) without a device pass per fit."""
+    import jax
+    import jax.numpy as jnp
+
+    from cycloneml_tpu.dataset.instance import compute_dtype, data_dtype
+    dt = compute_dtype()
+    xdt = data_dtype(getattr(ctx, "conf", None))
 
     def local(key, per):
         kx, ke = jax.random.split(key)
         beta = jax.random.normal(
             jax.random.fold_in(jax.random.PRNGKey(seed), 2 ** 31 - 1),
             (n_cols,), dtype=jnp.float32)
-        x = jax.random.normal(kx, (per, n_cols), dtype=jnp.float32)
-        margin = x @ beta + noise * jax.random.normal(ke, (per,),
-                                                      dtype=jnp.float32)
-        return x.astype(xdt), (margin > 0).astype(dt)
+        x, proj = _draw_projected(kx, per, n_cols, beta, xdt)
+        margin = proj + noise * jax.random.normal(ke, (per,),
+                                                  dtype=jnp.float32)
+        return x, label(margin).astype(dt)
 
-    from cycloneml_tpu.dataset.instance import compute_dtype, data_dtype
-    dt = compute_dtype()
-    xdt = data_dtype(getattr(ctx, "conf", None))
     (x, y), w, total, dt = _shard_generate(ctx, n_rows, seed, local, n_out=2)
     rt = ctx.mesh_runtime
     ds = InstanceDataset(ctx, x, y, rt.device_put_sharded_rows(w),
                          n_rows, n_cols)
-    # one small readback: estimators consult the host label histogram each
-    # fit — (n,) not (n, d), so this stays cheap even through a TPU relay
     return ds.attach_host_labels(np.asarray(y).astype(np.float64),
                                  w.astype(np.float64))
+
+
+def generate_classification(ctx, n_rows: int, n_cols: int, seed: int = 0,
+                            noise: float = 1.0) -> InstanceDataset:
+    """Labeled synthetic binary-classification dataset, generated entirely
+    on device (the benchmark/scale-test feeder; ref RandomRDDs +
+    LogisticRegressionDataGenerator, mllib/util/LogisticRegressionDataGenerator.scala:33):
+    ``y = 1[x·beta + noise·eps > 0]`` (see :func:`_generate_labelled`)."""
+    return _generate_labelled(ctx, n_rows, n_cols, seed, noise,
+                              lambda margin: margin > 0)
 
 
 def generate_regression(ctx, n_rows: int, n_cols: int, seed: int = 0,
                         noise: float = 0.1) -> InstanceDataset:
     """Labeled synthetic linear-regression dataset generated entirely on
     device (ref mllib/util/LinearDataGenerator.scala:120 — the epsilon-shape
-    BASELINE config-2 feeder): ``y = x·beta + noise·eps`` with a shared
-    ground-truth ``beta ~ N(0,1)`` drawn from ``fold_in(seed, 2^31-1)``."""
-    import jax
-    import jax.numpy as jnp
-
-    from cycloneml_tpu.dataset.instance import compute_dtype, data_dtype
-    dt = compute_dtype()
-    xdt = data_dtype(getattr(ctx, "conf", None))
-
-    def local(key, per):
-        kx, ke = jax.random.split(key)
-        beta = jax.random.normal(
-            jax.random.fold_in(jax.random.PRNGKey(seed), 2 ** 31 - 1),
-            (n_cols,), dtype=jnp.float32)
-        x = jax.random.normal(kx, (per, n_cols), dtype=jnp.float32)
-        y = x @ beta + noise * jax.random.normal(ke, (per,),
-                                                 dtype=jnp.float32)
-        return x.astype(xdt), y.astype(dt)
-
-    (x, y), w, total, dt = _shard_generate(ctx, n_rows, seed, local, n_out=2)
-    rt = ctx.mesh_runtime
-    ds = InstanceDataset(ctx, x, y, rt.device_put_sharded_rows(w),
-                         n_rows, n_cols)
-    return ds.attach_host_labels(np.asarray(y).astype(np.float64),
-                                 w.astype(np.float64))
+    BASELINE config-2 feeder): ``y = x·beta + noise·eps`` (see
+    :func:`_generate_labelled`)."""
+    return _generate_labelled(ctx, n_rows, n_cols, seed, noise,
+                              lambda margin: margin)
 
 
 class RandomDatasets:

@@ -6,7 +6,7 @@ memory instead of through a checkpoint file:
 
 - :func:`host_bounce` pulls every device leaf of a pytree to host numpy
   in ONE batched ``jax.device_get`` (the JX001 discipline — no piecemeal
-  per-leaf pulls through a TPU relay). Host leaves pass through
+  per-leaf pulls, each its own blocking transfer). Host leaves pass through
   untouched, so bouncing an already-host-resident L-BFGS state is free.
 - :func:`host_bounce_state` is the OptimState form: coefficients,
   gradient and the S/Y curvature rings come back as host float64 —

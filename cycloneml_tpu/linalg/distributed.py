@@ -107,8 +107,11 @@ class RowMatrix:
             out = self.dataset.tree_aggregate_fn(agg)()
             return DenseMatrix.from_array(np.asarray(out, dtype=np.float64))
 
-        from cycloneml_tpu.ops.kernels import fused_gramian, use_fused_kernels
-        if use_fused_kernels(self.dataset.ctx):
+        from cycloneml_tpu.ops.kernels import (fused_gramian,
+                                               fused_gramian_fits,
+                                               use_fused_kernels)
+        if use_fused_kernels(self.dataset.ctx) and fused_gramian_fits(
+                self.num_cols(), self.dataset.x.dtype):
             # fused Pallas sweep: per-tile MXU matmul into a revisited VMEM
             # accumulator, presence mask applied in-kernel — one storage-
             # width read of X, no masked copy

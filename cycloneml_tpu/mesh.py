@@ -11,7 +11,7 @@ Master-URL grammar (≈ SparkContext.scala:3058 master parsing):
   ``local-mesh[N]``   N host-platform devices (test fixture; requires
                       ``--xla_force_host_platform_device_count=N``)
   ``local-mesh[*]``   all visible devices of the default platform
-  ``tpu``             all attached TPU devices
+  ``tpu``             all attached TPU devices (raises when there are none)
   ``multihost``       ``jax.distributed.initialize()`` then all global devices
 
 The mesh is laid out ``(replica, data)``: ``data`` is the intra-slice axis
@@ -21,15 +21,15 @@ by a psum over ``replica`` — the hierarchical ICI-then-DCN reduction that
 replaces the reference's log-depth ``treeAggregate`` (ref: RDD.scala:1223).
 
 Multi-process masters route through :mod:`cycloneml_tpu.multihost`:
-``bootstrap`` owns the ``jax.distributed`` lifecycle (version-compat
-``is_initialized``, CPU-smoke gloo collectives, coordinator preflight,
-barriered teardown) and ``hierarchy`` builds the device grid so replica
-rows align with process (DCN) boundaries — ``n_replicas=None`` defaults
-to one replica row per process.
+``bootstrap`` owns the ``jax.distributed`` lifecycle (CPU-smoke gloo
+collectives, coordinator preflight, barriered teardown) and ``hierarchy``
+builds the device grid so replica rows align with process (DCN)
+boundaries — ``n_replicas=None`` defaults to one replica row per process.
 """
 
 from __future__ import annotations
 
+import os
 import re
 from typing import Optional, Sequence, Tuple
 
@@ -47,45 +47,55 @@ _LOCAL_MESH_RE = re.compile(r"local-mesh\[(\d+|\*)\]")
 _MULTIHOST_RE = re.compile(r"multihost\[([^,\]]+),(\d+),(\d+)\]")
 
 
-_comp_cache_enabled = False
+#: where compiled programs persist when ``JAX_COMPILATION_CACHE_DIR`` is not
+#: set: one fixed path inside the checkout (git-ignored). The directory is
+#: part of the cache key's lookup, so it must never move between runs.
+DEFAULT_COMPILATION_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_compilation_cache")
 
 
-def _enable_compilation_cache(jax) -> None:
-    """Persist compiled XLA executables on disk across processes.
+def compilation_cache_dir() -> str:
+    """The persistent compile cache's directory: wherever
+    ``JAX_COMPILATION_CACHE_DIR`` places it, else the fixed in-checkout
+    default."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or DEFAULT_COMPILATION_CACHE_DIR)
 
-    TPU compiles are the dominant fixed cost (tens of seconds per program
-    through a remote backend), and every new process would otherwise pay
-    them again — the reference ships pre-compiled JVM bytecode and never
-    has this problem, so matching its warm-start behavior requires the
-    persistent cache. Off-switch: CYCLONE_NO_COMPILATION_CACHE=1.
-    """
-    global _comp_cache_enabled
-    if _comp_cache_enabled or __import__("os").environ.get(
-            "CYCLONE_NO_COMPILATION_CACHE"):
+
+def _configure_compilation_cache(jax, platform: str) -> None:
+    """Persist compiled executables across processes on accelerators: a
+    cold TPU process otherwise pays every program's compile again. A
+    directory set from outside (``JAX_COMPILATION_CACHE_DIR``, which jax
+    reads itself) is never overridden; only when none is set does the
+    package point jax at its in-checkout default. Host-platform meshes are
+    left alone entirely: XLA:CPU cache entries record the compile
+    machine's features and were seen to reload with different codegen
+    (reduction-order drift in tests), and CPU compiles are cheap."""
+    if platform == "cpu":
         return
-    import os
-    path = os.environ.get(
-        "CYCLONE_COMPILATION_CACHE_DIR",
-        os.path.expanduser("~/.cache/cycloneml_tpu/xla-cache"))
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        _comp_cache_enabled = True
-    except Exception as e:  # cache is an optimization, never a hard failure
-        logger.info("persistent compilation cache unavailable: %s", e)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(DEFAULT_COMPILATION_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir",
+                          DEFAULT_COMPILATION_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
-def _disable_compilation_cache(jax) -> None:
-    global _comp_cache_enabled
-    if not _comp_cache_enabled:
-        return
+def _global_devices(master: str):
+    """The global device set once ``jax.distributed`` is up, with the
+    bring-up failure named. Seen on a four-chip host with two processes of
+    one app (PR 21): the first owns every chip, the second dies on libtpu's
+    lockfile, and the first then waits out jax's 2-minute topology exchange
+    for its dead peer."""
+    from cycloneml_tpu.multihost import bootstrap
     try:
-        jax.config.update("jax_compilation_cache_dir", None)
-        _comp_cache_enabled = False
-    except Exception:
-        pass
+        return bootstrap.global_devices()
+    except RuntimeError as e:
+        raise RuntimeError(
+            f"{master}: jax.distributed is up but a backend is not ({e}). "
+            f"One host's TPU chips belong to ONE process: run one process "
+            f"per host and let it drive all of the host's chips.") from e
 
 
 class MeshRuntime:
@@ -99,17 +109,7 @@ class MeshRuntime:
 
         self._jax = jax
         devices = self._resolve_devices(master)
-        if devices and devices[0].platform != "cpu":
-            # TPU/accelerator only: XLA:CPU AOT cache entries record compile-
-            # machine features that the loader may refuse or execute with
-            # different codegen (observed: prefer-no-scatter mismatch causing
-            # reduction-order drift in tests); CPU compiles are cheap anyway
-            _enable_compilation_cache(jax)
-        else:
-            # a reset()+rebuild onto CPU must also UNDO a previously enabled
-            # cache, or the CPU mesh inherits the TPU mesh's cache dir and
-            # hits the exact AOT hazard above
-            _disable_compilation_cache(jax)
+        _configure_compilation_cache(jax, devices[0].platform)
         from cycloneml_tpu.multihost import hierarchy
         dev_grid, n_replicas = hierarchy.build_device_grid(
             devices, n_replicas, model_parallelism)
@@ -162,7 +162,7 @@ class MeshRuntime:
             return devices
         if master == "multihost":
             bootstrap.initialize()  # env/cloud auto-detection
-            return bootstrap.global_devices()
+            return _global_devices(master)
         m = _MULTIHOST_RE.fullmatch(master)
         if m is not None:
             # explicit form for local-cluster-style testing and bare-metal
@@ -172,13 +172,16 @@ class MeshRuntime:
             bootstrap.initialize(coordinator_address=m.group(1),
                                  num_processes=int(m.group(2)),
                                  process_id=int(m.group(3)))
-            return bootstrap.global_devices()
+            return _global_devices(master)
         if master == "tpu":
             try:
                 return jax.devices("tpu")
-            except RuntimeError:
-                logger.warning("no TPU attached; falling back to default platform")
-                return jax.devices()
+            except RuntimeError as e:
+                raise RuntimeError(
+                    "master 'tpu' needs an attached TPU and jax found none "
+                    f"({e}); on a host-platform machine pass "
+                    "cyclone.master=local-mesh[N] (or run through "
+                    "cycloneml_tpu.submit --master local-mesh[N])") from e
         raise ValueError(f"cannot parse master URL: {master!r}")
 
     # -- sharding helpers ------------------------------------------------------

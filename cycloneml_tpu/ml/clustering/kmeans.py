@@ -103,8 +103,8 @@ class KMeans(Estimator, _KMeansParams, MLWritable, MLReadable):
         hi = jax.lax.Precision.HIGHEST
         from cycloneml_tpu.conf import USE_PALLAS_KERNELS
         # explicit opt-in only: the assignment kernel has no measured win
-        # over XLA at any committed shape (PALLAS_AB.md), so 'auto' keeps
-        # the XLA path here
+        # over XLA (builder run, rounds 3-5, record deleted in PR 21; not
+        # measured on the current machine), so 'auto' keeps the XLA path
         use_pallas = (hasattr(ds.ctx, "conf") and
                       str(ds.ctx.conf.get(USE_PALLAS_KERNELS)).lower()
                       == "true")

@@ -13,10 +13,6 @@ Contracts this module owns:
 - **Single-process no-op**: nothing here touches ``jax.distributed``
   unless a ``multihost[...]`` master (or an explicit call) asks for it —
   every in-core fit runs exactly as before.
-- **Version compat**: ``jax.distributed.is_initialized`` does not exist
-  on every supported jax (0.4.x has only ``initialize``/``shutdown``);
-  :func:`is_initialized` reads the distributed global state instead.
-  This was the root cause of the standing deploy-harness failures.
 - **CPU-smoke collectives**: the XLA:CPU backend refuses multi-process
   programs unless a CPU collectives implementation is configured;
   :func:`initialize` selects gloo (``cyclone.multihost.cpuCollectives``)
@@ -72,29 +68,9 @@ def configure(cpu_collectives: Optional[str] = None,
 
 def is_initialized() -> bool:
     """True when this process is part of an initialized
-    ``jax.distributed`` runtime. Compat shim: prefers the real API where
-    it exists, else reads the distributed global state (jax 0.4.x)."""
+    ``jax.distributed`` runtime."""
     import jax
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        try:
-            return bool(probe())
-        except Exception:  # pragma: no cover - defensive: fall through
-            pass
-    try:
-        from jax._src import distributed as _dist
-        return getattr(_dist.global_state, "client", None) is not None
-    except Exception:  # pragma: no cover - jax internals moved
-        return False
-
-
-def _client():
-    """The distributed-runtime client, or None."""
-    try:
-        from jax._src import distributed as _dist
-        return getattr(_dist.global_state, "client", None)
-    except Exception:  # pragma: no cover
-        return None
+    return bool(jax.distributed.is_initialized())
 
 
 def _platform_hint() -> str:
@@ -118,14 +94,7 @@ def _enable_cpu_collectives() -> None:
     if not impl or impl == "none":
         return
     import jax
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", impl)
-    except Exception:
-        try:  # older spelling: a bare gloo switch
-            jax.config.update("jax_cpu_enable_gloo_collectives", True)
-        except Exception:
-            logger.warning("no CPU collectives config in this jax; "
-                           "cross-process CPU programs will fail")
+    jax.config.update("jax_cpu_collectives_implementation", impl)
 
 
 def _preflight_coordinator_port(address: str) -> None:
@@ -250,9 +219,14 @@ def barrier(name: str = "cyclone-multihost",
     number of times, which the symmetric call sites (context teardown)
     guarantee. Returns False (no-op) when not distributed."""
     global _barrier_seq
-    client = _client()
-    if client is None:
+    if not is_initialized():
         return False
+    import jax
+    # jax 0.9.0 has no public timed barrier: the coordination-service
+    # client behind jax.distributed is the only handle that bounds the
+    # wait on a dead peer (multihost_utils.sync_global_devices is a device
+    # collective and would hang with it)
+    client = jax._src.distributed.global_state.client
     with _lock:
         _barrier_seq += 1
         seq = _barrier_seq

@@ -8,6 +8,7 @@ so the framework never hard-depends on the .so.
 
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,15 +18,35 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "src", "cyclone_host.cpp")
 _LIB_DIR = os.path.join(_HERE, "_lib")
 _LIB = os.path.join(_LIB_DIR, "libcyclone_host.so")
+_KEY = _LIB + ".key"
+
+# no -march=native: _lib/ is git-ignored but rides along when a tree is
+# copied between machines, and a library tuned to the build host's ISA must
+# not be loaded on another CPU. The parsers are I/O- and memory-bound.
+_FLAGS = ["-O3", "-std=c++17", "-shared", "-fPIC"]
+_LIBS = ["-lzstd", "-lpthread", "-ldl"]
 
 _lock = threading.Lock()
 _lib_handle = None
 _build_failed = False
 
 
+def _build_key() -> str:
+    """Identity of the library the current source and flags produce."""
+    h = hashlib.sha256(" ".join(_FLAGS + _LIBS).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return h.hexdigest()
+
+
 def _needs_build() -> bool:
-    return (not os.path.exists(_LIB)
-            or os.path.getmtime(_LIB) < os.path.getmtime(_SRC))
+    """Rebuild unless the key stored beside the library names exactly this
+    source and these flags — mtimes say nothing about a library that was
+    copied in with the tree."""
+    if not (os.path.exists(_LIB) and os.path.exists(_KEY)):
+        return True
+    with open(_KEY) as f:
+        return f.read() != _build_key()
 
 
 def build(force: bool = False) -> Optional[str]:
@@ -35,22 +56,18 @@ def build(force: bool = False) -> Optional[str]:
         if not force and not _needs_build():
             return _LIB
         os.makedirs(_LIB_DIR, exist_ok=True)
-        cmd = ["g++", "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
-               _SRC, "-o", _LIB, "-lzstd", "-lpthread", "-ldl"]
+        cmd = ["g++", *_FLAGS, _SRC, "-o", _LIB, *_LIBS]
         try:
             subprocess.run(cmd, check=True, capture_output=True, timeout=300)
-            _build_failed = False
-            return _LIB
-        except Exception:
-            # -march=native can be unsupported in exotic sandboxes; retry plain
-            try:
-                cmd.remove("-march=native")
-                subprocess.run(cmd, check=True, capture_output=True, timeout=300)
-                _build_failed = False
-                return _LIB
-            except Exception:
-                _build_failed = True
-                return None
+        except (OSError, subprocess.SubprocessError):
+            # no toolchain / compile error: host.py's pure-Python
+            # fallbacks engage
+            _build_failed = True
+            return None
+        with open(_KEY, "w") as f:
+            f.write(_build_key())
+        _build_failed = False
+        return _LIB
 
 
 def load():

@@ -413,25 +413,35 @@ def register_memory_gauges(registry) -> bool:
 
 # -- roofline peak table ---------------------------------------------------------
 
-def backend_peaks() -> Tuple[Optional[float], Optional[float]]:
-    """(peak matmul flop/s, peak HBM bytes/s) PER DEVICE for the attached
-    backend, or (None, None) when no published figure exists (CPU test
-    runs — roofline fields then report unavailable). Sources: public TPU
-    spec sheets, the same figures the scaling book uses."""
-    try:
+#: Published per-chip peaks, keyed by the EXACT ``device_kind`` string jax
+#: reports: (dense bf16 matmul flop/s, HBM bytes/s). One row per chip the
+#: program has been run on, each with its source; a chip that is not here
+#: is an error (add its row), never a default or a neighbour's figures.
+PEAKS_BY_DEVICE_KIND = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM
+    "TPU v5 lite": (197e12, 819e9),
+}
+
+
+def backend_peaks(device=None) -> Tuple[Optional[float], Optional[float]]:
+    """(peak matmul flop/s, peak HBM bytes/s) PER DEVICE from
+    :data:`PEAKS_BY_DEVICE_KIND`. ``(None, None)`` on the CPU platform only
+    (test runs — roofline fields then report unavailable); an accelerator
+    whose ``device_kind`` has no row raises rather than borrowing another
+    chip's figures. ``device`` defaults to the first attached device (a
+    mesh is one kind of chip)."""
+    if device is None:
         import jax
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
+        device = jax.devices()[0]
+    if device.platform == "cpu":
         return None, None
-    if "v5 lite" in kind or "v5e" in kind:
-        return 197e12, 819e9
-    if "v5p" in kind or "v5" in kind:
-        return 459e12, 2765e9
-    if "v4" in kind:
-        return 275e12, 1228e9
-    if "v6" in kind or "trillium" in kind:
-        return 918e12, 1640e9
-    return None, None
+    if device.device_kind not in PEAKS_BY_DEVICE_KIND:
+        raise KeyError(
+            f"no published peaks for {device.platform} device_kind "
+            f"{device.device_kind!r}; add a row with its source to "
+            f"observe/costs.py PEAKS_BY_DEVICE_KIND (known: "
+            f"{sorted(PEAKS_BY_DEVICE_KIND)})")
+    return PEAKS_BY_DEVICE_KIND[device.device_kind]
 
 
 # -- compile-time memory budget guard --------------------------------------------

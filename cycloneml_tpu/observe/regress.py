@@ -1,9 +1,8 @@
 """Regression sentinel: an append-only bench-history ledger plus robust
 drift detection over it.
 
-The five committed BENCH_r01-r05 runs document a 13.9 -> 190 G ops/s
-trajectory with no machinery watching it — a perf regression today lands
-silently. This module closes that gap:
+Bench runs accumulate with no machinery watching them — a perf regression
+lands silently. This module closes that gap:
 
 - ``rows_from_bench(block, meta)`` flattens one BENCH JSON block into
   gateable metric rows (headline throughput + the nested sub-metrics in
@@ -12,7 +11,7 @@ silently. This module closes that gap:
 - ``append(path, rows)`` appends canonical-JSON rows (sorted keys, tight
   separators: the autoscale-sim byte-determinism idiom) to
   ``artifacts/bench_history.jsonl``, idempotently keyed by
-  ``(run_id, metric)`` — re-running a backfill adds nothing.
+  ``(run_id, metric)`` — re-ingesting a run adds nothing.
 - ``detect(rows, cfg)`` judges the NEWEST row of each metric against the
   median + MAD of up to ``window`` preceding comparable rows (same
   metric + hardware), with per-direction thresholds: drift past
@@ -84,8 +83,8 @@ def rows_from_bench(block: Dict[str, Any],
                     meta: Optional[Dict[str, Any]] = None
                     ) -> List[Dict[str, Any]]:
     """Flatten one parsed BENCH block into ledger rows. ``meta``
-    overrides the block's own ``meta`` (backfills synthesize identity
-    for pre-meta BENCH files)."""
+    overrides the block's own ``meta`` (the self-test's synthetic rows
+    carry no block meta)."""
     meta = dict(meta if meta is not None else block.get("meta", {}))
     hw = block.get("hardware")
     hw_key = ({"platform": hw.get("platform"),

@@ -4,68 +4,84 @@ Each kernel fuses one estimator hot loop into a single VMEM-resident pass
 over row tiles (grid over the instance-block rows, accumulating into revisited
 output blocks — the standard Pallas reduction pattern):
 
-Measured on a v5e chip (131072×512 f32 blocks, 50-eval jit chain):
-the XLA-fused aggregator path and these kernels land within ~1.5× of each
-other (XLA slightly ahead), confirming SURVEY §2.6's call that jit fusion
-already covers the netlib-BLAS boundary for gemv-shaped MLlib workloads.
-The estimators therefore default to the jnp aggregators; these kernels are
-the escape hatch for shapes XLA schedules poorly and the foundation for
-genuinely fusion-resistant ops, and their parity is pinned by tests in both
-interpret mode (CPU) and native Mosaic lowering (bench/verify on hardware).
-
 - ``fused_binary_logistic``: the north-star hot loop (ref:
   BinaryLogisticBlockAggregator.scala:41 — forward gemv :97, multiplier :112,
   transpose gemv :130) as margin→softplus-loss→multiplier→grad in one kernel.
+- ``fused_least_squares_scaled``: the LinearRegression l-bfgs residual sweep
+  on the same row pass.
 - ``fused_kmeans_assign``: the KMeans distance+argmin inner loop (ref:
   DistanceMeasure.findClosest:123) as ‖x‖²−2x·c+‖c‖² with a fused argmin.
 - ``fused_gramian``: XᵀX accumulation (ref: RowMatrix.computeGramianMatrix:130
   — the treeAggregate of spr:147 rank-1 updates, batched onto the MXU).
 
-All wrappers pad rows to the tile size and features to the 128-lane boundary,
-and run anywhere via ``interpret=True`` (the CPU test path; on TPU the same
-code lowers to Mosaic).
+Under ``cyclone.ml.usePallasKernels=auto`` (the default) the GLM kernels
+and the Gramian ARE the dense sweep on a TPU backend, and the XLA-fused
+``jnp`` aggregators are the sweep everywhere else; the KMeans kernel is
+opt-in (``true``) only. Which of the twins is faster at which shape is not
+measured on the current machine (ROADMAP S8/D3).
+
+All wrappers pad rows to the tile size and features to the 128-lane
+boundary and lower to Mosaic. ``interpret=True`` runs the same kernel body
+in the Pallas interpreter — that is the tests' choice to make (they pass
+it explicitly); nothing in the package selects it, so a wrapper reached on
+a backend that cannot lower Mosaic raises instead of silently interpreting.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Dict, Optional
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 ROW_TILE = 256
 LANE = 128
 
+#: scoped-VMEM limit every kernel here declares to Mosaic. The backend's own
+#: default (16 MiB of a v5e core's 128 MiB) is an XLA flag the package does
+#: not control; an explicit figure makes what compiles independent of it.
+VMEM_LIMIT_BYTES = 32 << 20
+#: the share of that limit a kernel's own working-set estimate may claim —
+#: the rest is slack for Mosaic's internal scratch and for what the
+#: estimates below do not model
+_VMEM_BUDGET = 24 << 20
+
 
 def pallas_available() -> bool:
     """True when the default backend lowers Pallas natively (TPU)."""
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def use_fused_kernels(ctx) -> bool:
     """Whether the eligible dense sweeps route through the fused Pallas
     kernels: ``cyclone.ml.usePallasKernels`` 'auto' (default) says yes on
     natively-lowered backends (TPU) — the fused kernels ARE the default
-    sweep there — and no elsewhere (the interpreter exists for tests, not
-    speed); 'true'/'false' force one path everywhere."""
-    try:
-        from cycloneml_tpu.conf import USE_PALLAS_KERNELS
-        conf = getattr(ctx, "conf", None)
-        mode = (str(conf.get(USE_PALLAS_KERNELS)).lower()
-                if conf is not None else "auto")
-    except Exception:
-        mode = "auto"
+    sweep there — and no elsewhere; 'true'/'false' force one path
+    everywhere ('true' off a TPU raises at lowering: the package never
+    interprets). A ``ctx`` without a ``conf`` (bare runtime namespaces)
+    reads as 'auto'."""
+    from cycloneml_tpu.conf import USE_PALLAS_KERNELS
+    conf = getattr(ctx, "conf", None)
+    mode = (str(conf.get(USE_PALLAS_KERNELS)).lower()
+            if conf is not None else "auto")
     if mode == "true":
         return True
     if mode == "false":
         return False
     return pallas_available()
+
+
+def _compiler_params(semantics: str):
+    """Mosaic parameters for a one-axis row grid: ``arbitrary`` for the
+    kernels that accumulate into revisited output blocks (the axis must run
+    in order on one core), ``parallel`` where every step owns its output
+    block."""
+    return pltpu.CompilerParams(dimension_semantics=(semantics,),
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def _storage_width(x):
@@ -87,25 +103,45 @@ def _pad_to(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
 
-def _auto_row_tile(n: int, row_tile: int) -> int:
-    """A tile that DIVIDES n when one exists: row padding copies the whole
-    X operand (at bench scale that is a second ~10 GB HBM allocation — an
-    OOM, not a slowdown), so dividing beats the default tile size. Falls
-    back to the requested tile (with padding) for ns with no small
-    divisor — loudly, when the operand is big enough for the copy to
-    matter."""
-    if n % row_tile == 0:
-        return row_tile
-    for t in (1024, 512, 256, 128, 64, 32, 16, 8):
-        if n >= t and n % t == 0:
-            return t
+def _auto_row_tile(n: int, row_tile: int, dtype, row_bytes: int,
+                   fixed_bytes: int = 0) -> int:
+    """Row tile for an ``(n, ·)`` operand stored as ``dtype``.
+
+    Candidates are the powers of two from 8 up to the requested tile, kept
+    only while the kernel's VMEM estimate ``fixed_bytes + tile·row_bytes``
+    fits the budget — the tile is a function of the WIDTH too: a tile that
+    is right at d=1,280 is over the scoped limit near d≈16k. Among those,
+    the largest that DIVIDES n wins: row padding copies the whole X operand
+    (at HBM scale that is a second multi-GB allocation — an OOM, not a
+    slowdown). Tiles that are whole packed sublane groups of ``dtype``
+    (8 rows at 4 bytes, 16 at 2, 32 at 1) are preferred over narrower ones,
+    which Mosaic accepts but loads as partial tiles. When nothing divides,
+    the largest aligned candidate is returned and the caller pads — loudly
+    when the operand is big enough for the copy to matter. A width no
+    candidate fits raises: the caller asked for a kernel that cannot be
+    built, and the XLA aggregator is the path for that shape."""
+    fits = [t for t in (1024, 512, 256, 128, 64, 32, 16, 8)
+            if t <= max(row_tile, 8)
+            and fixed_bytes + t * row_bytes <= _VMEM_BUDGET]
+    if not fits:
+        raise ValueError(
+            f"pallas kernel: an 8-row tile needs "
+            f"{fixed_bytes + 8 * row_bytes} bytes of VMEM at this width, "
+            f"over the {_VMEM_BUDGET}-byte budget; this shape is beyond "
+            f"the fused kernels — set cyclone.ml.usePallasKernels=false")
+    sublane = 32 // np.dtype(dtype).itemsize
+    aligned = [t for t in fits if t % sublane == 0]
+    for group in (aligned, fits):
+        for t in group:
+            if n % t == 0:
+                return t
     if n > 1 << 20:
         import warnings
         warnings.warn(
             f"pallas kernel: no row tile divides n={n}; padding will COPY "
-            "the full operand in HBM — pad the input to a multiple of 8 "
+            "the full operand in HBM — pad the input to a multiple of 32 "
             "rows upstream to avoid it")
-    return row_tile
+    return (aligned or fits)[0]
 
 
 def _pad_scale(scale, d: int, d_pad: int):
@@ -123,8 +159,16 @@ def _pad_rows_cols(x, y, w, row_tile: int):
     tile is re-chosen to DIVIDE n when possible (see _auto_row_tile) and
     returned — row padding copies the whole X operand otherwise."""
     n, d = x.shape
-    row_tile = _auto_row_tile(n, row_tile)
-    n_pad, d_pad = _pad_to(max(n, row_tile), row_tile), _pad_to(d, LANE)
+    d_pad = _pad_to(d, LANE)
+    # GLM row-pass working set per tile row: the double-buffered storage-
+    # width x block plus up to three (T, d_pad) f32 temporaries (upcast,
+    # x∘β, mult∘x), and the lane-sparse (T, 1) y/w blocks and per-row
+    # temporaries (a (T, 1) f32 occupies T/8 whole vregs: 512 B a row).
+    # Fixed: the (1, d_pad) β / scale / grad / compensation rows.
+    row_bytes = d_pad * (2 * x.dtype.itemsize + 12) + 12 * 512
+    row_tile = _auto_row_tile(n, row_tile, x.dtype, row_bytes,
+                              fixed_bytes=8 * 4 * d_pad)
+    n_pad = _pad_to(max(n, row_tile), row_tile)
     if n_pad != n or d_pad != d:
         x = jnp.pad(x, ((0, n_pad - n), (0, d_pad - d)))
         y = jnp.pad(y, (0, n_pad - n))
@@ -135,7 +179,7 @@ def _pad_rows_cols(x, y, w, row_tile: int):
 # -- fused binary logistic loss + gradient -------------------------------------
 
 def fused_binary_logistic(x, y, w, coef, d: int, fit_intercept: bool = True,
-                          interpret: Optional[bool] = None,
+                          interpret: bool = False,
                           row_tile: int = ROW_TILE,
                           x_scale=None) -> Dict[str, jnp.ndarray]:
     """Drop-in for the ``aggregators.binary_logistic`` block math: one pass
@@ -145,8 +189,6 @@ def fused_binary_logistic(x, y, w, coef, d: int, fit_intercept: bool = True,
     the HBM traffic of an f32 sweep, no wide X copy anywhere. ``x_scale``
     is the fp8 tier's per-column dequantization vector, applied in-kernel
     per VMEM block."""
-    if interpret is None:
-        interpret = not pallas_available()
     dtype = jnp.float32
     x = _storage_width(x)
     y = jnp.asarray(y, dtype)
@@ -175,7 +217,7 @@ def fused_binary_logistic(x, y, w, coef, d: int, fit_intercept: bool = True,
 
 def fused_binary_logistic_scaled(x, y, w, inv_std, scaled_mean, coef,
                                  d: int, fit_intercept: bool = True,
-                                 interpret: Optional[bool] = None,
+                                 interpret: bool = False,
                                  row_tile: int = ROW_TILE,
                                  x_scale=None) -> Dict[str, jnp.ndarray]:
     """Folded-standardization twin of :func:`fused_binary_logistic`: the
@@ -191,8 +233,6 @@ def fused_binary_logistic_scaled(x, y, w, inv_std, scaled_mean, coef,
     Same contract as ``aggregators.binary_logistic_scaled``; the kernel
     itself is byte-identical to the unscaled one, so the A/B numbers carry.
     """
-    if interpret is None:
-        interpret = not pallas_available()
     dtype = jnp.float32
     x = _storage_width(x)
     y = jnp.asarray(y, dtype)
@@ -224,7 +264,7 @@ def fused_binary_logistic_scaled(x, y, w, inv_std, scaled_mean, coef,
 
 
 def fused_least_squares_scaled(x, y, w, inv_std, scaled_mean, y_pars, coef,
-                               d: int, interpret: Optional[bool] = None,
+                               d: int, interpret: bool = False,
                                row_tile: int = ROW_TILE,
                                x_scale=None) -> Dict[str, jnp.ndarray]:
     """Fused least-squares loss/grad sweep — the kernel twin of
@@ -240,8 +280,6 @@ def fused_least_squares_scaled(x, y, w, inv_std, scaled_mean, y_pars, coef,
     ``y_pars = [1/σ_y, ȳ̂]``; no intercept coordinate exists (recovered in
     closed form by the caller). Same Kahan-compensated grid accumulation
     as the logistic kernel."""
-    if interpret is None:
-        interpret = not pallas_available()
     dtype = jnp.float32
     x = _storage_width(x)
     y = jnp.asarray(y, dtype)
@@ -374,6 +412,7 @@ def _run_glm(x, y, w, beta_p, b0, ys, *, kind, row_tile, d_pad, grid,
             jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
             jax.ShapeDtypeStruct((1, 2), jnp.float32),
         ],
+        compiler_params=_compiler_params("arbitrary"),
         interpret=interpret,
     )(*args)
     return outs[:3]
@@ -381,7 +420,7 @@ def _run_glm(x, y, w, beta_p, b0, ys, *, kind, row_tile, d_pad, grid,
 
 # -- fused KMeans assignment ----------------------------------------------------
 
-def fused_kmeans_assign(x, centers, interpret: Optional[bool] = None,
+def fused_kmeans_assign(x, centers, interpret: bool = False,
                         row_tile: int = ROW_TILE, x_scale=None):
     """Nearest-center assignment: returns (best_idx (n,), min_dist² (n,)).
     Fuses ‖x‖² − 2x·cᵀ + ‖c‖² with the argmin so the (T, k) distance tile
@@ -392,16 +431,21 @@ def fused_kmeans_assign(x, centers, interpret: Optional[bool] = None,
     their per-column dequant vector as ``x_scale``, applied to every
     upcast VMEM block before the distance math (centers stay f32 in
     original space)."""
-    if interpret is None:
-        interpret = not pallas_available()
     x = _storage_width(x)
     centers = jnp.asarray(centers, jnp.float32)
     n, d = x.shape
     k = centers.shape[0]
-    row_tile = _auto_row_tile(n, row_tile)
-    n_pad = _pad_to(max(n, row_tile), row_tile)
     d_pad = _pad_to(d, LANE)
     k_pad = _pad_to(k, 8)
+    # per tile row: the double-buffered x block and its f32 upcast, plus
+    # the (T, k_pad) product and distance tiles (lane-padded to 128);
+    # fixed: the resident (k_pad, d_pad) centers and their transpose
+    row_tile = _auto_row_tile(
+        n, row_tile, x.dtype,
+        d_pad * (2 * x.dtype.itemsize + 8) + 3 * 4 * _pad_to(k_pad, LANE)
+        + 8 * 512,
+        fixed_bytes=3 * 4 * k_pad * d_pad)
+    n_pad = _pad_to(max(n, row_tile), row_tile)
     x_p = jnp.pad(x, ((0, n_pad - n), (0, d_pad - d)))
     c_p = jnp.pad(centers, ((0, k_pad - k), (0, d_pad - d)))
     # padded centers must never win the argmin
@@ -451,6 +495,7 @@ def fused_kmeans_assign(x, centers, interpret: Optional[bool] = None,
             jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
             jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
         ],
+        compiler_params=_compiler_params("parallel"),
         interpret=interpret,
     )(*args)
     return best[:n, 0], jnp.maximum(dist[:n, 0], 0.0)
@@ -458,7 +503,26 @@ def fused_kmeans_assign(x, centers, interpret: Optional[bool] = None,
 
 # -- fused Gramian --------------------------------------------------------------
 
-def fused_gramian(x, w=None, interpret: Optional[bool] = None,
+def _gramian_vmem(d_pad: int, itemsize: int):
+    """(bytes per tile row, fixed bytes) of the Gramian kernel's VMEM
+    working set: per row the double-buffered x block, its f32 upcast, the
+    masked copy and the transposed operand; fixed, the resident
+    (d_pad, d_pad) f32 accumulator block."""
+    return d_pad * (2 * itemsize + 12) + 4 * 512, 4 * d_pad * d_pad
+
+
+def fused_gramian_fits(d: int, dtype) -> bool:
+    """Whether :func:`fused_gramian` can be built at width ``d``: its
+    accumulator is one VMEM-resident ``(d_pad, d_pad)`` block, so the
+    kernel has a width ceiling (d ≈ 2.4k under the declared limit) that
+    the XLA einsum does not. ``RowMatrix.compute_gramian`` asks before
+    choosing the kernel."""
+    row_bytes, fixed = _gramian_vmem(_pad_to(d, LANE),
+                                     np.dtype(dtype).itemsize)
+    return fixed + 8 * row_bytes <= _VMEM_BUDGET
+
+
+def fused_gramian(x, w=None, interpret: bool = False,
                   row_tile: int = ROW_TILE, x_scale=None):
     """XᵀX over row tiles, accumulated in a revisited VMEM block (ref:
     RowMatrix.computeGramianMatrix:130 — spr rank-1 updates become one MXU
@@ -469,16 +533,15 @@ def fused_gramian(x, w=None, interpret: Optional[bool] = None,
     per-row weights) masks padding/invalid rows by presence (w > 0)
     INSIDE the kernel — the jnp path's ``x * (w > 0)`` row mask without
     the masked X copy."""
-    if interpret is None:
-        interpret = not pallas_available()
     x = _storage_width(x)
     n, d = x.shape
     if w is None:
         w = jnp.ones((n,), jnp.float32)
     w = jnp.asarray(w, jnp.float32)
-    row_tile = _auto_row_tile(n, row_tile)
-    n_pad = _pad_to(max(n, row_tile), row_tile)
     d_pad = _pad_to(d, LANE)
+    row_tile = _auto_row_tile(n, row_tile, x.dtype,
+                              *_gramian_vmem(d_pad, x.dtype.itemsize))
+    n_pad = _pad_to(max(n, row_tile), row_tile)
     x_p = jnp.pad(x, ((0, n_pad - n), (0, d_pad - d)))
     w_p = jnp.pad(w, (0, n_pad - n)).reshape(-1, 1)
     has_scale = x_scale is not None
@@ -515,6 +578,7 @@ def fused_gramian(x, w=None, interpret: Optional[bool] = None,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((d_pad, d_pad), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((d_pad, d_pad), jnp.float32),
+        compiler_params=_compiler_params("arbitrary"),
         interpret=interpret,
     )(*args)
     return g[:d, :d]

@@ -38,16 +38,13 @@ class StaleProgramError(RuntimeError):
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions (check_vma vs check_rep kwarg;
-    jax<0.5 has no ``jax.shard_map`` at all → AttributeError)."""
+    """``jax.shard_map`` with ``check_vma=False`` — the one spelling every
+    mesh program in the package uses (graftlint JX015 keys on this name).
+    The per-shard functions psum their own partials and run Pallas
+    kernels, neither of which the varying-manual-axes check can type."""
     import jax
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def psum_over_mesh(x, axes: Sequence[str] = (DATA_AXIS, REPLICA_AXIS),

@@ -1,56 +1,49 @@
 """bench-regress: the regression sentinel's gate (observe/regress.py).
 
-Three steps, all deterministic:
+Two steps, both deterministic:
 
-1. BACKFILL: ingest the committed BENCH_r*.json runs (the 13.9 -> 190
-   G ops/s trajectory) into ``artifacts/bench_history.jsonl``. Those
-   pre-meta files carry no run identity, so the backfill synthesizes it
-   from the run number (``run_id=rNN``, ``t_logical=NN``). Idempotent:
-   rows are keyed by (run_id, metric), so re-running appends nothing —
-   artifacts/ is gitignored and this re-seeds it on every fresh checkout.
-2. INGEST (optional): ``--ingest FILE`` appends the BENCH JSON line a
+1. INGEST (optional): ``--ingest FILE`` appends the BENCH JSON line a
    fresh ``python bench.py > FILE`` run produced (its own ``meta`` block
-   is the row identity). `make bench` tees stdout to
-   artifacts/bench_last.json, so `make bench bench-regress` gates the
-   run it just made.
-3. GATE: judge each metric's newest row against the median+MAD of its
+   is the row identity) to ``artifacts/bench_history.jsonl``. `make
+   bench` tees stdout to artifacts/bench_last.json, so `make bench
+   bench-regress` gates the run it just made. The ledger starts empty on
+   a fresh checkout (artifacts/ is gitignored); until it holds
+   ``cyclone.regress.minRuns`` comparable runs every verdict is
+   ``insufficient-history``.
+2. GATE: judge each metric's newest row against the median+MAD of its
    comparable history (cyclone.regress.* thresholds) and exit nonzero
-   on any regression verdict. ``--inject-regression`` appends a
-   synthetic 40%-of-median headline row to a THROWAWAY copy of the
-   ledger and asserts the gate trips — the sentinel's own self-test
-   (the committed history itself must stay green).
+   on any regression verdict.
+
+``--inject-regression`` is the sentinel's own self-test and touches no
+real history: it gates a THROWAWAY ledger seeded with synthetic steady
+rows plus one synthetic 40%-of-median headline row, and asserts the gate
+trips.
 """
 
 import argparse
-import glob
 import json
 import os
-import re
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LEDGER = os.path.join(REPO, "artifacts", "bench_history.jsonl")
+HEADLINE = "logreg_fit_e2e_throughput"
 
 
-def backfill(ledger: str) -> int:
+def synthetic_history(n: int = 6):
+    """``n`` steady synthetic headline rows (±1% around 100) then one at
+    40% of their median — fabricated values for the self-test only."""
     from cycloneml_tpu.observe import regress
+    values = [100.0 + (i % 3 - 1) for i in range(n)] + [40.0]
     rows = []
-    for path in sorted(glob.glob(os.path.join(REPO, "BENCH_r*.json"))):
-        m = re.search(r"BENCH_r(\d+)\.json$", path)
-        if not m:
-            continue
-        num = int(m.group(1))
-        with open(path, "r", encoding="utf-8") as fh:
-            rec = json.load(fh)
-        block = rec.get("parsed")
-        if not isinstance(block, dict) or "metric" not in block:
-            continue
+    for t, v in enumerate(values, start=1):
         rows.extend(regress.rows_from_bench(
-            block, meta={"run_id": f"r{num:02d}", "git_sha": "",
-                         "t_logical": num}))
-    return regress.append(ledger, rows)
+            {"metric": HEADLINE, "value": v, "unit": "M ops/s"},
+            meta={"run_id": f"synthetic-{t:02d}", "git_sha": "",
+                  "t_logical": t}))
+    return rows
 
 
 def ingest(ledger: str, path: str) -> int:
@@ -66,39 +59,18 @@ def main() -> int:
     ap.add_argument("--ingest", metavar="FILE",
                     help="BENCH JSON line (e.g. artifacts/bench_last.json)")
     ap.add_argument("--inject-regression", action="store_true",
-                    help="self-test: gate a throwaway ledger copy with a "
-                         "synthetic 40%%-of-median regression row appended")
+                    help="self-test: gate a throwaway ledger of synthetic "
+                         "rows ending in a 40%%-of-median regression")
     ns = ap.parse_args()
 
     from cycloneml_tpu.observe import regress
 
-    n_backfilled = backfill(ns.ledger)
-    n_ingested = 0
-    if ns.ingest and os.path.exists(ns.ingest):
-        n_ingested = ingest(ns.ledger, ns.ingest)
-    rows = regress.load(ns.ledger)
-    print(f"info: ledger {ns.ledger}: {len(rows)} row(s) "
-          f"(+{n_backfilled} backfilled, +{n_ingested} ingested)",
-          file=sys.stderr)
-
     if ns.inject_regression:
-        # the synthetic row rides a throwaway copy: the REAL ledger's
-        # history must never contain a fabricated measurement
-        headline = [r for r in rows
-                    if r["metric"] == "logreg_fit_e2e_throughput"]
-        if not headline:
-            print("FAIL: no headline history to inject against",
-                  file=sys.stderr)
-            return 1
-        med = sorted(float(r["value"]) for r in headline)[len(headline) // 2]
-        synthetic = dict(headline[-1], value=round(med * 0.4, 1),
-                         run_id="synthetic-regress",
-                         t_logical=max(int(r.get("t_logical", 0))
-                                       for r in rows) + 1)
         scratch = ns.ledger + ".selftest"
+        os.makedirs(os.path.dirname(scratch) or ".", exist_ok=True)
         try:
             with open(scratch, "w", encoding="utf-8") as fh:
-                for r in rows + [synthetic]:
+                for r in synthetic_history():
                     fh.write(regress.canonical_row(r) + "\n")
             verdicts = regress.detect(regress.load(scratch))
         finally:
@@ -107,13 +79,20 @@ def main() -> int:
         rc, bad = regress.gate(verdicts)
         for v in verdicts:
             print(json.dumps(v, sort_keys=True))
-        if rc == 0 or "logreg_fit_e2e_throughput" not in bad:
+        if rc == 0 or HEADLINE not in bad:
             print("FAIL: synthetic 40% regression row did not trip the "
                   "gate", file=sys.stderr)
             return 1
         print("info: synthetic regression correctly tripped the gate",
               file=sys.stderr)
         return 0
+
+    n_ingested = 0
+    if ns.ingest and os.path.exists(ns.ingest):
+        n_ingested = ingest(ns.ledger, ns.ingest)
+    rows = regress.load(ns.ledger)
+    print(f"info: ledger {ns.ledger}: {len(rows)} row(s) "
+          f"(+{n_ingested} ingested)", file=sys.stderr)
 
     verdicts = regress.detect(rows)
     for v in verdicts:

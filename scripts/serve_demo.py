@@ -45,7 +45,10 @@ def main() -> int:
     from cycloneml_tpu.serving import ModelServer, bucket_sizes
 
     ctx = CycloneContext.get_or_create(
-        CycloneConf().set("cyclone.app.name", "serve-demo"))
+        CycloneConf().set("cyclone.app.name", "serve-demo")
+        # this demo puts itself on the CPU (setdefault above) and says so:
+        # the default master 'tpu' means TPU or raise
+        .set("cyclone.master", "local-mesh[*]"))
     rng = np.random.RandomState(3)
     x = rng.randn(2048, D).astype(np.float32)
     w = rng.randn(D)

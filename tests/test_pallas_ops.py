@@ -1,5 +1,5 @@
 """Pallas kernel parity tests (interpret mode on the CPU mesh; the same
-kernels lower to Mosaic on TPU — bench.py exercises that path on hardware).
+kernels lower to Mosaic on TPU — chip_smoke.py exercises that path on hardware).
 
 Parity targets are the pure-jnp aggregator/Gramian implementations, which are
 themselves tested against sklearn/scipy golden numbers elsewhere.
@@ -203,7 +203,7 @@ def test_fused_gramian_bf16(ctx):
                                atol=1e-2)
 
 
-def test_estimators_run_on_pallas_kernels(ctx):
+def test_estimators_run_on_pallas_kernels(ctx, monkeypatch):
     """cyclone.ml.usePallasKernels routes LR's aggregator and KMeans
     assignment through ops/kernels.py; results match the XLA-fused default
     path to f32-kernel tolerance (VERDICT r2 item 6 — the kernels must be
@@ -217,6 +217,14 @@ def test_estimators_run_on_pallas_kernels(ctx):
     x = rng.randn(600, 12)
     y = (x[:, 0] - x[:, 1] > 0).astype(float)
     ds = InstanceDataset.from_numpy(ctx, x, y)
+
+    # the package never interprets: on the CPU mesh the TEST routes the
+    # kernels' pallas_calls through the interpreter for its own duration
+    from cycloneml_tpu.ops import kernels
+    native_call = kernels.pl.pallas_call
+    monkeypatch.setattr(
+        kernels.pl, "pallas_call",
+        lambda *a, **kw: native_call(*a, **{**kw, "interpret": True}))
 
     def both(fit):
         ctx.conf.set(USE_PALLAS_KERNELS, "false")

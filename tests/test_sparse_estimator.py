@@ -106,7 +106,8 @@ def test_criteo_class_end_to_end(tmp_path, monkeypatch):
     """BASELINE config-1 analog at committed-test scale: synthetic
     hashed-sparse libsvm (~0.25 GB) -> streamed bounded-memory ELL ingest
     -> sparse-tier LR fit -> AUC, with the driver's ingest staging bounded
-    (the full-size 2 GB run is recorded in BASELINE.md's round-3 ledger).
+    (the full-size 2 GB run was a builder run of round 3; its record was
+    deleted in PR 21).
     Runs examples/criteo_class_demo.py verbatim — the demo IS the test."""
     import io
     import runpy
