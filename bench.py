@@ -101,7 +101,6 @@ def profile_cost_fields(profile) -> dict:
         "hbm_peak_bytes": profile.get("hbm_peak_bytes"),
         "achieved_flops": profile.get("achieved_flops"),
         "arithmetic_intensity": profile.get("arithmetic_intensity"),
-        "roofline_fraction": profile.get("roofline_fraction"),
     }
 
 

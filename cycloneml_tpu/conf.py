@@ -1070,17 +1070,6 @@ DOCTOR_FALLBACK_MIN = (
     .int_conf(1)
 )
 
-DOCTOR_ROOFLINE_FRACTION = (
-    ConfigBuilder("cyclone.doctor.rooflineFraction")
-    .doc("Roofline classification threshold: a profile at or above this "
-         "fraction of its measured memory/compute ceiling is classified "
-         "bandwidth- or compute-bound (by arithmetic intensity vs the "
-         "ridge point); below it the fit is host-bound and the other "
-         "rules explain why. Abstains when costs carry no peaks (CPU).")
-    .check_value(lambda v: 0.0 < v <= 1.0, "must be in (0, 1]")
-    .float_conf(0.5)
-)
-
 DOCTOR_FLIGHT_DIAGNOSIS = (
     ConfigBuilder("cyclone.doctor.flightDiagnosis")
     .doc("Auto-attach a DiagnosisReport to every flight-recorder dump: "

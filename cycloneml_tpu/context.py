@@ -261,9 +261,18 @@ class CycloneContext:
             min_interval_s=self.conf.get(FLIGHT_MIN_INTERVAL_MS) / 1e3,
             diagnose=self.conf.get(_DOCTOR_FD))
 
+        # one clock: every live span of whichever tracer is active (flight
+        # ring or full) is also an event of a jax.profiler capture, so
+        # ``with ctx.profile(dir):`` shows the program's spans above the
+        # device operations. TraceAnnotation records only while a profiler
+        # session is open; observe/ itself stays jax-free
+        tracer = _tracing.active()
+        if tracer is not None:
+            import jax
+            tracer.annotation = jax.profiler.TraceAnnotation
+
         # distributed-trace adoption + span shipping (observe/collect.py):
         # a deploy-launched app joins the submitting process's trace
-        tracer = _tracing.active()
         trace_env_id = os.environ.get("CYCLONE_TRACE_ID", "")
         if tracer is not None and trace_env_id:
             tracer.set_trace_context(

@@ -37,6 +37,7 @@ from cycloneml_tpu.ml.shared import (
 )
 from cycloneml_tpu.ml.stat import Summarizer
 from cycloneml_tpu.ml.util_io import MLReadable, MLWritable, load_arrays, save_arrays
+from cycloneml_tpu.observe import tracing
 from cycloneml_tpu.util.logging import get_logger
 
 logger = get_logger(__name__)
@@ -681,207 +682,212 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
         d = ds.n_features
         # streamed datasets carry their Summarizer moments and the label
         # histogram from the shard WRITE pass — no stats epoch is paid
-        stats = ds.summary() if streamed else Summarizer.summarize(ds)
-        if not streamed:
-            # fp8 safety rail: the envelope probe may swap the quantized
-            # dataset for its bf16 dequantization (event + profile field)
-            from cycloneml_tpu.dataset.dataset import resolve_fp8_fit
-            ds = resolve_fp8_fit(ds, stats, "LogisticRegression")
-        fp8_scale = getattr(ds, "x_scale", None)
-        features_std = stats.std
-        weight_sum = stats.weight_sum
+        cached = streamed or Summarizer.is_cached(ds)
+        with tracing.span("phase", "fit.stats", cached=cached):
+            stats = ds.summary() if streamed else Summarizer.summarize(ds)
+        with tracing.span("phase", "fit.prepare"):
+            if not streamed:
+                # fp8 safety rail: the envelope probe may swap the quantized
+                # dataset for its bf16 dequantization (event + profile field)
+                from cycloneml_tpu.dataset.dataset import resolve_fp8_fit
+                ds = resolve_fp8_fit(ds, stats, "LogisticRegression")
+            fp8_scale = getattr(ds, "x_scale", None)
+            features_std = stats.std
+            weight_sum = stats.weight_sum
 
-        # label histogram via one psum pass (≈ the summary treeAggregate at
-        # LogisticRegression.scala:515 area)
-        if streamed:
-            hist = ds.label_histogram()
-            num_classes = max(len(hist), 2) if ds.n_rows else 2
-        else:
-            y_host = ds.y_host()
-            w_host = ds.w_host()
-            num_classes = int(y_host.max()) + 1 if ds.n_rows else 2
-        family = self.get("family")
-        if family == "auto":
-            is_multinomial = num_classes > 2
-        else:
-            is_multinomial = family == "multinomial"
-            if not is_multinomial and num_classes > 2:
-                raise ValueError(
-                    f"Binomial family requires <= 2 label classes, found "
-                    f"{num_classes} (the reference rejects this too)")
-            num_classes = max(num_classes, 2)
-        if streamed:
-            histogram = np.zeros(num_classes)
-            histogram[:len(hist)] = hist[:num_classes]
-        else:
-            histogram = np.bincount(y_host.astype(np.int64), weights=w_host,
-                                    minlength=num_classes)[:num_classes]
-
-        fit_intercept = self.get("fitIntercept")
-        standardize = self.get("standardization")
-        reg = self.get("regParam")
-        alpha = self.get("elasticNetParam")
-        l2 = (1.0 - alpha) * reg
-        l1 = alpha * reg
-
-        # fitWithMean (ref LogisticRegression.scala:946-955, SPARK-34448):
-        # with a free intercept, train on CENTERED standardized features —
-        # decorrelates the intercept from offset features so small-variance
-        # columns condition properly. Allowed exactly when the intercept is
-        # unbounded; the intercept is mapped back after optimization.
-        fit_with_mean = fit_intercept and all(
-            self._opt(p) is None for p in ("lowerBoundsOnIntercepts",
-                                           "upperBoundsOnIntercepts"))
-
-        rt = ds.ctx.mesh_runtime
-        from cycloneml_tpu.ops.kernels import use_fused_kernels
-        from cycloneml_tpu.parallel import feature_sharding as fs
-        m = fs.model_parallelism(rt)
-        tp_active = (not is_multinomial) and m > 1 and d % m == 0 \
-            and not streamed
-        # fused Pallas kernels are the DEFAULT sweep on natively-lowered
-        # backends (usePallasKernels=auto): one VMEM-resident row pass per
-        # evaluation, bf16 blocks read at storage width with fp32 in-kernel
-        # accumulation; the XLA-fused jnp aggregator stays as the fallback
-        # (and the only path on CPU, where the interpreter is for tests)
-        use_pallas = (not is_multinomial) and use_fused_kernels(ds.ctx)
-        # EVERY fit path folds standardization (and fitWithMean centering)
-        # INTO the aggregator read — no standardized copy exists anywhere:
-        # replicated binomial/multinomial since r4; the feature-sharded TP
-        # program and the Pallas kernel path since r5 (r4 verdict item 3 —
-        # the paths that exist for models too big for one chip must not
-        # carry 2× the memory they need). The fit's HBM working set is X
-        # itself and the pre-fit standardize pass disappears.
-        from cycloneml_tpu.ml.optim.loss import inv_std_vector
-        inv_std = inv_std_vector(features_std)
-        scaled_mean = stats.mean * inv_std if fit_with_mean else None
-        # fp8 tier: dequantization folds into the replicated inv_std the
-        # aggregators already carry — x̂ = (codes∘scale − μ)/σ =
-        # codes∘(scale/σ) − μ/σ, so the AGGREGATOR sees scale∘inv_std
-        # while scaled_mean (μ/σ) and the final unscaling (β/σ) keep the
-        # original inv_std. The wide X never re-materializes.
-        inv_std_agg = inv_std * fp8_scale if fp8_scale is not None \
-            else inv_std
-
-        if is_multinomial:
-            # always the scaled aggregator: the TP/pallas alternatives are
-            # binomial-only, so use_scaled cannot be False here
-            agg = aggregators.multinomial_logistic_scaled(
-                d, num_classes, fit_intercept)
-            n_coef = d * num_classes + (num_classes if fit_intercept else 0)
-            x0 = np.zeros(n_coef)
-            if fit_intercept and histogram.min() > 0:
-                logs = np.log(histogram / histogram.sum())
-                x0[d * num_classes:] = logs - logs.mean()
-            l2_fn = l2_regularization(
-                l2, d * num_classes, fit_intercept,
-                features_std=np.tile(features_std, num_classes),
-                standardize=standardize) if l2 > 0 else None
-        else:
-            if use_pallas:
-                agg = aggregators.binary_logistic_pallas_scaled(
-                    d, fit_intercept)
-            else:
-                agg = aggregators.binary_logistic_scaled(d, fit_intercept)
-            n_coef = d + (1 if fit_intercept else 0)
-            x0 = np.zeros(n_coef)
-            if fit_intercept and 0 < histogram[1:].sum() < weight_sum:
-                p1 = histogram[1:].sum() / weight_sum
-                x0[d] = np.log(p1 / (1.0 - p1))
-            l2_fn = l2_regularization(
-                l2, d, fit_intercept, features_std=features_std,
-                standardize=standardize) if l2 > 0 else None
-
-        mu_or_zero = scaled_mean if fit_with_mean else np.zeros(d)
-        if tp_active:
-            # model axis present: feature-shard the RAW blocks, the
-            # coefficients, AND the standardization vectors (SURVEY §5.7a
-            # — the path for d beyond one device's HBM; binomial only, the
-            # multinomial aggregator stays replicated for now). Narrow
-            # data-tier blocks upcast at the TP boundary
-            # (fs.accumulator_width — the engine keys optimizer state off
-            # X's dtype).
-            x_tp = fs.feature_sharded_put(rt, fs.accumulator_width(ds.x))
-            loss_fn = fs.FeatureShardedLossFunction(
-                rt, x_tp, ds.y, ds.w, d, fit_intercept, l2_fn,
-                weight_sum, ctx=ds.ctx, inv_std=inv_std_agg,
-                scaled_mean=mu_or_zero)
-        else:
-            import jax.numpy as jnp
-            from cycloneml_tpu.dataset.instance import compute_dtype
-            # standardization vectors ride in the ACCUMULATOR tier: (d,)
-            # replicated vectors are free next to X, and the fold's
-            # corrections (inv_std∘g − μ̂·Σmult) must not round through the
-            # bf16 data tier
-            adt = compute_dtype()
-            extras = (jnp.asarray(inv_std_agg.astype(adt)),
-                      jnp.asarray(mu_or_zero.astype(adt)))
+            # label histogram via one psum pass (≈ the summary treeAggregate at
+            # LogisticRegression.scala:515 area)
             if streamed:
-                # the streamed twin: SAME aggregator, same extras, same
-                # normalization — one loss/grad evaluation is one
-                # double-buffered epoch over the shard set
-                from cycloneml_tpu.oocore import StreamingLossFunction
-                loss_fn = StreamingLossFunction(
-                    ds, agg, l2_fn, weight_sum, extra_args=extras)
+                hist = ds.label_histogram()
+                num_classes = max(len(hist), 2) if ds.n_rows else 2
             else:
-                loss_fn = DistributedLossFunction(
-                    ds, agg, l2_fn, weight_sum, extra_args=extras)
+                y_host = ds.y_host()
+                w_host = ds.w_host()
+                num_classes = int(y_host.max()) + 1 if ds.n_rows else 2
+            family = self.get("family")
+            if family == "auto":
+                is_multinomial = num_classes > 2
+            else:
+                is_multinomial = family == "multinomial"
+                if not is_multinomial and num_classes > 2:
+                    raise ValueError(
+                        f"Binomial family requires <= 2 label classes, found "
+                        f"{num_classes} (the reference rejects this too)")
+                num_classes = max(num_classes, 2)
+            if streamed:
+                histogram = np.zeros(num_classes)
+                histogram[:len(hist)] = hist[:num_classes]
+            else:
+                histogram = np.bincount(y_host.astype(np.int64), weights=w_host,
+                                        minlength=num_classes)[:num_classes]
 
-        if self._has_bounds():
-            # box-constrained path (ref createOptimizer selects BreezeLBFGSB
-            # whenever bounds are set, LogisticRegression.scala:788; bounds
-            # are only legal with none/L2 regularization there too)
-            if alpha != 0.0:
-                # the reference rejects ANY nonzero elasticNetParam with
-                # bounds, regardless of regParam
-                raise ValueError(
-                    "coefficient bounds are only supported with none or L2 "
-                    "regularization (elasticNetParam must be 0, as the "
-                    "reference enforces)")
-            lo, hi = self._flat_bounds(d, num_classes, is_multinomial,
-                                       fit_intercept, n_coef, features_std)
-            opt = LBFGSB(lo, hi, max_iter=self.get("maxIter"),
-                         tol=self.get("tol"))
-        elif l1 > 0:
-            n_feat_coords = d * num_classes if is_multinomial else d
-            l1_vec = np.zeros(n_coef)
-            per_coord = np.full(n_feat_coords, l1)
-            if not standardize:
-                stds = np.tile(features_std, num_classes) if is_multinomial else features_std
-                per_coord = np.where(stds > 0, l1 / np.where(stds > 0, stds, 1.0), 0.0)
-            l1_vec[:n_feat_coords] = per_coord
-            opt = OWLQN(max_iter=self.get("maxIter"), tol=self.get("tol"),
-                        l1_reg=l1_vec)
-        else:
-            opt = LBFGS(max_iter=self.get("maxIter"), tol=self.get("tol"))
-            # chunked device optimizer: K whole iterations per dispatch
-            # (two-loop + Wolfe + convergence all on device). Eligible when
-            # the loss is the dense replicated tier with a standardized (or
-            # no) L2, and no checkpointing (checkpoints want per-iteration
-            # states).
-            from cycloneml_tpu.conf import LBFGS_DEVICE_CHUNK
-            chunk = int(ds.ctx.conf.get(LBFGS_DEVICE_CHUNK)) \
-                if hasattr(ds.ctx, "conf") else 0
-            if (chunk > 0 and not self.get("checkpointDir")
-                    and isinstance(loss_fn, DistributedLossFunction)
-                    and (l2_fn is None or hasattr(l2_fn, "traceable"))):
-                from cycloneml_tpu.ml.optim.device_lbfgs import DeviceLBFGS
-                opt = DeviceLBFGS(max_iter=self.get("maxIter"),
-                                  tol=self.get("tol"), chunk=chunk)
-                # this fit HAS a streaming twin: when chunk-halving bottoms
-                # out still over budget, degrade to it instead of
-                # warn-proceeding toward an OOM (cyclone.oocore.mode=auto)
-                opt.oocore_fallback = True
+            fit_intercept = self.get("fitIntercept")
+            standardize = self.get("standardization")
+            reg = self.get("regParam")
+            alpha = self.get("elasticNetParam")
+            l2 = (1.0 - alpha) * reg
+            l1 = alpha * reg
+
+            # fitWithMean (ref LogisticRegression.scala:946-955, SPARK-34448):
+            # with a free intercept, train on CENTERED standardized features —
+            # decorrelates the intercept from offset features so small-variance
+            # columns condition properly. Allowed exactly when the intercept is
+            # unbounded; the intercept is mapped back after optimization.
+            fit_with_mean = fit_intercept and all(
+                self._opt(p) is None for p in ("lowerBoundsOnIntercepts",
+                                               "upperBoundsOnIntercepts"))
+
+            rt = ds.ctx.mesh_runtime
+            from cycloneml_tpu.ops.kernels import use_fused_kernels
+            from cycloneml_tpu.parallel import feature_sharding as fs
+            m = fs.model_parallelism(rt)
+            tp_active = (not is_multinomial) and m > 1 and d % m == 0 \
+                and not streamed
+            # fused Pallas kernels are the DEFAULT sweep on natively-lowered
+            # backends (usePallasKernels=auto): one VMEM-resident row pass per
+            # evaluation, bf16 blocks read at storage width with fp32 in-kernel
+            # accumulation; the XLA-fused jnp aggregator stays as the fallback
+            # (and the only path on CPU, where the interpreter is for tests)
+            use_pallas = (not is_multinomial) and use_fused_kernels(ds.ctx)
+            # EVERY fit path folds standardization (and fitWithMean centering)
+            # INTO the aggregator read — no standardized copy exists anywhere:
+            # replicated binomial/multinomial since r4; the feature-sharded TP
+            # program and the Pallas kernel path since r5 (r4 verdict item 3 —
+            # the paths that exist for models too big for one chip must not
+            # carry 2× the memory they need). The fit's HBM working set is X
+            # itself and the pre-fit standardize pass disappears.
+            from cycloneml_tpu.ml.optim.loss import inv_std_vector
+            inv_std = inv_std_vector(features_std)
+            scaled_mean = stats.mean * inv_std if fit_with_mean else None
+            # fp8 tier: dequantization folds into the replicated inv_std the
+            # aggregators already carry — x̂ = (codes∘scale − μ)/σ =
+            # codes∘(scale/σ) − μ/σ, so the AGGREGATOR sees scale∘inv_std
+            # while scaled_mean (μ/σ) and the final unscaling (β/σ) keep the
+            # original inv_std. The wide X never re-materializes.
+            inv_std_agg = inv_std * fp8_scale if fp8_scale is not None \
+                else inv_std
+
+            if is_multinomial:
+                # always the scaled aggregator: the TP/pallas alternatives are
+                # binomial-only, so use_scaled cannot be False here
+                agg = aggregators.multinomial_logistic_scaled(
+                    d, num_classes, fit_intercept)
+                n_coef = d * num_classes + (num_classes if fit_intercept else 0)
+                x0 = np.zeros(n_coef)
+                if fit_intercept and histogram.min() > 0:
+                    logs = np.log(histogram / histogram.sum())
+                    x0[d * num_classes:] = logs - logs.mean()
+                l2_fn = l2_regularization(
+                    l2, d * num_classes, fit_intercept,
+                    features_std=np.tile(features_std, num_classes),
+                    standardize=standardize) if l2 > 0 else None
+            else:
+                if use_pallas:
+                    agg = aggregators.binary_logistic_pallas_scaled(
+                        d, fit_intercept)
+                else:
+                    agg = aggregators.binary_logistic_scaled(d, fit_intercept)
+                n_coef = d + (1 if fit_intercept else 0)
+                x0 = np.zeros(n_coef)
+                if fit_intercept and 0 < histogram[1:].sum() < weight_sum:
+                    p1 = histogram[1:].sum() / weight_sum
+                    x0[d] = np.log(p1 / (1.0 - p1))
+                l2_fn = l2_regularization(
+                    l2, d, fit_intercept, features_std=features_std,
+                    standardize=standardize) if l2 > 0 else None
+
+            mu_or_zero = scaled_mean if fit_with_mean else np.zeros(d)
+            if tp_active:
+                # model axis present: feature-shard the RAW blocks, the
+                # coefficients, AND the standardization vectors (SURVEY §5.7a
+                # — the path for d beyond one device's HBM; binomial only, the
+                # multinomial aggregator stays replicated for now). Narrow
+                # data-tier blocks upcast at the TP boundary
+                # (fs.accumulator_width — the engine keys optimizer state off
+                # X's dtype).
+                x_tp = fs.feature_sharded_put(rt, fs.accumulator_width(ds.x))
+                loss_fn = fs.FeatureShardedLossFunction(
+                    rt, x_tp, ds.y, ds.w, d, fit_intercept, l2_fn,
+                    weight_sum, ctx=ds.ctx, inv_std=inv_std_agg,
+                    scaled_mean=mu_or_zero)
+            else:
+                import jax.numpy as jnp
+                from cycloneml_tpu.dataset.instance import compute_dtype
+                # standardization vectors ride in the ACCUMULATOR tier: (d,)
+                # replicated vectors are free next to X, and the fold's
+                # corrections (inv_std∘g − μ̂·Σmult) must not round through the
+                # bf16 data tier
+                adt = compute_dtype()
+                extras = (jnp.asarray(inv_std_agg.astype(adt)),
+                          jnp.asarray(mu_or_zero.astype(adt)))
+                if streamed:
+                    # the streamed twin: SAME aggregator, same extras, same
+                    # normalization — one loss/grad evaluation is one
+                    # double-buffered epoch over the shard set
+                    from cycloneml_tpu.oocore import StreamingLossFunction
+                    loss_fn = StreamingLossFunction(
+                        ds, agg, l2_fn, weight_sum, extra_args=extras)
+                else:
+                    loss_fn = DistributedLossFunction(
+                        ds, agg, l2_fn, weight_sum, extra_args=extras)
+
+            if self._has_bounds():
+                # box-constrained path (ref createOptimizer selects BreezeLBFGSB
+                # whenever bounds are set, LogisticRegression.scala:788; bounds
+                # are only legal with none/L2 regularization there too)
+                if alpha != 0.0:
+                    # the reference rejects ANY nonzero elasticNetParam with
+                    # bounds, regardless of regParam
+                    raise ValueError(
+                        "coefficient bounds are only supported with none or L2 "
+                        "regularization (elasticNetParam must be 0, as the "
+                        "reference enforces)")
+                lo, hi = self._flat_bounds(d, num_classes, is_multinomial,
+                                           fit_intercept, n_coef, features_std)
+                opt = LBFGSB(lo, hi, max_iter=self.get("maxIter"),
+                             tol=self.get("tol"))
+            elif l1 > 0:
+                n_feat_coords = d * num_classes if is_multinomial else d
+                l1_vec = np.zeros(n_coef)
+                per_coord = np.full(n_feat_coords, l1)
+                if not standardize:
+                    stds = np.tile(features_std, num_classes) if is_multinomial else features_std
+                    per_coord = np.where(stds > 0, l1 / np.where(stds > 0, stds, 1.0), 0.0)
+                l1_vec[:n_feat_coords] = per_coord
+                opt = OWLQN(max_iter=self.get("maxIter"), tol=self.get("tol"),
+                            l1_reg=l1_vec)
+            else:
+                opt = LBFGS(max_iter=self.get("maxIter"), tol=self.get("tol"))
+                # chunked device optimizer: K whole iterations per dispatch
+                # (two-loop + Wolfe + convergence all on device). Eligible when
+                # the loss is the dense replicated tier with a standardized (or
+                # no) L2, and no checkpointing (checkpoints want per-iteration
+                # states).
+                from cycloneml_tpu.conf import LBFGS_DEVICE_CHUNK
+                chunk = int(ds.ctx.conf.get(LBFGS_DEVICE_CHUNK)) \
+                    if hasattr(ds.ctx, "conf") else 0
+                if (chunk > 0 and not self.get("checkpointDir")
+                        and isinstance(loss_fn, DistributedLossFunction)
+                        and (l2_fn is None or hasattr(l2_fn, "traceable"))):
+                    from cycloneml_tpu.ml.optim.device_lbfgs import DeviceLBFGS
+                    opt = DeviceLBFGS(max_iter=self.get("maxIter"),
+                                      tol=self.get("tol"), chunk=chunk)
+                    # this fit HAS a streaming twin: when chunk-halving bottoms
+                    # out still over budget, degrade to it instead of
+                    # warn-proceeding toward an OOM (cyclone.oocore.mode=auto)
+                    opt.oocore_fallback = True
 
         from cycloneml_tpu.observe.costs import OutOfCoreRequired
         try:
-            state = self._optimize(opt, loss_fn, x0, (
-                ds.n_rows, d, num_classes, float(weight_sum),
-                np.asarray(histogram).round(6).tolist(),
-                np.asarray(features_std).round(6).tolist(),
-                reg, alpha, self.get("tol"), fit_intercept, standardize,
-                fit_with_mean,
-            ))
+            with tracing.span("phase", "fit.optimize",
+                              optimizer=type(opt).__name__):
+                state = self._optimize(opt, loss_fn, x0, (
+                    ds.n_rows, d, num_classes, float(weight_sum),
+                    np.asarray(histogram).round(6).tolist(),
+                    np.asarray(features_std).round(6).tolist(),
+                    reg, alpha, self.get("tol"), fit_intercept, standardize,
+                    fit_with_mean,
+                ))
         except OutOfCoreRequired as e:
             # the budget guard's terminal degradation: re-route the whole
             # fit through the streaming epoch engine (same objective, host
@@ -902,51 +908,52 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
             return self._fit_dataset(fp8_fallback(
                 ds, "LogisticRegression", "non-finite fp8 solution"))
 
-        sol = state.x
-        if is_multinomial:
-            wmat = sol[: d * num_classes].reshape(num_classes, d) * inv_std[None, :]
-            icpt = sol[d * num_classes:] if fit_intercept else np.zeros(num_classes)
-            if fit_with_mean:
-                # un-adapt: centered-problem intercepts back to original
-                # space (ref LogisticRegression.scala:1018-1024 dgemv adapt)
-                icpt = icpt - sol[: d * num_classes].reshape(
-                    num_classes, d) @ scaled_mean
-            if not self._has_bounds():
-                if reg == 0.0:
-                    # center coefficients for identifiability, as the
-                    # reference does when the multinomial problem has no
-                    # regularization (LogisticRegression.scala:656-674,
-                    # following glmnet)
-                    wmat = wmat - wmat.mean(axis=0, keepdims=True)
-                # intercepts are NEVER regularized, so their additive
-                # constant stays free under ANY regParam — the reference
-                # centers them unconditionally for multinomial
-                # (LogisticRegression.scala:676-681); without this, L1
-                # fits match glmnet in coefficients but drift in
-                # intercepts by a shared constant
-                if fit_intercept:
-                    icpt = icpt - icpt.mean()
-            model = LogisticRegressionModel(
-                coefficient_matrix=wmat, intercept_vector=icpt,
-                num_classes=num_classes, is_multinomial=True, uid=self.uid)
-        else:
-            beta = sol[:d] * inv_std
-            icpt = float(sol[d]) if fit_intercept else 0.0
-            if fit_with_mean:
-                # ref LogisticRegression.scala:1027-1031: solution(num) -= adapt
-                icpt -= float(sol[:d] @ scaled_mean)
-            model = LogisticRegressionModel(
-                coefficient_matrix=beta[None, :], intercept_vector=np.array([icpt]),
-                num_classes=2, is_multinomial=False, uid=self.uid)
-        self._copy_values(model)
-        model._set_parent(self)
-        model.summary = LogisticRegressionTrainingSummary(
-            objective_history=list(state.loss_history),
-            total_iterations=state.iteration,
-            total_evals=loss_fn.n_evals,
-            total_dispatches=loss_fn.n_dispatches,
-            streamed=streamed)
-        return model
+        with tracing.span("phase", "fit.finish"):
+            sol = state.x
+            if is_multinomial:
+                wmat = sol[: d * num_classes].reshape(num_classes, d) * inv_std[None, :]
+                icpt = sol[d * num_classes:] if fit_intercept else np.zeros(num_classes)
+                if fit_with_mean:
+                    # un-adapt: centered-problem intercepts back to original
+                    # space (ref LogisticRegression.scala:1018-1024 dgemv adapt)
+                    icpt = icpt - sol[: d * num_classes].reshape(
+                        num_classes, d) @ scaled_mean
+                if not self._has_bounds():
+                    if reg == 0.0:
+                        # center coefficients for identifiability, as the
+                        # reference does when the multinomial problem has no
+                        # regularization (LogisticRegression.scala:656-674,
+                        # following glmnet)
+                        wmat = wmat - wmat.mean(axis=0, keepdims=True)
+                    # intercepts are NEVER regularized, so their additive
+                    # constant stays free under ANY regParam — the reference
+                    # centers them unconditionally for multinomial
+                    # (LogisticRegression.scala:676-681); without this, L1
+                    # fits match glmnet in coefficients but drift in
+                    # intercepts by a shared constant
+                    if fit_intercept:
+                        icpt = icpt - icpt.mean()
+                model = LogisticRegressionModel(
+                    coefficient_matrix=wmat, intercept_vector=icpt,
+                    num_classes=num_classes, is_multinomial=True, uid=self.uid)
+            else:
+                beta = sol[:d] * inv_std
+                icpt = float(sol[d]) if fit_intercept else 0.0
+                if fit_with_mean:
+                    # ref LogisticRegression.scala:1027-1031: solution(num) -= adapt
+                    icpt -= float(sol[:d] @ scaled_mean)
+                model = LogisticRegressionModel(
+                    coefficient_matrix=beta[None, :], intercept_vector=np.array([icpt]),
+                    num_classes=2, is_multinomial=False, uid=self.uid)
+            self._copy_values(model)
+            model._set_parent(self)
+            model.summary = LogisticRegressionTrainingSummary(
+                objective_history=list(state.loss_history),
+                total_iterations=state.iteration,
+                total_evals=loss_fn.n_evals,
+                total_dispatches=loss_fn.n_dispatches,
+                streamed=streamed)
+            return model
 
     def copy(self, extra=None) -> "LogisticRegression":
         return super().copy(extra)

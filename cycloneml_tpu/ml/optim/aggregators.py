@@ -32,6 +32,16 @@ import jax.numpy as jnp
 
 Agg = Callable[..., Dict[str, jnp.ndarray]]
 
+
+def _named(name: str):
+    """Give a factory's ``agg`` closure the factory's name: the aggregation
+    program is named after it (``tree_aggregate__<name>``), which is what
+    a device trace shows."""
+    def rename(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+    return rename
+
 def matmul_precision():
     """Matmul precision for the aggregator hot path, resolved from
     ``cyclone.compute.matmulPrecision`` when an aggregator is BUILT (each
@@ -121,6 +131,7 @@ def _binary_logistic(d: int, fit_intercept: bool, prec) -> Agg:
     # hand tree_aggregate the SAME function object — program-cache identity
     # (collectives._program_cache) is what prevents a recompile per fit
 
+    @_named("binary_logistic")
     def agg(x, y, w, coef):
         beta, b0 = _split_coef(coef, d, fit_intercept)
         margin = _tier_dot(x, beta, prec) + b0                  # forward gemv:97
@@ -154,6 +165,7 @@ def binary_logistic_scaled(d: int, fit_intercept: bool = True) -> Agg:
 @functools.lru_cache(maxsize=None)
 def _binary_logistic_scaled(d: int, fit_intercept: bool, prec) -> Agg:
 
+    @_named("binary_logistic_scaled")
     def agg(x, y, w, inv_std, scaled_mean, coef):
         beta, b0 = _split_coef(coef, d, fit_intercept)
         sb = inv_std * beta
@@ -181,6 +193,7 @@ def multinomial_logistic(d: int, k: int, fit_intercept: bool = True) -> Agg:
 @functools.lru_cache(maxsize=None)
 def _multinomial_logistic(d: int, k: int, fit_intercept: bool, prec) -> Agg:
 
+    @_named("multinomial_logistic")
     def agg(x, y, w, coef):
         if fit_intercept:
             wmat = coef[: d * k].reshape(k, d)
@@ -221,6 +234,7 @@ def multinomial_logistic_scaled(d: int, k: int,
 def _multinomial_logistic_scaled(d: int, k: int, fit_intercept: bool,
                                  prec) -> Agg:
 
+    @_named("multinomial_logistic_scaled")
     def agg(x, y, w, inv_std, scaled_mean, coef):
         if fit_intercept:
             wmat = coef[: d * k].reshape(k, d)
@@ -259,6 +273,7 @@ def least_squares(d: int, fit_intercept: bool = True) -> Agg:
 @functools.lru_cache(maxsize=None)
 def _least_squares(d: int, fit_intercept: bool, prec) -> Agg:
 
+    @_named("least_squares")
     def agg(x, y, w, coef):
         beta, b0 = _split_coef(coef, d, fit_intercept)
         err = _tier_dot(x, beta, prec) + b0 - y
@@ -299,6 +314,7 @@ def least_squares_scaled(d: int) -> Agg:
 @functools.lru_cache(maxsize=None)
 def _least_squares_scaled(d: int, prec) -> Agg:
 
+    @_named("least_squares_scaled")
     def agg(x, y, w, inv_std, scaled_mean, y_pars, coef):
         sb = inv_std * coef
         off = jnp.dot(scaled_mean, coef, precision=prec) - y_pars[1]
@@ -321,6 +337,7 @@ def hinge(d: int, fit_intercept: bool = True) -> Agg:
 @functools.lru_cache(maxsize=None)
 def _hinge(d: int, fit_intercept: bool, prec) -> Agg:
 
+    @_named("hinge")
     def agg(x, y, w, coef):
         beta, b0 = _split_coef(coef, d, fit_intercept)
         margin = _tier_dot(x, beta, prec) + b0
@@ -345,6 +362,7 @@ def huber(d: int, fit_intercept: bool = True, epsilon: float = 1.35) -> Agg:
 @functools.lru_cache(maxsize=None)
 def _huber(d: int, fit_intercept: bool, epsilon: float, prec) -> Agg:
 
+    @_named("huber")
     def agg(x, y, w, coef):
         beta, b0 = _split_coef(coef[:-1], d, fit_intercept)
         sigma = coef[-1]
@@ -418,6 +436,7 @@ def binary_logistic_pallas_scaled(d: int, fit_intercept: bool = True) -> Agg:
 def _binary_logistic_pallas_scaled(d: int, fit_intercept: bool) -> Agg:
     from cycloneml_tpu.ops.kernels import fused_binary_logistic_scaled
 
+    @_named("binary_logistic_pallas_scaled")
     def agg(x, y, w, inv_std, scaled_mean, coef):
         return fused_binary_logistic_scaled(
             x, y, w, inv_std, scaled_mean, coef, d, fit_intercept)
@@ -438,6 +457,7 @@ def least_squares_pallas_scaled(d: int) -> Agg:
 def _least_squares_pallas_scaled(d: int) -> Agg:
     from cycloneml_tpu.ops.kernels import fused_least_squares_scaled
 
+    @_named("least_squares_pallas_scaled")
     def agg(x, y, w, inv_std, scaled_mean, y_pars, coef):
         return fused_least_squares_scaled(
             x, y, w, inv_std, scaled_mean, y_pars, coef, d)

@@ -140,7 +140,7 @@ def _build_chunk(compiled, l2_t, m: int, K: int, c1: float, c2: float,
 
     from cycloneml_tpu.ml.optim.loss import wolfe_search
 
-    def program(*args):
+    def lbfgs_chunk(*args):
         (arrays, coef0, S0, Y0, k0, f_in, g_in, first,
          ws, tol, grad_tol, it_limit, need_init) = \
             (args[:-12], *args[-12:])
@@ -186,44 +186,49 @@ def _build_chunk(compiled, l2_t, m: int, K: int, c1: float, c2: float,
 
         def body(carry):
             (coef, S, Y, k, f, g, it, evals, done, losses) = carry
-            d = two_loop(S, Y, k, g)
-            dg0 = jnp.dot(d, g)
-            # non-descent: reset history, steepest descent (host semantics)
-            bad = dg0 >= 0
-            d = jnp.where(bad, -g, d)
-            k = jnp.where(bad, 0, k)
-            dg0 = jnp.where(bad, -jnp.dot(g, g), dg0)
-            gnorm = jnp.sqrt(jnp.maximum(jnp.dot(g, g), 1e-300))
-            # host semantics: the scaled step min(1, 1/||g||) applies on the
-            # very first iteration AND on every steepest-descent restart
-            init_alpha = jnp.where(
-                (first & (it == 0)) | bad,
-                jnp.minimum(1.0, 1.0 / gnorm), cdt.type(1.0))
+            with jax.named_scope("lbfgs.direction"):
+                d = two_loop(S, Y, k, g)
+                dg0 = jnp.dot(d, g)
+                # non-descent: reset history, steepest descent (host
+                # semantics)
+                bad = dg0 >= 0
+                d = jnp.where(bad, -g, d)
+                k = jnp.where(bad, 0, k)
+                dg0 = jnp.where(bad, -jnp.dot(g, g), dg0)
+                gnorm = jnp.sqrt(jnp.maximum(jnp.dot(g, g), 1e-300))
+                # host semantics: the scaled step min(1, 1/||g||) applies on
+                # the very first iteration AND on every steepest-descent
+                # restart
+                init_alpha = jnp.where(
+                    (first & (it == 0)) | bad,
+                    jnp.minimum(1.0, 1.0 / gnorm), cdt.type(1.0))
 
             def phi(alpha):
                 v, grad = f_and_g(coef + alpha * d)
                 return v, grad, jnp.dot(d, grad)
 
-            alpha, f_new, g_new, ev = wolfe_search(
-                phi, jnp.zeros_like(g), f, dg0, init_alpha,
-                c1, c2, max_ls, cdt)
-            s = alpha * d
-            y = g_new - g
-            # curvature condition (host _History.update)
-            keep = jnp.dot(s, y) > 1e-10 * jnp.dot(y, y)
-            S = jnp.where(keep, jnp.roll(S, -1, axis=0).at[-1].set(s), S)
-            Y = jnp.where(keep, jnp.roll(Y, -1, axis=0).at[-1].set(y), Y)
-            k = jnp.where(keep, jnp.minimum(k + 1, m), k)
-            # Breeze-style convergence (host LBFGS._converged)
-            denom = jnp.maximum(jnp.maximum(jnp.abs(f_new), jnp.abs(f)),
-                                1e-6)
-            f_conv = jnp.abs(f - f_new) <= tol * denom
-            gn = jnp.sqrt(jnp.maximum(jnp.dot(g_new, g_new), 0.0))
-            xn = jnp.sqrt(jnp.maximum(jnp.dot(coef + s, coef + s), 0.0))
-            g_conv = gn <= grad_tol * jnp.maximum(xn, 1.0)
-            code = jnp.where(f_conv, 1,
-                             jnp.where(g_conv, 2, 0)).astype(jnp.int32)
-            losses = losses.at[it].set(f_new)
+            with jax.named_scope("lbfgs.line_search"):
+                alpha, f_new, g_new, ev = wolfe_search(
+                    phi, jnp.zeros_like(g), f, dg0, init_alpha,
+                    c1, c2, max_ls, cdt)
+            with jax.named_scope("lbfgs.update"):
+                s = alpha * d
+                y = g_new - g
+                # curvature condition (host _History.update)
+                keep = jnp.dot(s, y) > 1e-10 * jnp.dot(y, y)
+                S = jnp.where(keep, jnp.roll(S, -1, axis=0).at[-1].set(s), S)
+                Y = jnp.where(keep, jnp.roll(Y, -1, axis=0).at[-1].set(y), Y)
+                k = jnp.where(keep, jnp.minimum(k + 1, m), k)
+                # Breeze-style convergence (host LBFGS._converged)
+                denom = jnp.maximum(jnp.maximum(jnp.abs(f_new), jnp.abs(f)),
+                                    1e-6)
+                f_conv = jnp.abs(f - f_new) <= tol * denom
+                gn = jnp.sqrt(jnp.maximum(jnp.dot(g_new, g_new), 0.0))
+                xn = jnp.sqrt(jnp.maximum(jnp.dot(coef + s, coef + s), 0.0))
+                g_conv = gn <= grad_tol * jnp.maximum(xn, 1.0)
+                code = jnp.where(f_conv, 1,
+                                 jnp.where(g_conv, 2, 0)).astype(jnp.int32)
+                losses = losses.at[it].set(f_new)
             return (coef + s, S, Y, k, f_new, g_new, it + 1,
                     evals + ev, code, losses)
 
@@ -253,7 +258,8 @@ def _build_chunk(compiled, l2_t, m: int, K: int, c1: float, c2: float,
     # those states across chunk dispatches — donating them would delete
     # the retained state's buffers behind the caller's back (exactly the
     # JX009 hazard class, one dispatch later)
-    return jax.jit(program, donate_argnums=(n_arrays + 1, n_arrays + 2))
+    return jax.jit(lbfgs_chunk,
+                   donate_argnums=(n_arrays + 1, n_arrays + 2))
 
 
 class DeviceLBFGS(LBFGS):
@@ -356,101 +362,112 @@ class DeviceLBFGS(LBFGS):
         guarded = False
         pid = None
         while True:
-            # big state (coef/S/Y/grad) stays ON DEVICE between chunks —
-            # only scalars and the per-iteration loss vector come back per
-            # dispatch; the full f64 state materializes on yield only when
-            # a consumer touches the arrays (np.asarray forces the copy)
-            base_iter = state.iteration if state is not None else 0
-            args = (*arrays, coef, S_d, Y_d, k_d, f_d, g_d,
-                    np.bool_(first), cdt.type(f.weight_sum),
-                    cdt.type(self.tol), cdt.type(self.grad_tol),
-                    np.int32(max(self.max_iter - base_iter, 0)),
-                    np.bool_(need_init))
-            if not guarded:
-                # args are chunk-size-independent, so a degraded program
-                # dispatches the same operands — only K shrinks
-                guarded = True
-                chunk, key, prog, new_fresh = _budget_guarded_chunk(
-                    "lbfgs.chunk", key, prog, args, chunk,
-                    getattr(f, "_ctx", None), build,
-                    allow_stream=self.oocore_fallback)
-                if new_fresh is not None:
-                    fresh = new_fresh
-                    self.effective_chunk = chunk
-            win = attribution.dispatch_window()
-            with win:
-                with tracing.span("dispatch", "lbfgs.chunk") as dsp:
-                    if fresh:
-                        with tracing.span("compile", "lbfgs.chunk"):
+            # one chunk turn is one `optim.iteration` span: argument tuple,
+            # dispatch, readback and state build. It closes BEFORE the
+            # turn's states are yielded — a span held across a yield would
+            # be charged the consumer's time, and an abandoned generator
+            # would leave it on the thread's span stack
+            start = None
+            with tracing.span("phase", "optim.iteration",
+                              iteration=state.iteration
+                              if state is not None else 0):
+                # big state (coef/S/Y/grad) stays ON DEVICE between chunks —
+                # only scalars and the per-iteration loss vector come back per
+                # dispatch; the full f64 state materializes on yield only when
+                # a consumer touches the arrays (np.asarray forces the copy)
+                base_iter = state.iteration if state is not None else 0
+                args = (*arrays, coef, S_d, Y_d, k_d, f_d, g_d,
+                        np.bool_(first), cdt.type(f.weight_sum),
+                        cdt.type(self.tol), cdt.type(self.grad_tol),
+                        np.int32(max(self.max_iter - base_iter, 0)),
+                        np.bool_(need_init))
+                if not guarded:
+                    # args are chunk-size-independent, so a degraded program
+                    # dispatches the same operands — only K shrinks
+                    guarded = True
+                    chunk, key, prog, new_fresh = _budget_guarded_chunk(
+                        "lbfgs.chunk", key, prog, args, chunk,
+                        getattr(f, "_ctx", None), build,
+                        allow_stream=self.oocore_fallback)
+                    if new_fresh is not None:
+                        fresh = new_fresh
+                        self.effective_chunk = chunk
+                win = attribution.dispatch_window()
+                with win:
+                    with tracing.span("dispatch", "lbfgs.chunk") as dsp:
+                        if fresh:
+                            with tracing.span("compile", "lbfgs.chunk"):
+                                (coef_d, S_d, Y_d, k_d, f_d, g_d, losses_d,
+                                 it_d, evals_d, code_d, f0_d, g0_d) = \
+                                    prog(*args)
+                            fresh = False
+                        else:
                             (coef_d, S_d, Y_d, k_d, f_d, g_d, losses_d, it_d,
                              evals_d, code_d, f0_d, g0_d) = prog(*args)
-                        fresh = False
-                    else:
-                        (coef_d, S_d, Y_d, k_d, f_d, g_d, losses_d, it_d,
-                         evals_d, code_d, f0_d, g0_d) = prog(*args)
-                    with tracing.span("transfer", "lbfgs.readback") as tsp:
-                        f_h, losses, it, evals, code, k_h, f0_h = \
-                            jax.device_get(
-                                (f_d, losses_d, it_d, evals_d, code_d, k_d,
-                                 f0_d))
-                        tsp.annotate_bytes(
-                            (f_h, losses, it, evals, code, k_h, f0_h))
-                dsp.annotate(evals=int(evals))
-                # cost harvest only under a FULL tracer OR a live
-                # attribution window: the flight-recorder ring records
-                # spans and must not pay an AOT analyze, but a scoped fit
-                # buys the FLOPs/bytes join (shared registry, one harvest
-                # per program either way)
-                tr = tracing.full_active()
-                if (tr is not None or win.live) and pid is None:
-                    pid = costs.ensure("lbfgs.chunk", key, prog, args)
-                win.annotate_program(pid)
-                if tr is not None:
-                    dsp.annotate(program=pid)
-                    costs.note_execution(tr, pid)
-            coef = coef_d
-            first = False
-            f.n_evals += int(evals)
-            f.n_dispatches += 1
-            if need_init:
+                        with tracing.span("transfer", "lbfgs.readback") as tsp:
+                            f_h, losses, it, evals, code, k_h, f0_h = \
+                                jax.device_get(
+                                    (f_d, losses_d, it_d, evals_d, code_d, k_d,
+                                     f0_d))
+                            tsp.annotate_bytes(
+                                (f_h, losses, it, evals, code, k_h, f0_h))
+                    dsp.annotate(evals=int(evals))
+                    # cost harvest only under a FULL tracer OR a live
+                    # attribution window: the flight-recorder ring records
+                    # spans and must not pay an AOT analyze, but a scoped fit
+                    # buys the FLOPs/bytes join (shared registry, one harvest
+                    # per program either way)
+                    tr = tracing.full_active()
+                    if (tr is not None or win.live) and pid is None:
+                        pid = costs.ensure("lbfgs.chunk", key, prog, args)
+                    win.annotate_program(pid)
+                    if tr is not None:
+                        dsp.annotate(program=pid)
+                        costs.note_execution(tr, pid)
+                coef = coef_d
+                first = False
+                f.n_evals += int(evals)
+                f.n_dispatches += 1
+                if need_init:
+                    start = state = OptimState(
+                        x=np.asarray(x0, np.float64).copy(),
+                        value=float(f0_h), grad=g0_d,
+                        loss_history=[float(f0_h)])
+                    need_init = False
+                n_new = int(it)
+                losses = [float(v) for v in losses[:n_new]]
+                hk = int(k_h)
+                # device slices: no host transfer unless a consumer (the
+                # checkpoint/resume path) actually reads them
+                hist_s = [S_d[i] for i in range(self.m - hk, self.m)]
+                hist_y = [Y_d[i] for i in range(self.m - hk, self.m)]
                 state = OptimState(
-                    x=np.asarray(x0, np.float64).copy(),
-                    value=float(f0_h), grad=g0_d,
-                    loss_history=[float(f0_h)])
-                need_init = False
-                yield state
-            n_new = int(it)
-            losses = [float(v) for v in losses[:n_new]]
-            hk = int(k_h)
-            # device slices: no host transfer unless a consumer (the
-            # checkpoint/resume path) actually reads them
-            hist_s = [S_d[i] for i in range(self.m - hk, self.m)]
-            hist_y = [Y_d[i] for i in range(self.m - hk, self.m)]
-            state = OptimState(
-                x=coef_d, value=float(f_h), grad=g_d,
-                iteration=state.iteration + n_new,
-                loss_history=state.loss_history + losses,
-                hist_s=hist_s, hist_y=hist_y)
-            if hasattr(f, "_ctx") and hasattr(f._ctx, "record_step"):
-                f._ctx.record_step({"loss": state.value,
-                                    "chunk_iterations": n_new})
-            # precedence matches host _converged: a budget stop outranks
-            # the value/gradient tests (the estimator's non-convergence
-            # warning keys off this reason)
-            if state.iteration >= self.max_iter:
-                state.converged = True
-                state.converged_reason = "max iterations reached"
-            elif int(code) == 1:
-                state.converged = True
-                state.converged_reason = "function value converged"
-            elif int(code) == 2:
-                state.converged = True
-                state.converged_reason = "gradient converged"
-            if state.converged:
-                # terminal state: hand back host-f64 arrays as the host
-                # optimizer does
-                state.x = np.asarray(coef_d, np.float64)
-                state.grad = np.asarray(g_d, np.float64)
+                    x=coef_d, value=float(f_h), grad=g_d,
+                    iteration=state.iteration + n_new,
+                    loss_history=state.loss_history + losses,
+                    hist_s=hist_s, hist_y=hist_y)
+                if hasattr(f, "_ctx") and hasattr(f._ctx, "record_step"):
+                    f._ctx.record_step({"loss": state.value,
+                                        "chunk_iterations": n_new})
+                # precedence matches host _converged: a budget stop outranks
+                # the value/gradient tests (the estimator's non-convergence
+                # warning keys off this reason)
+                if state.iteration >= self.max_iter:
+                    state.converged = True
+                    state.converged_reason = "max iterations reached"
+                elif int(code) == 1:
+                    state.converged = True
+                    state.converged_reason = "function value converged"
+                elif int(code) == 2:
+                    state.converged = True
+                    state.converged_reason = "gradient converged"
+                if state.converged:
+                    # terminal state: hand back host-f64 arrays as the host
+                    # optimizer does
+                    state.x = np.asarray(coef_d, np.float64)
+                    state.grad = np.asarray(g_d, np.float64)
+            if start is not None:
+                yield start   # the iteration-0 state of a fresh fit
             yield state
             if state.converged:
                 return
@@ -520,7 +537,7 @@ def _build_stacked_chunk(compiled, m: int, K_iters: int, c1: float, c2: float,
 
     two_loop = jax.vmap(two_loop_one)
 
-    def program(*args):
+    def lbfgs_stacked_chunk(*args):
         (arrays, coef0, S0, Y0, k0, f_in, g_in, first,
          ws, reg, l2s, tol, grad_tol, it_limit, need_init, code_in) = \
             (args[:-15], *args[-15:])
@@ -619,7 +636,7 @@ def _build_stacked_chunk(compiled, m: int, K_iters: int, c1: float, c2: float,
     # the driver rebinds all four from the outputs every chunk and the
     # inputs really are dead on dispatch; the (K,m,n) ring buffers
     # dominate the optimizer state's HBM at stacked widths
-    return jax.jit(program, donate_argnums=(
+    return jax.jit(lbfgs_stacked_chunk, donate_argnums=(
         n_arrays, n_arrays + 1, n_arrays + 2, n_arrays + 5))
 
 

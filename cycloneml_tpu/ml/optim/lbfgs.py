@@ -23,11 +23,23 @@ from typing import Callable, List, Optional, Tuple
 
 import numpy as np
 
+from cycloneml_tpu.observe import tracing
 from cycloneml_tpu.util.logging import get_logger
 
 logger = get_logger(__name__)
 
 LossGrad = Callable[[np.ndarray], Tuple[float, np.ndarray]]
+
+
+def _turn(iteration: int):
+    """The ``optim.iteration`` phase span of one turn of a host loop: the
+    initial evaluation (``iteration`` 0) or one quasi-Newton iteration —
+    direction, line search (its ``dispatch`` spans nest here), update and
+    convergence test. Its self time is the host optimizer's own work. A
+    turn opens and closes its span between two yields, never across one:
+    the consumer's time is not the optimizer's, and an abandoned generator
+    must leave the thread's span stack as it found it."""
+    return tracing.span("phase", "optim.iteration", iteration=iteration)
 
 
 @dataclass
@@ -219,40 +231,44 @@ class LBFGS:
             hist.s = [np.asarray(s) for s in resume.hist_s]
             hist.y = [np.asarray(y) for y in resume.hist_y]
         else:
-            x = np.asarray(x0, dtype=np.float64).copy()
-            value, grad = f(x)
-            state = OptimState(x=x, value=float(value),
-                               grad=np.asarray(grad, dtype=np.float64))
-            state.loss_history.append(state.value)
+            with _turn(0):
+                x = np.asarray(x0, dtype=np.float64).copy()
+                value, grad = f(x)
+                state = OptimState(x=x, value=float(value),
+                                   grad=np.asarray(grad, dtype=np.float64))
+                state.loss_history.append(state.value)
         yield state
         if state.converged:
             return  # resumed from a finished checkpoint: nothing to do
         while True:
-            d = hist.direction(state.grad)
-            init_alpha = 1.0 if state.iteration > 0 else \
-                min(1.0, 1.0 / max(float(np.linalg.norm(state.grad)), 1e-12))
-            try:
-                alpha, v_new, g_new = _strong_wolfe(
-                    f, state.x, state.value, state.grad, d, init_alpha)
-            except ValueError:
-                hist = _History(self.m)  # reset on non-descent (Breeze retries)
-                d = -state.grad
-                alpha, v_new, g_new = _strong_wolfe(
-                    f, state.x, state.value, state.grad, d,
-                    min(1.0, 1.0 / max(float(np.linalg.norm(state.grad)), 1e-12)))
-            x_new = state.x + alpha * d
-            g_new = np.asarray(g_new, dtype=np.float64)
-            hist.update(x_new - state.x, g_new - state.grad)
-            f_old = state.value
-            state = OptimState(
-                x=x_new, value=float(v_new), grad=g_new,
-                iteration=state.iteration + 1,
-                loss_history=state.loss_history + [float(v_new)],
-                hist_s=list(hist.s), hist_y=list(hist.y))
-            reason = self._converged(state, f_old)
-            if reason is not None:
-                state.converged = True
-                state.converged_reason = reason
+            with _turn(state.iteration + 1):
+                d = hist.direction(state.grad)
+                init_alpha = 1.0 if state.iteration > 0 else min(
+                    1.0, 1.0 / max(float(np.linalg.norm(state.grad)), 1e-12))
+                try:
+                    alpha, v_new, g_new = _strong_wolfe(
+                        f, state.x, state.value, state.grad, d, init_alpha)
+                except ValueError:
+                    # reset on non-descent (Breeze retries)
+                    hist = _History(self.m)
+                    d = -state.grad
+                    alpha, v_new, g_new = _strong_wolfe(
+                        f, state.x, state.value, state.grad, d,
+                        min(1.0, 1.0 / max(
+                            float(np.linalg.norm(state.grad)), 1e-12)))
+                x_new = state.x + alpha * d
+                g_new = np.asarray(g_new, dtype=np.float64)
+                hist.update(x_new - state.x, g_new - state.grad)
+                f_old = state.value
+                state = OptimState(
+                    x=x_new, value=float(v_new), grad=g_new,
+                    iteration=state.iteration + 1,
+                    loss_history=state.loss_history + [float(v_new)],
+                    hist_s=list(hist.s), hist_y=list(hist.y))
+                reason = self._converged(state, f_old)
+                if reason is not None:
+                    state.converged = True
+                    state.converged_reason = reason
             yield state
             if state.converged:
                 return
@@ -316,13 +332,14 @@ class LBFGSB(LBFGS):
             raw_grad = (np.asarray(resume.raw_grad)
                         if resume.raw_grad is not None else resume.grad)
         else:
-            x = self._clip(np.asarray(x0, dtype=np.float64))
-            value, grad = f(x)
-            raw_grad = np.asarray(grad, dtype=np.float64)
-            state = OptimState(x=x, value=float(value),
-                               grad=self._projected_grad(x, raw_grad),
-                               raw_grad=raw_grad)
-            state.loss_history.append(state.value)
+            with _turn(0):
+                x = self._clip(np.asarray(x0, dtype=np.float64))
+                value, grad = f(x)
+                raw_grad = np.asarray(grad, dtype=np.float64)
+                state = OptimState(x=x, value=float(value),
+                                   grad=self._projected_grad(x, raw_grad),
+                                   raw_grad=raw_grad)
+                state.loss_history.append(state.value)
             if not np.any(state.grad):
                 # the (clipped) start is already a KKT point of the box —
                 # degenerate bounds (lower == upper) land here too
@@ -339,60 +356,61 @@ class LBFGSB(LBFGS):
                     converged_reason="gradient converged")
                 yield state
                 return
-            d = hist.direction(state.grad)
-            # zero direction components that would immediately leave the box
-            at_lo = (state.x <= self.lower) & (d < 0)
-            at_hi = (state.x >= self.upper) & (d > 0)
-            d = np.where(at_lo | at_hi, 0.0, d)
-            if not np.any(d):
-                d = -state.grad
+            with _turn(state.iteration + 1):
+                d = hist.direction(state.grad)
+                # zero direction components that would immediately leave the box
+                at_lo = (state.x <= self.lower) & (d < 0)
+                at_hi = (state.x >= self.upper) & (d > 0)
+                d = np.where(at_lo | at_hi, 0.0, d)
+                if not np.any(d):
+                    d = -state.grad
 
-            def f_boxed(xt: np.ndarray):
-                xt = self._clip(xt)
-                v, g = f(xt)
-                return float(v), np.asarray(g, dtype=np.float64)
+                def f_boxed(xt: np.ndarray):
+                    xt = self._clip(xt)
+                    v, g = f(xt)
+                    return float(v), np.asarray(g, dtype=np.float64)
 
-            init_alpha = 1.0 if state.iteration > 0 else \
-                min(1.0, 1.0 / max(float(np.linalg.norm(state.grad)), 1e-12))
-            try:
-                alpha, v_new, g_new = _strong_wolfe(
-                    f_boxed, state.x, state.value, state.grad, d, init_alpha)
-            except ValueError:
-                hist = _History(self.m)
-                d = -state.grad
-                alpha, v_new, g_new = _strong_wolfe(
-                    f_boxed, state.x, state.value, state.grad, d,
-                    min(1.0, 1.0 / max(float(np.linalg.norm(state.grad)),
-                                       1e-12)))
-            x_new = self._clip(state.x + alpha * d)
-            raw_grad_new = np.asarray(g_new, dtype=np.float64)
-            pg_new = self._projected_grad(x_new, raw_grad_new)
-            # reduced-space curvature: pairs are only meaningful within one
-            # face of the box. When the active set changes, old pairs
-            # describe a different subspace — drop them (the classic
-            # active-set restart); within a face, mask y to the free
-            # coordinates so the two-loop recursion models the reduced
-            # Hessian (s is already zero at active coordinates).
-            active_new = (x_new <= self.lower) | (x_new >= self.upper)
-            active_old = (state.x <= self.lower) | (state.x >= self.upper)
-            if not np.array_equal(active_new, active_old):
-                hist = _History(self.m)
-            else:
-                free = ~active_new
-                hist.update((x_new - state.x) * free,
-                            (raw_grad_new - raw_grad) * free)
-            f_old = state.value
-            raw_grad = raw_grad_new
-            state = OptimState(
-                x=x_new, value=float(v_new), grad=pg_new,
-                iteration=state.iteration + 1,
-                loss_history=state.loss_history + [float(v_new)],
-                hist_s=list(hist.s), hist_y=list(hist.y),
-                raw_grad=raw_grad_new)
-            reason = self._converged(state, f_old)
-            if reason is not None:
-                state.converged = True
-                state.converged_reason = reason
+                init_alpha = 1.0 if state.iteration > 0 else \
+                    min(1.0, 1.0 / max(float(np.linalg.norm(state.grad)), 1e-12))
+                try:
+                    alpha, v_new, g_new = _strong_wolfe(
+                        f_boxed, state.x, state.value, state.grad, d, init_alpha)
+                except ValueError:
+                    hist = _History(self.m)
+                    d = -state.grad
+                    alpha, v_new, g_new = _strong_wolfe(
+                        f_boxed, state.x, state.value, state.grad, d,
+                        min(1.0, 1.0 / max(float(np.linalg.norm(state.grad)),
+                                           1e-12)))
+                x_new = self._clip(state.x + alpha * d)
+                raw_grad_new = np.asarray(g_new, dtype=np.float64)
+                pg_new = self._projected_grad(x_new, raw_grad_new)
+                # reduced-space curvature: pairs are only meaningful within one
+                # face of the box. When the active set changes, old pairs
+                # describe a different subspace — drop them (the classic
+                # active-set restart); within a face, mask y to the free
+                # coordinates so the two-loop recursion models the reduced
+                # Hessian (s is already zero at active coordinates).
+                active_new = (x_new <= self.lower) | (x_new >= self.upper)
+                active_old = (state.x <= self.lower) | (state.x >= self.upper)
+                if not np.array_equal(active_new, active_old):
+                    hist = _History(self.m)
+                else:
+                    free = ~active_new
+                    hist.update((x_new - state.x) * free,
+                                (raw_grad_new - raw_grad) * free)
+                f_old = state.value
+                raw_grad = raw_grad_new
+                state = OptimState(
+                    x=x_new, value=float(v_new), grad=pg_new,
+                    iteration=state.iteration + 1,
+                    loss_history=state.loss_history + [float(v_new)],
+                    hist_s=list(hist.s), hist_y=list(hist.y),
+                    raw_grad=raw_grad_new)
+                reason = self._converged(state, f_old)
+                if reason is not None:
+                    state.converged = True
+                    state.converged_reason = reason
             yield state
             if state.converged:
                 return
@@ -435,60 +453,63 @@ class OWLQN(LBFGS):
             raw_grad = (np.asarray(resume.raw_grad)
                         if resume.raw_grad is not None else resume.grad)
         else:
-            x = np.asarray(x0, dtype=np.float64).copy()
-            value, grad = f(x)
-            value = float(value) + self._l1(x)
-            grad = np.asarray(grad, dtype=np.float64)
-            state = OptimState(x=x, value=value,
-                               grad=self._pseudo_grad(x, grad), raw_grad=grad)
-            state.loss_history.append(state.value)
-            raw_grad = grad
+            with _turn(0):
+                x = np.asarray(x0, dtype=np.float64).copy()
+                value, grad = f(x)
+                value = float(value) + self._l1(x)
+                grad = np.asarray(grad, dtype=np.float64)
+                state = OptimState(x=x, value=value,
+                                   grad=self._pseudo_grad(x, grad),
+                                   raw_grad=grad)
+                state.loss_history.append(state.value)
+                raw_grad = grad
         yield state
         if state.converged:
             return  # resumed from a finished checkpoint: nothing to do
         while True:
-            d = hist.direction(state.grad)
-            # project direction onto the pseudo-gradient descent orthant
-            d = np.where(d * state.grad >= 0, 0.0, d) if self._has_l1() else d
-            if not np.any(d):
-                d = -state.grad
-            orthant = np.where(x != 0, np.sign(x), -np.sign(state.grad))
+            with _turn(state.iteration + 1):
+                d = hist.direction(state.grad)
+                # project direction onto the pseudo-gradient descent orthant
+                d = np.where(d * state.grad >= 0, 0.0, d) if self._has_l1() else d
+                if not np.any(d):
+                    d = -state.grad
+                orthant = np.where(x != 0, np.sign(x), -np.sign(state.grad))
 
-            def f_projected(xt: np.ndarray):
-                xt = np.where(xt * orthant >= 0, xt, 0.0)  # orthant projection
-                v, g = f(xt)
-                return float(v) + self._l1(xt), np.asarray(g, dtype=np.float64)
+                def f_projected(xt: np.ndarray):
+                    xt = np.where(xt * orthant >= 0, xt, 0.0)  # orthant projection
+                    v, g = f(xt)
+                    return float(v) + self._l1(xt), np.asarray(g, dtype=np.float64)
 
-            init_alpha = 1.0 if state.iteration > 0 else \
-                min(1.0, 1.0 / max(float(np.linalg.norm(state.grad)), 1e-12))
-            try:
-                alpha, v_new, g_new = _strong_wolfe(
-                    f_projected, state.x, state.value, state.grad, d, init_alpha,
-                    c2=0.99)  # Breeze OWLQN relaxes curvature
-            except ValueError:
-                d = -state.grad
-                alpha, v_new, g_new = _strong_wolfe(
-                    f_projected, state.x, state.value, state.grad, d,
-                    min(1.0, 1.0 / max(float(np.linalg.norm(state.grad)), 1e-12)),
-                    c2=0.99)
-            x_new = state.x + alpha * d
-            x_new = np.where(x_new * orthant >= 0, x_new, 0.0)
-            raw_grad_new = g_new
-            pg_new = self._pseudo_grad(x_new, raw_grad_new)
-            hist.update(x_new - state.x, raw_grad_new - raw_grad)
-            f_old = state.value
-            x = x_new
-            raw_grad = raw_grad_new
-            state = OptimState(
-                x=x_new, value=float(v_new), grad=pg_new,
-                iteration=state.iteration + 1,
-                loss_history=state.loss_history + [float(v_new)],
-                hist_s=list(hist.s), hist_y=list(hist.y),
-                raw_grad=raw_grad_new)
-            reason = self._converged(state, f_old)
-            if reason is not None:
-                state.converged = True
-                state.converged_reason = reason
+                init_alpha = 1.0 if state.iteration > 0 else \
+                    min(1.0, 1.0 / max(float(np.linalg.norm(state.grad)), 1e-12))
+                try:
+                    alpha, v_new, g_new = _strong_wolfe(
+                        f_projected, state.x, state.value, state.grad, d, init_alpha,
+                        c2=0.99)  # Breeze OWLQN relaxes curvature
+                except ValueError:
+                    d = -state.grad
+                    alpha, v_new, g_new = _strong_wolfe(
+                        f_projected, state.x, state.value, state.grad, d,
+                        min(1.0, 1.0 / max(float(np.linalg.norm(state.grad)), 1e-12)),
+                        c2=0.99)
+                x_new = state.x + alpha * d
+                x_new = np.where(x_new * orthant >= 0, x_new, 0.0)
+                raw_grad_new = g_new
+                pg_new = self._pseudo_grad(x_new, raw_grad_new)
+                hist.update(x_new - state.x, raw_grad_new - raw_grad)
+                f_old = state.value
+                x = x_new
+                raw_grad = raw_grad_new
+                state = OptimState(
+                    x=x_new, value=float(v_new), grad=pg_new,
+                    iteration=state.iteration + 1,
+                    loss_history=state.loss_history + [float(v_new)],
+                    hist_s=list(hist.s), hist_y=list(hist.y),
+                    raw_grad=raw_grad_new)
+                reason = self._converged(state, f_old)
+                if reason is not None:
+                    state.converged = True
+                    state.converged_reason = reason
             yield state
             if state.converged:
                 return

@@ -307,7 +307,7 @@ def _build_line_search(compiled, l2_t, c1: float, c2: float, max_evals: int,
     import jax
     import jax.numpy as jnp
 
-    def program(*args):
+    def lbfgs_line_search(*args):
         arrays = args[:-6]
         x0, dirn, value0, dg0, init_alpha, ws = args[-6:]
         # divide by ws, matching the host path's `loss / weight_sum`
@@ -329,7 +329,7 @@ def _build_line_search(compiled, l2_t, c1: float, c2: float, max_evals: int,
         return wolfe_search(phi, g_zero, value0, dg0, init_alpha,
                             c1, c2, max_evals, cdt)
 
-    return jax.jit(program)
+    return jax.jit(lbfgs_line_search)
 
 
 def _select_bcast(mask, a, b):

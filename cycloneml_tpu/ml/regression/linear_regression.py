@@ -36,6 +36,7 @@ from cycloneml_tpu.ml.shared import (
 )
 from cycloneml_tpu.ml.stat import Summarizer
 from cycloneml_tpu.ml.util_io import MLReadable, MLWritable, load_arrays, save_arrays
+from cycloneml_tpu.observe import tracing
 from cycloneml_tpu.util.logging import get_logger
 
 logger = get_logger(__name__)
@@ -153,131 +154,140 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
                 max(len(wm.objective_history) - 1, 0))
             return model
 
-        stats = ds.summary() if streamed else Summarizer.summarize(ds)
-        if not streamed:
-            # fp8 safety rail: envelope probe, bf16 fallback on failure
-            from cycloneml_tpu.dataset.dataset import resolve_fp8_fit
-            ds = resolve_fp8_fit(ds, stats, "LinearRegression")
-        x_mean, x_std = stats.mean, stats.std
-        w_sum = stats.weight_sum
+        cached = streamed or Summarizer.is_cached(ds)
+        with tracing.span("phase", "fit.stats", cached=cached):
+            stats = ds.summary() if streamed else Summarizer.summarize(ds)
+        with tracing.span("phase", "fit.prepare"):
+            if not streamed:
+                # fp8 safety rail: envelope probe, bf16 fallback on failure
+                from cycloneml_tpu.dataset.dataset import resolve_fp8_fit
+                ds = resolve_fp8_fit(ds, stats, "LinearRegression")
+            x_mean, x_std = stats.mean, stats.std
+            w_sum = stats.weight_sum
 
-        # label moments: one psum pass in-core; already harvested in the
-        # shard write pass for streamed datasets
-        if streamed:
-            s1y, s2y, w2y = ds.y_moments()
-            ymom = {"s1": s1y, "s2": s2y, "w2": w2y}
-        else:
-            ymom = ds.tree_aggregate_fn(
-                lambda x, y, w: {"s1": jnp.sum(w * y),
-                                 "s2": jnp.sum(w * y * y),
-                                 "w2": jnp.sum(w * w)})()
-        y_mean = float(ymom["s1"]) / w_sum
-        denom = w_sum - float(ymom["w2"]) / w_sum
-        y_var = max((float(ymom["s2"]) - w_sum * y_mean ** 2) / denom, 0.0) if denom > 0 else 0.0
-        y_std = float(np.sqrt(y_var))
-        if y_std == 0.0:
-            # constant label (ref LinearRegression.scala:388-414, mirroring
-            # WeightedLeastSquares.scala:117-141): with an intercept (or an
-            # all-zero label) the exact fit is zero coefficients; WITHOUT
-            # an intercept a nonzero constant label still needs solving —
-            # the reference sets yStd = |yMean| so the label is "not scaled
-            # anymore" and proceeds, and REFUSES regularization because the
-            # label-standardized penalty is undefined at σy=0
-            if self.get("fitIntercept") or y_mean == 0.0:
-                model = LinearRegressionModel(
-                    np.zeros(d), y_mean if self.get("fitIntercept") else 0.0,
-                    uid=self.uid)
-                self._copy_values(model)
-                model._set_parent(self)
-                model.summary = LinearRegressionTrainingSummary([0.0], 0)
-                return model
-            if reg > 0.0:
-                raise ValueError(
-                    "The standard deviation of the label is zero. Model "
-                    "cannot be regularized when labels are standardized "
-                    "(ref WeightedLeastSquares require)")
-            y_std = abs(y_mean)
+            # label moments: one psum pass in-core; already harvested in the
+            # shard write pass for streamed datasets
+            if streamed:
+                s1y, s2y, w2y = ds.y_moments()
+                ymom = {"s1": s1y, "s2": s2y, "w2": w2y}
+            else:
+                ymom = ds.tree_aggregate_fn(
+                    lambda x, y, w: {"s1": jnp.sum(w * y),
+                                     "s2": jnp.sum(w * y * y),
+                                     "w2": jnp.sum(w * w)})()
+            y_mean = float(ymom["s1"]) / w_sum
+            denom = w_sum - float(ymom["w2"]) / w_sum
+            y_var = max((float(ymom["s2"]) - w_sum * y_mean ** 2) / denom, 0.0) if denom > 0 else 0.0
+            y_std = float(np.sqrt(y_var))
+            if y_std == 0.0:
+                # constant label (ref LinearRegression.scala:388-414, mirroring
+                # WeightedLeastSquares.scala:117-141): with an intercept (or an
+                # all-zero label) the exact fit is zero coefficients; WITHOUT
+                # an intercept a nonzero constant label still needs solving —
+                # the reference sets yStd = |yMean| so the label is "not scaled
+                # anymore" and proceeds, and REFUSES regularization because the
+                # label-standardized penalty is undefined at σy=0
+                if self.get("fitIntercept") or y_mean == 0.0:
+                    model = LinearRegressionModel(
+                        np.zeros(d), y_mean if self.get("fitIntercept") else 0.0,
+                        uid=self.uid)
+                    self._copy_values(model)
+                    model._set_parent(self)
+                    model.summary = LinearRegressionTrainingSummary([0.0], 0)
+                    return model
+                if reg > 0.0:
+                    raise ValueError(
+                        "The standard deviation of the label is zero. Model "
+                        "cannot be regularized when labels are standardized "
+                        "(ref WeightedLeastSquares require)")
+                y_std = abs(y_mean)
 
-        # glmnet semantics (the reference's parity target): the penalty is
-        # applied on the label-standardized problem, so the user's regParam
-        # is divided by the label std (ref LinearRegression.scala:396
-        # effectiveRegParam = regParam / yStd; WeightedLeastSquares.scala:209)
-        eff_reg = reg / y_std
-        coef, icpt, history = self._solve_quasi_newton(
+            # glmnet semantics (the reference's parity target): the penalty is
+            # applied on the label-standardized problem, so the user's regParam
+            # is divided by the label std (ref LinearRegression.scala:396
+            # effectiveRegParam = regParam / yStd; WeightedLeastSquares.scala:209)
+            eff_reg = reg / y_std
+        coef, icpt, history, loss_fn = self._solve_quasi_newton(
             ds, stats, y_mean, y_std, eff_reg, alpha)
 
-        model = LinearRegressionModel(coef, icpt, uid=self.uid)
-        self._copy_values(model)
-        model._set_parent(self)
-        model.summary = LinearRegressionTrainingSummary(
-            history, max(len(history) - 1, 0), streamed=streamed)
-        return model
+        with tracing.span("phase", "fit.finish"):
+            model = LinearRegressionModel(coef, icpt, uid=self.uid)
+            self._copy_values(model)
+            model._set_parent(self)
+            model.summary = LinearRegressionTrainingSummary(
+                history, max(len(history) - 1, 0),
+                total_evals=loss_fn.n_evals,
+                total_dispatches=loss_fn.n_dispatches, streamed=streamed)
+            return model
 
     # -- quasi-Newton in doubly standardized space -----------------------------
     def _solve_quasi_newton(self, ds, stats, y_mean, y_std, reg, alpha):
         import jax
         import jax.numpy as jnp
 
-        d = ds.n_features
-        fit_intercept = self.get("fitIntercept")
-        standardize = self.get("standardization")
-        x_mean, x_std = stats.mean, stats.std
-        inv_std = np.where(x_std > 0, 1.0 / np.where(x_std > 0, x_std, 1.0), 0.0)
+        with tracing.span("phase", "fit.prepare"):
+            d = ds.n_features
+            fit_intercept = self.get("fitIntercept")
+            standardize = self.get("standardization")
+            x_mean, x_std = stats.mean, stats.std
+            inv_std = np.where(x_std > 0, 1.0 / np.where(x_std > 0, x_std, 1.0), 0.0)
 
-        # the doubly-standardized objective folds INTO the aggregator read
-        # (aggregators.least_squares_scaled): err = x·(inv_std∘β) −
-        # (μ̂·β − ȳ̂) − y/σ_y, grad unscales by inv_std — algebraically the
-        # aggregation over (x̂−μ̂, ŷ−ȳ̂) without EVER materializing the
-        # standardized X copy or the scaled-y vector (pre-tier this path
-        # re-wrote both, a full read+write X sweep and 2x the HBM working
-        # set per fit). Raw data-tier blocks (bf16 by default) are read at
-        # storage width with fp32 accumulation inside the kernel; the
-        # fused Pallas kernel is the default sweep on native backends.
-        from cycloneml_tpu.dataset.instance import compute_dtype
-        from cycloneml_tpu.ops.kernels import use_fused_kernels
-        adt = compute_dtype()
-        scaled_mean = (x_mean * inv_std) if fit_intercept else np.zeros(d)
-        y_mean_std = (y_mean / y_std) if fit_intercept else 0.0
-        y_pars = np.array([1.0 / y_std, y_mean_std])
-        # fp8 tier: the per-column dequant scale folds into the
-        # aggregator-side inv_std (x̂ = codes∘(scale/σ) − μ/σ); the final
-        # unscaling keeps the original inv_std
-        fp8_scale = getattr(ds, "x_scale", None)
-        inv_std_agg = inv_std * fp8_scale if fp8_scale is not None \
-            else inv_std
-        agg = (aggregators.least_squares_pallas_scaled(d)
-               if use_fused_kernels(ds.ctx)
-               else aggregators.least_squares_scaled(d))
+            # the doubly-standardized objective folds INTO the aggregator read
+            # (aggregators.least_squares_scaled): err = x·(inv_std∘β) −
+            # (μ̂·β − ȳ̂) − y/σ_y, grad unscales by inv_std — algebraically the
+            # aggregation over (x̂−μ̂, ŷ−ȳ̂) without EVER materializing the
+            # standardized X copy or the scaled-y vector (pre-tier this path
+            # re-wrote both, a full read+write X sweep and 2x the HBM working
+            # set per fit). Raw data-tier blocks (bf16 by default) are read at
+            # storage width with fp32 accumulation inside the kernel; the
+            # fused Pallas kernel is the default sweep on native backends.
+            from cycloneml_tpu.dataset.instance import compute_dtype
+            from cycloneml_tpu.ops.kernels import use_fused_kernels
+            adt = compute_dtype()
+            scaled_mean = (x_mean * inv_std) if fit_intercept else np.zeros(d)
+            y_mean_std = (y_mean / y_std) if fit_intercept else 0.0
+            y_pars = np.array([1.0 / y_std, y_mean_std])
+            # fp8 tier: the per-column dequant scale folds into the
+            # aggregator-side inv_std (x̂ = codes∘(scale/σ) − μ/σ); the final
+            # unscaling keeps the original inv_std
+            fp8_scale = getattr(ds, "x_scale", None)
+            inv_std_agg = inv_std * fp8_scale if fp8_scale is not None \
+                else inv_std
+            agg = (aggregators.least_squares_pallas_scaled(d)
+                   if use_fused_kernels(ds.ctx)
+                   else aggregators.least_squares_scaled(d))
 
-        l2 = (1.0 - alpha) * reg
-        l1 = alpha * reg
-        l2_fn = l2_regularization(l2, d, False, features_std=x_std,
-                                  standardize=standardize) if l2 > 0 else None
-        extras = (jnp.asarray(inv_std_agg.astype(adt)),
-                  jnp.asarray(scaled_mean.astype(adt)),
-                  jnp.asarray(y_pars.astype(adt)))
-        from cycloneml_tpu.oocore import StreamingDataset
-        if isinstance(ds, StreamingDataset):
-            # the streamed twin: same scaled aggregator, same extras —
-            # each loss/grad evaluation is one double-buffered epoch
-            from cycloneml_tpu.oocore import StreamingLossFunction
-            loss_fn = StreamingLossFunction(ds, agg, l2_fn,
-                                            stats.weight_sum,
-                                            extra_args=extras)
-        else:
-            loss_fn = DistributedLossFunction(ds, agg, l2_fn,
-                                              stats.weight_sum,
-                                              extra_args=extras)
+            l2 = (1.0 - alpha) * reg
+            l1 = alpha * reg
+            l2_fn = l2_regularization(l2, d, False, features_std=x_std,
+                                      standardize=standardize) if l2 > 0 else None
+            extras = (jnp.asarray(inv_std_agg.astype(adt)),
+                      jnp.asarray(scaled_mean.astype(adt)),
+                      jnp.asarray(y_pars.astype(adt)))
+            from cycloneml_tpu.oocore import StreamingDataset
+            if isinstance(ds, StreamingDataset):
+                # the streamed twin: same scaled aggregator, same extras —
+                # each loss/grad evaluation is one double-buffered epoch
+                from cycloneml_tpu.oocore import StreamingLossFunction
+                loss_fn = StreamingLossFunction(ds, agg, l2_fn,
+                                                stats.weight_sum,
+                                                extra_args=extras)
+            else:
+                loss_fn = DistributedLossFunction(ds, agg, l2_fn,
+                                                  stats.weight_sum,
+                                                  extra_args=extras)
 
-        if l1 > 0:
-            l1_vec = np.full(d, l1)
-            if not standardize:
-                l1_vec = np.where(x_std > 0, l1 / np.where(x_std > 0, x_std, 1.0), 0.0)
-            opt = OWLQN(max_iter=self.get("maxIter"), tol=self.get("tol"),
-                        l1_reg=l1_vec)
-        else:
-            opt = LBFGS(max_iter=self.get("maxIter"), tol=self.get("tol"))
-        state = opt.minimize(loss_fn, np.zeros(d))
+            if l1 > 0:
+                l1_vec = np.full(d, l1)
+                if not standardize:
+                    l1_vec = np.where(x_std > 0, l1 / np.where(x_std > 0, x_std, 1.0), 0.0)
+                opt = OWLQN(max_iter=self.get("maxIter"), tol=self.get("tol"),
+                            l1_reg=l1_vec)
+            else:
+                opt = LBFGS(max_iter=self.get("maxIter"), tol=self.get("tol"))
+        with tracing.span("phase", "fit.optimize",
+                          optimizer=type(opt).__name__):
+            state = opt.minimize(loss_fn, np.zeros(d))
         if state.converged_reason == "max iterations reached":
             logger.warning("LinearRegression did not converge in %d iterations",
                            self.get("maxIter"))
@@ -289,10 +299,11 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
                              "non-finite fp8 solution"),
                 stats, y_mean, y_std, reg, alpha)
 
-        beta_hat = state.x  # standardized-space coefficients
-        coef = beta_hat * inv_std * y_std
-        icpt = y_mean - float(coef @ x_mean) if fit_intercept else 0.0
-        return coef, icpt, list(state.loss_history)
+        with tracing.span("phase", "fit.finish"):
+            beta_hat = state.x  # standardized-space coefficients
+            coef = beta_hat * inv_std * y_std
+            icpt = y_mean - float(coef @ x_mean) if fit_intercept else 0.0
+        return coef, icpt, list(state.loss_history), loss_fn
 
 
 class LinearRegressionModel(PredictionModel, _LinearRegressionParams,
@@ -346,8 +357,15 @@ class LinearRegressionModel(PredictionModel, _LinearRegressionParams,
 
 
 class LinearRegressionTrainingSummary:
-    def __init__(self, objective_history, total_iterations, streamed=False):
+    def __init__(self, objective_history, total_iterations,
+                 total_evals=None, total_dispatches=None, streamed=False):
         self.objective_history = objective_history
         self.total_iterations = total_iterations
+        # optimizer-path telemetry, as LogisticRegressionTrainingSummary
+        # carries it: loss/grad evaluations and host->device round trips of
+        # the quasi-Newton solve (None for the normal-equations solver and
+        # the constant-label shortcut, which evaluate no loss function)
+        self.total_evals = total_evals
+        self.total_dispatches = total_dispatches
         # True when the fit ran on the out-of-core streaming engine
         self.streamed = streamed

@@ -59,6 +59,12 @@ class Summarizer:
         return out
 
     @staticmethod
+    def is_cached(dataset: InstanceDataset) -> bool:
+        """Whether :meth:`summarize` will answer from the dataset's cached
+        moments (no device pass) — what a fit's ``fit.stats`` span notes."""
+        return getattr(dataset, "_summary_cache", None) is not None
+
+    @staticmethod
     def mean_std(dataset: InstanceDataset):
         s = Summarizer.summarize(dataset)
         return s.mean, s.std
@@ -115,8 +121,9 @@ def _psum_parts(moments):
     import jax.numpy as jnp
     from cycloneml_tpu.mesh import DATA_AXIS, REPLICA_AXIS
 
-    def fn(x, y, w):
-        parts = moments(x, y, w)
+    def summarizer_moments(x, y, w):
+        with jax.named_scope("summarizer.moments"):
+            parts = moments(x, y, w)
         summed = {}
         for k, v in parts.items():
             if k == "mx":
@@ -134,7 +141,7 @@ def _psum_parts(moments):
             summed[k] = r
         return summed
 
-    return fn
+    return summarizer_moments
 
 
 def _finalize(out, dataset: InstanceDataset) -> SummaryStats:

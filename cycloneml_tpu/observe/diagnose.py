@@ -7,8 +7,6 @@ plane already records — no new instrumentation:
 ==================== ==========================================================
 finding kind         evidence joined
 ==================== ==========================================================
-bandwidth-bound /    costs registry rollup (``FitProfile.roofline_fraction``
-compute-bound        / ``arithmetic_intensity`` vs the ridge point)
 recompile-storm      compile spans recurring past warm-up, keyed by
                      program-cache identity (span name)
 transfer-stall       non-streaming transfer-span seconds vs dispatch +
@@ -26,7 +24,7 @@ cache-restream       ShardSetCache stats (LRU thrash: evictions + misses
 fault-pressure       chaos instants (injected faults) + staging retries
 ==================== ==========================================================
 
-Rules ABSTAIN when their evidence plane is absent (no costs peaks on CPU,
+Rules ABSTAIN when their evidence plane is absent (no profile,
 no stream spans, no serving stats) — a clean warm fit diagnoses to ZERO
 findings. The report is deterministic: same inputs => byte-identical
 canonical JSON (``DiagnosisReport.to_json``), no wall-clock fields, all
@@ -40,7 +38,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from cycloneml_tpu.conf import (DOCTOR_FALLBACK_MIN, DOCTOR_MIN_STREAM_SPANS,
                                 DOCTOR_OVERLAP_MIN, DOCTOR_RECOMPILE_MIN,
-                                DOCTOR_ROOFLINE_FRACTION, DOCTOR_SHED_MIN,
+                                DOCTOR_SHED_MIN,
                                 DOCTOR_TRANSFER_MIN_COUNT,
                                 DOCTOR_TRANSFER_STALL_FRACTION,
                                 SKEW_MAD_FACTOR, SKEW_MIN_GAP_MS,
@@ -142,7 +140,6 @@ class DoctorConfig:
     min_stream_spans: int = 8
     shed_min: int = 1
     fallback_min: int = 1
-    roofline_fraction: float = 0.5
     skew_mad_factor: float = 4.0
     skew_rel_factor: float = 1.5
     skew_min_gap_s: float = 0.010
@@ -159,7 +156,6 @@ class DoctorConfig:
             min_stream_spans=conf.get(DOCTOR_MIN_STREAM_SPANS),
             shed_min=conf.get(DOCTOR_SHED_MIN),
             fallback_min=conf.get(DOCTOR_FALLBACK_MIN),
-            roofline_fraction=conf.get(DOCTOR_ROOFLINE_FRACTION),
             skew_mad_factor=conf.get(SKEW_MAD_FACTOR),
             skew_rel_factor=conf.get(SKEW_REL_FACTOR),
             skew_min_gap_s=conf.get(SKEW_MIN_GAP_MS) / 1e3,
@@ -240,31 +236,6 @@ def _straggler_lanes(lanes: Dict[str, List[float]],
 
 
 # -- rules ---------------------------------------------------------------------
-
-def _rule_roofline(profile: Optional[FitProfile],
-                   cfg: DoctorConfig) -> List[Finding]:
-    if profile is None or profile.roofline_fraction is None:
-        return []     # CPU / no costs peaks: nothing measured, abstain
-    frac = profile.roofline_fraction
-    if frac < cfg.roofline_fraction:
-        return []     # host-bound: the other rules explain why
-    intensity = profile.arithmetic_intensity
-    bandwidth = intensity is not None and intensity < 1.0
-    kind = "bandwidth-bound" if bandwidth else "compute-bound"
-    remedy = ("fewer bytes per flop: narrower data tier, fused sweeps, "
-              "larger shards" if bandwidth else
-              "the fit is at the compute roof: more devices or a cheaper "
-              "algorithm, not tuning")
-    return [Finding(
-        kind=kind, severity="info", score=round(frac, 6),
-        summary=f"running at {frac:.0%} of the measured "
-                f"{'memory' if bandwidth else 'compute'} ceiling",
-        evidence={"roofline_fraction": round(frac, 6),
-                  "arithmetic_intensity": (round(intensity, 6)
-                                           if intensity is not None else None),
-                  "total_flops": profile.total_flops},
-        remedy=remedy)]
-
 
 def _rule_recompile(spans, cfg: DoctorConfig) -> List[Finding]:
     if not spans:
@@ -545,7 +516,6 @@ def diagnose(subject: Any = None, *,
                                 or stats.get("misses", 0)) else None
 
     findings: List[Finding] = []
-    findings += _rule_roofline(profile, cfg)
     findings += _rule_recompile(spans, cfg)
     findings += _rule_transfer_stall(spans, profile, cfg)
     findings += _rule_straggler(spans, skew_snapshot, cfg)

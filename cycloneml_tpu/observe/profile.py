@@ -26,6 +26,11 @@ class FitProfile:
     the same way ``dispatch_count`` matches ``n_dispatches``.
     ``steady_seconds`` is dispatch time excluding dispatches that paid a
     compile (their wall time is staging, not steady state).
+    ``phase_seconds`` is the host's side of the fit: SELF time by
+    ``phase`` span name (``fit.stats`` / ``fit.prepare`` / ``fit.optimize``
+    / ``fit.finish`` / ``optim.iteration``) — a phase's duration less the
+    ``phase`` and ``dispatch`` spans directly inside it — so the values sum
+    to the phases' union less the dispatch time under them.
     ``n_models`` is the model-axis width of the fit's dispatches (stacked
     fits — ``n_models`` > 1 — amortize every compile in this profile over
     that many models; see docs/multi-model.md).
@@ -41,9 +46,11 @@ class FitProfile:
     executions' FLOPs over those same executions' dispatch time (staging
     executions excluded from both sides); ``arithmetic_intensity`` is
     FLOPs per byte
-    accessed; ``roofline_fraction`` scores achieved FLOP/s against the
-    per-backend roofline ``min(peak_flops, peak_bw × intensity)`` (Williams
-    et al. 2009). Every cost field is ``None`` — explicitly "unavailable" —
+    accessed. (No share of a roofline is derived here: XLA's analysis
+    cannot see inside a Mosaic custom call, so on the chip the harvest is
+    ``unavailable``; the benchmark measures kernel rooflines and the
+    fit's share of the HBM peak from the device trace — PERF.md §3.)
+    Every cost field is ``None`` — explicitly "unavailable" —
     when the backend (or an untraced run) cannot report it;
     ``cost_availability`` summarizes (``full`` / ``flops_only`` /
     ``unavailable``) and ``memory_stats_available`` records whether live
@@ -73,6 +80,8 @@ class FitProfile:
     rebuilds: int = 0
     faults_injected: int = 0
     n_models: int = 1
+    phase_seconds: Dict[str, float] = dataclasses.field(
+        default_factory=dict)
     # fp8 tier fallbacks during this fit: the envelope probe (or a
     # non-finite fp8 solution) re-routed the fit to bf16 storage — see
     # docs/mixed-precision.md and the PrecisionFallback event
@@ -90,7 +99,6 @@ class FitProfile:
     hbm_temp_bytes: Optional[int] = None
     achieved_flops: Optional[float] = None
     arithmetic_intensity: Optional[float] = None
-    roofline_fraction: Optional[float] = None
     n_devices: int = 0
     cost_availability: str = "unavailable"
     memory_stats_available: bool = False
@@ -200,13 +208,14 @@ class FitProfile:
                 sid = parents.get(sid, "")
         p.steady_seconds = sum(
             s.duration_s for s in dispatches if s.span_id not in staging)
+        p.phase_seconds = _phase_self_seconds(spans)
         p._fold_costs(spans, cost_lookup, staging)
         return p
 
     def _fold_costs(self, spans: Sequence[Any], cost_lookup,
                     staging) -> None:
         """Join the spans' per-program execution counts onto the harvested
-        XLA cost registry and derive the roofline fields.
+        XLA cost registry and derive the rate fields.
 
         ``achieved_flops`` keeps numerator and denominator consistent:
         steady-state executions' FLOPs over those same spans' wall time.
@@ -273,14 +282,6 @@ class FitProfile:
                 self.achieved_flops = steady_flops / steady_cost_seconds
             elif all_cost_seconds > 0:
                 self.achieved_flops = flops_total / all_cost_seconds
-            peak_flops, peak_bw = _costs.backend_peaks()
-            if (self.achieved_flops and peak_flops and self.n_devices
-                    and self.arithmetic_intensity):
-                ceiling = min(peak_flops,
-                              (peak_bw or peak_flops)
-                              * self.arithmetic_intensity)
-                self.roofline_fraction = (
-                    self.achieved_flops / self.n_devices / ceiling)
         self.cost_availability = (
             "full" if any_flops and any_mem
             else "flops_only" if any_flops
@@ -295,3 +296,22 @@ class FitProfile:
             "checkpoint_s": round(self.checkpoint_seconds, 4),
             "wall_s": round(self.wall_seconds, 4),
         }
+
+
+def _phase_self_seconds(spans: Sequence[Any]) -> Dict[str, float]:
+    """Self seconds by ``phase`` span name: each phase's duration less the
+    ``phase`` and ``dispatch`` spans whose nearest ancestor of either kind
+    it is (a dispatch nested in a dispatch is its parent's time already)."""
+    by_id = {s.span_id: s for s in spans}
+    out: Dict[str, float] = {}
+    for s in spans:
+        if s.kind not in ("phase", "dispatch"):
+            continue
+        if s.kind == "phase":
+            out[s.name] = out.get(s.name, 0.0) + s.duration_s
+        above = by_id.get(s.parent_id)
+        while above is not None and above.kind not in ("phase", "dispatch"):
+            above = by_id.get(above.parent_id)
+        if above is not None and above.kind == "phase":
+            out[above.name] = out.get(above.name, 0.0) - s.duration_s
+    return out

@@ -27,6 +27,11 @@ kind           opened around
 ``compile``    the FIRST dispatch of a freshly built program — the call that
                pays tracing + XLA compilation (program-cache misses)
 ``transfer``   a blocking ``jax.device_get`` readback; ``bytes`` attr
+``phase``      host work of a fit between its dispatches: ``fit.stats`` /
+               ``fit.prepare`` / ``fit.optimize`` / ``fit.finish`` in the
+               estimator, ``optim.iteration`` per turn of an optimizer's
+               host loop (its self time — duration less its ``dispatch``
+               children — is the host optimizer's own work)
 ``checkpoint`` ``TrainingCheckpointer`` save / commit / restore
 ``rebuild``    a ``MeshSupervisor.recover`` mesh rebuild
 ``instant``    zero-duration annotations: injected faults, step retries,
@@ -35,6 +40,17 @@ kind           opened around
                ``hbm.bytes_in_use`` / ``hbm.predicted_peak_bytes`` /
                ``flops.cumulative`` timelines from ``observe.costs``
 =============  ==============================================================
+
+One clock with the device: when the tracer carries an ``annotation``
+factory (``CycloneContext`` installs ``jax.profiler.TraceAnnotation``; this
+module never imports jax), every live span also enters
+``annotation("cyclone.<kind>.<name>")``, so a ``jax.profiler`` capture
+(``with ctx.profile(dir):``) holds the program's spans on the profiler's own
+clock, above the device operations. The annotation records only while a
+profiler session is open. :func:`instant`, :func:`counter` and
+:meth:`Tracer.record_span` emit nothing there: an annotation brackets a live
+region on one thread, and those are points or regions that already ended
+(possibly on another thread).
 
 Off by default with near-zero disabled cost: every instrumentation site
 performs ONE module-global read (the same pattern ``faults.inject`` uses)
@@ -119,17 +135,27 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
+#: what every span's event in a ``jax.profiler`` capture starts with (a
+#: benchmark finds ITS spans by its own prefix: this one is the program's)
+ANNOTATION_PREFIX = "cyclone."
+
 
 class _LiveSpan:
     """Context manager recording one span into its tracer."""
 
-    __slots__ = ("_tracer", "span")
+    __slots__ = ("_tracer", "span", "_annotation")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self.span = span
+        self._annotation = None
 
     def __enter__(self) -> "_LiveSpan":
+        factory = self._tracer.annotation
+        if factory is not None:
+            self._annotation = factory(
+                f"{ANNOTATION_PREFIX}{self.span.kind}.{self.span.name}")
+            self._annotation.__enter__()
         stack = self._tracer._stack()
         if stack and not self.span.parent_id:
             self.span.parent_id = stack[-1].span_id
@@ -148,6 +174,8 @@ class _LiveSpan:
         if stack and stack[-1] is self.span:
             stack.pop()
         self._tracer._record(self.span)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         return False
 
     def annotate(self, **attrs) -> None:
@@ -195,6 +223,11 @@ class Tracer:
     #: per-job profile rollups) run only under a FULL tracer — the flight
     #: ring records spans and nothing else.
     full = True
+
+    #: ``name -> context manager`` entered and exited with every live span
+    #: (None: spans stay on this tracer's clock only). The context sets
+    #: ``jax.profiler.TraceAnnotation``; tests set a recording fake.
+    annotation = None
 
     def __init__(self, max_spans: int = 100_000, registry=None):
         self.max_spans = max(1, int(max_spans))
@@ -263,7 +296,8 @@ class Tracer:
 
     def instant(self, name: str, **attrs) -> None:
         """Zero-duration annotation under the current span (faults,
-        retries, cache hits/misses)."""
+        retries, cache hits/misses). Not mirrored into a profiler capture
+        (see the module docstring)."""
         s = Span(f"s{next(self._ids)}", self.current_span_id(), "instant",
                  name, threading.get_ident(), attrs)
         s.t0 = s.t1 = time.perf_counter()
@@ -276,7 +310,9 @@ class Tracer:
         threads — the serving batcher times a request's queue phase on
         the submitting thread and its dispatch on the worker, then
         records one request span after the fact; a context-manager span
-        could not bracket that lifetime."""
+        could not bracket that lifetime. For the same reason it is not
+        mirrored into a profiler capture: the region is over, and not this
+        thread's."""
         s = Span(f"s{next(self._ids)}", parent, kind, name or kind,
                  threading.get_ident(), attrs)
         s.t0, s.t1 = t0, t1

@@ -170,9 +170,11 @@ def _pad_rows_cols(x, y, w, row_tile: int):
                               fixed_bytes=8 * 4 * d_pad)
     n_pad = _pad_to(max(n, row_tile), row_tile)
     if n_pad != n or d_pad != d:
-        x = jnp.pad(x, ((0, n_pad - n), (0, d_pad - d)))
-        y = jnp.pad(y, (0, n_pad - n))
-        w = jnp.pad(w, (0, n_pad - n))
+        with jax.named_scope("glm.prepare_x"):
+            x = jnp.pad(x, ((0, n_pad - n), (0, d_pad - d)))
+        with jax.named_scope("glm.prepare_vectors"):
+            y = jnp.pad(y, (0, n_pad - n))
+            w = jnp.pad(w, (0, n_pad - n))
     return x, y, w, n_pad, d_pad, row_tile
 
 
@@ -198,15 +200,15 @@ def fused_binary_logistic(x, y, w, coef, d: int, fit_intercept: bool = True,
     b0 = coef[d] if fit_intercept else jnp.zeros((), dtype)
 
     x, y, w, n_pad, d_pad, row_tile = _pad_rows_cols(x, y, w, row_tile)
-    beta_p = jnp.pad(beta, (0, d_pad - d)).reshape(1, d_pad)
+    with jax.named_scope("glm.prepare_vectors"):
+        beta_p = jnp.pad(beta, (0, d_pad - d)).reshape(1, d_pad)
     grid = (n_pad // row_tile,)
 
     kernel = functools.partial(
         _run_glm, kind="logistic", row_tile=row_tile, d_pad=d_pad,
         grid=grid, interpret=interpret,
         scale=None if x_scale is None else _pad_scale(x_scale, d, d_pad))
-    loss, grad_row, aux = kernel(x, y.reshape(-1, 1), w.reshape(-1, 1),
-                                 beta_p, b0, jnp.zeros((), dtype))
+    loss, grad_row, aux = kernel(x, y, w, beta_p, b0, jnp.zeros((), dtype))
     g = grad_row[0, :d]
     if fit_intercept:
         grad = jnp.concatenate([g, aux[0, 0][None]])
@@ -246,14 +248,14 @@ def fused_binary_logistic_scaled(x, y, w, inv_std, scaled_mean, coef,
     off = b0 - jnp.dot(scaled_mean, beta)
 
     x, y, w, n_pad, d_pad, row_tile = _pad_rows_cols(x, y, w, row_tile)
-    beta_p = jnp.pad(sb, (0, d_pad - d)).reshape(1, d_pad)
+    with jax.named_scope("glm.prepare_vectors"):
+        beta_p = jnp.pad(sb, (0, d_pad - d)).reshape(1, d_pad)
     grid = (n_pad // row_tile,)
     kernel = functools.partial(
         _run_glm, kind="logistic", row_tile=row_tile, d_pad=d_pad,
         grid=grid, interpret=interpret,
         scale=None if x_scale is None else _pad_scale(x_scale, d, d_pad))
-    loss, grad_row, aux = kernel(x, y.reshape(-1, 1), w.reshape(-1, 1),
-                                 beta_p, off, jnp.zeros((), dtype))
+    loss, grad_row, aux = kernel(x, y, w, beta_p, off, jnp.zeros((), dtype))
     msum = aux[0, 0]
     g = inv_std * grad_row[0, :d] - scaled_mean * msum
     if fit_intercept:
@@ -292,14 +294,14 @@ def fused_least_squares_scaled(x, y, w, inv_std, scaled_mean, y_pars, coef,
     off = y_pars[1] - jnp.dot(scaled_mean, coef)  # rides the b0 slot
 
     x, y, w, n_pad, d_pad, row_tile = _pad_rows_cols(x, y, w, row_tile)
-    beta_p = jnp.pad(sb, (0, d_pad - d)).reshape(1, d_pad)
+    with jax.named_scope("glm.prepare_vectors"):
+        beta_p = jnp.pad(sb, (0, d_pad - d)).reshape(1, d_pad)
     grid = (n_pad // row_tile,)
     kernel = functools.partial(
         _run_glm, kind="squared", row_tile=row_tile, d_pad=d_pad,
         grid=grid, interpret=interpret,
         scale=None if x_scale is None else _pad_scale(x_scale, d, d_pad))
-    loss, grad_row, aux = kernel(x, y.reshape(-1, 1), w.reshape(-1, 1),
-                                 beta_p, off, y_pars[0])
+    loss, grad_row, aux = kernel(x, y, w, beta_p, off, y_pars[0])
     msum = aux[0, 0]
     g = inv_std * grad_row[0, :d] - scaled_mean * msum
     return {"loss": loss[0, 0], "grad": g, "count": aux[0, 1]}
@@ -319,7 +321,7 @@ def _run_glm(x, y, w, beta_p, b0, ys, *, kind, row_tile, d_pad, grid,
     kernel byte-for-byte."""
     has_scale = scale is not None
 
-    def kern(*refs):
+    def glm_sweep(*refs):
         if has_scale:
             (b0_ref, ys_ref, x_ref, y_ref, w_ref, beta_ref, s_ref,
              loss_ref, grad_ref, aux_ref,
@@ -388,12 +390,22 @@ def _run_glm(x, y, w, beta_p, b0, ys, *, kind, row_tile, d_pad, grid,
         pl.BlockSpec((row_tile, 1), lambda i: (i, 0)),
         pl.BlockSpec((1, d_pad), lambda i: (0, 0)),      # beta
     ]
-    args = [b0.reshape(1, 1), ys.reshape(1, 1), x, y, w, beta_p]
+    with jax.named_scope("glm.prepare_vectors"):
+        # (n,) -> (n, 1): Mosaic rejects 1-D blocks (see glm_sweep); on the
+        # chip each reshape is a relayout pass over the vector
+        args = [b0.reshape(1, 1), ys.reshape(1, 1), x,
+                y.reshape(-1, 1), w.reshape(-1, 1), beta_p]
     if has_scale:
         in_specs.append(pl.BlockSpec((1, d_pad), lambda i: (0, 0)))
         args.append(scale)
-    outs = pl.pallas_call(
-        kern,
+    # named by kind: what a device trace, the Mosaic dump and a metric's
+    # pattern call this sweep
+    name = {"logistic": "glm_sweep_logistic",
+            "squared": "glm_sweep_least_squares"}[kind]
+    glm_sweep.__name__ = glm_sweep.__qualname__ = name
+    sweep = pl.pallas_call(
+        glm_sweep,
+        name=name,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -414,7 +426,9 @@ def _run_glm(x, y, w, beta_p, b0, ys, *, kind, row_tile, d_pad, grid,
         ],
         compiler_params=_compiler_params("arbitrary"),
         interpret=interpret,
-    )(*args)
+    )
+    with jax.named_scope("glm.sweep"):
+        outs = sweep(*args)
     return outs[:3]
 
 
@@ -455,7 +469,7 @@ def fused_kmeans_assign(x, centers, interpret: bool = False,
     has_scale = x_scale is not None
     s_p = _pad_scale(x_scale, d, d_pad) if has_scale else None
 
-    def kern(*refs):
+    def kmeans_assign(*refs):
         if has_scale:
             x_ref, c_ref, cn_ref, s_ref, best_ref, dist_ref = refs
         else:
@@ -484,7 +498,8 @@ def fused_kmeans_assign(x, centers, interpret: bool = False,
         in_specs.append(pl.BlockSpec((1, d_pad), lambda i: (0, 0)))
         args.append(s_p)
     best, dist = pl.pallas_call(
-        kern,
+        kmeans_assign,
+        name="kmeans_assign",
         grid=(n_pad // row_tile,),
         in_specs=in_specs,
         out_specs=[
@@ -547,7 +562,7 @@ def fused_gramian(x, w=None, interpret: bool = False,
     has_scale = x_scale is not None
     s_p = _pad_scale(x_scale, d, d_pad) if has_scale else None
 
-    def kern(*refs):
+    def gramian(*refs):
         if has_scale:
             x_ref, w_ref, s_ref, out_ref = refs
         else:
@@ -573,7 +588,8 @@ def fused_gramian(x, w=None, interpret: bool = False,
         in_specs.append(pl.BlockSpec((1, d_pad), lambda i: (0, 0)))
         args.append(s_p)
     g = pl.pallas_call(
-        kern,
+        gramian,
+        name="gramian",
         grid=(n_pad // row_tile,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((d_pad, d_pad), lambda i: (0, 0)),

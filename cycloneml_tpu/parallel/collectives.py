@@ -19,6 +19,7 @@ result — semantically identical to the reference's
 from __future__ import annotations
 
 import functools
+import re
 from typing import Any, Callable, Optional, Sequence
 
 from cycloneml_tpu import mesh as _mesh_mod
@@ -133,6 +134,16 @@ class BoundedProgramCache:
 
     def __len__(self) -> int:
         return len(self._d)
+
+
+def _name_program(program, prefix: str, fn) -> None:
+    """Name ``program`` ``<prefix>__<fn's name>`` before it is jitted: the
+    HLO module (``jit_<name>``), the ``op_name`` of every instruction and
+    the device trace carry the name, so an aggregation reads as what it
+    aggregates instead of as ``sharded``."""
+    inner = getattr(fn, "__name__", None) or type(fn).__name__
+    program.__name__ = program.__qualname__ = \
+        f"{prefix}__" + re.sub(r"\W", "_", inner)
 
 
 def _instrument_dispatch(jitted, name: str = "tree_aggregate", key=None,
@@ -333,11 +344,12 @@ def tree_aggregate(fn: Callable, runtime: MeshRuntime, *arrays,
         if not auto_psum:
             # fn performs its own collectives (e.g. pmax/pmin stats)
             return partial
-        return jax.tree_util.tree_map(
-            lambda t: psum_over_mesh(t, (DATA_AXIS, REPLICA_AXIS),
-                                     depth=depth), partial)
+        with jax.named_scope("tree_aggregate.psum"):
+            return jax.tree_util.tree_map(
+                lambda t: psum_over_mesh(t, (DATA_AXIS, REPLICA_AXIS),
+                                         depth=depth), partial)
 
-    def sharded(*all_args):
+    def program(*all_args):
         def local(*a):
             if with_state:
                 stats, rows = fn(*a)
@@ -349,8 +361,9 @@ def tree_aggregate(fn: Callable, runtime: MeshRuntime, *arrays,
         out_specs = (P(), row_spec) if with_state else P()
         return shard_map_compat(local, mesh, in_specs, out_specs)(*all_args)
 
+    _name_program(program, "tree_aggregate", fn)
     jitted = _instrument_dispatch(
-        jax.jit(sharded,
+        jax.jit(program,
                 donate_argnums=tuple(range(n_sharded)) if donate else ()),
         key=key, levels=reduction_levels(depth) if auto_psum else ())
     if key is not None:
@@ -382,14 +395,15 @@ def all_gather_hosts(runtime: MeshRuntime, fn: Callable, *arrays):
     mesh = runtime.mesh
     row_spec = P((REPLICA_AXIS, DATA_AXIS))
 
-    def sharded(*arrs):
+    def program(*arrs):
         def local(*a):
             v = fn(*a)
             v = jax.lax.all_gather(v, DATA_AXIS)
             return jax.lax.all_gather(v, REPLICA_AXIS).reshape((-1,) + v.shape[1:])
         return shard_map_compat(local, mesh, (row_spec,) * len(arrs), P())(*arrs)
 
-    return jax.jit(sharded)(*arrays)
+    _name_program(program, "all_gather_hosts", fn)
+    return jax.jit(program)(*arrays)
 
 
 def barrier(runtime: MeshRuntime) -> None:

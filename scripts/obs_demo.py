@@ -118,7 +118,6 @@ def main() -> int:
               f"hbm_peak_bytes={profile.hbm_peak_bytes} "
               f"achieved_flops={profile.achieved_flops} "
               f"intensity={profile.arithmetic_intensity} "
-              f"roofline={profile.roofline_fraction if profile.roofline_fraction is not None else 'unavailable'} "
               f"memory_stats="
               f"{'live' if profile.memory_stats_available else 'unavailable'}")
         if profile.total_flops is None or profile.total_flops <= 0:

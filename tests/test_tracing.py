@@ -414,3 +414,412 @@ def test_chaos_fault_lands_in_trace(ctx, tmp_path):
         assert prof.checkpoint_saves >= 1
     finally:
         tracing.disable()
+
+
+# -- one clock: spans mirrored into the profiler's capture -----------------------
+
+class _RecordingAnnotation:
+    """Stand-in for ``jax.profiler.TraceAnnotation``: logs enter/exit."""
+
+    def __init__(self, log):
+        self.log = log
+
+    def __call__(self, name):
+        log = self.log
+
+        class _Region:
+            def __enter__(self):
+                log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name, exc[0]))
+                return False
+        return _Region()
+
+
+def test_annotation_factory_sees_spans_in_nesting_order(tracer):
+    log = []
+    tracer.annotation = _RecordingAnnotation(log)
+    with tracing.span("job", "LogisticRegression.fit"):
+        with tracing.span("phase", "fit.optimize"):
+            with tracing.span("dispatch", "lbfgs.chunk"):
+                pass
+        with tracing.span("phase", "fit.finish"):
+            pass
+    names = ["cyclone.job.LogisticRegression.fit",
+             "cyclone.phase.fit.optimize", "cyclone.dispatch.lbfgs.chunk",
+             "cyclone.phase.fit.finish"]
+    assert [e[:2] for e in log] == [
+        ("enter", names[0]), ("enter", names[1]), ("enter", names[2]),
+        ("exit", names[2]), ("exit", names[1]), ("enter", names[3]),
+        ("exit", names[3]), ("exit", names[0])]
+    # a benchmark finds ITS window by its own prefix: never ours
+    assert all(n.startswith(tracing.ANNOTATION_PREFIX)
+               and not n.startswith("perfbench.") for _, n, *_ in log)
+    # the recorded spans are the same four, on the tracer's own clock
+    assert [s.name for s in tracer.snapshot()] == [
+        "lbfgs.chunk", "fit.optimize", "fit.finish",
+        "LogisticRegression.fit"]
+
+
+@pytest.mark.parametrize("emit", ["instant", "record_span", "counter"])
+def test_points_and_retroactive_spans_emit_no_annotation(tracer, emit):
+    """An annotation brackets a live region on one thread; an instant, a
+    counter sample and a span recorded after the fact are none."""
+    log = []
+    tracer.annotation = _RecordingAnnotation(log)
+    if emit == "instant":
+        tracing.instant("cache.miss", cache="program")
+    elif emit == "counter":
+        tracing.counter("hbm.bytes_in_use", 1.0)
+    else:
+        tracer.record_span("request", "predict", t0=1.0, t1=2.0)
+    assert len(tracer.snapshot()) == 1 and log == []
+
+
+def test_no_tracer_means_no_annotation():
+    """With no tracer the path is the unchanged shared no-op: a factory
+    left on a tracer that was uninstalled is never called."""
+    tracing.disable()
+    t = tracing.enable(max_spans=10)
+    log = []
+    t.annotation = _RecordingAnnotation(log)
+    tracing.disable()
+    with tracing.span("phase", "fit.prepare") as s:
+        assert s is tracing.NOOP_SPAN
+    assert log == []
+    # and a tracer nobody gave a factory records spans and calls nothing
+    t2 = tracing.enable(max_spans=10)
+    try:
+        assert t2.annotation is None
+        with tracing.span("phase", "fit.prepare"):
+            pass
+        assert len(t2.snapshot()) == 1
+    finally:
+        tracing.disable()
+
+
+def test_exception_inside_a_span_still_exits_its_annotation(tracer):
+    log = []
+    tracer.annotation = _RecordingAnnotation(log)
+    with pytest.raises(KeyError):
+        with tracing.span("phase", "fit.prepare"):
+            with tracing.span("dispatch", "loss.eval"):
+                raise KeyError("boom")
+    assert [e[0] for e in log] == ["enter", "enter", "exit", "exit"]
+    assert log[2][1] == "cyclone.dispatch.loss.eval" and \
+        log[3][1] == "cyclone.phase.fit.prepare"
+    assert log[2][2] is KeyError and log[3][2] is KeyError
+    assert tracing.current_span_id() == ""
+    assert [s.name for s in tracer.snapshot()] == ["loss.eval",
+                                                   "fit.prepare"]
+
+
+def test_spans_land_in_a_profiler_capture(ctx, tmp_path):
+    """The real factory: under ``ctx.profile`` a fit's spans are events of
+    the capture's host plane, the phases inside the job's window."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    ds, est = _phase_case(ctx, "logistic")
+    est.fit(ds)                                   # compile outside
+    tracing.disable()
+    tracer = tracing.enable(max_spans=10_000)
+    tracer.annotation = jax.profiler.TraceAnnotation
+    try:
+        with ctx.profile(str(tmp_path)):
+            est.fit(ds)
+    finally:
+        tracing.disable()
+    capture, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                             / "*.xplane.pb"))
+    data = ProfileData.from_file(capture)
+    events = {}
+    for plane in data.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("cyclone."):
+                        events.setdefault(ev.name, []).append(
+                            (ev.start_ns, ev.start_ns + ev.duration_ns))
+    (j0, j1), = events["cyclone.job.LogisticRegression.fit"]
+    for name in ("cyclone.phase.fit.stats", "cyclone.phase.fit.prepare",
+                 "cyclone.phase.fit.optimize", "cyclone.phase.fit.finish",
+                 "cyclone.phase.optim.iteration",
+                 "cyclone.dispatch.lbfgs.chunk",
+                 "cyclone.transfer.lbfgs.readback"):
+        assert name in events, sorted(events)
+        assert all(j0 <= s and e <= j1 for s, e in events[name]), name
+
+
+# -- phase spans over the fit path -----------------------------------------------
+
+def _phase_case(ctx, which):
+    """A dense fit of each estimator the benchmark's cells run: logistic
+    (device-resident L-BFGS: one chunk a dispatch) and elastic-net linear
+    regression (host OWL-QN: one dispatch an evaluation)."""
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    rng = np.random.RandomState(5)
+    x = rng.randn(4096, 24)
+    beta = rng.randn(24)
+    if which == "logistic":
+        from cycloneml_tpu.ml.classification import LogisticRegression
+        y = (x @ beta + 0.5 * rng.randn(4096) > 0).astype(np.float64)
+        est = LogisticRegression(maxIter=40, regParam=0.01)
+    else:
+        from cycloneml_tpu.ml.regression import LinearRegression
+        y = x @ beta + 0.5 * rng.randn(4096)
+        est = LinearRegression(maxIter=40, regParam=0.05,
+                               elasticNetParam=0.5)
+    return InstanceDataset.from_numpy(ctx, x, y), est
+
+
+def _traced_fit(ctx, which):
+    """``(model, job span, the job's spans)`` of a warm traced fit."""
+    ds, est = _phase_case(ctx, which)
+    est.fit(ds)            # compiles, and caches the dataset's moments
+    tracing.disable()
+    tracer = tracing.enable(max_spans=50_000)
+    try:
+        model = est.fit(ds)
+        spans = tracer.snapshot()
+    finally:
+        tracing.disable()
+    job = [s for s in spans if s.kind == "job"][-1]
+    by_id = {s.span_id: s for s in spans}
+
+    def under_job(s):
+        while s is not None and s is not job:
+            s = by_id.get(s.parent_id)
+        return s is job
+    return model, job, [s for s in spans if under_job(s)], by_id
+
+
+def _ancestors(s, by_id):
+    s = by_id.get(s.parent_id)
+    while s is not None:
+        yield s
+        s = by_id.get(s.parent_id)
+
+
+@pytest.mark.parametrize("which", ["logistic", "linreg_enet"])
+def test_phase_spans_cover_the_fit(ctx, which):
+    model, job, spans, by_id = _traced_fit(ctx, which)
+    phases = [s for s in spans if s.kind == "phase"]
+    names = {s.name for s in phases}
+    assert names == {"fit.stats", "fit.prepare", "fit.optimize",
+                     "fit.finish", "optim.iteration"}
+    # every phase hangs off the job span run_job opened
+    assert all(job in _ancestors(s, by_id) for s in phases)
+    stats, = [s for s in phases if s.name == "fit.stats"]
+    assert stats.attrs == {"cached": True}        # second fit of one ds
+    opt, = [s for s in phases if s.name == "fit.optimize"]
+    assert opt.attrs["optimizer"] == ("DeviceLBFGS" if which == "logistic"
+                                      else "OWLQN")
+    # the fit.* phases tile the job: their union covers >= 95 % of it
+    covered, at = 0.0, job.t0
+    for s in sorted((s for s in phases if s.name.startswith("fit.")),
+                    key=lambda s: s.t0):
+        assert s.t0 >= at - 1e-9, "fit.* phases overlap"
+        covered += s.t1 - s.t0
+        at = s.t1
+    assert covered >= 0.95 * job.duration_s, (covered, job.duration_s)
+    # one optim.iteration per turn: a chunk (device) / the initial
+    # evaluation plus every iteration (host)
+    turns = [s for s in phases if s.name == "optim.iteration"]
+    summary = model.summary
+    want = summary.total_dispatches if which == "logistic" \
+        else summary.total_iterations + 1
+    assert len(turns) == want and want >= 2 - (which == "logistic")
+    assert [s.attrs["iteration"] for s in turns] == sorted(
+        s.attrs["iteration"] for s in turns)
+    assert all(opt in _ancestors(s, by_id) for s in turns)
+    # and every dispatch of the optimiser happens inside a turn
+    dispatches = [s for s in spans if s.kind == "dispatch"]
+    assert len(dispatches) == summary.total_dispatches
+    for dsp in dispatches:
+        assert any(a in turns for a in _ancestors(dsp, by_id)), dsp
+
+
+@pytest.mark.parametrize("which", ["logistic", "linreg_enet"])
+def test_fit_profile_phase_seconds_are_the_hosts_side(ctx, which):
+    _, job, spans, _ = _traced_fit(ctx, which)
+    prof = FitProfile.from_spans(spans, root_id=job.span_id)
+    assert set(prof.phase_seconds) == {
+        "fit.stats", "fit.prepare", "fit.optimize", "fit.finish",
+        "optim.iteration"}
+    assert all(v >= 0.0 for v in prof.phase_seconds.values())
+    host = prof.wall_seconds - prof.dispatch_seconds
+    assert sum(prof.phase_seconds.values()) == pytest.approx(host, rel=0.05)
+    # fit.optimize's own time is what is left outside its turns
+    assert prof.phase_seconds["fit.optimize"] < \
+        0.5 * sum(s.duration_s for s in spans if s.name == "fit.optimize")
+    assert FitProfile.from_dict(prof.to_dict()).phase_seconds == \
+        prof.phase_seconds
+
+
+def test_phase_self_time_takes_nested_spans_off_once(tracer):
+    """Hand-built tree: a dispatch nested in a dispatch is its parent's
+    time already; a phase in a phase comes off the outer one."""
+    def rec(kind, name, t0, t1, parent=""):
+        return tracer.record_span(kind, name, t0=t0, t1=t1,
+                                  parent=parent).span_id
+    job = rec("job", "fit", 0.0, 10.0)
+    outer = rec("phase", "fit.optimize", 1.0, 9.0, job)
+    turn = rec("phase", "optim.iteration", 2.0, 8.0, outer)
+    d = rec("dispatch", "lbfgs.stacked_host", 3.0, 7.0, turn)
+    rec("dispatch", "loss.eval", 4.0, 5.0, d)
+    coll = rec("collective", "tree_aggregate", 2.0, 2.5, turn)
+    rec("dispatch", "loss.eval", 2.1, 2.4, coll)     # through a non-phase
+    prof = FitProfile.from_spans(tracer.snapshot(), root_id=job)
+    assert prof.phase_seconds == pytest.approx(
+        {"fit.optimize": 2.0, "optim.iteration": 6.0 - 4.0 - 0.3})
+
+
+def _abandon_cases():
+    from cycloneml_tpu.ml.optim.lbfgs import LBFGS, LBFGSB, OWLQN
+    return {"LBFGS": lambda: LBFGS(max_iter=20),
+            "OWLQN": lambda: OWLQN(max_iter=20, l1_reg=0.01),
+            "LBFGSB": lambda: LBFGSB(-np.ones(3), np.ones(3), max_iter=20)}
+
+
+@pytest.mark.parametrize("name", ["LBFGS", "OWLQN", "LBFGSB", "DeviceLBFGS"])
+def test_abandoned_iterations_leave_the_span_stack_empty(ctx, tracer, name):
+    """A consumer that stops reading ``iterations()`` mid-run (or raises
+    while holding a yielded state) must find the thread's span stack as it
+    left it: no turn's span is open across a yield."""
+    if name == "DeviceLBFGS":
+        from cycloneml_tpu.dataset.dataset import InstanceDataset
+        from cycloneml_tpu.ml.optim import aggregators
+        from cycloneml_tpu.ml.optim.device_lbfgs import DeviceLBFGS
+        from cycloneml_tpu.ml.optim.loss import DistributedLossFunction
+        rng = np.random.RandomState(1)
+        x = rng.randn(256, 3)
+        y = (x @ np.array([1.0, -2.0, 0.5]) > 0).astype(np.float64)
+        f = DistributedLossFunction(
+            InstanceDataset.from_numpy(ctx, x, y),
+            aggregators.binary_logistic(3, fit_intercept=False))
+        opt = DeviceLBFGS(max_iter=20, chunk=2)
+    else:
+        def f(v):
+            return float(np.sum((v - 0.3) ** 4 + (v - 0.3) ** 2)), \
+                4 * (v - 0.3) ** 3 + 2 * (v - 0.3)
+        opt = _abandon_cases()[name]()
+    with tracing.span("job", "abandoned") as job:
+        gen = opt.iterations(f, np.zeros(3))
+        seen = []
+        for state in gen:
+            # between yields only the caller's own span is open
+            assert tracing.current_span_id() == job.span_id
+            seen.append(state.iteration)
+            if len(seen) == 3:
+                break
+        gen.close()
+        assert tracing.current_span_id() == job.span_id
+    assert tracing.current_span_id() == ""
+    turns = [s for s in tracer.snapshot() if s.name == "optim.iteration"]
+    assert turns and all(s.parent_id == job.span_id and s.t1 >= s.t0 > 0
+                         for s in turns)
+
+
+# -- names on the device ---------------------------------------------------------
+
+def test_aggregation_program_is_named_after_its_aggregator(ctx):
+    import jax.numpy as jnp
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    from cycloneml_tpu.ml.optim import aggregators
+    from cycloneml_tpu.ml.stat.summarizer import _get_moments_fn
+    rng = np.random.RandomState(2)
+    ds = InstanceDataset.from_numpy(ctx, rng.randn(64, 5),
+                                    (rng.rand(64) > 0.5).astype(float))
+    call = ds.tree_aggregate_fn(aggregators.binary_logistic_scaled(5, True))
+    extras = (jnp.ones(5), jnp.zeros(5), jnp.zeros(6))
+    text = call.compiled.__wrapped__.lower(
+        *call.arrays(), *extras).as_text(debug_info=True)
+    assert "jit_tree_aggregate__binary_logistic_scaled" in text
+    assert "tree_aggregate.psum" in text
+    assert "jit_sharded" not in text and ".sharded" not in text
+    moments = ds.tree_aggregate_fn(_get_moments_fn(), auto_psum=False)
+    text = moments.compiled.__wrapped__.lower(
+        *moments.arrays()).as_text(debug_info=True)
+    assert "jit_tree_aggregate__summarizer_moments" in text
+    assert "summarizer.moments" in text
+
+
+@pytest.mark.parametrize("kind,name", [
+    ("logistic", "glm_sweep_logistic"),
+    ("squared", "glm_sweep_least_squares")])
+def test_glm_kernel_and_scopes_are_named_in_the_tpu_lowering(kind, name):
+    """Lowered FOR the TPU from here (no chip): the Mosaic call carries the
+    kernel's name, and the pad of X, the vector reshapes and the sweep sit
+    under their scopes."""
+    import jax
+    import jax.numpy as jnp
+    from cycloneml_tpu.ml.optim import aggregators
+    n, d = 512, 200
+    x = jnp.zeros((n, d), jnp.bfloat16)
+    v, z = jnp.zeros(n, jnp.float32), jnp.zeros(d, jnp.float32)
+    if kind == "logistic":
+        agg = aggregators.binary_logistic_pallas_scaled(d, True)
+        args = (x, v, v, z, z, jnp.zeros(d + 1, jnp.float32))
+    else:
+        agg = aggregators.least_squares_pallas_scaled(d)
+        args = (x, v, v, z, z, jnp.zeros(2, jnp.float32), z)
+    text = jax.jit(agg).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "tpu_custom_call" in text and name in text
+    for scope in ("glm.prepare_x", "glm.prepare_vectors", "glm.sweep"):
+        assert scope in text, scope
+    assert "kern" not in text.replace("kernel", "")
+
+
+def test_chunk_and_line_search_programs_are_named(ctx):
+    import jax.numpy as jnp
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    from cycloneml_tpu.dataset.instance import compute_dtype
+    from cycloneml_tpu.ml.optim import aggregators
+    from cycloneml_tpu.ml.optim.device_lbfgs import _build_chunk
+    from cycloneml_tpu.ml.optim.loss import _build_line_search
+    rng = np.random.RandomState(3)
+    d = 4
+    ds = InstanceDataset.from_numpy(ctx, rng.randn(64, d),
+                                    (rng.rand(64) > 0.5).astype(float))
+    call = ds.tree_aggregate_fn(aggregators.binary_logistic(d, False))
+    cdt = np.dtype(compute_dtype())
+    arrays = call.arrays()
+    chunk = _build_chunk(call.compiled, None, 3, 2, 1e-4, 0.9, 5, cdt,
+                         n_arrays=len(arrays))
+    c = jnp.zeros(d, cdt)
+    hist = jnp.zeros((3, d), cdt)
+    one = cdt.type(1.0)
+    text = chunk.lower(*arrays, c, hist, hist, jnp.int32(0), one, c,
+                       np.bool_(True), one, one, one, np.int32(2),
+                       np.bool_(True)).as_text(debug_info=True)
+    assert "jit_lbfgs_chunk" in text
+    for scope in ("lbfgs.direction", "lbfgs.line_search", "lbfgs.update",
+                  "tree_aggregate.psum"):
+        assert scope in text, scope
+    search = _build_line_search(call.compiled, None, 1e-4, 0.9, 5, cdt)
+    text = search.lower(*arrays, c, c, one, one, one, one).as_text()
+    assert "jit_lbfgs_line_search" in text
+
+
+# -- counters at the boundary ----------------------------------------------------
+
+def test_linear_regression_summary_counts_what_the_step_counter_counts(ctx):
+    """The benchmark's entry reads the delta of the context's
+    ``steps.completed`` counter today; the summary now carries the same
+    counts itself."""
+    ds, est = _phase_case(ctx, "linreg_enet")
+    counter = ctx.metrics.registry.counter("steps.completed")
+    before = counter.count
+    model = est.fit(ds)
+    steps = counter.count - before
+    s = model.summary
+    assert s.total_evals == s.total_dispatches == steps
+    assert s.total_evals > s.total_iterations >= 1
+    # a solver that evaluates no loss function says so
+    from cycloneml_tpu.ml.regression import LinearRegression
+    normal = LinearRegression(solver="normal").fit(ds).summary
+    assert normal.total_evals is None and normal.total_dispatches is None
