@@ -114,6 +114,10 @@ def assert_fit_in_core(model, what: str) -> list:
           f"{what}: objective history increases: {hist}")
     check(np.all(np.isfinite(np.asarray(model.coefficients))),
           f"{what}: non-finite coefficients")
+    # d = 1,280 is stored row-major ({1,0}): the fused sweep keeps the
+    # row-major tiling, and the XLA sweep names none
+    check(s.orientation == ("row_major" if "pallas" in what else None),
+          f"{what}: the fit ran the {s.orientation} sweep at d = {N_COLS}")
     return hist
 
 
@@ -343,6 +347,46 @@ def kernel_matrix() -> dict:
                    lambda x, s=scale: {"g": kernels.fused_gramian(
                        x, w, x_scale=s)},
                    (xs,), {"g": xv.T @ xv})
+
+    # the feature-major tiling: arrays XLA:TPU stores with the rows on the
+    # lanes (a width that is no multiple of 128, and enough rows that
+    # padding THEM to 128 wastes less: a (4104, 2000) array is still stored
+    # row-major), read as they lie — d whole on the sublanes, 16392 rows =
+    # full lane tiles + a masked tail of 8
+    n = 16392
+    for d_odd, tiers in ((2000, ("float32", "bfloat16", "float8")),
+                         (28, ("bfloat16",))):
+        for tier in tiers:
+            x32 = rng.standard_normal((n, d_odd), dtype=np.float32)
+            xs, scale, xv = stored(x32, tier)
+            check(kernels.stored_feature_major(xs),
+                  f"a ({n}, {d_odd}) {tier} array is not stored "
+                  f"feature-major: {xs.format}")
+            y = (rng.random(n) > 0.5).astype(np.float32)
+            w = (0.5 + rng.random(n)).astype(np.float32)
+            inv_std = (1.0 + rng.random(d_odd)).astype(np.float32)
+            mean = (0.1 * rng.standard_normal(d_odd)).astype(np.float32)
+            coef = (0.05 * rng.standard_normal(d_odd + 1)).astype(np.float32)
+            xh = xv * inv_std - mean
+            m = xh @ coef[:d_odd].astype(np.float64) + coef[d_odd]
+            mult = w * (1.0 / (1.0 + np.exp(-m)) - y)
+            record(f"logistic_feature_major/{tier}/n={n},d={d_odd}",
+                   lambda x, s=scale: kernels.fused_binary_logistic_scaled(
+                       x, y, w, inv_std, mean, coef, d_odd, True, x_scale=s,
+                       feature_major=True),
+                   (xs,), {"loss": np.sum(w * (np.logaddexp(0.0, m) - y * m)),
+                           "grad": np.append(xh.T @ mult, mult.sum()),
+                           "count": w.sum()})
+            yr = (xv @ rng.standard_normal(d_odd) / 30.0).astype(np.float32)
+            y_pars = np.asarray([0.5, 0.2], np.float32)
+            c = coef[:d_odd]
+            err = xh @ c.astype(np.float64) + y_pars[1] - y_pars[0] * yr
+            record(f"least_squares_feature_major/{tier}/n={n},d={d_odd}",
+                   lambda x, s=scale: kernels.fused_least_squares_scaled(
+                       x, yr, w, inv_std, mean, y_pars, c, d_odd, x_scale=s,
+                       feature_major=True),
+                   (xs,), {"loss": 0.5 * np.sum(w * err * err),
+                           "grad": xh.T @ (w * err)})
 
     # stacked fits vmap the GLM kernel (labels on axis 1 of an (n, K) bf16
     # stack, coefficients on axis 0)

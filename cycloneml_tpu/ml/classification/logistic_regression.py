@@ -351,7 +351,10 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
         # the vmapped jnp aggregator is the fallback
         from cycloneml_tpu.dataset.instance import compute_dtype
         from cycloneml_tpu.ops.kernels import use_fused_kernels
-        base_agg = (aggregators.binary_logistic_pallas_scaled(d, fit_intercept)
+        # row-major tiling whatever X's layout: the feature-major sweep
+        # is not proved under vmap yet
+        base_agg = (aggregators.binary_logistic_pallas_scaled(
+                        d, fit_intercept, feature_major=False)
                     if use_fused_kernels(ds.ctx)
                     else aggregators.binary_logistic_scaled(d, fit_intercept))
         agg = aggregators.stack_scaled_aggregator(base_agg)
@@ -495,8 +498,8 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
             x0[:, d] = np.where(ok, np.log(p1 / (1.0 - p1)), 0.0)
 
         from cycloneml_tpu.ops.kernels import use_fused_kernels
-        base_agg = (aggregators.binary_logistic_pallas_scaled(d,
-                                                              fit_intercept)
+        base_agg = (aggregators.binary_logistic_pallas_scaled(
+                        d, fit_intercept, feature_major=False)
                     if use_fused_kernels(sds.ctx)
                     else aggregators.binary_logistic_scaled(d, fit_intercept))
         agg = aggregators.stack_scaled_aggregator(base_agg)
@@ -749,6 +752,7 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
             # accumulation; the XLA-fused jnp aggregator stays as the fallback
             # (and the only path on CPU, where the interpreter is for tests)
             use_pallas = (not is_multinomial) and use_fused_kernels(ds.ctx)
+            orientation = None  # tiling of the fused sweep, if it runs
             # EVERY fit path folds standardization (and fitWithMean centering)
             # INTO the aggregator read — no standardized copy exists anywhere:
             # replicated binomial/multinomial since r4; the feature-sharded TP
@@ -783,8 +787,18 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
                     standardize=standardize) if l2 > 0 else None
             else:
                 if use_pallas:
+                    # the sweep's tiling follows the way X is stored (a
+                    # streamed fit's shards are staged per dispatch: no
+                    # resident array to observe, row-major as before)
+                    from cycloneml_tpu.ops.kernels import \
+                        glm_sweep_orientation
+                    tiling = "row_major" if streamed else \
+                        glm_sweep_orientation(ds.x, fp8_scale is not None)
                     agg = aggregators.binary_logistic_pallas_scaled(
-                        d, fit_intercept)
+                        d, fit_intercept,
+                        feature_major=tiling == "feature_major")
+                    if not tp_active:   # the TP program has its own sweep
+                        orientation = tiling
                 else:
                     agg = aggregators.binary_logistic_scaled(d, fit_intercept)
                 n_coef = d + (1 if fit_intercept else 0)
@@ -952,7 +966,7 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
                 total_iterations=state.iteration,
                 total_evals=loss_fn.n_evals,
                 total_dispatches=loss_fn.n_dispatches,
-                streamed=streamed)
+                streamed=streamed, orientation=orientation)
             return model
 
     def copy(self, extra=None) -> "LogisticRegression":
@@ -1075,7 +1089,7 @@ class LogisticRegressionTrainingSummary:
 
     def __init__(self, objective_history, total_iterations,
                  total_evals=None, total_dispatches=None, n_models=1,
-                 streamed=False):
+                 streamed=False, orientation=None):
         self.objective_history = objective_history
         self.total_iterations = total_iterations
         # optimizer-path telemetry: loss/grad evaluations and host->device
@@ -1090,6 +1104,10 @@ class LogisticRegressionTrainingSummary:
         # explicitly (oocore.mode=force / a StreamingDataset input) or by
         # budget-guard degradation; dispatches then count SHARD dispatches
         self.streamed = streamed
+        # tiling of the fused GLM sweep the fit ran ("feature_major" /
+        # "row_major", ops/kernels.glm_sweep_orientation); None when the
+        # sweep was not the fused kernel
+        self.orientation = orientation
 
 
 class BinaryLogisticRegressionSummary:

@@ -424,42 +424,63 @@ def autodiff_check(agg_loss_only: Callable, d: int):
     return jax.grad(agg_loss_only)
 
 
-def binary_logistic_pallas_scaled(d: int, fit_intercept: bool = True) -> Agg:
+def binary_logistic_pallas_scaled(d: int, fit_intercept: bool = True,
+                                  feature_major=None) -> Agg:
     """Pallas twin of :func:`binary_logistic_scaled`: raw feature blocks,
     standardization folded around the kernel's row pass
     (ops/kernels.fused_binary_logistic_scaled) — the kernel path no longer
-    needs the standardized copy either."""
-    return _binary_logistic_pallas_scaled(d, fit_intercept)
+    needs the standardized copy either.
+
+    ``feature_major`` is the tiling of the sweep (ops/kernels: rows on the
+    lanes, for an X stored that way). An estimator passes what it observed
+    on its X (``kernels.glm_sweep_orientation``); ``None`` — a caller with
+    no array in hand — means what the device's default layout gives an
+    ``(n, d)`` array, so asking again with the same ``d`` returns the very
+    aggregator a fit of a default-layout X used (one program per
+    orientation in the program cache, keyed by this function's identity)."""
+    return _binary_logistic_pallas_scaled(
+        d, fit_intercept, _feature_major(d, feature_major))
+
+
+def _feature_major(d: int, feature_major) -> bool:
+    if feature_major is None:
+        from cycloneml_tpu.ops.kernels import default_feature_major
+        return default_feature_major(d)
+    return bool(feature_major)
 
 
 @functools.lru_cache(maxsize=None)
-def _binary_logistic_pallas_scaled(d: int, fit_intercept: bool) -> Agg:
+def _binary_logistic_pallas_scaled(d: int, fit_intercept: bool,
+                                   feature_major: bool) -> Agg:
     from cycloneml_tpu.ops.kernels import fused_binary_logistic_scaled
 
     @_named("binary_logistic_pallas_scaled")
     def agg(x, y, w, inv_std, scaled_mean, coef):
         return fused_binary_logistic_scaled(
-            x, y, w, inv_std, scaled_mean, coef, d, fit_intercept)
+            x, y, w, inv_std, scaled_mean, coef, d, fit_intercept,
+            feature_major=feature_major)
 
     return agg
 
 
-def least_squares_pallas_scaled(d: int) -> Agg:
+def least_squares_pallas_scaled(d: int, feature_major=None) -> Agg:
     """Pallas twin of :func:`least_squares_scaled`: the residual sweep
     (margin → err → loss/mult/grad) runs as one VMEM-resident row pass
     (ops/kernels.fused_least_squares_scaled); standardization and the
     label scaling are algebra outside it, so the kernel reads the raw
-    data-tier blocks exactly once per evaluation."""
-    return _least_squares_pallas_scaled(d)
+    data-tier blocks exactly once per evaluation. ``feature_major``: as
+    :func:`binary_logistic_pallas_scaled`."""
+    return _least_squares_pallas_scaled(d, _feature_major(d, feature_major))
 
 
 @functools.lru_cache(maxsize=None)
-def _least_squares_pallas_scaled(d: int) -> Agg:
+def _least_squares_pallas_scaled(d: int, feature_major: bool) -> Agg:
     from cycloneml_tpu.ops.kernels import fused_least_squares_scaled
 
     @_named("least_squares_pallas_scaled")
     def agg(x, y, w, inv_std, scaled_mean, y_pars, coef):
         return fused_least_squares_scaled(
-            x, y, w, inv_std, scaled_mean, y_pars, coef, d)
+            x, y, w, inv_std, scaled_mean, y_pars, coef, d,
+            feature_major=feature_major)
 
     return agg

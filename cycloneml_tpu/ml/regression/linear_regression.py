@@ -207,7 +207,7 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
             # is divided by the label std (ref LinearRegression.scala:396
             # effectiveRegParam = regParam / yStd; WeightedLeastSquares.scala:209)
             eff_reg = reg / y_std
-        coef, icpt, history, loss_fn = self._solve_quasi_newton(
+        coef, icpt, history, loss_fn, orientation = self._solve_quasi_newton(
             ds, stats, y_mean, y_std, eff_reg, alpha)
 
         with tracing.span("phase", "fit.finish"):
@@ -217,7 +217,8 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
             model.summary = LinearRegressionTrainingSummary(
                 history, max(len(history) - 1, 0),
                 total_evals=loss_fn.n_evals,
-                total_dispatches=loss_fn.n_dispatches, streamed=streamed)
+                total_dispatches=loss_fn.n_dispatches, streamed=streamed,
+                orientation=orientation)
             return model
 
     # -- quasi-Newton in doubly standardized space -----------------------------
@@ -253,9 +254,20 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
             fp8_scale = getattr(ds, "x_scale", None)
             inv_std_agg = inv_std * fp8_scale if fp8_scale is not None \
                 else inv_std
-            agg = (aggregators.least_squares_pallas_scaled(d)
-                   if use_fused_kernels(ds.ctx)
-                   else aggregators.least_squares_scaled(d))
+            from cycloneml_tpu.oocore import StreamingDataset
+            streamed = isinstance(ds, StreamingDataset)
+            orientation = None
+            if use_fused_kernels(ds.ctx):
+                # the sweep's tiling follows the way X is stored (a
+                # streamed fit's shards are staged per dispatch: no
+                # resident array to observe, row-major as before)
+                from cycloneml_tpu.ops.kernels import glm_sweep_orientation
+                orientation = "row_major" if streamed else \
+                    glm_sweep_orientation(ds.x, fp8_scale is not None)
+                agg = aggregators.least_squares_pallas_scaled(
+                    d, feature_major=orientation == "feature_major")
+            else:
+                agg = aggregators.least_squares_scaled(d)
 
             l2 = (1.0 - alpha) * reg
             l1 = alpha * reg
@@ -264,8 +276,7 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
             extras = (jnp.asarray(inv_std_agg.astype(adt)),
                       jnp.asarray(scaled_mean.astype(adt)),
                       jnp.asarray(y_pars.astype(adt)))
-            from cycloneml_tpu.oocore import StreamingDataset
-            if isinstance(ds, StreamingDataset):
+            if streamed:
                 # the streamed twin: same scaled aggregator, same extras —
                 # each loss/grad evaluation is one double-buffered epoch
                 from cycloneml_tpu.oocore import StreamingLossFunction
@@ -303,7 +314,7 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
             beta_hat = state.x  # standardized-space coefficients
             coef = beta_hat * inv_std * y_std
             icpt = y_mean - float(coef @ x_mean) if fit_intercept else 0.0
-        return coef, icpt, list(state.loss_history), loss_fn
+        return coef, icpt, list(state.loss_history), loss_fn, orientation
 
 
 class LinearRegressionModel(PredictionModel, _LinearRegressionParams,
@@ -358,7 +369,8 @@ class LinearRegressionModel(PredictionModel, _LinearRegressionParams,
 
 class LinearRegressionTrainingSummary:
     def __init__(self, objective_history, total_iterations,
-                 total_evals=None, total_dispatches=None, streamed=False):
+                 total_evals=None, total_dispatches=None, streamed=False,
+                 orientation=None):
         self.objective_history = objective_history
         self.total_iterations = total_iterations
         # optimizer-path telemetry, as LogisticRegressionTrainingSummary
@@ -369,3 +381,7 @@ class LinearRegressionTrainingSummary:
         self.total_dispatches = total_dispatches
         # True when the fit ran on the out-of-core streaming engine
         self.streamed = streamed
+        # tiling of the fused GLM sweep the fit ran ("feature_major" /
+        # "row_major", ops/kernels.glm_sweep_orientation); None when the
+        # sweep was not the fused kernel
+        self.orientation = orientation

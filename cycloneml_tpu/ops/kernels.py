@@ -20,8 +20,19 @@ and the Gramian ARE the dense sweep on a TPU backend, and the XLA-fused
 opt-in (``true``) only. Which of the twins is faster at which shape is not
 measured on the current machine (ROADMAP S8/D3).
 
-All wrappers pad rows to the tile size and features to the 128-lane
-boundary and lower to Mosaic. ``interpret=True`` runs the same kernel body
+The KMeans and Gramian wrappers pad rows to the tile size and features to
+the 128-lane boundary. The GLM sweep has two tilings of one body, chosen by
+the way X is stored (``stored_feature_major``): *row-major* — ``(row_tile,
+d_pad)`` blocks, d on the lanes, padded to the 128-lane boundary only when
+d is no multiple of 128 — and *feature-major* — ``(d, lane_tile)`` blocks of
+the ``(d, n)`` view, rows on the lanes, d whole on the sublanes, no pad on
+either axis. XLA:TPU stores a 2-D array whose minor dimension is no multiple
+of 128 feature-major (``{0,1}``) once it has enough rows that padding THEM
+to the lanes wastes less than padding the width (observed on the v5e:
+(100000, 2000) and (4096, 200) yes, (4104, 2000) no), so for such an X the
+row-major tiling costs a layout copy AND a lane pad of all of X per
+evaluation, and the feature-major tiling costs nothing: ``x.T`` is a
+bitcast. All lower to Mosaic. ``interpret=True`` runs the same kernel body
 in the Pallas interpreter — that is the tests' choice to make (they pass
 it explicitly); nothing in the package selects it, so a wrapper reached on
 a backend that cannot lower Mosaic raises instead of silently interpreting.
@@ -29,7 +40,6 @@ a backend that cannot lower Mosaic raises instead of silently interpreting.
 
 from __future__ import annotations
 
-import functools
 from typing import Dict
 
 import jax
@@ -99,6 +109,12 @@ def _storage_width(x):
     return x.astype(jnp.float32)
 
 
+def _storage_dtype(dtype):
+    """The dtype :func:`_storage_width` leaves an array of ``dtype`` in."""
+    from cycloneml_tpu.dataset.instance import is_narrow_dtype
+    return np.dtype(dtype) if is_narrow_dtype(dtype) else np.dtype(np.float32)
+
+
 def _pad_to(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
@@ -144,6 +160,69 @@ def _auto_row_tile(n: int, row_tile: int, dtype, row_bytes: int,
     return (aligned or fits)[0]
 
 
+def stored_feature_major(x) -> bool:
+    """Whether the concrete 2-D array ``x`` sits in device memory with its
+    FIRST dimension minor — rows on the lanes, XLA's ``{0,1}`` — which is
+    XLA:TPU's default for a tall array whose width is no multiple of 128
+    (epsilon's 2,000, HIGGS's 28). That is the layout the feature-major
+    GLM sweep reads as it lies. Anything that is not a committed 2-D
+    ``jax.Array`` whose layout can be read (numpy arrays, tracers, host-
+    platform arrays) answers False: the row-major sweep is right for
+    them."""
+    if not isinstance(x, jax.Array) or isinstance(x, jax.core.Tracer) \
+            or x.ndim != 2:
+        return False
+    try:
+        if next(iter(x.sharding.device_set)).platform == "cpu":
+            return False
+        order = x.format.layout.major_to_minor
+    except Exception:  # no layout to read (deleted, abstract, older PJRT)
+        return False
+    return tuple(order) == (1, 0)
+
+
+def default_feature_major(d: int) -> bool:
+    """The orientation XLA:TPU's DEFAULT layout gives a tall ``(n, d)``
+    array — what :func:`stored_feature_major` observes on a dataset-sized
+    array nobody laid out by hand (a few thousand rows can still be stored
+    row-major: the compiler pads whichever axis wastes less). For callers
+    that ask for an aggregator with no array in hand; the estimators pass
+    what they observed, and a wrong guess costs a layout copy, never a
+    result."""
+    return pallas_available() and d % LANE != 0
+
+
+def glm_sweep_orientation(x, has_scale: bool = False) -> str:
+    """The tiling the fused GLM sweep takes for the concrete X of a fit:
+    ``"feature_major"`` when X is stored that way AND a shard's ``(d,
+    lane_tile)`` block can be built, else ``"row_major"`` — what the
+    estimators hand their aggregator and write on their summary."""
+    if stored_feature_major(x):
+        rows, d = x.sharding.shard_shape(x.shape)
+        if _auto_lane_tile(rows, d, _storage_dtype(x.dtype),
+                           has_scale) is not None:
+            return "feature_major"
+    return "row_major"
+
+
+def _auto_lane_tile(n: int, d: int, dtype, has_scale: bool):
+    """Lane tile of the feature-major sweep — how many ROWS of X one
+    ``(d, T)`` block holds on the lanes — or None when that block cannot
+    be built. Candidates are 1024/512/256/128; the largest whose working
+    set fits the budget and that does not exceed n wins: the double-
+    buffered storage-width block plus three ``(d, T)`` f32 temporaries
+    (upcast, x∘β, mult∘x), and the fixed ``(d, 128)`` blocks (grad,
+    compensation: double-buffered outputs; the lane-padded ``(d, 1)`` β
+    and scale). Fewer than 128 rows, or a d so large that not even a
+    128-lane tile fits, is the row-major path's."""
+    fixed = 4 * d * LANE * (4 + 2 * (2 if has_scale else 1)) + 4 * d * LANE
+    per_lane = d * (2 * np.dtype(dtype).itemsize + 12) + 16 * 8 * 4
+    for t in (1024, 512, 256, 128):
+        if t <= n and fixed + t * per_lane <= _VMEM_BUDGET:
+            return t
+    return None
+
+
 def _pad_scale(scale, d: int, d_pad: int):
     """Per-column fp8 dequant scales as a (1, d_pad) f32 block (padding
     columns carry 1.0 — their x entries are zero anyway)."""
@@ -154,10 +233,13 @@ def _pad_scale(scale, d: int, d_pad: int):
 
 
 def _pad_rows_cols(x, y, w, row_tile: int):
-    """Zero-pad rows to the tile multiple and features to the lane multiple;
-    padding rows carry w=0 so they contribute nothing to any sum. The row
-    tile is re-chosen to DIVIDE n when possible (see _auto_row_tile) and
-    returned — row padding copies the whole X operand otherwise."""
+    """Row-major tiling's operands: zero-pad rows to the tile multiple and
+    features to the lane multiple (nothing when d is a multiple of 128 and
+    a tile divides n); padding rows carry w=0 so they contribute nothing to
+    any sum. The row tile is re-chosen to DIVIDE n when possible (see
+    _auto_row_tile) and returned — row padding copies the whole X operand
+    otherwise. The feature-major tiling (:func:`_glm_sums`) never comes
+    here: it pads neither axis."""
     n, d = x.shape
     d_pad = _pad_to(d, LANE)
     # GLM row-pass working set per tile row: the double-buffered storage-
@@ -178,19 +260,73 @@ def _pad_rows_cols(x, y, w, row_tile: int):
     return x, y, w, n_pad, d_pad, row_tile
 
 
+def _glm_sums(x, y, w, beta, b0, ys, *, kind: str, d: int, row_tile: int,
+              interpret: bool, x_scale, feature_major: bool):
+    """One GLM sweep of the shard in the tiling the caller observed:
+    ``(loss, Σ mult·x (d,), Σ mult, Σ w)``. ``feature_major`` says X is
+    stored with the rows on the lanes, so the sweep reads ``x.T`` — a
+    bitcast of such an array — in ``(d, lane_tile)`` blocks and nothing of
+    X is padded, copied or reshaped; where that block cannot be built
+    (:func:`_auto_lane_tile`) and for every other X it is the row-major
+    tiling, as before."""
+    n = x.shape[0]
+    has_scale = x_scale is not None
+    lane_tile = _auto_lane_tile(n, d, x.dtype, has_scale) \
+        if feature_major else None
+    if lane_tile is None:
+        x, y, w, n_pad, d_pad, row_tile = _pad_rows_cols(x, y, w, row_tile)
+        with jax.named_scope("glm.prepare_vectors"):
+            beta_p = jnp.pad(beta, (0, d_pad - d)).reshape(1, d_pad)
+        _note_sweep(kind, "row_major", row_tile=row_tile,
+                    pad_cols=d_pad - d, tail_rows=n_pad - n)
+        loss, grad_row, aux = _run_glm(
+            x, y, w, beta_p, b0, ys, kind=kind, tile=row_tile, width=d_pad,
+            grid=(n_pad // row_tile,), interpret=interpret,
+            scale=_pad_scale(x_scale, d, d_pad) if has_scale else None)
+        grad = grad_row[0, :d]
+    else:
+        _note_sweep(kind, "feature_major", lane_tile=lane_tile, pad_cols=0,
+                    tail_rows=n % lane_tile)
+        loss, grad_lanes, aux = _run_glm(
+            x.T, y, w, beta.reshape(d, 1), b0, ys, kind=kind,
+            tile=lane_tile, width=d, grid=(pl.cdiv(n, lane_tile),),
+            interpret=interpret, feature_major=True,
+            scale=_pad_scale(x_scale, d, d).reshape(d, 1)
+            if has_scale else None)
+        # the kernel keeps 128 lane-wise partial sums a feature (VPU adds
+        # only); the one cross-lane reduction of a sweep is this (d, 128)
+        # XLA sum
+        grad = jnp.sum(grad_lanes, axis=1)
+    return loss[0, 0], grad, aux[0, 0], aux[0, 1]
+
+
+def _note_sweep(kind: str, orientation: str, **attrs) -> None:
+    """``kernel.glm_sweep`` instant, one per sweep BUILT (this runs while
+    the aggregation program is traced, not per dispatch): which tiling the
+    program got and what it pads — the counter of how often the
+    feature-major tiling engages."""
+    from cycloneml_tpu.observe import tracing
+    tracing.instant("kernel.glm_sweep", kind=kind, orientation=orientation,
+                    **attrs)
+
+
 # -- fused binary logistic loss + gradient -------------------------------------
 
 def fused_binary_logistic(x, y, w, coef, d: int, fit_intercept: bool = True,
                           interpret: bool = False,
                           row_tile: int = ROW_TILE,
-                          x_scale=None) -> Dict[str, jnp.ndarray]:
+                          x_scale=None,
+                          feature_major: bool = False
+                          ) -> Dict[str, jnp.ndarray]:
     """Drop-in for the ``aggregators.binary_logistic`` block math: one pass
     over HBM computing {loss, grad, count} sums for the shard. Narrow
     (bf16/fp8) data-tier blocks are read at storage width and upcast to
     the f32 accumulator per VMEM tile — half (bf16) or a quarter (fp8) of
     the HBM traffic of an f32 sweep, no wide X copy anywhere. ``x_scale``
     is the fp8 tier's per-column dequantization vector, applied in-kernel
-    per VMEM block."""
+    per VMEM block. ``feature_major`` (static) is the caller's observation
+    that X is stored rows-on-lanes (:func:`stored_feature_major`); it
+    picks the tiling, never the result."""
     dtype = jnp.float32
     x = _storage_width(x)
     y = jnp.asarray(y, dtype)
@@ -199,29 +335,24 @@ def fused_binary_logistic(x, y, w, coef, d: int, fit_intercept: bool = True,
     beta = coef[:d] if fit_intercept else coef
     b0 = coef[d] if fit_intercept else jnp.zeros((), dtype)
 
-    x, y, w, n_pad, d_pad, row_tile = _pad_rows_cols(x, y, w, row_tile)
-    with jax.named_scope("glm.prepare_vectors"):
-        beta_p = jnp.pad(beta, (0, d_pad - d)).reshape(1, d_pad)
-    grid = (n_pad // row_tile,)
-
-    kernel = functools.partial(
-        _run_glm, kind="logistic", row_tile=row_tile, d_pad=d_pad,
-        grid=grid, interpret=interpret,
-        scale=None if x_scale is None else _pad_scale(x_scale, d, d_pad))
-    loss, grad_row, aux = kernel(x, y, w, beta_p, b0, jnp.zeros((), dtype))
-    g = grad_row[0, :d]
+    loss, g, msum, count = _glm_sums(
+        x, y, w, beta, b0, jnp.zeros((), dtype), kind="logistic", d=d,
+        row_tile=row_tile, interpret=interpret, x_scale=x_scale,
+        feature_major=feature_major)
     if fit_intercept:
-        grad = jnp.concatenate([g, aux[0, 0][None]])
+        grad = jnp.concatenate([g, msum[None]])
     else:
         grad = g
-    return {"loss": loss[0, 0], "grad": grad, "count": aux[0, 1]}
+    return {"loss": loss, "grad": grad, "count": count}
 
 
 def fused_binary_logistic_scaled(x, y, w, inv_std, scaled_mean, coef,
                                  d: int, fit_intercept: bool = True,
                                  interpret: bool = False,
                                  row_tile: int = ROW_TILE,
-                                 x_scale=None) -> Dict[str, jnp.ndarray]:
+                                 x_scale=None,
+                                 feature_major: bool = False
+                                 ) -> Dict[str, jnp.ndarray]:
     """Folded-standardization twin of :func:`fused_binary_logistic`: the
     kernel reads RAW feature rows — no standardized copy — because the
     scaling is algebra OUTSIDE the row pass:
@@ -247,28 +378,24 @@ def fused_binary_logistic_scaled(x, y, w, inv_std, scaled_mean, coef,
     sb = inv_std * beta
     off = b0 - jnp.dot(scaled_mean, beta)
 
-    x, y, w, n_pad, d_pad, row_tile = _pad_rows_cols(x, y, w, row_tile)
-    with jax.named_scope("glm.prepare_vectors"):
-        beta_p = jnp.pad(sb, (0, d_pad - d)).reshape(1, d_pad)
-    grid = (n_pad // row_tile,)
-    kernel = functools.partial(
-        _run_glm, kind="logistic", row_tile=row_tile, d_pad=d_pad,
-        grid=grid, interpret=interpret,
-        scale=None if x_scale is None else _pad_scale(x_scale, d, d_pad))
-    loss, grad_row, aux = kernel(x, y, w, beta_p, off, jnp.zeros((), dtype))
-    msum = aux[0, 0]
-    g = inv_std * grad_row[0, :d] - scaled_mean * msum
+    loss, raw, msum, count = _glm_sums(
+        x, y, w, sb, off, jnp.zeros((), dtype), kind="logistic", d=d,
+        row_tile=row_tile, interpret=interpret, x_scale=x_scale,
+        feature_major=feature_major)
+    g = inv_std * raw - scaled_mean * msum
     if fit_intercept:
         grad = jnp.concatenate([g, msum[None]])
     else:
         grad = g
-    return {"loss": loss[0, 0], "grad": grad, "count": aux[0, 1]}
+    return {"loss": loss, "grad": grad, "count": count}
 
 
 def fused_least_squares_scaled(x, y, w, inv_std, scaled_mean, y_pars, coef,
                                d: int, interpret: bool = False,
                                row_tile: int = ROW_TILE,
-                               x_scale=None) -> Dict[str, jnp.ndarray]:
+                               x_scale=None,
+                               feature_major: bool = False
+                               ) -> Dict[str, jnp.ndarray]:
     """Fused least-squares loss/grad sweep — the kernel twin of
     ``aggregators.least_squares_scaled`` (the LinearRegression l-bfgs
     objective). The kernel reads RAW data-tier rows once (margin → residual
@@ -293,33 +420,76 @@ def fused_least_squares_scaled(x, y, w, inv_std, scaled_mean, y_pars, coef,
     sb = inv_std * coef
     off = y_pars[1] - jnp.dot(scaled_mean, coef)  # rides the b0 slot
 
-    x, y, w, n_pad, d_pad, row_tile = _pad_rows_cols(x, y, w, row_tile)
-    with jax.named_scope("glm.prepare_vectors"):
-        beta_p = jnp.pad(sb, (0, d_pad - d)).reshape(1, d_pad)
-    grid = (n_pad // row_tile,)
-    kernel = functools.partial(
-        _run_glm, kind="squared", row_tile=row_tile, d_pad=d_pad,
-        grid=grid, interpret=interpret,
-        scale=None if x_scale is None else _pad_scale(x_scale, d, d_pad))
-    loss, grad_row, aux = kernel(x, y, w, beta_p, off, y_pars[0])
-    msum = aux[0, 0]
-    g = inv_std * grad_row[0, :d] - scaled_mean * msum
-    return {"loss": loss[0, 0], "grad": g, "count": aux[0, 1]}
+    loss, raw, msum, count = _glm_sums(
+        x, y, w, sb, off, y_pars[0], kind="squared", d=d,
+        row_tile=row_tile, interpret=interpret, x_scale=x_scale,
+        feature_major=feature_major)
+    g = inv_std * raw - scaled_mean * msum
+    return {"loss": loss, "grad": g, "count": count}
 
 
-def _run_glm(x, y, w, beta_p, b0, ys, *, kind, row_tile, d_pad, grid,
-             interpret, scale=None):
-    """Shared one-pass GLM row sweep: margin → per-row loss/multiplier →
-    grad, with ``kind`` selecting the link ("logistic" softplus/sigmoid,
+def _run_glm(x, y, w, beta_p, b0, ys, *, kind, tile, width, grid,
+             interpret, scale=None, feature_major=False):
+    """Shared one-pass GLM sweep: margin → per-row loss/multiplier → grad,
+    with ``kind`` selecting the link ("logistic" softplus/sigmoid,
     "squared" residual). ``ys`` is the label scale (squared only; the
     logistic path carries a zero). X tiles arrive at STORAGE width (bf16
     or fp8 when the data tier is narrow) and upcast to the f32
     accumulator in VMEM — the bytes HBM sees per sweep are exactly the
-    tier's. ``scale`` (optional, (1, d_pad)) is the fp8 tier's per-column
+    tier's. ``scale`` (optional) is the fp8 tier's per-column
     dequantization vector, applied to every upcast VMEM block (one VPU
     broadcast-multiply per tile); ``scale=None`` compiles the pre-fp8
-    kernel byte-for-byte."""
+    kernel byte-for-byte.
+
+    Two tilings of that one body (the link and the Kahan update are
+    shared; only the order of the in-tile additions differs):
+
+    - row-major (default): ``x`` is ``(n_pad, width)`` with ``width`` a
+      multiple of 128, blocks ``(tile, width)``; y/w ride as ``(n, 1)``
+      columns, β/scale/grad as ``(1, width)`` rows; the margin is a lane
+      reduction a row, the gradient a sublane reduction.
+    - ``feature_major``: ``x`` is the ``(width, n)`` view (``width`` = d,
+      whole on the sublanes, NOT padded), blocks ``(width, tile)`` with
+      the rows on the lanes; y/w ride as lane-dense ``(1, n)`` rows,
+      β/scale as ``(width, 1)`` columns; the margin is a sum over
+      sublanes (VPU adds), the gradient is kept as ``(width, 128)``
+      lane-wise partial sums the caller reduces once. ``n`` need not fill
+      the last tile: its lanes past n are masked in that grid step alone
+      (Pallas leaves out-of-bounds lanes undefined, so a zero weight would
+      not do), which counts the tail rows without copying X."""
     has_scale = scale is not None
+    n = x.shape[1] if feature_major else x.shape[0]
+    # feature-major: rows the last lane tile holds (0: every tile is full)
+    tail = n % tile if feature_major else 0
+
+    def link(margin, yv, wv, ys_ref):
+        """Per-row multiplier and this tile's loss: one body, whatever
+        axis the rows lie on."""
+        if kind == "logistic":
+            mult = wv * (jax.nn.sigmoid(margin) - yv)
+            v_loss = jnp.sum(wv * (jax.nn.softplus(margin)
+                                   - yv * margin)).reshape(1, 1)
+        else:  # squared (least-squares residual)
+            err = margin - ys_ref[0, 0] * yv
+            mult = wv * err
+            v_loss = (0.5 * jnp.sum(wv * err * err)).reshape(1, 1)
+        v_aux = jnp.concatenate(
+            [jnp.sum(mult)[None], jnp.sum(wv)[None]]).reshape(1, 2)
+        return mult, v_loss, v_aux
+
+    def kahan_add(pairs):
+        # Kahan-compensated accumulation across the (sequential) grid: a
+        # plain f32 `+=` over thousands of row tiles drifts ~n_tiles ulps,
+        # which is enough to break the strong-Wolfe first-try acceptance
+        # when this kernel feeds the chunked device L-BFGS (measured: 46
+        # line-search evals vs 10 for the tree-reducing XLA path at
+        # n=2M×d=1280). The running compensation keeps the total at ~1 ulp
+        # — cheaper than the XLA tree and exact enough for the Wolfe tests.
+        for acc, comp, v in pairs:
+            yk = v - comp[:]
+            t = acc[:] + yk
+            comp[:] = (t - acc[:]) - yk
+            acc[:] = t
 
     def glm_sweep(*refs):
         if has_scale:
@@ -343,60 +513,77 @@ def _run_glm(x, y, w, beta_p, b0, ys, *, kind, row_tile, d_pad, grid,
             cgrad_ref[:] = jnp.zeros_like(cgrad_ref)
             caux_ref[:] = jnp.zeros_like(caux_ref)
 
-        # fp32 accumulator tier from here on: the convert is a VPU op on a
-        # VMEM-resident tile, not an HBM materialization
-        xv = x_ref[:].astype(jnp.float32)
-        if s_ref is not None:
-            # fp8 dequant per VMEM block: codes * per-column scale
-            xv = xv * s_ref[:]
-        yv = y_ref[:]          # (T, 1) — Mosaic rejects 1-D blocks that
-        wv = w_ref[:]          # don't align to the T(1024) XLA layout
-        # matvecs with a width-1 output don't lower to the MXU (Mosaic:
-        # non-constant reduction accumulator); broadcast-multiply + reduce on
-        # the VPU instead — the pass is HBM-bound, not FLOP-bound
-        margin = jnp.sum(xv * beta_ref[:], axis=1,
-                         keepdims=True) + b0_ref[0, 0]       # (T, 1)
-        if kind == "logistic":
-            mult = wv * (jax.nn.sigmoid(margin) - yv)
-            v_loss = jnp.sum(wv * (jax.nn.softplus(margin)
-                                   - yv * margin)).reshape(1, 1)
-        else:  # squared (least-squares residual)
-            err = margin - ys_ref[0, 0] * yv
-            mult = wv * err
-            v_loss = (0.5 * jnp.sum(wv * err * err)).reshape(1, 1)
-        v_aux = jnp.concatenate(
-            [jnp.sum(mult)[None], jnp.sum(wv)[None]]).reshape(1, 2)
-        v_grad = jnp.sum(mult * xv, axis=0, keepdims=True)
-        # Kahan-compensated accumulation across the (sequential) grid: a
-        # plain f32 `+=` over thousands of row tiles drifts ~n_tiles ulps,
-        # which is enough to break the strong-Wolfe first-try acceptance
-        # when this kernel feeds the chunked device L-BFGS (measured: 46
-        # line-search evals vs 10 for the tree-reducing XLA path at
-        # n=2M×d=1280). The running compensation keeps the total at ~1 ulp
-        # — cheaper than the XLA tree and exact enough for the Wolfe tests.
-        for acc, comp, v in ((loss_ref, closs_ref, v_loss),
-                             (grad_ref, cgrad_ref, v_grad),
-                             (aux_ref, caux_ref, v_aux)):
-            yk = v - comp[:]
-            t = acc[:] + yk
-            comp[:] = (t - acc[:]) - yk
-            acc[:] = t
+        def tile_sums(live=None):
+            # fp32 accumulator tier from here on: the convert is a VPU op
+            # on a VMEM-resident tile, not an HBM materialization
+            xv = x_ref[:].astype(jnp.float32)
+            if s_ref is not None:
+                # fp8 dequant per VMEM block: codes * per-column scale
+                xv = xv * s_ref[:]
+            # (T, 1) columns / (1, T) rows — Mosaic rejects 1-D blocks that
+            # don't align to the T(1024) XLA layout
+            yv = y_ref[:]
+            wv = w_ref[:]
+            if live is not None:
+                # the last lane tile's lanes past n hold whatever the
+                # buffer held: select, never multiply (0 · NaN is NaN)
+                xv = jnp.where(live, xv, 0.0)
+                yv = jnp.where(live, yv, 0.0)
+                wv = jnp.where(live, wv, 0.0)
+            # matvecs with a width-1 output don't lower to the MXU (Mosaic:
+            # non-constant reduction accumulator); broadcast-multiply +
+            # reduce over the feature axis on the VPU instead — the pass is
+            # HBM-bound, not FLOP-bound
+            margin = jnp.sum(xv * beta_ref[:], axis=0 if feature_major else 1,
+                             keepdims=True) + b0_ref[0, 0]
+            mult, v_loss, v_aux = link(margin, yv, wv, ys_ref)
+            gx = mult * xv
+            if feature_major:
+                # lane-chunk adds (whole vregs, VPU): (d, T) → (d, 128)
+                v_grad = gx[:, :LANE]
+                for c in range(1, tile // LANE):
+                    v_grad = v_grad + gx[:, c * LANE:(c + 1) * LANE]
+            else:
+                v_grad = jnp.sum(gx, axis=0, keepdims=True)
+            kahan_add(((loss_ref, closs_ref, v_loss),
+                       (grad_ref, cgrad_ref, v_grad),
+                       (aux_ref, caux_ref, v_aux)))
 
+        if tail == 0:
+            tile_sums()
+        else:
+            last = grid[0] - 1
+            pl.when(i < last)(tile_sums)
+            pl.when(i == last)(lambda: tile_sums(
+                jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1) < tail))
+
+    if feature_major:
+        x_spec = pl.BlockSpec((width, tile), lambda i: (0, i))
+        vec_spec = pl.BlockSpec((1, tile), lambda i: (0, i))     # y, w
+        col_shape = (width, 1)                                   # β, scale
+        grad_shape = (width, LANE)
+    else:
+        x_spec = pl.BlockSpec((tile, width), lambda i: (i, 0))
+        vec_spec = pl.BlockSpec((tile, 1), lambda i: (i, 0))
+        col_shape = (1, width)
+        grad_shape = (1, width)
     in_specs = [
         pl.BlockSpec((1, 1), lambda i: (0, 0)),          # b0 / -offset
         pl.BlockSpec((1, 1), lambda i: (0, 0)),          # label scale
-        pl.BlockSpec((row_tile, d_pad), lambda i: (i, 0)),
-        pl.BlockSpec((row_tile, 1), lambda i: (i, 0)),
-        pl.BlockSpec((row_tile, 1), lambda i: (i, 0)),
-        pl.BlockSpec((1, d_pad), lambda i: (0, 0)),      # beta
+        x_spec,
+        vec_spec,
+        vec_spec,
+        pl.BlockSpec(col_shape, lambda i: (0, 0)),       # beta
     ]
     with jax.named_scope("glm.prepare_vectors"):
-        # (n,) -> (n, 1): Mosaic rejects 1-D blocks (see glm_sweep); on the
-        # chip each reshape is a relayout pass over the vector
+        # Mosaic rejects 1-D blocks (see glm_sweep). (n,) -> (n, 1) is a
+        # relayout pass over the vector into a lane-sparse column on the
+        # chip; (n,) -> (1, n) keeps it lane-dense
+        vec_shape = (1, -1) if feature_major else (-1, 1)
         args = [b0.reshape(1, 1), ys.reshape(1, 1), x,
-                y.reshape(-1, 1), w.reshape(-1, 1), beta_p]
+                y.reshape(vec_shape), w.reshape(vec_shape), beta_p]
     if has_scale:
-        in_specs.append(pl.BlockSpec((1, d_pad), lambda i: (0, 0)))
+        in_specs.append(pl.BlockSpec(col_shape, lambda i: (0, 0)))
         args.append(scale)
     # named by kind: what a device trace, the Mosaic dump and a metric's
     # pattern call this sweep
@@ -410,18 +597,18 @@ def _run_glm(x, y, w, beta_p, b0, ys, *, kind, row_tile, d_pad, grid,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, d_pad), lambda i: (0, 0)),
+            pl.BlockSpec(grad_shape, lambda i: (0, 0)),
             pl.BlockSpec((1, 2), lambda i: (0, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, d_pad), lambda i: (0, 0)),
+            pl.BlockSpec(grad_shape, lambda i: (0, 0)),
             pl.BlockSpec((1, 2), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
+            jax.ShapeDtypeStruct(grad_shape, jnp.float32),
             jax.ShapeDtypeStruct((1, 2), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
+            jax.ShapeDtypeStruct(grad_shape, jnp.float32),
             jax.ShapeDtypeStruct((1, 2), jnp.float32),
         ],
         compiler_params=_compiler_params("arbitrary"),

@@ -1,0 +1,121 @@
+"""The GLM sweep's tiling against the layout XLA:TPU gives X, checked by
+compiling for a described v5e (no chip attached: counts and sizes, never
+times). All such compiles live in THIS file and describe the topology inside
+a fixture: one pytest worker loads libtpu, and only when it runs these tests.
+"""
+
+import re
+
+import numpy as np
+import pytest
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+def _aggregator(kind, d, feature_major):
+    """(the scaled Pallas aggregator the estimators use, its extras)"""
+    import jax.numpy as jnp
+    from cycloneml_tpu.ml.optim import aggregators
+    v = (d,), jnp.float32
+    if kind == "logistic":
+        return (aggregators.binary_logistic_pallas_scaled(
+            d, True, feature_major=feature_major),
+            [v, v, ((d + 1,), jnp.float32)])
+    return (aggregators.least_squares_pallas_scaled(
+        d, feature_major=feature_major), [v, v, ((2,), jnp.float32), v])
+
+
+def _compile(topo, kind, n, d, feature_major, n_chips=1):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    agg, extras = _aggregator(kind, d, feature_major)
+    mesh = Mesh(np.array(topo.devices[:n_chips]), ("data",))
+    rows, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    args = [jax.ShapeDtypeStruct((n, d), jnp.bfloat16, sharding=rows),
+            jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rows),
+            jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rows)]
+    args += [jax.ShapeDtypeStruct(s, t, sharding=rep) for s, t in extras]
+
+    def program(*a):
+        local = lambda *b: jax.tree_util.tree_map(
+            lambda t: jax.lax.psum(t, "data"), agg(*b))
+        return jax.shard_map(
+            local, mesh=mesh, in_specs=(P("data"),) * 3 + (P(),) * len(extras),
+            out_specs=P(), check_vma=False)(*a)
+
+    # the chip runs without x64 (tests/conftest.py turns it on for the CPU
+    # parity suites; Mosaic refuses the i64 block indices it would bring)
+    with jax.enable_x64(False):
+        return jax.jit(program).lower(*args).compile()
+
+
+def _x_ops(text, n):
+    """Instructions that write a bf16 array with X's row count: the lane
+    pad and the layout copy are such."""
+    return [line.strip() for line in text.splitlines()
+            if re.search(rf"= bf16\[{n},\d+\]\S* (pad|copy)\(", line)]
+
+
+def _entry_layout_of_x(text):
+    return re.search(r"entry_computation_layout=\{\(bf16\[\d+,\d+\]\{([\d,]+)",
+                     text).group(1)
+
+
+@pytest.mark.parametrize("kind", ["logistic", "squared"])
+def test_feature_major_program_makes_no_copy_of_x(topo, kind):
+    """epsilon's width: X arrives ``{0,1}`` (rows on the lanes), and the
+    feature-major program holds the Mosaic call and no pad or copy of X —
+    the last tile's 64 rows are masked in the kernel, not padded."""
+    n, d = 8192 * 4 + 64, 2000
+    compiled = _compile(topo, kind, n, d, feature_major=True)
+    text = compiled.as_text()
+    assert _entry_layout_of_x(text) == "0,1"
+    assert "tpu_custom_call" in text
+    assert _x_ops(text, n) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_row_major_program_at_epsilon_width_is_what_the_selector_avoids(topo):
+    """Same X, row-major tiling: the layout copy and the lane pad the
+    feature-major tiling exists to avoid are both there."""
+    n, d = 8192 * 4 + 64, 2000
+    ops = _x_ops(_compile(topo, "logistic", n, d, False).as_text(), n)
+    assert any(" pad(" in op for op in ops)
+    assert any(" copy(" in op for op in ops)
+
+
+@pytest.mark.parametrize("kind", ["logistic", "squared"])
+def test_lane_aligned_width_stays_row_major(topo, kind):
+    """d = 1,280: X arrives ``{1,0}``, the default orientation is row-major
+    and that program has the Mosaic call and still no pad or copy of X."""
+    from cycloneml_tpu.ops import kernels
+    n, d = 8192 * 4, 1280
+    assert d % kernels.LANE == 0      # default_feature_major's rule
+    text = _compile(topo, kind, n, d, feature_major=False).as_text()
+    assert _entry_layout_of_x(text) == "1,0"
+    assert "tpu_custom_call" in text
+    assert _x_ops(text, n) == []
+
+
+def test_feature_major_program_on_four_chips(topo):
+    """Under shard_map on the 2x2 host every chip's (n/4, 2000) shard is
+    still read as it lies: the held-back x4 cell's program."""
+    n, d = 4 * (8192 * 2 + 64), 2000
+    compiled = _compile(topo, "logistic", n, d, True, n_chips=4)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+    assert _x_ops(text, n // 4) == [] and _x_ops(text, n) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
