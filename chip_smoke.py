@@ -275,6 +275,14 @@ def rel_err(got, want) -> float:
     return float(np.max(np.abs(got - want)) / max(np.max(np.abs(want)), 1e-30))
 
 
+def moment_reference(xv, y, w) -> dict:
+    """float64 weighted moments of the stored values ``xv``."""
+    y, w = np.asarray(y, np.float64), np.asarray(w, np.float64)
+    return {"w_sum": w.sum(), "b_sum": (w * y).sum(),
+            "bb_sum": (w * y * y).sum(), "a_sum": w @ xv,
+            "ab_sum": (w * y) @ xv, "aa_sum": (xv * w[:, None]).T @ xv}
+
+
 def kernel_matrix() -> dict:
     """Every kernel in ops/kernels.py, compiled natively (never
     interpreted) for every storage tier it claims, at published widths and
@@ -343,10 +351,18 @@ def kernel_matrix() -> dict:
                        x, yr, w, inv_std, mean, y_pars, c, d, x_scale=s),
                    (xs,), {"loss": 0.5 * np.sum(w * err * err),
                            "grad": xh.T @ (w * err)})
-            record(f"gramian/{tier}/n={n}",
-                   lambda x, s=scale: {"g": kernels.fused_gramian(
-                       x, w, x_scale=s)},
-                   (xs,), {"g": xv.T @ xv})
+            if tier == "bfloat16":
+                # the moment Gramian claims the bf16 tier only (every
+                # other storage takes XLA's contraction): row-major tile
+                # at this width, one MXU pass under a presence mask and
+                # three under weights that are not 0/1
+                for kind, wm in (("mask", (rng.random(n) > 0.2)),
+                                 ("weights", 0.5 + rng.random(n))):
+                    wm = wm.astype(np.float32)
+                    record(f"moment_gramian/{kind}/n={n}",
+                           lambda x, wm=wm: kernels.moment_sums(
+                               x, yr, wm, feature_major=False),
+                           (xs,), moment_reference(xv, yr, wm))
 
     # the feature-major tiling: arrays XLA:TPU stores with the rows on the
     # lanes (a width that is no multiple of 128, and enough rows that
@@ -387,6 +403,13 @@ def kernel_matrix() -> dict:
                        feature_major=True),
                    (xs,), {"loss": 0.5 * np.sum(w * err * err),
                            "grad": xh.T @ (w * err)})
+            if tier == "bfloat16" and d_odd % kernels.MOMENT_ROWS == 0:
+                # the moment Gramian on the array as it lies: (d, T)
+                # tiles of x.T, the tail of 8 rows masked in the kernel
+                record(f"moment_gramian_feature_major/n={n},d={d_odd}",
+                       lambda x: kernels.moment_sums(
+                           x, yr, w, feature_major=True),
+                       (xs,), moment_reference(xv, yr, w))
 
     # stacked fits vmap the GLM kernel (labels on axis 1 of an (n, K) bf16
     # stack, coefficients on axis 0)

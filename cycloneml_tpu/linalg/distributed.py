@@ -19,6 +19,7 @@ EigenValueDecomposition.scala:87 ARPACK Lanczos): a RowMatrix is an
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -33,6 +34,18 @@ class SVDResult(NamedTuple):
     U: Optional["RowMatrix"]
     s: DenseVector
     V: DenseMatrix
+
+
+@functools.lru_cache(maxsize=None)
+def _presence_gramian(feature_major: bool):
+    """Per-shard ``X'X`` over the rows that exist (w > 0); cached so every
+    call reuses one aggregation program."""
+    def presence_gramian(x, y, w):
+        import jax.numpy as jnp
+        from cycloneml_tpu.ops.kernels import moment_sums
+        return moment_sums(x, jnp.zeros_like(w), (w > 0).astype(w.dtype),
+                           feature_major=feature_major)["aa_sum"]
+    return presence_gramian
 
 
 class RowMatrix:
@@ -107,28 +120,12 @@ class RowMatrix:
             out = self.dataset.tree_aggregate_fn(agg)()
             return DenseMatrix.from_array(np.asarray(out, dtype=np.float64))
 
-        from cycloneml_tpu.ops.kernels import (fused_gramian,
-                                               fused_gramian_fits,
-                                               use_fused_kernels)
-        if use_fused_kernels(self.dataset.ctx) and fused_gramian_fits(
-                self.num_cols(), self.dataset.x.dtype):
-            # fused Pallas sweep: per-tile MXU matmul into a revisited VMEM
-            # accumulator, presence mask applied in-kernel — one storage-
-            # width read of X, no masked copy
-            out = self.dataset.tree_aggregate_fn(
-                lambda x, y, w: fused_gramian(x, w=w))()
-        else:
-            def agg(x, y, w):
-                # presence-masked XᵀX; narrow (bf16) blocks keep their
-                # storage dtype as the einsum operands ({0,1} mask is
-                # exact) and accumulate into f32
-                from cycloneml_tpu.dataset.instance import is_narrow_dtype
-                acc = jnp.float32 if is_narrow_dtype(x.dtype) else x.dtype
-                return jnp.einsum(
-                    "bi,bj->ij", x * (w > 0)[:, None].astype(x.dtype), x,
-                    precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=acc)
-            out = self.dataset.tree_aggregate_fn(agg)()
+        from cycloneml_tpu.ops.kernels import stored_feature_major
+        # the package's one dense Gramian (ops/kernels.moment_sums: the
+        # moment pass WeightedLeastSquares aggregates) under the presence
+        # mask — one storage-width read of X, tiled the way X is stored
+        out = self.dataset.tree_aggregate_fn(_presence_gramian(
+            stored_feature_major(self.dataset.x)))()
         return DenseMatrix.from_array(np.asarray(out, dtype=np.float64))
 
     def compute_gramian_sharded(self):

@@ -3,10 +3,11 @@
 Semantics port of ml/optim/WeightedLeastSquares.scala:101-326 and
 NormalEquationSolver.scala:59-153 (CholeskySolver + QuasiNewtonSolver),
 TPU-shaped: the moment aggregation (the reference's ``treeAggregate(new
-Aggregator)``) is ONE jitted device pass producing {wSum, bBar, bbBar,
-aBar, aaBar, abBar}; the (d+1)-sized standardized normal-equation solve
-then runs on the driver in f64, exactly where the reference solves after
-its aggregate.
+Aggregator)``) is ONE ``tree_aggregate`` program over the dataset's
+row-sharded arrays (``moments_aggregator``: one read of X at storage width,
+psum over the mesh) producing {wSum, bSum, bbSum, aSum, abSum, aaSum}; the
+(d+1)-sized standardized normal-equation solve then runs on the driver in
+f64, exactly where the reference solves after its aggregate.
 
 Distinctions that matter for golden parity (and differ from the
 LinearRegression l-bfgs path):
@@ -29,6 +30,7 @@ estimator-level callers in the reference (SURVEY §2.3 optimizers row).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import numpy as np
@@ -41,45 +43,49 @@ MAX_NUM_FEATURES = 4096  # ref WeightedLeastSquares.MAX_NUM_FEATURES:335
 
 
 class WeightedLeastSquaresModel:
+    """``diag_inv_atwa`` — the diagonal of ``(AᵀWA)⁻¹`` the reference's
+    summaries take standard errors from — may be handed over as a
+    callable: the Cholesky solver's costs a second pass over its factor
+    (LAPACK ``potri``), which a fit that never reads it does not pay."""
+
     def __init__(self, coefficients: np.ndarray, intercept: float,
-                 diag_inv_atwa: np.ndarray, objective_history):
+                 diag_inv_atwa, objective_history):
         self.coefficients = coefficients
         self.intercept = intercept
-        self.diag_inv_atwa = diag_inv_atwa
+        self._diag_inv_atwa = diag_inv_atwa
         self.objective_history = list(objective_history)
+
+    @property
+    def diag_inv_atwa(self) -> np.ndarray:
+        if callable(self._diag_inv_atwa):
+            self._diag_inv_atwa = self._diag_inv_atwa()
+        return self._diag_inv_atwa
 
     def predict(self, x: np.ndarray) -> np.ndarray:
         return np.asarray(x) @ self.coefficients + self.intercept
 
 
-_agg_jit = None
+@functools.lru_cache(maxsize=None)
+def moments_aggregator(feature_major: bool = False):
+    """The per-shard moment pass (ref Aggregator.add; the psum over shards
+    is ``tree_aggregate``'s, replacing treeAggregate's merge): ``{w_sum,
+    b_sum, bb_sum, a_sum, ab_sum, aa_sum}`` from ONE read of X at storage
+    width (``ops/kernels.moment_sums``). Cached, so every fit of every
+    dataset asks ``tree_aggregate`` for the same function and gets the same
+    program (``jit_tree_aggregate__wls_moments`` in a device capture);
+    ``feature_major`` is the caller's observation of how X is stored."""
+    def wls_moments(x, y, w):
+        from cycloneml_tpu.ops.kernels import moment_sums
+        return moment_sums(x, y, w, feature_major=feature_major)
+    return wls_moments
 
 
-def _moments(x, y, w):
-    """One device pass for the summary moments (ref Aggregator.add/merge;
-    the psum over blocks replaces treeAggregate). The jitted kernel is
-    module-cached so repeated fits at one shape (IRLS iterations,
-    hyperparameter sweeps) compile once and dispatch thereafter."""
+@functools.lru_cache(maxsize=None)
+def _array_moments():
+    """The same pass for bare arrays (the small callers: golden parity,
+    IRLS-sized systems handed numpy): one jitted call, no mesh."""
     import jax
-    import jax.numpy as jnp
-
-    global _agg_jit
-    if _agg_jit is None:
-        @jax.jit
-        def agg(x, y, w):
-            return {
-                "w_sum": jnp.sum(w),
-                "b_sum": jnp.sum(w * y),
-                "bb_sum": jnp.sum(w * y * y),
-                "a_sum": jnp.sum(x * w[:, None], axis=0),
-                "ab_sum": jnp.sum(x * (w * y)[:, None], axis=0),
-                "aa_sum": jnp.einsum("bi,bj->ij", x * w[:, None], x,
-                                     precision=jax.lax.Precision.HIGHEST),
-            }
-        _agg_jit = agg
-
-    out = _agg_jit(jnp.asarray(x), jnp.asarray(y), jnp.asarray(w))
-    return {k: np.asarray(v, dtype=np.float64) for k, v in out.items()}
+    return jax.jit(moments_aggregator(False))
 
 
 class WeightedLeastSquares:
@@ -105,31 +111,65 @@ class WeightedLeastSquares:
         self.solver_type = solver_type
         self.max_iter = max_iter
         self.tol = tol
+        # passes over the data so far, each one dispatch: the count the
+        # estimator's training summary states
+        self.n_passes = 0
 
     # -- public ----------------------------------------------------------
-    def fit(self, x, y, w: Optional[np.ndarray] = None
+    def fit(self, x, y=None, w: Optional[np.ndarray] = None
             ) -> WeightedLeastSquaresModel:
-        """``x``/``y``/``w`` may be numpy OR live (possibly sharded)
-        device arrays — they pass straight into the jitted moment pass
-        with no host round-trip, so a mesh-sharded dataset aggregates in
-        place and only the O(d²) moments come back to the driver."""
+        """Fit an in-core ``InstanceDataset`` (``fit(ds)``: the moment pass
+        is one ``tree_aggregate`` program over its row-sharded arrays) or
+        bare ``x``/``y``/``w`` arrays. Only the O(d²) moments come back to
+        the driver either way."""
+        d = x.n_features if y is None else x.shape[1]
+        return self.solve(self.moments(x, y, w), d)
+
+    def moments(self, x, y=None, w=None) -> dict:
+        """The one pass over the data: its six weighted sums as host
+        arrays at the accumulator's width (:meth:`solve` widens them).
+        Every call pays the pass — nothing here remembers a dataset's
+        Gramian. For a dataset the dispatch and the readback
+        are spans (``cyclone.dispatch.wls.moments``, ``cyclone.transfer.
+        wls.readback``) and one completed step of its context."""
+        import jax
+        from cycloneml_tpu.observe import tracing
         n, d = x.shape
         if d > MAX_NUM_FEATURES:
             raise ValueError(
                 f"WeightedLeastSquares supports at most {MAX_NUM_FEATURES} "
                 f"features, got {d}")
-        if w is None:
-            w = np.ones(n)
-        m = _moments(x, y, w)
-        return self._solve_from_moments(m, d)
+        if y is None:
+            from cycloneml_tpu.ops.kernels import stored_feature_major
+            ds = x
+            call = ds.tree_aggregate_fn(
+                moments_aggregator(stored_feature_major(ds.x)))
+            with tracing.span("dispatch", "wls.moments", passes=1):
+                out_dev = call()            # 'collective' span inside
+                with tracing.span("transfer", "wls.readback") as tsp:
+                    out = jax.device_get(out_dev)
+                    tsp.annotate_bytes(out)
+            if hasattr(ds.ctx, "record_step"):
+                ds.ctx.record_step({"wls_passes": 1.0})
+        else:
+            import jax.numpy as jnp
+            if w is None:
+                w = np.ones(n)
+            out = jax.device_get(_array_moments()(
+                jnp.asarray(x), jnp.asarray(y), jnp.asarray(w)))
+        self.n_passes += 1
+        return out
 
     # -- the reference algorithm -----------------------------------------
-    def _solve_from_moments(self, m, d: int) -> WeightedLeastSquaresModel:
-        w_sum = m["w_sum"]
+    def solve(self, m: dict, d: int) -> WeightedLeastSquaresModel:
+        """The driver's half (ref WeightedLeastSquares.fit after its
+        treeAggregate): standardise the moments, solve the (d+1)-sized
+        system in float64, map back."""
+        w_sum = float(m["w_sum"])
         if w_sum <= 0:
             raise ValueError("sum of weights must be positive")
-        raw_b_bar = m["b_sum"] / w_sum
-        raw_bb_bar = m["bb_sum"] / w_sum
+        raw_b_bar = float(m["b_sum"]) / w_sum
+        raw_bb_bar = float(m["bb_sum"]) / w_sum
         raw_b_std = float(np.sqrt(max(raw_bb_bar - raw_b_bar ** 2, 0.0)))
 
         if raw_b_std == 0.0:
@@ -146,17 +186,18 @@ class WeightedLeastSquares:
         b_bar = float(raw_b_bar) / b_std
         bb_bar = float(raw_bb_bar) / (b_std * b_std)
 
-        raw_a_bar = m["a_sum"] / w_sum
-        raw_aa_bar = m["aa_sum"] / w_sum
-        raw_ab_bar = m["ab_sum"] / w_sum
-        a_var = np.maximum(np.diag(raw_aa_bar) - raw_a_bar ** 2, 0.0)
+        raw_a_bar = np.asarray(m["a_sum"], np.float64) / w_sum
+        raw_ab_bar = np.asarray(m["ab_sum"], np.float64) / w_sum
+        aa_sum = np.asarray(m["aa_sum"])
+        a_var = np.maximum(
+            np.diagonal(aa_sum).astype(np.float64) / w_sum - raw_a_bar ** 2,
+            0.0)
         a_std = np.sqrt(a_var)
         live = a_std > 0
         inv_std = np.where(live, 1.0 / np.where(live, a_std, 1.0), 0.0)
 
         a_bar = raw_a_bar * inv_std
         ab_bar = raw_ab_bar * inv_std / b_std
-        aa_bar = raw_aa_bar * np.outer(inv_std, inv_std)
 
         eff_reg = self.reg_param / b_std
         eff_l1 = self.elastic_net_param * eff_reg
@@ -168,15 +209,24 @@ class WeightedLeastSquares:
             lam = np.where(live, lam * inv_std * inv_std, 0.0)
         if not self.standardize_label:
             lam = lam * b_std
-        aa_bar = aa_bar + np.diag(lam)
 
-        # augmented system: intercept rides as an appended bias column
+        # the standardized system, built ONCE in the column-major block
+        # LAPACK factors in place: at d = 2,000 every pass over a (d, d)
+        # float64 array is 32 MB of host memory traffic, and a fit waits
+        # for each (aa_sum is symmetric: its transpose is the same matrix
+        # in the layout of the target, so the widening pass is contiguous).
+        # The intercept rides as an appended bias column (getAtA, ref :312)
+        k = d + 1 if self.fit_intercept else d
+        ata = np.empty((k, k), order="F")
+        core = ata[:d, :d]
+        np.multiply(aa_sum.T, (inv_std / w_sum)[:, None], out=core)
+        core *= inv_std[None, :]
+        core[np.arange(d), np.arange(d)] += lam
         if self.fit_intercept:
-            ata = np.block([[aa_bar, a_bar[:, None]],
-                            [a_bar[None, :], np.ones((1, 1))]])
+            ata[:d, d] = ata[d, :d] = a_bar
+            ata[d, d] = 1.0
             atb = np.concatenate([ab_bar, [b_bar]])
         else:
-            ata = aa_bar
             atb = ab_bar
 
         use_qn = (self.solver_type == QUASI_NEWTON
@@ -188,7 +238,7 @@ class WeightedLeastSquares:
                 ata, atb, a_bar, b_bar, bb_bar, a_std, eff_l1, d)
         else:
             try:
-                sol, history, aa_inv = self._cholesky(ata, atb)
+                sol, history, aa_inv = self._cholesky(ata, atb, bb_bar)
             except np.linalg.LinAlgError:
                 if self.solver_type != AUTO:
                     raise
@@ -205,20 +255,46 @@ class WeightedLeastSquares:
         if aa_inv is not None:
             mult = np.concatenate([a_var, [1.0]]) if self.fit_intercept \
                 else a_var
-            with np.errstate(divide="ignore"):
-                diag = np.where(mult > 0,
-                                np.diag(aa_inv) / (w_sum * mult), np.inf)
+
+            def diag():
+                with np.errstate(divide="ignore"):
+                    return np.where(mult > 0, aa_inv() / (w_sum * mult),
+                                    np.inf)
         else:
             diag = np.zeros(1)
         return WeightedLeastSquaresModel(coef, intercept, diag, history)
 
-    def _cholesky(self, ata, atb):
-        # np.linalg.cholesky raises LinAlgError on non-PD — the reference's
-        # SingularMatrixException analog
-        chol = np.linalg.cholesky(ata)
-        sol = np.linalg.solve(chol.T, np.linalg.solve(chol, atb))
-        inv = np.linalg.inv(ata)
-        return sol, [0.0], inv
+    def _cholesky(self, ata, atb, bb_bar):
+        """LAPACK's symmetric positive-definite routines on the ONE
+        column-major block (``potrf`` / ``potrs``, and ``potri`` when the
+        inverse's diagonal is asked for; ref CholeskySolver's ``dppsv`` +
+        ``dpptri``), in place: the factor overwrites the lower triangle,
+        the strict upper triangle keeps the matrix, so the objective's
+        ``sol'A sol`` is a ``symv`` over the upper half with the saved
+        diagonal put back — no second (d, d) array. A matrix that is not
+        positive definite raises LinAlgError (the reference's
+        SingularMatrixException analog) with ``ata`` restored."""
+        from scipy.linalg import blas, lapack
+        diag = ata.diagonal().copy()
+        chol, info = lapack.dpotrf(ata, lower=1, clean=0, overwrite_a=1)
+        if info != 0:
+            upper = np.triu(chol, 1)
+            ata[...] = upper + upper.T
+            ata[np.arange(len(diag)), np.arange(len(diag))] = diag
+            raise np.linalg.LinAlgError(
+                f"normal-equation matrix is not positive definite "
+                f"(potrf info={info})")
+        sol, _ = lapack.dpotrs(chol, atb, lower=1)
+        # the objective the solution reaches: the standardised quadratic
+        # _quasi_newton's cost function evaluates, penalty included
+        a_sol = blas.dsymv(1.0, chol, sol, lower=0) \
+            + (diag - chol.diagonal()) * sol
+        loss = 0.5 * bb_bar - float(atb @ sol) + 0.5 * float(sol @ a_sol)
+
+        def inv_diag():
+            return lapack.dpotri(chol, lower=1, overwrite_c=1)[0] \
+                .diagonal().copy()
+        return sol, [loss], inv_diag
 
     def _quasi_newton(self, ata, atb, a_bar, b_bar, bb_bar, a_std,
                       eff_l1, d: int):

@@ -124,35 +124,7 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
                 sds.close()
 
         if solver == "normal":
-            if getattr(ds, "x_scale", None) is not None:
-                # the moment solver reads ds.x directly; e4m3 codes are
-                # not values — leave the fp8 rung, visibly
-                from cycloneml_tpu.dataset.dataset import fp8_fallback
-                ds = fp8_fallback(ds, "LinearRegression",
-                                  "solver='normal' is not fp8-eligible")
-            # delegate to the WLS COMPONENT exactly as the reference does
-            # (LinearRegression.scala:446-448: WeightedLeastSquares with
-            # solverType=Auto, standardizeLabel=true) — population-weighted
-            # moments, appended-bias system, Cholesky with singular→QN
-            # fallback, and the constant-label/zero-variance degeneracies
-            # live in ONE place (ml/optim/wls.py)
-            from cycloneml_tpu.ml.optim.wls import (AUTO,
-                                                    WeightedLeastSquares)
-            wls = WeightedLeastSquares(
-                fit_intercept=self.get("fitIntercept"), reg_param=reg,
-                elastic_net_param=alpha,
-                standardize_features=self.get("standardization"),
-                standardize_label=True, solver_type=AUTO,
-                max_iter=self.get("maxIter"), tol=self.get("tol"))
-            wm = wls.fit(ds.x, ds.y, ds.w)
-            model = LinearRegressionModel(wm.coefficients, wm.intercept,
-                                          uid=self.uid)
-            self._copy_values(model)
-            model._set_parent(self)
-            model.summary = LinearRegressionTrainingSummary(
-                wm.objective_history,
-                max(len(wm.objective_history) - 1, 0))
-            return model
+            return self._fit_normal(ds, reg, alpha)
 
         cached = streamed or Summarizer.is_cached(ds)
         with tracing.span("phase", "fit.stats", cached=cached):
@@ -218,7 +190,46 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
                 history, max(len(history) - 1, 0),
                 total_evals=loss_fn.n_evals,
                 total_dispatches=loss_fn.n_dispatches, streamed=streamed,
-                orientation=orientation)
+                orientation=orientation, total_passes=loss_fn.n_evals)
+            return model
+
+    # -- normal equations: one moment pass, then the driver's solve ------------
+    def _fit_normal(self, ds, reg, alpha) -> "LinearRegressionModel":
+        """Delegate to the WLS COMPONENT exactly as the reference does
+        (LinearRegression.scala:446-448: WeightedLeastSquares with
+        solverType=Auto, standardizeLabel=true) — population-weighted
+        moments, appended-bias system, Cholesky with singular→QN fallback,
+        and the constant-label/zero-variance degeneracies live in ONE
+        place (ml/optim/wls.py). A fit is one aggregation program over X
+        (dispatch + readback spans of its own), then ``fit.solve`` on the
+        host; every fit pays its pass."""
+        from cycloneml_tpu.ml.optim.wls import AUTO, WeightedLeastSquares
+        with tracing.span("phase", "fit.prepare"):
+            if getattr(ds, "x_scale", None) is not None:
+                # the moment pass reads ds.x directly; e4m3 codes are
+                # not values — leave the fp8 rung, visibly
+                from cycloneml_tpu.dataset.dataset import fp8_fallback
+                ds = fp8_fallback(ds, "LinearRegression",
+                                  "solver='normal' is not fp8-eligible")
+            wls = WeightedLeastSquares(
+                fit_intercept=self.get("fitIntercept"), reg_param=reg,
+                elastic_net_param=alpha,
+                standardize_features=self.get("standardization"),
+                standardize_label=True, solver_type=AUTO,
+                max_iter=self.get("maxIter"), tol=self.get("tol"))
+        moments = wls.moments(ds)
+        with tracing.span("phase", "fit.solve"):
+            wm = wls.solve(moments, ds.n_features)
+        with tracing.span("phase", "fit.finish"):
+            model = LinearRegressionModel(wm.coefficients, wm.intercept,
+                                          uid=self.uid)
+            self._copy_values(model)
+            model._set_parent(self)
+            model.summary = LinearRegressionTrainingSummary(
+                wm.objective_history,
+                max(len(wm.objective_history) - 1, 0),
+                total_dispatches=wls.n_passes, solver="normal",
+                total_passes=wls.n_passes)
             return model
 
     # -- quasi-Newton in doubly standardized space -----------------------------
@@ -370,13 +381,21 @@ class LinearRegressionModel(PredictionModel, _LinearRegressionParams,
 class LinearRegressionTrainingSummary:
     def __init__(self, objective_history, total_iterations,
                  total_evals=None, total_dispatches=None, streamed=False,
-                 orientation=None):
+                 orientation=None, solver="l-bfgs", total_passes=None):
+        # the objective per iteration (quasi-Newton), or the one value the
+        # closed-form solution reaches (normal: the standardised
+        # quadratic, penalty included)
         self.objective_history = objective_history
         self.total_iterations = total_iterations
+        # which solver ran ("normal" / "l-bfgs") and how often it read X:
+        # one pass for the normal equations, one per evaluation otherwise
+        self.solver = solver
+        self.total_passes = total_passes
         # optimizer-path telemetry, as LogisticRegressionTrainingSummary
-        # carries it: loss/grad evaluations and host->device round trips of
-        # the quasi-Newton solve (None for the normal-equations solver and
-        # the constant-label shortcut, which evaluate no loss function)
+        # carries it: loss/grad evaluations and host->device round trips
+        # (the normal solver evaluates no loss function: total_evals None,
+        # one dispatch for its moment pass; the constant-label shortcut
+        # states neither)
         self.total_evals = total_evals
         self.total_dispatches = total_dispatches
         # True when the fit ran on the out-of-core streaming engine

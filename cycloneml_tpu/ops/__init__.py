@@ -10,11 +10,12 @@ per L-BFGS evaluation instead of once per op.
 
 from cycloneml_tpu.ops.kernels import (fused_binary_logistic,
                                        fused_binary_logistic_scaled,
-                                       fused_gramian, fused_kmeans_assign,
+                                       fused_kmeans_assign,
                                        fused_least_squares_scaled,
+                                       fused_moment_gramian, moment_sums,
                                        pallas_available, use_fused_kernels)
 
 __all__ = ["fused_binary_logistic", "fused_binary_logistic_scaled",
-           "fused_gramian", "fused_kmeans_assign",
-           "fused_least_squares_scaled", "pallas_available",
+           "fused_kmeans_assign", "fused_least_squares_scaled",
+           "fused_moment_gramian", "moment_sums", "pallas_available",
            "use_fused_kernels"]

@@ -11,17 +11,21 @@ output blocks — the standard Pallas reduction pattern):
   on the same row pass.
 - ``fused_kmeans_assign``: the KMeans distance+argmin inner loop (ref:
   DistanceMeasure.findClosest:123) as ‖x‖²−2x·c+‖c‖² with a fused argmin.
-- ``fused_gramian``: XᵀX accumulation (ref: RowMatrix.computeGramianMatrix:130
-  — the treeAggregate of spr:147 rank-1 updates, batched onto the MXU).
+- ``fused_moment_gramian``: the augmented Gramian ``[1|y|X]'W[1|y|X]`` —
+  WeightedLeastSquares' moment pass and RowMatrix.computeGramianMatrix:130
+  (the treeAggregate of spr:147 rank-1 updates) as upper-triangle MXU
+  products of bf16 tiles; ``moment_sums`` is its front door.
 
 Under ``cyclone.ml.usePallasKernels=auto`` (the default) the GLM kernels
-and the Gramian ARE the dense sweep on a TPU backend, and the XLA-fused
-``jnp`` aggregators are the sweep everywhere else; the KMeans kernel is
-opt-in (``true``) only. Which of the twins is faster at which shape is not
-measured on the current machine (ROADMAP S8/D3).
+ARE the dense sweep on a TPU backend, and the XLA-fused ``jnp`` aggregators
+are the sweep everywhere else; the KMeans kernel is opt-in (``true``) only.
+The Gramian reads no conf key: ``moment_sums`` takes the kernel wherever the
+array it is handed allows one (PERF.md §6, PR 29: 54 ms against 105 ms for
+XLA's contraction at 2,000,000 x 2,000 on the v5e).
 
-The KMeans and Gramian wrappers pad rows to the tile size and features to
-the 128-lane boundary. The GLM sweep has two tilings of one body, chosen by
+The KMeans wrapper pads rows to the tile size and features to the 128-lane
+boundary; the Gramian pads nothing (it tiles like the feature-major sweep
+below, or transposes a row-major tile in VMEM). The GLM sweep has two tilings of one body, chosen by
 the way X is stored (``stored_feature_major``): *row-major* — ``(row_tile,
 d_pad)`` blocks, d on the lanes, padded to the 128-lane boundary only when
 d is no multiple of 128 — and *feature-major* — ``(d, lane_tile)`` blocks of
@@ -703,85 +707,224 @@ def fused_kmeans_assign(x, centers, interpret: bool = False,
     return best[:n, 0], jnp.maximum(dist[:n, 0], 0.0)
 
 
-# -- fused Gramian --------------------------------------------------------------
+# -- moment Gramian (WeightedLeastSquares, RowMatrix) -----------------------------
 
-def _gramian_vmem(d_pad: int, itemsize: int):
-    """(bytes per tile row, fixed bytes) of the Gramian kernel's VMEM
-    working set: per row the double-buffered x block, its f32 upcast, the
-    masked copy and the transposed operand; fixed, the resident
-    (d_pad, d_pad) f32 accumulator block."""
-    return d_pad * (2 * itemsize + 12) + 4 * 512, 4 * d_pad * d_pad
+#: rows the small moments take AHEAD of X in the kernel's tile: one packed
+#: bf16 sublane group, so X starts on a group boundary whatever d is
+MOMENT_ROWS = 16
+#: rows / columns of one MXU product of the triangle: the MXU's own tile.
+#: Smaller blocks hug the diagonal closer (136 of 256 products at d = 2,000
+#: against 10 of 16 at 512); measured on the v5e at 2,000,000 x 2,000 with a
+#: 1,024-row tile: 48.7 ms at 128, 51.3 at 256, 56.2 at 512, 66.1 at 1,024
+GRAM_BLOCK = 128
+#: scoped VMEM the Gramian declares: its accumulator, the compensation and
+#: the pipeline's second output buffer are three resident (d, d) f32 blocks
+#: (49.5 MB at d = 2,000) — beyond the 32 MiB of the sweeps, inside the
+#: 128 MiB a v5e core has
+GRAM_VMEM_LIMIT_BYTES = 100 << 20
+_GRAM_VMEM_BUDGET = 88 << 20
+
+_NT = (((1,), (1,)), ((), ()))     # contract the lanes of two (rows, T) tiles
 
 
-def fused_gramian_fits(d: int, dtype) -> bool:
-    """Whether :func:`fused_gramian` can be built at width ``d``: its
-    accumulator is one VMEM-resident ``(d_pad, d_pad)`` block, so the
-    kernel has a width ceiling (d ≈ 2.4k under the declared limit) that
-    the XLA einsum does not. ``RowMatrix.compute_gramian`` asks before
-    choosing the kernel."""
-    row_bytes, fixed = _gramian_vmem(_pad_to(d, LANE),
-                                     np.dtype(dtype).itemsize)
-    return fixed + 8 * row_bytes <= _VMEM_BUDGET
+def _split3(v):
+    """A float32 array as three bfloat16 pieces whose sum is the array to
+    its last bit (8 + 8 + 8 mantissa bits): what lets an f32 operand ride
+    the MXU in one bf16 pass a piece with exact products."""
+    hi = v.astype(jnp.bfloat16)
+    rest = v - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
 
 
-def fused_gramian(x, w=None, interpret: bool = False,
-                  row_tile: int = ROW_TILE, x_scale=None):
-    """XᵀX over row tiles, accumulated in a revisited VMEM block (ref:
-    RowMatrix.computeGramianMatrix:130 — spr rank-1 updates become one MXU
-    matmul per tile). bf16 blocks are read at storage width and upcast per
-    VMEM tile into the f32 accumulator; fp8 blocks additionally apply
-    their per-column ``x_scale`` to each upcast VMEM block, so the
-    accumulated Gramian is already in value space. ``w`` (optional
-    per-row weights) masks padding/invalid rows by presence (w > 0)
-    INSIDE the kernel — the jnp path's ``x * (w > 0)`` row mask without
-    the masked X copy."""
-    x = _storage_width(x)
+def moment_gramian_tile(rows: int, d: int, dtype, feature_major: bool):
+    """Lane tile of :func:`fused_moment_gramian` — how many rows of X one
+    grid step takes — for a shard of ``rows`` x ``d`` stored as ``dtype``
+    in the given orientation, or None where the kernel cannot be built and
+    the XLA contraction (:func:`moment_sums`) is the path: storage other
+    than bfloat16 (f32/f64 want multi-pass products on BOTH operands, fp8
+    wants its scale), a width that does not end on a packed sublane group
+    (feature-major: d % 16) or on a lane (row-major: d % 128), fewer than
+    128 rows, or a d whose three resident ``(d, d)`` f32 blocks pass the
+    VMEM the kernel declares (d ≈ 2,700)."""
+    if np.dtype(dtype) != np.dtype(jnp.bfloat16) or rows < LANE:
+        return None
+    if d % (MOMENT_ROWS if feature_major else LANE):
+        return None
+    big = MOMENT_ROWS + d
+    fixed = 3 * 4 * big * _pad_to(big, LANE)
+    # per lane: the double-buffered x tile, the (big, T) bf16 work tile and
+    # the weighted pass's f32 block with its three pieces
+    per_lane = 2 * (2 * d) + 2 * big + GRAM_BLOCK * (4 + 4 + 6)
+    # 2,048-row tiles measured slower than 1,024 (65.9 against 56.2 ms)
+    for t in (1024, 512, 256, 128):
+        if t <= rows and fixed + t * per_lane <= _GRAM_VMEM_BUDGET:
+            return t
+    return None
+
+
+def fused_moment_gramian(x, y, w, *, feature_major: bool, lane_tile: int,
+                         weighted: bool, interpret: bool = False):
+    """``Z'WZ`` of the augmented design ``Z = [1 | y | X]`` in ONE read of
+    a bfloat16 X, as the upper-triangular blocks of a ``(16 + d, 16 + d)``
+    f32 matrix: row 0 holds ``Σw, Σwy, Σwx``, rows 1-3 (y as three bf16
+    pieces, :func:`_split3`) ``Σwy², Σwyx``, the rest ``X'WX`` (ref:
+    WeightedLeastSquares' Aggregator, RowMatrix.computeGramianMatrix:130).
+
+    A grid step takes ``lane_tile`` rows of X — a ``(d, T)`` block of the
+    ``(d, n)`` view when X is stored ``feature_major`` (``x.T`` is a
+    bitcast of such an array; nothing of X is padded, copied or upcast in
+    HBM), a ``(T, d)`` block transposed in VMEM otherwise — puts the
+    moment rows ahead of it in a VMEM work tile and multiplies that tile
+    with itself on the MXU, ``GRAM_BLOCK``-square products of the upper
+    triangle only. Every product is a bf16 x bf16 product accumulated in
+    f32, so it is exact. With ``weighted=False`` w is a presence mask
+    (rows with w = 0, and the lanes of the last tile past n, are selected
+    out of the tile): one MXU pass. With ``weighted=True`` the left
+    operand is ``Z·w`` in f32, split into three bf16 pieces per VMEM
+    block: three passes, f32-faithful, still no copy of X.
+
+    Accumulation: one tile's products are summed by the MXU in f32
+    (T ≤ 1,024 terms an entry), the tiles are added with Kahan
+    compensation across the sequential grid, so against float64
+    ``|err_ij| ≤ (T + 4) 2^-24 Σ_r w_r |z_ri z_rj|`` whatever n is — the
+    worst case of the in-tile sum; observed on the v5e at 2,000,000 rows:
+    7e-8 of the diagonal, where a plain ``+=`` over the grid read 1.5e-6.
+    """
     n, d = x.shape
-    if w is None:
-        w = jnp.ones((n,), jnp.float32)
-    w = jnp.asarray(w, jnp.float32)
-    d_pad = _pad_to(d, LANE)
-    row_tile = _auto_row_tile(n, row_tile, x.dtype,
-                              *_gramian_vmem(d_pad, x.dtype.itemsize))
-    n_pad = _pad_to(max(n, row_tile), row_tile)
-    x_p = jnp.pad(x, ((0, n_pad - n), (0, d_pad - d)))
-    w_p = jnp.pad(w, (0, n_pad - n)).reshape(-1, 1)
-    has_scale = x_scale is not None
-    s_p = _pad_scale(x_scale, d, d_pad) if has_scale else None
+    big = MOMENT_ROWS + d
+    edges = list(range(0, big, GRAM_BLOCK)) + [big]
+    spans = list(zip(edges[:-1], edges[1:]))
+    tile = lane_tile
+    n_steps = pl.cdiv(n, tile)
 
-    def gramian(*refs):
-        if has_scale:
-            x_ref, w_ref, s_ref, out_ref = refs
-        else:
-            x_ref, w_ref, out_ref = refs
-            s_ref = None
+    def moment_gramian(x_ref, y_ref, w_ref, acc_ref, comp_ref, z_ref):
         i = pl.program_id(0)
 
         @pl.when(i == 0)
         def _():
-            out_ref[:] = jnp.zeros_like(out_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+            comp_ref[:] = jnp.zeros_like(comp_ref)
 
-        xv = x_ref[:].astype(jnp.float32)
-        if s_ref is not None:
-            xv = xv * s_ref[:]          # fp8 dequant per VMEM block
-        xv = xv * (w_ref[:] > 0).astype(jnp.float32)
-        out_ref[:] += jnp.dot(xv.T, xv, preferred_element_type=jnp.float32,
-                              precision=jax.lax.Precision.HIGHEST)
+        # lanes of the last tile past n hold whatever the buffer held:
+        # select, never multiply (0 · NaN is NaN)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1) + i * tile
+        valid = lane < n
+        wv = jnp.where(valid, w_ref[:], 0.0)
+        keep = valid if weighted else wv > 0
+        y_hi, y_mid, y_lo = (p.astype(jnp.float32) for p in _split3(
+            jnp.where(keep, y_ref[:], 0.0)))
+        row = jax.lax.broadcasted_iota(jnp.int32, (MOMENT_ROWS, tile), 0)
+        moments = jnp.where(
+            row == 0, keep.astype(jnp.float32),
+            jnp.where(row == 1, y_hi, jnp.where(
+                row == 2, y_mid, jnp.where(row == 3, y_lo, 0.0))))
+        z_ref[0:MOMENT_ROWS, :] = moments.astype(jnp.bfloat16)
+        xv = x_ref[:] if feature_major else x_ref[:].T
+        z_ref[MOMENT_ROWS:big, :] = jnp.where(keep, xv,
+                                              jnp.zeros((), xv.dtype))
 
-    in_specs = [pl.BlockSpec((row_tile, d_pad), lambda i: (i, 0)),
-                pl.BlockSpec((row_tile, 1), lambda i: (i, 0))]
-    args = [x_p, w_p]
-    if has_scale:
-        in_specs.append(pl.BlockSpec((1, d_pad), lambda i: (0, 0)))
-        args.append(s_p)
-    g = pl.pallas_call(
-        gramian,
-        name="gramian",
-        grid=(n_pad // row_tile,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((d_pad, d_pad), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((d_pad, d_pad), jnp.float32),
-        compiler_params=_compiler_params("arbitrary"),
+        for a, (i0, i1) in enumerate(spans):
+            if weighted:
+                left = _split3(z_ref[i0:i1, :].astype(jnp.float32) * wv)
+            else:
+                left = (z_ref[i0:i1, :],)
+            for j0, j1 in spans[a:]:
+                right = z_ref[j0:j1, :]
+                v = sum(jax.lax.dot_general(
+                    piece, right, _NT, preferred_element_type=jnp.float32)
+                    for piece in left)
+                # Kahan across the grid, as the GLM sweep's sums
+                yk = v - comp_ref[i0:i1, j0:j1]
+                t = acc_ref[i0:i1, j0:j1] + yk
+                comp_ref[i0:i1, j0:j1] = (t - acc_ref[i0:i1, j0:j1]) - yk
+                acc_ref[i0:i1, j0:j1] = t
+
+    if feature_major:
+        x_arg, x_spec = x.T, pl.BlockSpec((d, tile), lambda i: (0, i))
+    else:
+        x_arg, x_spec = x, pl.BlockSpec((tile, d), lambda i: (i, 0))
+    vec_spec = pl.BlockSpec((1, tile), lambda i: (0, i))
+    return pl.pallas_call(
+        moment_gramian,
+        name="moment_gramian",
+        grid=(n_steps,),
+        in_specs=[x_spec, vec_spec, vec_spec],
+        out_specs=pl.BlockSpec((big, big), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((big, big), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((big, big), jnp.float32),
+                        pltpu.VMEM((big, tile), jnp.bfloat16)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=GRAM_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(*args)
-    return g[:d, :d]
+    )(x_arg, jnp.asarray(y, jnp.float32).reshape(1, n),
+      jnp.asarray(w, jnp.float32).reshape(1, n))
+
+
+def moment_sums(x, y, w, *, feature_major: bool = False,
+                interpret: bool = False) -> Dict[str, jnp.ndarray]:
+    """A shard's weighted moments ``{w_sum, b_sum, bb_sum, a_sum, ab_sum,
+    aa_sum}`` (``a`` the features, ``b`` the label: WeightedLeastSquares'
+    names) from one read of X at storage width — the one Gramian of the
+    package: WeightedLeastSquares aggregates it, ``RowMatrix.
+    compute_gramian`` takes its ``aa_sum`` under a presence mask.
+
+    Which form runs is read off the array, not off a conf key: where
+    :func:`moment_gramian_tile` finds a tile (bfloat16 X on a backend
+    that lowers Mosaic) it is :func:`fused_moment_gramian`, and INSIDE the
+    program the weights pick its pass count — all 0/1 (a presence mask:
+    every default-weight fit) one MXU pass, anything else three. Every
+    other X (the host platform, f32/f64/fp8 storage, widths the kernel's
+    tile or VMEM cannot take) is XLA's own contraction at ``highest``
+    with the weight folded into one operand: on the TPU an operand fusion
+    of the convolution, so no weighted or widened copy of X there either
+    (sandbox AOT at 2,000,000 x 2,000: 0 B of temporaries). ``feature_
+    major`` is the caller's observation (:func:`stored_feature_major`)
+    and picks the kernel's tiling, never the result."""
+    from cycloneml_tpu.observe import tracing
+    n, d = x.shape
+    tile = moment_gramian_tile(n, d, x.dtype, feature_major) \
+        if (interpret or pallas_available()) else None
+    if tile is None:
+        tracing.instant("kernel.wls_moments", orientation="xla",
+                        mxu_passes="highest", pad_cols=0, tail_rows=0)
+        from cycloneml_tpu.dataset.instance import is_narrow_dtype
+        acc = jnp.float32 if is_narrow_dtype(x.dtype) else x.dtype
+        hi = jax.lax.Precision.HIGHEST
+        w = jnp.asarray(w, acc)
+        wy = w * jnp.asarray(y, acc)
+        xw = x.astype(acc) * w[:, None]
+        return {"w_sum": jnp.sum(w), "b_sum": jnp.sum(wy),
+                "bb_sum": jnp.sum(wy * jnp.asarray(y, acc)),
+                # each sum reads X itself: a second consumer of ``xw``
+                # would make XLA write the weighted copy out
+                "a_sum": jnp.dot(w, x, precision=hi,
+                                 preferred_element_type=acc),
+                "ab_sum": jnp.dot(wy, x, precision=hi,
+                                  preferred_element_type=acc),
+                "aa_sum": jnp.einsum("bi,bj->ij", xw, x, precision=hi,
+                                     preferred_element_type=acc)}
+    tracing.instant(
+        "kernel.wls_moments",
+        orientation="feature_major" if feature_major else "row_major",
+        lane_tile=tile, block=GRAM_BLOCK, mxu_passes=1,
+        mxu_passes_weighted=3, pad_cols=0, tail_rows=n % tile)
+    w = jnp.asarray(w, jnp.float32)
+
+    def run(weighted):
+        return lambda: fused_moment_gramian(
+            x, y, w, feature_major=feature_major, lane_tile=tile,
+            weighted=weighted, interpret=interpret)
+
+    upper = jax.lax.cond(jnp.all((w == 0) | (w == 1)), run(False), run(True))
+    k = MOMENT_ROWS
+    y_rows, gram = upper[1:4], upper[k:, k:]
+    return {"w_sum": upper[0, 0], "b_sum": jnp.sum(upper[0, 1:4]),
+            # (y_hi + y_mid + y_lo)^2 from the pieces' upper triangle
+            "bb_sum": jnp.sum(jnp.triu(y_rows[:, 1:4])
+                              + jnp.triu(y_rows[:, 1:4], 1)),
+            "a_sum": upper[0, k:], "ab_sum": jnp.sum(y_rows[:, k:], axis=0),
+            # the lower half is the upper's mirror: symmetric to the bit
+            "aa_sum": jnp.triu(gram) + jnp.triu(gram, 1).T}

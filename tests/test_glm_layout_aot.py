@@ -119,3 +119,93 @@ def test_feature_major_program_on_four_chips(topo):
     assert "tpu_custom_call" in text and "all-reduce" in text
     assert _x_ops(text, n // 4) == [] and _x_ops(text, n) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# -- the normal equations' moment program (WeightedLeastSquares) ---------------
+
+def _compile_moments(topo, n, d, feature_major, n_chips=1):
+    """The aggregation program ``LinearRegression``'s normal solver
+    dispatches — ``wls.moments_aggregator`` under psum — for ``n_chips``
+    described v5e chips. The aggregator asks ``pallas_available()``, which
+    sees this sandbox's CPU: the test answers for the chip."""
+    import jax
+    import jax.numpy as jnp
+    from unittest.mock import patch
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from cycloneml_tpu.ml.optim import wls
+    from cycloneml_tpu.ops import kernels
+    agg = wls.moments_aggregator(feature_major)
+    mesh = Mesh(np.array(topo.devices[:n_chips]), ("data",))
+    rows = NamedSharding(mesh, P("data"))
+    args = [jax.ShapeDtypeStruct((n, d), jnp.bfloat16, sharding=rows),
+            jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rows),
+            jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rows)]
+
+    def program(*a):
+        local = lambda *b: jax.tree_util.tree_map(
+            lambda t: jax.lax.psum(t, "data"), agg(*b))
+        return jax.shard_map(local, mesh=mesh, in_specs=(P("data"),) * 3,
+                             out_specs=P(), check_vma=False)(*a)
+
+    with jax.enable_x64(False), \
+            patch.object(kernels, "pallas_available", lambda: True):
+        return jax.jit(program).lower(*args).compile()
+
+
+def _wide_x(text, n, d):
+    """Instructions that hold an f32 value of X's shape."""
+    return [line.strip() for line in text.splitlines()
+            if re.search(rf"= f32\[{n},{d}\]", line)]
+
+
+def test_moment_program_at_the_cells_shape_reads_x_as_stored(topo):
+    """``linreg_ridge_normal_fit``: 2,000,000 x 2,000 bf16 on one chip.
+    X arrives ``{0,1}``, the program is the Mosaic call (one a branch of
+    the weights' cond) over 8.0 GB of arguments with temporaries of a few
+    (d, d) blocks, and holds no f32 copy, pad or layout copy of X."""
+    n, d = 2_000_000, 2000
+    compiled = _compile_moments(topo, n, d, feature_major=True)
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert _entry_layout_of_x(text) == "0,1"
+    assert text.count("tpu_custom_call") >= 2
+    assert 8.0e9 <= mem.argument_size_in_bytes <= 8.1e9
+    assert mem.temp_size_in_bytes < 64 << 20
+    assert _x_ops(text, n) == [] and _wide_x(text, n, d) == []
+
+
+def test_moment_program_on_four_chips(topo):
+    """Under shard_map on the 2x2 host every chip's 2,000,000-row shard is
+    read as it lies and the (d, d) moments are all-reduced."""
+    n, d = 8_000_000, 2000
+    compiled = _compile_moments(topo, n, d, feature_major=True, n_chips=4)
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+    assert 8.0e9 <= mem.argument_size_in_bytes <= 8.1e9
+    assert mem.temp_size_in_bytes < 64 << 20
+    for rows in (n, n // 4):
+        assert _x_ops(text, rows) == [] and _wide_x(text, rows, d) == []
+
+
+def test_moment_program_at_a_lane_aligned_width_stays_row_major(topo):
+    """d = 1,280: X arrives ``{1,0}``; the row-major tiling transposes its
+    tile in VMEM, so the program still holds no pad or copy of X."""
+    n, d = 2_000_000, 1280
+    compiled = _compile_moments(topo, n, d, feature_major=False)
+    text = compiled.as_text()
+    assert _entry_layout_of_x(text) == "1,0"
+    assert "tpu_custom_call" in text
+    assert _x_ops(text, n) == [] and _wide_x(text, n, d) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_moment_program_where_no_kernel_fits_widens_no_copy_of_x(topo):
+    """d = 200 (200 % 16 = 8: no kernel tile): XLA's contraction with the
+    weight folded into the convolution's operand — no f32 or weighted copy
+    of X in HBM either (temporaries stay under 64 MiB at 1.6 GB of X)."""
+    n, d = 4_000_000, 200
+    compiled = _compile_moments(topo, n, d, feature_major=True)
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from cycloneml_tpu.ops import (fused_binary_logistic,
-                               fused_binary_logistic_scaled, fused_gramian,
+                               fused_binary_logistic_scaled,
                                fused_kmeans_assign,
                                fused_least_squares_scaled)
 from cycloneml_tpu.ml.optim import aggregators
@@ -83,27 +83,6 @@ def test_fused_kmeans_padded_centers_never_win(ctx):
     centers = rng.randn(3, 5) * 1000
     best, _ = fused_kmeans_assign(x, centers, interpret=True, row_tile=128)
     assert np.asarray(best).max() < 3
-
-
-def test_fused_gramian(ctx):
-    rng = np.random.RandomState(3)
-    x = rng.randn(400, 19)
-    g = fused_gramian(x, interpret=True, row_tile=128)
-    np.testing.assert_allclose(np.asarray(g), x.T @ x, rtol=1e-4, atol=1e-3)
-    # symmetry is exact, not approximate
-    np.testing.assert_array_equal(np.asarray(g), np.asarray(g).T)
-
-
-def test_fused_gramian_weight_mask(ctx):
-    """w masks rows by presence INSIDE the kernel — the jnp path's
-    x * (w > 0) row mask without the masked X copy."""
-    rng = np.random.RandomState(4)
-    x = rng.randn(120, 11)
-    w = np.ones(120)
-    w[60:] = 0.0  # masked rows must contribute nothing
-    g = fused_gramian(x, w=w, interpret=True, row_tile=64)
-    ref = x[:60].T @ x[:60]
-    np.testing.assert_allclose(np.asarray(g), ref, rtol=1e-4, atol=1e-3)
 
 
 # -- bf16 data tier: storage-width reads, fp32 in-kernel accumulation --------
@@ -191,15 +170,6 @@ def test_fused_kmeans_assign_bf16_points(ctx):
     d2 = ((xf[:, None, :] - centers[None, :, :]) ** 2).sum(-1)
     np.testing.assert_array_equal(np.asarray(best), d2.argmin(1))
     np.testing.assert_allclose(np.asarray(dist), d2.min(1), rtol=1e-2,
-                               atol=1e-2)
-
-
-def test_fused_gramian_bf16(ctx):
-    rng = np.random.RandomState(10)
-    xbf = _bf16(rng.randn(256, 13))
-    g = fused_gramian(xbf, interpret=True, row_tile=128)
-    xf = np.asarray(xbf, np.float64)
-    np.testing.assert_allclose(np.asarray(g), xf.T @ xf, rtol=1e-3,
                                atol=1e-2)
 
 
@@ -299,17 +269,6 @@ def test_fused_least_squares_fp8_scale_operand(data, ctx):
                                rtol=1e-3)
     np.testing.assert_allclose(np.asarray(got["grad"]),
                                np.asarray(ref["grad"]), rtol=5e-3, atol=5e-3)
-
-
-def test_fused_gramian_fp8(ctx):
-    rng = np.random.RandomState(10)
-    x = rng.randn(96, 9) * np.array([1.0, 4.0, 0.5, 2.0, 1.0, 3.0, 1.0,
-                                     0.25, 1.0])
-    x8, scale = _fp8_cols(x)
-    deq = np.asarray(x8, np.float64) * scale[None, :]
-    g = fused_gramian(x8, interpret=True, row_tile=32, x_scale=scale)
-    np.testing.assert_allclose(np.asarray(g), deq.T @ deq,
-                               rtol=1e-4, atol=1e-3)
 
 
 def test_fused_kmeans_assign_fp8(ctx):

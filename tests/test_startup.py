@@ -76,14 +76,17 @@ def test_backend_peaks_exact_device_kind_only():
 
 
 def test_kernel_wrappers_never_interpret_on_their_own():
-    from cycloneml_tpu.ops import (fused_binary_logistic, fused_gramian,
-                                   fused_kmeans_assign)
+    from cycloneml_tpu.ops import (fused_binary_logistic,
+                                   fused_kmeans_assign,
+                                   fused_moment_gramian)
     rng = np.random.RandomState(0)
     x = rng.randn(64, 8).astype(np.float32)
     y = (x[:, 0] > 0).astype(np.float32)
     w = np.ones(64, np.float32)
     for call in (lambda: fused_binary_logistic(x, y, w, np.zeros(9), 8),
-                 lambda: fused_gramian(x),
+                 lambda: fused_moment_gramian(
+                     x.astype("bfloat16"), y, w, feature_major=True,
+                     lane_tile=64, weighted=False),
                  lambda: fused_kmeans_assign(x, x[:4])):
         with pytest.raises(ValueError, match="[Oo]nly interpret mode"):
             call()

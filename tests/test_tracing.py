@@ -819,7 +819,71 @@ def test_linear_regression_summary_counts_what_the_step_counter_counts(ctx):
     s = model.summary
     assert s.total_evals == s.total_dispatches == steps
     assert s.total_evals > s.total_iterations >= 1
-    # a solver that evaluates no loss function says so
+    assert s.solver == "l-bfgs" and s.total_passes == s.total_evals
+    # the normal equations evaluate no loss function and say so; since
+    # PR 29 they state what they did instead: one pass over X, one dispatch
     from cycloneml_tpu.ml.regression import LinearRegression
+    before = counter.count
     normal = LinearRegression(solver="normal").fit(ds).summary
-    assert normal.total_evals is None and normal.total_dispatches is None
+    assert normal.total_evals is None
+    assert (normal.solver, normal.total_passes, normal.total_dispatches,
+            counter.count - before) == ("normal", 1, 1, 1)
+
+
+def test_normal_solver_fit_is_a_traced_counted_fit(ctx):
+    """A normal-equation fit under the tracer: ``fit.prepare``, the moment
+    program's dispatch with its collective and its readback inside,
+    ``fit.solve``, ``fit.finish`` — all under the job span, the phases
+    disjoint from each other and from the dispatch (what the idle readers
+    partition by), together covering the job — and one ``kernel.
+    wls_moments`` instant for the program the first fit built."""
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    from cycloneml_tpu.ml.regression import LinearRegression
+    from cycloneml_tpu.parallel import collectives
+    rng = np.random.RandomState(9)
+    x = rng.randn(4096, 24)
+    y = x @ rng.randn(24) + 0.5 * rng.randn(4096)
+    ds = InstanceDataset.from_numpy(ctx, x, y)
+    est = LinearRegression(regParam=0.01)
+    collectives.clear_program_cache()
+    tracing.disable()
+    tracer = tracing.enable(max_spans=50_000)
+    try:
+        est.fit(ds)                       # builds the program
+        built = tracer.snapshot()
+        tracer.clear()
+        est.fit(ds)
+        spans = tracer.snapshot()
+    finally:
+        tracing.disable()
+    notes = [s for s in built if s.name == "kernel.wls_moments"]
+    assert len(notes) == 1
+    assert notes[0].attrs["pad_cols"] == 0
+    assert notes[0].attrs["orientation"] == "xla"     # the host platform
+    assert not [s for s in spans if s.name == "kernel.wls_moments"]
+
+    job, = [s for s in spans if s.kind == "job"]
+    by_id = {s.span_id: s for s in spans}
+    named = {(s.kind, s.name): s for s in spans}
+    order = [("phase", "fit.prepare"), ("dispatch", "wls.moments"),
+             ("phase", "fit.solve"), ("phase", "fit.finish")]
+    assert [k for k in order if k in named] == order
+    assert {s.name for s in spans if s.kind == "phase"} == {
+        "fit.prepare", "fit.solve", "fit.finish"}
+    assert len([s for s in spans if s.kind == "dispatch"]) == 1
+    at, covered = job.t0, 0.0
+    for key in order:
+        s = named[key]
+        assert by_id[s.parent_id] is job
+        assert s.t0 >= at - 1e-9, f"{key} overlaps what came before"
+        at, covered = s.t1, covered + s.t1 - s.t0
+    # a 3 ms fit: the estimator's entry code (0.3 ms) is a tenth of it
+    assert covered >= 0.75 * job.duration_s, (covered, job.duration_s)
+    dispatch = named[("dispatch", "wls.moments")]
+    assert dispatch.attrs["passes"] == 1
+    readback = named[("transfer", "wls.readback")]
+    collective, = [s for s in spans if s.kind == "collective"]
+    assert by_id[readback.parent_id] is dispatch
+    assert by_id[collective.parent_id] is dispatch
+    assert collective.t1 <= readback.t0 + 1e-9
+    assert readback.attrs["bytes"] >= 4 * 24 * 24

@@ -1,0 +1,318 @@
+"""The normal-equation path of ``LinearRegression``: the moment pass
+(``ops/kernels.moment_sums`` — the Pallas kernel in the interpreter here,
+XLA's contraction on the host platform) against float64, and whole fits
+through ``fit(InstanceDataset)`` against the benchmark's plain reference
+(``perfbench/reference/linreg_ridge.py``, which imports nothing of the
+program).
+
+Tolerances. The products of stored-bf16 operands are exact and every sum
+is f32 (the reference's too: f32 ``highest`` blocks, float64 from there),
+so a model of 4,096 rows agrees to a few 1e-6 of its norm; 2e-5 leaves
+room for the f32 sums' order and would still fail a bf16 accumulator or a
+Gramian of rounded X (1e-3 and up).
+"""
+
+from unittest.mock import patch
+
+import numpy as np
+import pytest
+
+from cycloneml_tpu.ops import kernels
+
+COEF_RTOL = 2e-5
+
+
+def _bf16(a):
+    import jax.numpy as jnp
+    return jnp.asarray(np.asarray(a, np.float32), jnp.bfloat16)
+
+
+# -- the moment pass against float64 ------------------------------------------
+
+def _moment_reference(x, y, w):
+    x, y, w = (np.asarray(a, np.float64) for a in (x, y, w))
+    return {"w_sum": w.sum(), "b_sum": (w * y).sum(),
+            "bb_sum": (w * y * y).sum(), "a_sum": w @ x,
+            "ab_sum": (w * y) @ x, "aa_sum": (x * w[:, None]).T @ x}
+
+
+def _assert_moments(got, want, rtol):
+    for k, v in want.items():
+        scale = max(np.max(np.abs(v)), 1.0)
+        np.testing.assert_allclose(np.asarray(got[k], np.float64), v,
+                                   rtol=0, atol=rtol * scale, err_msg=k)
+
+
+def _kernel_moments(x, y, w, feature_major, tile):
+    with patch.object(kernels, "moment_gramian_tile", lambda *a: tile):
+        return kernels.moment_sums(x, y, w, feature_major=feature_major,
+                                   interpret=True)
+
+
+@pytest.mark.parametrize("n,d,feature_major,tile", [
+    (400, 32, True, 128),      # a tail of 16 rows, one block
+    (1000, 528, True, 256),    # 16 + 528 rows: several blocks of the triangle
+    (384, 128, False, 128),    # row-major tile, transposed in VMEM
+    (900, 256, False, 256),    # row-major with a tail
+])
+def test_fused_moment_gramian(ctx, n, d, feature_major, tile):
+    """The augmented Gramian [1|y|X]'W[1|y|X] of a bf16 X under a presence
+    mask: every moment WeightedLeastSquares wants, against float64 over
+    the stored values (products are exact; 3e-6 is the f32 sums' room)."""
+    rng = np.random.RandomState(3)
+    x = _bf16(rng.randn(n, d))
+    y = rng.randn(n).astype(np.float32)
+    w = (rng.rand(n) > 0.2).astype(np.float32)
+    got = _kernel_moments(x, y, w, feature_major, tile)
+    _assert_moments(got, _moment_reference(x, y, w), 3e-6)
+    # symmetry is exact, not approximate: the lower half is the upper's
+    g = np.asarray(got["aa_sum"])
+    np.testing.assert_array_equal(g, g.T)
+
+
+@pytest.mark.parametrize("feature_major", [True, False])
+def test_fused_moment_gramian_weights_not_binary(ctx, feature_major):
+    """Weights that are not 0/1 take the three-pass branch INSIDE the same
+    program (the f32 product x·w as three bf16 pieces per VMEM block) and
+    agree with float64 as the one-pass branch does; zero weights still
+    drop their rows."""
+    rng = np.random.RandomState(4)
+    n, d = 640, 128
+    x = _bf16(rng.randn(n, d))
+    y = rng.randn(n).astype(np.float32)
+    w = (0.25 + rng.rand(n)).astype(np.float32)
+    w[::7] = 0.0
+    got = _kernel_moments(x, y, w, feature_major, 128)
+    _assert_moments(got, _moment_reference(x, y, w), 3e-6)
+
+
+@pytest.mark.parametrize("feature_major", [True, False])
+def test_moment_gramian_tail_rows_carry_their_weight(ctx, feature_major):
+    """Rows that do not fill the last tile are counted (once) and the
+    lanes past n are selected out: the tail rows carry 1000x the weight,
+    so a dropped or a garbage row moves every sum."""
+    rng = np.random.RandomState(5)
+    n, d, tile = 128 * 3 + 37, 128, 128
+    x = _bf16(rng.randn(n, d))
+    y = rng.randn(n).astype(np.float32)
+    w = np.ones(n, np.float32)
+    w[-37:] = 1000.0
+    got = _kernel_moments(x, y, w, feature_major, tile)
+    _assert_moments(got, _moment_reference(x, y, w), 3e-6)
+
+
+def test_moment_gramian_accumulation_bound(ctx):
+    """The stated bound against float64, |err_ij| <= (T + 4) 2^-24
+    sum_r w_r |z_ri z_rj|, over many grid steps (157 tiles of 128 rows) of
+    same-sign products: the compensated sum stays at the in-tile error
+    whatever the step count; the test holds a twentieth of the worst
+    case."""
+    rng = np.random.RandomState(6)
+    n, d, tile = 20000, 16, 128
+    x = _bf16(np.abs(rng.randn(n, d)) + 0.5)
+    y = np.abs(rng.randn(n)).astype(np.float32)
+    w = np.ones(n, np.float32)
+    got = _kernel_moments(x, y, w, True, tile)
+    want = _moment_reference(x, y, w)
+    x64 = np.abs(np.asarray(x, np.float64))
+    bound = (tile + 4) * 2.0 ** -24 * (x64.T @ x64)
+    err = np.abs(np.asarray(got["aa_sum"], np.float64) - want["aa_sum"])
+    assert np.all(err <= bound / 20), float(np.max(err / bound))
+    assert abs(float(got["bb_sum"]) - want["bb_sum"]) <= \
+        (tile + 4) * 2.0 ** -24 * want["bb_sum"] / 20
+
+
+def test_moment_sums_reads_the_array_not_a_conf_key(ctx):
+    """Where no kernel can be built — the host platform, f32 storage, a
+    width that ends inside a packed sublane group, too few rows, a width
+    past the VMEM the kernel declares — the moments are XLA's
+    contraction; where one can, a tile comes back."""
+    import jax.numpy as jnp
+    tile = kernels.moment_gramian_tile
+    assert tile(2_000_000, 2000, jnp.bfloat16, True) is not None
+    assert tile(2_000_000, 1280, jnp.bfloat16, False) is not None
+    assert tile(2_000_000, 2000, np.float32, True) is None
+    assert tile(2_000_000, 200, jnp.bfloat16, True) is None    # 200 % 16
+    assert tile(2_000_000, 2000, jnp.bfloat16, False) is None  # 2000 % 128
+    assert tile(100, 2000, jnp.bfloat16, True) is None
+    assert tile(2_000_000, 4096, jnp.bfloat16, False) is None  # VMEM
+    rng = np.random.RandomState(7)
+    x = _bf16(rng.randn(300, 28))
+    y = rng.randn(300).astype(np.float32)
+    w = np.ones(300, np.float32)
+    got = kernels.moment_sums(x, y, w)          # no TPU here: XLA
+    _assert_moments(got, _moment_reference(x, y, w), 3e-6)
+
+
+# -- whole fits against the plain reference ------------------------------------
+
+ROW_AXES = ("replica", "data")
+
+
+def _case(seed, n, d, constant_col=None):
+    rng = np.random.RandomState(seed)
+    x = rng.randn(n, d)
+    if constant_col is not None:
+        x[:, constant_col] = 1.5
+    beta = rng.randn(d) / np.sqrt(d)
+    y = x @ beta + 0.5 * rng.randn(n) + 0.3
+    return x, y
+
+
+def _stored(x, dtype):
+    """X rounded to its storage type, as float64: what both sides see."""
+    import jax.numpy as jnp
+    return np.asarray(jnp.asarray(x, jnp.dtype(dtype)), np.float64)
+
+
+def _reference(ctx, x, y, params):
+    """The plain reference on (replicated) rows placed on the mesh."""
+    from perfbench.reference import linreg_ridge
+    rt = ctx.mesh_runtime
+    data = (rt.device_put_sharded_rows(x.astype(np.float32)),
+            rt.device_put_sharded_rows(y.astype(np.float32)),
+            rt.mesh, ROW_AXES)
+    return linreg_ridge.fit(data, params)
+
+
+def _fit(ctx, x, y, w, dtype, params):
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    from cycloneml_tpu.ml.regression import LinearRegression
+    import jax.numpy as jnp
+    ds = InstanceDataset.from_numpy(ctx, x, y, w, dtype=jnp.dtype(dtype))
+    return LinearRegression(**params).fit(ds), ds
+
+
+def _gap(model, ref):
+    got = np.append(np.asarray(model.coefficients, np.float64),
+                    model.intercept)
+    want = np.append(ref["coef"], ref["intercept"])
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+PARAMS = {"maxIter": 100, "regParam": 0.01, "elasticNetParam": 0.0}
+
+
+@pytest.mark.parametrize("dtype,d", [("float32", 200), ("bfloat16", 200),
+                                     ("float32", 28), ("bfloat16", 28),
+                                     ("bfloat16", 256)])
+def test_ridge_fit_equals_the_plain_reference(ctx, dtype, d):
+    """``LinearRegression(regParam=0.01).fit(InstanceDataset)`` takes the
+    normal equations and lands on the reference's closed-form optimum, at
+    f32 and bf16 storage, at widths that are no multiple of 128 (200, 28)
+    and one that is (256); the objective it reports is the reference's
+    objective at the fit's own model."""
+    x, y = _case(11, 4096, d)
+    xs = _stored(x, dtype)
+    y32 = y.astype(np.float32).astype(np.float64)
+    model, _ = _fit(ctx, xs, y32, None, dtype, PARAMS)
+    s = model.summary
+    assert (s.solver, s.total_passes, s.total_dispatches) == ("normal", 1, 1)
+    ref = _reference(ctx, xs, y32, PARAMS)
+    assert _gap(model, ref) <= COEF_RTOL
+    ours = ref["problem"].objective_of(
+        np.asarray(model.coefficients)[None], np.array([model.intercept]))[0]
+    assert s.objective_history[-1] == pytest.approx(ours, rel=1e-6)
+    assert s.objective_history[-1] == pytest.approx(ref["objective"],
+                                                    rel=1e-6)
+
+
+@pytest.mark.parametrize("path", ["feature_major", "row_major"])
+def test_ridge_fit_on_the_kernel_equals_the_plain_reference(ctx, monkeypatch,
+                                                            path):
+    """The same fit with the moment pass on the Pallas kernel (the test
+    routes its pallas_call through the interpreter and says what the chip
+    would: Mosaic lowers, X is stored this way): both tilings, rows that
+    do not fill the last tile (4,096 rows over 8 shards = 512 a shard, the
+    tile 384), one dispatch a fit."""
+    from cycloneml_tpu.parallel import collectives
+    d = 48 if path == "feature_major" else 128
+    x, y = _case(12, 4096, d)
+    xs = _stored(x, "bfloat16")
+    y32 = y.astype(np.float32).astype(np.float64)
+    native_call = kernels.pl.pallas_call
+    monkeypatch.setattr(
+        kernels.pl, "pallas_call",
+        lambda *a, **kw: native_call(*a, **{**kw, "interpret": True}))
+    monkeypatch.setattr(kernels, "pallas_available", lambda: True)
+    monkeypatch.setattr(kernels, "stored_feature_major",
+                        lambda a: path == "feature_major")
+    monkeypatch.setattr(kernels, "moment_gramian_tile", lambda *a: 384)
+    collectives.clear_program_cache()
+    try:
+        model, _ = _fit(ctx, xs, y32, None, "bfloat16", PARAMS)
+    finally:
+        collectives.clear_program_cache()
+    ref = _reference(ctx, xs, y32, PARAMS)
+    assert _gap(model, ref) <= COEF_RTOL
+    assert model.summary.total_dispatches == 1
+
+
+def test_ridge_fit_with_weights_equals_the_reference_on_repeated_rows(ctx):
+    """Weights that are not 0/1, and zero-weight rows among them: a row of
+    weight k is the reference's row k times, a row of weight 0 is not in
+    the reference's data at all (the dataset's own padding rows are such
+    rows too: 4,090 rows over 8 shards)."""
+    x, y = _case(13, 4090, 40)
+    rng = np.random.RandomState(14)
+    w = rng.randint(0, 4, size=4090).astype(np.float64)
+    w[-2:] = 3.0
+    w[: (8 - int(w.sum()) % 8) % 8] += 1.0     # rows the shards divide
+    xs = _stored(x, "bfloat16")
+    y32 = y.astype(np.float32).astype(np.float64)
+    model, _ = _fit(ctx, xs, y32, w, "bfloat16", PARAMS)
+    times = w.astype(int)
+    ref = _reference(ctx, np.repeat(xs, times, axis=0),
+                     np.repeat(y32, times), PARAMS)
+    assert _gap(model, ref) <= COEF_RTOL
+
+
+def test_ridge_fit_with_a_constant_column(ctx):
+    """A column that does not vary gets the coefficient 0 on both sides
+    (its standardised column is zero) and the rest agree."""
+    x, y = _case(15, 4096, 24, constant_col=5)
+    xs = _stored(x, "bfloat16")
+    y32 = y.astype(np.float32).astype(np.float64)
+    model, _ = _fit(ctx, xs, y32, None, "bfloat16", PARAMS)
+    ref = _reference(ctx, xs, y32, PARAMS)
+    assert model.coefficients[5] == 0.0 and ref["coef"][5] == 0.0
+    assert _gap(model, ref) <= COEF_RTOL
+
+
+def test_every_fit_pays_its_pass(ctx):
+    """Three fits of one dataset in a row: each dispatches the moment
+    program once (the context's step counter and the summary agree), no
+    fit is handed another's Gramian, and the program is built once."""
+    from cycloneml_tpu.ml.regression import LinearRegression
+    from cycloneml_tpu.parallel import collectives
+    x, y = _case(16, 2048, 20)
+    model, ds = _fit(ctx, x, y, None, "float32", PARAMS)
+    counter = ctx.metrics.registry.counter("steps.completed")
+    programs = len(collectives._program_cache)
+    for reg in (0.01, 0.01, 0.5):
+        before = counter.count
+        m = LinearRegression(regParam=reg).fit(ds)
+        assert counter.count - before == 1
+        s = m.summary
+        assert (s.solver, s.total_passes, s.total_dispatches,
+                s.total_evals) == ("normal", 1, 1, None)
+    assert len(collectives._program_cache) == programs
+    # another regParam moved the model: the moments were used, not a model
+    assert np.linalg.norm(np.asarray(m.coefficients)
+                          - np.asarray(model.coefficients)) > 1e-3
+
+
+def test_arrays_and_dataset_take_the_same_pass(ctx):
+    """``WeightedLeastSquares.fit(x, y, w)`` on bare arrays (the small
+    callers) and ``fit(dataset)`` solve the same system."""
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    from cycloneml_tpu.ml.optim.wls import WeightedLeastSquares
+    x, y = _case(17, 1000, 12)
+    w = np.random.RandomState(18).rand(1000) + 0.5
+    ds = InstanceDataset.from_numpy(ctx, x, y, w, dtype=np.float64)
+    a = WeightedLeastSquares(True, reg_param=0.1).fit(x, y, w)
+    b = WeightedLeastSquares(True, reg_param=0.1).fit(ds)
+    np.testing.assert_allclose(a.coefficients, b.coefficients, rtol=1e-9)
+    assert a.intercept == pytest.approx(b.intercept, rel=1e-9)
+    assert a.objective_history == pytest.approx(b.objective_history)
+    np.testing.assert_allclose(a.diag_inv_atwa, b.diag_inv_atwa, rtol=1e-7)
