@@ -661,7 +661,8 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
             objective_history=list(state.loss_history),
             total_iterations=state.iteration,
             total_evals=loss_fn.n_evals,
-            total_dispatches=loss_fn.n_dispatches)
+            total_dispatches=loss_fn.n_dispatches,
+            search_evals=list(state.search_evals) or None)
         return model
 
     def _fit_dataset(self, ds: InstanceDataset) -> "LogisticRegressionModel":
@@ -966,7 +967,8 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
                 total_iterations=state.iteration,
                 total_evals=loss_fn.n_evals,
                 total_dispatches=loss_fn.n_dispatches,
-                streamed=streamed, orientation=orientation)
+                streamed=streamed, orientation=orientation,
+                search_evals=list(state.search_evals) or None)
             return model
 
     def copy(self, extra=None) -> "LogisticRegression":
@@ -1089,7 +1091,7 @@ class LogisticRegressionTrainingSummary:
 
     def __init__(self, objective_history, total_iterations,
                  total_evals=None, total_dispatches=None, n_models=1,
-                 streamed=False, orientation=None):
+                 streamed=False, orientation=None, search_evals=None):
         self.objective_history = objective_history
         self.total_iterations = total_iterations
         # optimizer-path telemetry: loss/grad evaluations and host->device
@@ -1097,6 +1099,10 @@ class LogisticRegressionTrainingSummary:
         # not ~ evals)
         self.total_evals = total_evals
         self.total_dispatches = total_dispatches
+        # OWL-QN only (None otherwise): evaluations per turn, [1] for the
+        # initial one and then one entry per iteration's line search;
+        # sums to total_evals
+        self.search_evals = search_evals
         # >1 when this model trained inside a stacked (vmapped model-axis)
         # fit: its compiles AND dispatches were shared by n_models models
         self.n_models = n_models

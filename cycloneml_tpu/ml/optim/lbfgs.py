@@ -9,7 +9,13 @@ Nocedal–Wright L-BFGS with strong-Wolfe line search (what Breeze's
 m=10 (Spark's default ``aggregationDepth``-independent corrections), initial
 Hessian scaling γ = sᵀy/yᵀy, and Breeze-compatible convergence tests
 (max iterations; relative function-value improvement ≤ tol; gradient-norm
-ratio). OWL-QN adds the L1 pseudo-gradient and orthant projection.
+ratio). OWL-QN adds the L1 pseudo-gradient and orthant projection, and
+searches the line as Andrew & Gao's OWL-QN and Breeze's
+``OWLQN.determineStepSize`` do: Armijo backtracking on the penalised,
+orthant-projected objective (``OWLQN._search``). Strong Wolfe is for the
+smooth objectives of ``LBFGS`` / ``LBFGSB`` only: under an L1 term the slope
+of the smooth part along the ray is not the slope of the objective, so a
+curvature test on it cannot be met near the optimum.
 
 The loss/grad callable is typically the jit-compiled mesh aggregation
 (psum over ICI); optimizer state stays on the host in float64 — exactly the
@@ -57,6 +63,10 @@ class OptimState:
     hist_s: List[np.ndarray] = field(default_factory=list)
     hist_y: List[np.ndarray] = field(default_factory=list)
     raw_grad: Optional[np.ndarray] = None  # OWLQN: grad before pseudo-grad
+    # OWLQN: evaluations per turn — [1] for the initial evaluation, then one
+    # entry per iteration's line search ("4 iterations, 1+1+1+1+1"); sums to
+    # the evaluations the optimizer asked of the loss function
+    search_evals: List[int] = field(default_factory=list)
 
     def to_pytree(self) -> dict:
         return {"x": self.x, "value": self.value, "grad": self.grad,
@@ -65,7 +75,8 @@ class OptimState:
                 "converged_reason": self.converged_reason,
                 "loss_history": list(self.loss_history),
                 "hist_s": list(self.hist_s), "hist_y": list(self.hist_y),
-                "raw_grad": self.raw_grad}
+                "raw_grad": self.raw_grad,
+                "search_evals": list(self.search_evals)}
 
     @classmethod
     def from_pytree(cls, t: dict) -> "OptimState":
@@ -77,7 +88,8 @@ class OptimState:
                    hist_s=[np.asarray(s) for s in t["hist_s"]],
                    hist_y=[np.asarray(y) for y in t["hist_y"]],
                    raw_grad=(np.asarray(t["raw_grad"])
-                             if t.get("raw_grad") is not None else None))
+                             if t.get("raw_grad") is not None else None),
+                   search_evals=[int(n) for n in t.get("search_evals", [])])
 
 
 class _History:
@@ -423,6 +435,9 @@ class OWLQN(LBFGS):
 
     ``l1_reg`` may be a scalar or per-coordinate array (the reference passes
     0 for the intercept and per-feature values under standardization).
+
+    Line searches run on the HOST, one evaluation per trial step (for a
+    streamed fit: one epoch), and usually one trial: see :meth:`_search`.
     """
 
     def __init__(self, max_iter: int = 100, m: int = 10, tol: float = 1e-6,
@@ -442,6 +457,61 @@ class OWLQN(LBFGS):
         pg = np.where(at_zero & (grad - lam > 0), grad - lam, pg)
         return pg
 
+    def _search(self, f: LossGrad, state: OptimState, raw_grad: np.ndarray,
+                d: np.ndarray, orthant: np.ndarray, init_alpha: float):
+        """OWL-QN's line search: backtrack from ``init_alpha`` by halves to
+        the first α with sufficient decrease of the PENALISED objective at
+        the orthant-projected point, ``F(π(x + αd)) ≤ F(x) + c1·α·d·pg``,
+        and nothing else. The slope is Breeze's (``OWLQN.determineStepSize``
+        backtracks on ``dir·adjustedGradient``) rather than Andrew & Gao's
+        per-trial ``pg·(π(x + αd) − x)``: it is one number a search,
+        negative whatever ``l1_reg`` is (with no L1 share ``d`` is not
+        projected, and a slope over the clipped step need not be), and at
+        c1 = 1e-4 the two differ by a ten-thousandth of what the clipped
+        coordinates were predicted to give. No curvature condition:
+        ``d·∇f`` along the ray is the slope of the smooth part only (it
+        differs from φ' by ``d·(λ·sign x)``, which does not vanish at the
+        optimum), and ``_History.update`` keeps a pair only when ``sᵀy`` is
+        safely positive, which is all a Wolfe condition would buy.
+
+        The search also ends when the first-order decrease still on offer,
+        ``α·|d·pg|``, is below what the objective resolves: ``eps·|F(x)|``,
+        ``eps`` of the dtype the loss function accumulates in
+        (``f.accumulator_dtype``; float64 for a plain callable). A trial
+        that fails Armijo there fails by rounding, and every smaller α
+        offers less. It then keeps the lowest point seen if that lies no
+        more than the resolution above ``F(x)``; otherwise (as when all 30
+        trials fail, the backstop) the step is empty and the caller's
+        ``|Δf|`` test ends the run.
+
+        Returns ``(alpha, x_new, value, raw_grad, evals, outcome)``,
+        outcome ``first_trial`` / ``backtracked`` (Armijo held) or
+        ``unresolved`` (no α could be certified)."""
+        c1, max_evals = 1e-4, 30
+        slope = float(np.dot(d, state.grad))
+        if slope >= 0:
+            raise ValueError("direction is not a descent direction")
+        resolution = float(np.finfo(
+            getattr(f, "accumulator_dtype", np.float64)).eps) * abs(state.value)
+        best = (0.0, state.x, state.value, raw_grad)  # the empty step
+        lowest = state.value + resolution
+        alpha = init_alpha
+        for evals in range(1, max_evals + 1):
+            xt = state.x + alpha * d
+            xt = np.where(xt * orthant >= 0, xt, 0.0)  # orthant projection
+            v, g = f(xt)
+            v = float(v) + self._l1(xt)
+            g = np.asarray(g, dtype=np.float64)
+            if v <= state.value + c1 * alpha * slope:
+                return (alpha, xt, v, g, evals,
+                        "first_trial" if evals == 1 else "backtracked")
+            if v <= lowest:
+                best, lowest = (alpha, xt, v, g), v
+            if -alpha * slope <= resolution:
+                break
+            alpha *= 0.5
+        return best + (evals, "unresolved")
+
     def iterations(self, f: LossGrad, x0: np.ndarray,
                    resume: Optional[OptimState] = None):
         hist = _History(self.m)
@@ -460,41 +530,32 @@ class OWLQN(LBFGS):
                 grad = np.asarray(grad, dtype=np.float64)
                 state = OptimState(x=x, value=value,
                                    grad=self._pseudo_grad(x, grad),
-                                   raw_grad=grad)
+                                   raw_grad=grad, search_evals=[1])
                 state.loss_history.append(state.value)
                 raw_grad = grad
         yield state
         if state.converged:
             return  # resumed from a finished checkpoint: nothing to do
         while True:
-            with _turn(state.iteration + 1):
+            with _turn(state.iteration + 1) as turn:
                 d = hist.direction(state.grad)
                 # project direction onto the pseudo-gradient descent orthant
                 d = np.where(d * state.grad >= 0, 0.0, d) if self._has_l1() else d
                 if not np.any(d):
                     d = -state.grad
                 orthant = np.where(x != 0, np.sign(x), -np.sign(state.grad))
-
-                def f_projected(xt: np.ndarray):
-                    xt = np.where(xt * orthant >= 0, xt, 0.0)  # orthant projection
-                    v, g = f(xt)
-                    return float(v) + self._l1(xt), np.asarray(g, dtype=np.float64)
-
-                init_alpha = 1.0 if state.iteration > 0 else \
-                    min(1.0, 1.0 / max(float(np.linalg.norm(state.grad)), 1e-12))
+                steepest_alpha = min(1.0, 1.0 / max(
+                    float(np.linalg.norm(state.grad)), 1e-12))
                 try:
-                    alpha, v_new, g_new = _strong_wolfe(
-                        f_projected, state.x, state.value, state.grad, d, init_alpha,
-                        c2=0.99)  # Breeze OWLQN relaxes curvature
+                    alpha, x_new, v_new, raw_grad_new, evals, outcome = \
+                        self._search(
+                            f, state, raw_grad, d, orthant,
+                            1.0 if state.iteration > 0 else steepest_alpha)
                 except ValueError:
-                    d = -state.grad
-                    alpha, v_new, g_new = _strong_wolfe(
-                        f_projected, state.x, state.value, state.grad, d,
-                        min(1.0, 1.0 / max(float(np.linalg.norm(state.grad)), 1e-12)),
-                        c2=0.99)
-                x_new = state.x + alpha * d
-                x_new = np.where(x_new * orthant >= 0, x_new, 0.0)
-                raw_grad_new = g_new
+                    alpha, x_new, v_new, raw_grad_new, evals, outcome = \
+                        self._search(f, state, raw_grad, -state.grad, orthant,
+                                     steepest_alpha)
+                turn.annotate(search_evals=evals, alpha=alpha, search=outcome)
                 pg_new = self._pseudo_grad(x_new, raw_grad_new)
                 hist.update(x_new - state.x, raw_grad_new - raw_grad)
                 f_old = state.value
@@ -505,7 +566,8 @@ class OWLQN(LBFGS):
                     iteration=state.iteration + 1,
                     loss_history=state.loss_history + [float(v_new)],
                     hist_s=list(hist.s), hist_y=list(hist.y),
-                    raw_grad=raw_grad_new)
+                    raw_grad=raw_grad_new,
+                    search_evals=state.search_evals + [evals])
                 reason = self._converged(state, f_old)
                 if reason is not None:
                     state.converged = True

@@ -71,6 +71,10 @@ class DistributedLossFunction:
             ws = dataset.tree_aggregate_fn(_weight_sum_agg)()
             weight_sum = float(ws["ws"])
         self.weight_sum = weight_sum
+        # what the sums are accumulated in on the device: the resolution of
+        # the loss this returns (OWLQN's line search reads it)
+        from cycloneml_tpu.dataset.instance import compute_dtype
+        self.accumulator_dtype = np.dtype(compute_dtype())
         self.n_evals = 0
         self.n_dispatches = 0  # host->device dispatch round trips
 

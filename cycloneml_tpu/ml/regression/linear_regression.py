@@ -179,8 +179,9 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
             # is divided by the label std (ref LinearRegression.scala:396
             # effectiveRegParam = regParam / yStd; WeightedLeastSquares.scala:209)
             eff_reg = reg / y_std
-        coef, icpt, history, loss_fn, orientation = self._solve_quasi_newton(
+        coef, icpt, state, loss_fn, orientation = self._solve_quasi_newton(
             ds, stats, y_mean, y_std, eff_reg, alpha)
+        history = list(state.loss_history)
 
         with tracing.span("phase", "fit.finish"):
             model = LinearRegressionModel(coef, icpt, uid=self.uid)
@@ -190,7 +191,8 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
                 history, max(len(history) - 1, 0),
                 total_evals=loss_fn.n_evals,
                 total_dispatches=loss_fn.n_dispatches, streamed=streamed,
-                orientation=orientation, total_passes=loss_fn.n_evals)
+                orientation=orientation, total_passes=loss_fn.n_evals,
+                search_evals=list(state.search_evals) or None)
             return model
 
     # -- normal equations: one moment pass, then the driver's solve ------------
@@ -325,7 +327,7 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
             beta_hat = state.x  # standardized-space coefficients
             coef = beta_hat * inv_std * y_std
             icpt = y_mean - float(coef @ x_mean) if fit_intercept else 0.0
-        return coef, icpt, list(state.loss_history), loss_fn, orientation
+        return coef, icpt, state, loss_fn, orientation
 
 
 class LinearRegressionModel(PredictionModel, _LinearRegressionParams,
@@ -381,7 +383,8 @@ class LinearRegressionModel(PredictionModel, _LinearRegressionParams,
 class LinearRegressionTrainingSummary:
     def __init__(self, objective_history, total_iterations,
                  total_evals=None, total_dispatches=None, streamed=False,
-                 orientation=None, solver="l-bfgs", total_passes=None):
+                 orientation=None, solver="l-bfgs", total_passes=None,
+                 search_evals=None):
         # the objective per iteration (quasi-Newton), or the one value the
         # closed-form solution reaches (normal: the standardised
         # quadratic, penalty included)
@@ -398,6 +401,10 @@ class LinearRegressionTrainingSummary:
         # states neither)
         self.total_evals = total_evals
         self.total_dispatches = total_dispatches
+        # OWL-QN only (None otherwise): evaluations per turn, [1] for the
+        # initial one and then one entry per iteration's line search;
+        # sums to total_evals ("4 iterations, 1+1+1+1+1")
+        self.search_evals = search_evals
         # True when the fit ran on the out-of-core streaming engine
         self.streamed = streamed
         # tiling of the fused GLM sweep the fit ran ("feature_major" /

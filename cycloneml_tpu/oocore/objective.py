@@ -65,6 +65,10 @@ class StreamingLossFunction:
         self.l2_reg_fn = l2_reg_fn
         self.weight_sum = float(weight_sum) if weight_sum is not None \
             else float(sds.weight_sum)
+        # each shard's sums are accumulated in this on the device (the fold
+        # across shards is float64): the resolution of the loss returned
+        from cycloneml_tpu.dataset.instance import compute_dtype
+        self.accumulator_dtype = np.dtype(compute_dtype())
         self.n_evals = 0
         self.n_dispatches = 0   # shard dispatches (n_shards per epoch)
         self.epochs = 0

@@ -85,6 +85,115 @@ def test_owlqn_zero_l1_equals_lbfgs():
     np.testing.assert_allclose(a.x, o.x, atol=1e-8)
 
 
+# -- OWL-QN's line search ------------------------------------------------------
+
+@pytest.fixture
+def tracer():
+    from cycloneml_tpu.observe import tracing
+    tracing.disable()  # defend against a leak from a dirty test
+    t = tracing.enable(max_spans=10_000)
+    yield t
+    tracing.disable()
+
+
+def _enet_quadratic(d=200, n=100_000, l1=0.005, l2=0.005, seed=7,
+                    dtype=np.float64):
+    """A seeded elastic-net quadratic shaped like a standardised regression
+    fit: Gramian I + O(n^-1/2) noise, ||beta|| = 1, noise 0.5, unit label
+    variance. Returns a counting loss/grad callable that rounds what it
+    returns to ``dtype`` (and says so, as the distributed loss functions
+    do), and a long proximal-gradient solve's point and objective."""
+    rng = np.random.RandomState(seed)
+    e = rng.randn(d, d) / np.sqrt(n)
+    h = np.eye(d) + (e + e.T) / 2
+    beta = rng.randn(d)
+    beta /= np.linalg.norm(beta)
+    b = (h @ beta + 0.5 * rng.randn(d) / np.sqrt(n)) / np.sqrt(1.25)
+
+    def smooth(x):
+        hx = h @ x
+        return (0.5 - b @ x + 0.5 * x @ hx + 0.5 * l2 * x @ x,
+                hx - b + l2 * x)
+
+    class Counted:
+        accumulator_dtype = np.dtype(dtype)
+        n_evals = 0
+
+        def __call__(self, x):
+            self.n_evals += 1
+            v, g = smooth(x)
+            return float(dtype(v)), g.astype(dtype).astype(np.float64)
+
+    x = np.zeros(d)
+    step = 1.0 / (np.linalg.eigvalsh(h)[-1] + l2)
+    for _ in range(2000):
+        z = x - step * smooth(x)[1]
+        x = np.sign(z) * np.maximum(np.abs(z) - step * l1, 0.0)
+    return Counted(), np.full(d, l1), x, smooth(x)[0] + l1 * np.abs(x).sum()
+
+
+@pytest.mark.parametrize("dtype", [np.float64, np.float32])
+def test_owlqn_search_takes_one_evaluation_an_iteration(dtype):
+    """The regression this guards: with the strong-Wolfe zoom in OWL-QN's
+    place (curvature tested on the smooth part's slope, which the L1 term
+    keeps from vanishing) this case counted 67 (float64) and 66 (float32)
+    evaluations for its 4 iterations, two searches running out their
+    30-evaluation cap; the Armijo backtracking search takes the first trial
+    step: 5."""
+    f, l1, x_ref, f_ref = _enet_quadratic(dtype=dtype)
+    st = OWLQN(tol=1e-6, l1_reg=l1).minimize(f, np.zeros(len(l1)))
+    assert st.converged and st.iteration >= 2
+    assert f.n_evals <= st.iteration + 3, st.search_evals
+    assert abs(st.value - f_ref) <= 1e-7 * f_ref
+    assert np.linalg.norm(st.x - x_ref) <= 1e-4 * np.linalg.norm(x_ref)
+
+
+def test_owlqn_search_never_climbs_and_says_unresolved(tracer):
+    """A loss that carries +-1 ulp of float32 noise: once the decrease on
+    offer is under that resolution the search stops instead of halving to
+    its cap, says so, and hands back nothing that lies above F(x) by more
+    than the resolution."""
+    f, l1, _, _ = _enet_quadratic(dtype=np.float32)
+    rng = np.random.RandomState(0)
+
+    def noisy(x):
+        v, g = f(x)
+        return float(np.nextafter(np.float32(v), np.float32(
+            rng.choice([-np.inf, np.inf])))), g
+    noisy.accumulator_dtype = np.dtype(np.float32)
+
+    eps = float(np.finfo(np.float32).eps)
+    states = list(OWLQN(tol=1e-12, max_iter=40, l1_reg=l1).iterations(
+        noisy, np.zeros(len(l1))))
+    for before, after in zip(states, states[1:]):
+        assert after.value <= before.value + eps * abs(before.value)
+    turns = [s.attrs for s in tracer.snapshot()
+             if s.name == "optim.iteration" and s.attrs["iteration"] > 0]
+    assert [t["search_evals"] for t in turns] == states[-1].search_evals[1:]
+    assert {t["search"] for t in turns} <= {
+        "first_trial", "backtracked", "unresolved"}
+    assert turns[-1]["search"] == "unresolved"
+    # a search that cannot be resolved is short: far under the 30-trial cap
+    assert max(states[-1].search_evals) <= 12, states[-1].search_evals
+    assert states[-1].converged_reason == "function value converged"
+
+
+def test_owlqn_search_evals_sum_to_the_evaluations():
+    f, l1, _, _ = _enet_quadratic(d=50, n=2_000, seed=3)
+    opt = OWLQN(tol=1e-10, l1_reg=l1)
+    states = list(opt.iterations(f, np.zeros(len(l1))))
+    last = states[-1]
+    assert last.search_evals[0] == 1
+    assert len(last.search_evals) == last.iteration + 1
+    assert sum(last.search_evals) == f.n_evals
+    # carried through a checkpoint, and continued from it on resume
+    from cycloneml_tpu.ml.optim import OptimState
+    mid = OptimState.from_pytree(states[2].to_pytree())
+    assert mid.search_evals == states[2].search_evals
+    resumed = opt.minimize(f, None, resume=mid)
+    assert resumed.search_evals == last.search_evals
+
+
 # -- aggregator gradients vs jax.grad ----------------------------------------
 
 def _check_grad(agg, coef_len, k_classes=None, extra_tail=0):

@@ -635,6 +635,17 @@ def test_phase_spans_cover_the_fit(ctx, which):
     assert [s.attrs["iteration"] for s in turns] == sorted(
         s.attrs["iteration"] for s in turns)
     assert all(opt in _ancestors(s, by_id) for s in turns)
+    if which == "linreg_enet":
+        # OWL-QN's turns say how their search went, and the summary
+        # carries the same counts without a trace
+        searched = [s.attrs for s in turns if s.attrs["iteration"] > 0]
+        assert [1] + [a["search_evals"] for a in searched] \
+            == summary.search_evals
+        assert sum(summary.search_evals) == summary.total_evals
+        assert all(a["search"] in ("first_trial", "backtracked", "unresolved")
+                   and a["alpha"] >= 0 for a in searched)
+    else:
+        assert summary.search_evals is None
     # and every dispatch of the optimiser happens inside a turn
     dispatches = [s for s in spans if s.kind == "dispatch"]
     assert len(dispatches) == summary.total_dispatches
@@ -721,6 +732,15 @@ def test_abandoned_iterations_leave_the_span_stack_empty(ctx, tracer, name):
     turns = [s for s in tracer.snapshot() if s.name == "optim.iteration"]
     assert turns and all(s.parent_id == job.span_id and s.t1 >= s.t0 > 0
                          for s in turns)
+    # OWL-QN's own search annotates its turn (not the initial evaluation);
+    # the strong-Wolfe optimisers' turns carry the iteration alone
+    for s in turns:
+        searched = name == "OWLQN" and s.attrs["iteration"] > 0
+        assert ({"search_evals", "alpha", "search"} <= set(s.attrs)) \
+            == searched, s.attrs
+        if searched:
+            assert s.attrs["search_evals"] >= 1 and s.attrs["alpha"] > 0
+            assert s.attrs["search"] in ("first_trial", "backtracked")
 
 
 # -- names on the device ---------------------------------------------------------
