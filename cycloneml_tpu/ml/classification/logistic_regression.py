@@ -25,7 +25,8 @@ from cycloneml_tpu.dataset.frame import MLFrame
 from cycloneml_tpu.linalg.matrices import DenseMatrix
 from cycloneml_tpu.linalg.vectors import DenseVector, Vectors
 from cycloneml_tpu.ml.base import Predictor, ProbabilisticClassificationModel
-from cycloneml_tpu.ml.optim import LBFGS, LBFGSB, OWLQN, aggregators
+from cycloneml_tpu.ml.optim import LBFGS, aggregators
+from cycloneml_tpu.ml.optim.lbfgs import optimizer_for
 from cycloneml_tpu.ml.optim.loss import (
     DistributedLossFunction, l2_regularization,
 )
@@ -131,12 +132,23 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
     def set_threshold(self, v):
         return self.set("threshold", v)
 
-    def _flat_bounds(self, d, num_classes, is_multinomial, fit_intercept,
-                     n_coef, features_std):
-        """Flatten user bounds into the optimizer's coefficient layout, in
-        STANDARDIZED space: β_std = β_orig·std, so coefficient bounds scale
-        by featuresStd exactly as the reference's createBounds does
+    def _flat_bounds(self, alpha, d, num_classes, is_multinomial,
+                     fit_intercept, n_coef, features_std):
+        """User bounds flattened into the optimizer's coefficient layout
+        as ``(lower, upper)``, or None when none are set. In STANDARDIZED
+        space: β_std = β_orig·std, so coefficient bounds scale by
+        featuresStd exactly as the reference's createBounds does
         (LogisticRegression.scala:2085-2156). Intercepts are unscaled."""
+        if not self._has_bounds():
+            return None
+        if alpha != 0.0:
+            # bounds are only legal with none/L2 regularization: the
+            # reference rejects ANY nonzero elasticNetParam with bounds,
+            # regardless of regParam
+            raise ValueError(
+                "coefficient bounds are only supported with none or L2 "
+                "regularization (elasticNetParam must be 0, as the "
+                "reference enforces)")
         k_rows = num_classes if is_multinomial else 1
         n_feat = d * k_rows
         out = []
@@ -618,28 +630,12 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
             standardize=standardize) if l2 > 0 else None
         loss_fn = DistributedLossFunction(ds_std, agg, l2_fn, weight_sum)
 
-        if self._has_bounds():
-            if alpha != 0.0:
-                raise ValueError(
-                    "coefficient bounds are only supported with none or L2 "
-                    "regularization (elasticNetParam must be 0, as the "
-                    "reference enforces)")
-            lo, hi = self._flat_bounds(d, 2, False, fit_intercept, n_coef,
-                                       features_std)
-            opt = LBFGSB(lo, hi, max_iter=self.get("maxIter"),
-                         tol=self.get("tol"))
-        elif l1 > 0:
-            l1_vec = np.zeros(n_coef)
-            per = np.full(d, l1)
-            if not standardize:
-                per = np.where(features_std > 0,
-                               l1 / np.where(features_std > 0,
-                                             features_std, 1.0), 0.0)
-            l1_vec[:d] = per
-            opt = OWLQN(max_iter=self.get("maxIter"), tol=self.get("tol"),
-                        l1_reg=l1_vec)
-        else:
-            opt = LBFGS(max_iter=self.get("maxIter"), tol=self.get("tol"))
+        opt = optimizer_for(
+            self.get("maxIter"), self.get("tol"), n_coef,
+            bounds=self._flat_bounds(alpha, d, 2, False, fit_intercept,
+                                     n_coef, features_std),
+            l1=l1, n_penalized=d,
+            penalty_std=None if standardize else features_std)
         state = self._optimize(opt, loss_fn, x0, (
             ds.n_rows, d, 2, float(weight_sum),
             np.asarray(histogram).round(6).tolist(),
@@ -846,33 +842,16 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
                     loss_fn = DistributedLossFunction(
                         ds, agg, l2_fn, weight_sum, extra_args=extras)
 
-            if self._has_bounds():
-                # box-constrained path (ref createOptimizer selects BreezeLBFGSB
-                # whenever bounds are set, LogisticRegression.scala:788; bounds
-                # are only legal with none/L2 regularization there too)
-                if alpha != 0.0:
-                    # the reference rejects ANY nonzero elasticNetParam with
-                    # bounds, regardless of regParam
-                    raise ValueError(
-                        "coefficient bounds are only supported with none or L2 "
-                        "regularization (elasticNetParam must be 0, as the "
-                        "reference enforces)")
-                lo, hi = self._flat_bounds(d, num_classes, is_multinomial,
-                                           fit_intercept, n_coef, features_std)
-                opt = LBFGSB(lo, hi, max_iter=self.get("maxIter"),
-                             tol=self.get("tol"))
-            elif l1 > 0:
-                n_feat_coords = d * num_classes if is_multinomial else d
-                l1_vec = np.zeros(n_coef)
-                per_coord = np.full(n_feat_coords, l1)
-                if not standardize:
-                    stds = np.tile(features_std, num_classes) if is_multinomial else features_std
-                    per_coord = np.where(stds > 0, l1 / np.where(stds > 0, stds, 1.0), 0.0)
-                l1_vec[:n_feat_coords] = per_coord
-                opt = OWLQN(max_iter=self.get("maxIter"), tol=self.get("tol"),
-                            l1_reg=l1_vec)
-            else:
-                opt = LBFGS(max_iter=self.get("maxIter"), tol=self.get("tol"))
+            k_rows = num_classes if is_multinomial else 1
+            opt = optimizer_for(
+                self.get("maxIter"), self.get("tol"), n_coef,
+                bounds=self._flat_bounds(
+                    alpha, d, num_classes, is_multinomial, fit_intercept,
+                    n_coef, features_std),
+                l1=l1, n_penalized=d * k_rows,
+                penalty_std=None if standardize else np.tile(features_std,
+                                                             k_rows))
+            if type(opt) is LBFGS:
                 # chunked device optimizer: K whole iterations per dispatch
                 # (two-loop + Wolfe + convergence all on device). Eligible when
                 # the loss is the dense replicated tier with a standardized (or

@@ -2,14 +2,16 @@
 
 The host optimizer (``lbfgs.LBFGS``) pays one device dispatch and one
 blocking readback per iteration even with the fused line search — host
-work and a device idle gap between every two steps (per-dispatch cost on
-the current machine: not measured; ROADMAP S5). This module runs WHOLE
-CHUNKS of K iterations inside one jitted program: the two-loop recursion over a
-fixed-size (m, n) curvature ring buffer, the strong-Wolfe search
-(``loss.wolfe_search`` — the same traced state machine the per-iteration
-fused path uses), the curvature-condition history update, and the
-Breeze-style convergence tests all stay on device; the host sees one
-dispatch and one small readback per chunk.
+work and a device idle gap between every two steps (per dispatch on the
+v5e, in ``lr_epsilon_fit``: ``idle_dispatch_ms`` 0.7–1.6 and
+``idle_readback_ms`` 2.1–3.3 a fit of one dispatch; ledger, PR 30). This
+module runs WHOLE CHUNKS of K iterations inside one jitted program: the
+two-loop recursion over a fixed-size (m, n) curvature ring buffer, the
+strong-Wolfe search (``loss.wolfe_search`` — the same traced state machine
+the per-iteration fused path uses), the curvature-condition history update,
+and the Breeze-style convergence tests all stay on device; the host sees
+one dispatch (``collectives.dispatch_fused``) and one small readback per
+chunk.
 
 Structure beaten, not emulated: the reference pays one Spark JOB per loss
 evaluation (RDDLossFunction.scala:56) — ~30 jobs per iteration; the host
@@ -20,6 +22,20 @@ curvature condition sᵀy > 1e-10·yᵀy, same convergence tests) computed in
 the accumulator tier's dtype — f64 under the CPU test config (trajectories match
 the host path), f32 on TPU (last-ulp drift; the convergence thresholds are
 ~1e-6 relative, within f32's resolution for these well-scaled problems).
+
+Two chunk programs, one set of parts. The parts of an iteration
+(``_two_loop``, ``_descent_or_reset``, ``_init_alpha``, ``_push_pair``,
+``_convergence_code``) are written once, for one model; ``_build_chunk``
+calls them and ``_build_stacked_chunk`` calls ``jax.vmap`` of them. The
+builders and their ``while_loop`` bodies stay two: the serial program is
+NOT the stacked one at K = 1. Under ``vmap`` the objective's sweep cannot
+take the feature-major tiling (``logistic_regression.py`` passes
+``feature_major=False`` to the stacked aggregator), so a serial fit routed
+through the stacked program would pay the lane pad and the layout copy of X
+again — 3.3× of ``lr_epsilon_fit``'s ``fit_s`` (ledger, PR 28).
+
+``StackedHostLBFGS`` (the streamed regime) is host code: it drives K of
+``lbfgs.LBFGS``'s coroutines and lives here only beside its device twin.
 """
 
 from __future__ import annotations
@@ -30,8 +46,9 @@ from typing import List, Optional
 import numpy as np
 
 from cycloneml_tpu.ml.optim.lbfgs import LBFGS, OptimState
-from cycloneml_tpu.observe import attribution, costs, tracing
-from cycloneml_tpu.parallel.collectives import BoundedProgramCache
+from cycloneml_tpu.observe import costs, tracing
+from cycloneml_tpu.parallel.collectives import (BoundedProgramCache,
+                                                dispatch_fused)
 from cycloneml_tpu.util.logging import get_logger
 
 logger = get_logger(__name__)
@@ -113,6 +130,94 @@ def _budget_guarded_chunk(name: str, key, prog, args, chunk: int, ctx,
     return chunk, key, prog, fresh
 
 
+# -- the parts of one device iteration, written for ONE model ----------------
+# Trace-time helpers: ``_build_chunk`` calls them as they are, and
+# ``_build_stacked_chunk`` calls ``jax.vmap`` of them, so the stacked
+# program's dots lower like the serial program's and the two trajectories
+# stay bit-aligned.
+
+def _two_loop(S, Y, k, g, m: int):
+    """Two-loop recursion over the ``(m, n)`` ring buffers, of which the
+    LAST ``k`` rows are live (newest last): the direction ``-H·g``."""
+    import jax
+    import jax.numpy as jnp
+
+    idxs_bwd = jnp.arange(m - 1, -1, -1)
+
+    def bwd(q, i):
+        valid = i >= m - k
+        sy = jnp.dot(Y[i], S[i])
+        rho = jnp.where(valid, 1.0 / jnp.where(valid, sy, 1.0), 0.0)
+        a = rho * jnp.dot(S[i], q)
+        return q - a * Y[i], (a, rho)
+
+    q, (alphas, rhos) = jax.lax.scan(bwd, g, idxs_bwd)
+    last_sy = jnp.dot(S[m - 1], Y[m - 1])
+    last_yy = jnp.dot(Y[m - 1], Y[m - 1])
+    gamma = jnp.where(k > 0, last_sy / jnp.maximum(last_yy, 1e-300), 1.0)
+    r = gamma * q
+
+    def fwd(r, inp):
+        i, a, rho = inp
+        beta = rho * jnp.dot(Y[i], r)
+        return r + (a - beta) * S[i], None
+
+    # forward pass visits oldest→newest: reverse the bwd outputs
+    r, _ = jax.lax.scan(fwd, r, (idxs_bwd[::-1], alphas[::-1], rhos[::-1]))
+    return -r
+
+
+def _descent_or_reset(d, g, k):
+    """Host semantics of a non-descent direction: forget the history and
+    take steepest descent. Returns ``(d, k, d·g, was_reset)``."""
+    import jax.numpy as jnp
+
+    dg0 = jnp.dot(d, g)
+    bad = dg0 >= 0
+    return (jnp.where(bad, -g, d), jnp.where(bad, 0, k),
+            jnp.where(bad, -jnp.dot(g, g), dg0), bad)
+
+
+def _init_alpha(first_step, bad, g):
+    """First trial step: 1, but the scaled ``min(1, 1/‖g‖)`` on the very
+    first iteration of a fit AND on every steepest-descent restart."""
+    import jax.numpy as jnp
+
+    gnorm = jnp.sqrt(jnp.maximum(jnp.dot(g, g), 1e-300))
+    return jnp.where(first_step | bad, jnp.minimum(1.0, 1.0 / gnorm),
+                     g.dtype.type(1.0))
+
+
+def _push_pair(S, Y, k, s, y, m: int):
+    """Roll ``(s, y)`` into the ring buffers if it passes the curvature
+    condition (host ``_History.update``): sᵀy > 1e-10·yᵀy."""
+    import jax.numpy as jnp
+
+    keep = jnp.dot(s, y) > 1e-10 * jnp.dot(y, y)
+    return (jnp.where(keep, jnp.roll(S, -1, axis=0).at[-1].set(s), S),
+            jnp.where(keep, jnp.roll(Y, -1, axis=0).at[-1].set(y), Y),
+            jnp.where(keep, jnp.minimum(k + 1, m), k))
+
+
+# what a chunk's convergence code says; 0 at the end of a run is the budget
+_REASONS = ("max iterations reached", "function value converged",
+            "gradient converged")
+
+
+def _convergence_code(f, f_new, g_new, x_new, tol, grad_tol):
+    """Breeze-style convergence (host ``LBFGS._converged`` minus the
+    budget stop, which the drivers apply): 1 function value converged,
+    2 gradient converged, 0 neither."""
+    import jax.numpy as jnp
+
+    denom = jnp.maximum(jnp.maximum(jnp.abs(f_new), jnp.abs(f)), 1e-6)
+    f_conv = jnp.abs(f - f_new) <= tol * denom
+    gn = jnp.sqrt(jnp.maximum(jnp.dot(g_new, g_new), 0.0))
+    xn = jnp.sqrt(jnp.maximum(jnp.dot(x_new, x_new), 0.0))
+    g_conv = gn <= grad_tol * jnp.maximum(xn, 1.0)
+    return jnp.where(f_conv, 1, jnp.where(g_conv, 2, 0)).astype(jnp.int32)
+
+
 def _build_chunk(compiled, l2_t, m: int, K: int, c1: float, c2: float,
                  max_ls: int, cdt: np.dtype, *, n_arrays: int):
     """jit program: K L-BFGS iterations on device.
@@ -123,12 +228,12 @@ def _build_chunk(compiled, l2_t, m: int, K: int, c1: float, c2: float,
     (``l2_regularization(...).traceable``) — the SAME implementation the
     fused line search inlines, so the two device paths cannot drift.
 
-    The big state operands — coef ``(n,)`` and the two ``(m, n)``
-    curvature ring buffers plus the gradient — are DONATED: each chunk
-    consumes the previous chunk's output, so the old buffers are dead the
-    moment the dispatch leaves the host (graftlint JX009 is the static
-    safety net for exactly this discipline). XLA aliases them onto the
-    matching outputs, shaving ``2·m·n + 2·n`` accumulator-width elements
+    The big state operands — the two ``(m, n)`` curvature ring buffers —
+    are DONATED (not coef and the gradient: see the end of this function):
+    each chunk consumes the previous chunk's output, so the old buffers
+    are dead the moment the dispatch leaves the host (graftlint JX009 is
+    the static safety net for exactly this discipline). XLA aliases them
+    onto the matching outputs, shaving ``2·m·n`` accumulator-width elements
     off the program's peak HBM — visible as an ``hbm_peak_bytes`` drop in
     the cost rollup (`alias_size_in_bytes` is subtracted at the
     observe/costs.py waist). ``n_arrays`` positions the donated argnums
@@ -155,53 +260,12 @@ def _build_chunk(compiled, l2_t, m: int, K: int, c1: float, c2: float,
                 grad = grad + rg
             return loss, grad
 
-        def two_loop(S, Y, k, g):
-            idxs_bwd = jnp.arange(m - 1, -1, -1)
-
-            def bwd(q, i):
-                valid = i >= m - k
-                sy = jnp.dot(Y[i], S[i])
-                rho = jnp.where(valid, 1.0 / jnp.where(valid, sy, 1.0), 0.0)
-                a = rho * jnp.dot(S[i], q)
-                return q - a * Y[i], (a, rho)
-
-            q, (alphas, rhos) = jax.lax.scan(bwd, g, idxs_bwd)
-            last_sy = jnp.dot(S[m - 1], Y[m - 1])
-            last_yy = jnp.dot(Y[m - 1], Y[m - 1])
-            gamma = jnp.where(k > 0, last_sy / jnp.maximum(last_yy, 1e-300),
-                              1.0)
-            r = gamma * q
-
-            def fwd(r, inp):
-                i, a, rho = inp
-                beta = rho * jnp.dot(Y[i], r)
-                return r + (a - beta) * S[i], None
-
-            # forward pass visits oldest→newest: reverse the bwd outputs
-            r, _ = jax.lax.scan(
-                fwd, r, (idxs_bwd[::-1], alphas[::-1], rhos[::-1]))
-            return -r
-
-        zero = cdt.type(0.0)
-
         def body(carry):
             (coef, S, Y, k, f, g, it, evals, done, losses) = carry
             with jax.named_scope("lbfgs.direction"):
-                d = two_loop(S, Y, k, g)
-                dg0 = jnp.dot(d, g)
-                # non-descent: reset history, steepest descent (host
-                # semantics)
-                bad = dg0 >= 0
-                d = jnp.where(bad, -g, d)
-                k = jnp.where(bad, 0, k)
-                dg0 = jnp.where(bad, -jnp.dot(g, g), dg0)
-                gnorm = jnp.sqrt(jnp.maximum(jnp.dot(g, g), 1e-300))
-                # host semantics: the scaled step min(1, 1/||g||) applies on
-                # the very first iteration AND on every steepest-descent
-                # restart
-                init_alpha = jnp.where(
-                    (first & (it == 0)) | bad,
-                    jnp.minimum(1.0, 1.0 / gnorm), cdt.type(1.0))
+                d, k, dg0, bad = _descent_or_reset(_two_loop(S, Y, k, g, m),
+                                                   g, k)
+                init_alpha = _init_alpha(first & (it == 0), bad, g)
 
             def phi(alpha):
                 v, grad = f_and_g(coef + alpha * d)
@@ -213,21 +277,9 @@ def _build_chunk(compiled, l2_t, m: int, K: int, c1: float, c2: float,
                     c1, c2, max_ls, cdt)
             with jax.named_scope("lbfgs.update"):
                 s = alpha * d
-                y = g_new - g
-                # curvature condition (host _History.update)
-                keep = jnp.dot(s, y) > 1e-10 * jnp.dot(y, y)
-                S = jnp.where(keep, jnp.roll(S, -1, axis=0).at[-1].set(s), S)
-                Y = jnp.where(keep, jnp.roll(Y, -1, axis=0).at[-1].set(y), Y)
-                k = jnp.where(keep, jnp.minimum(k + 1, m), k)
-                # Breeze-style convergence (host LBFGS._converged)
-                denom = jnp.maximum(jnp.maximum(jnp.abs(f_new), jnp.abs(f)),
-                                    1e-6)
-                f_conv = jnp.abs(f - f_new) <= tol * denom
-                gn = jnp.sqrt(jnp.maximum(jnp.dot(g_new, g_new), 0.0))
-                xn = jnp.sqrt(jnp.maximum(jnp.dot(coef + s, coef + s), 0.0))
-                g_conv = gn <= grad_tol * jnp.maximum(xn, 1.0)
-                code = jnp.where(f_conv, 1,
-                                 jnp.where(g_conv, 2, 0)).astype(jnp.int32)
+                S, Y, k = _push_pair(S, Y, k, s, g_new - g, m)
+                code = _convergence_code(f, f_new, g_new, coef + s,
+                                         tol, grad_tol)
                 losses = losses.at[it].set(f_new)
             return (coef + s, S, Y, k, f_new, g_new, it + 1,
                     evals + ev, code, losses)
@@ -260,6 +312,13 @@ def _build_chunk(compiled, l2_t, m: int, K: int, c1: float, c2: float,
     # JX009 hazard class, one dispatch later)
     return jax.jit(lbfgs_chunk,
                    donate_argnums=(n_arrays + 1, n_arrays + 2))
+
+
+def _chunk_scalars(out):
+    """What the host reads back of a serial chunk's outputs: everything but
+    the ``(n,)`` / ``(m, n)`` state, which stays on the device."""
+    coef, S, Y, k, f, g, losses, it, evals, code, f0, g0 = out
+    return f, losses, it, evals, code, k, f0
 
 
 class DeviceLBFGS(LBFGS):
@@ -307,22 +366,17 @@ class DeviceLBFGS(LBFGS):
         def build(k):
             key = ("lbfgs_chunk", f._agg_call.compiled, l2_t, self.m, k,
                    float(self.c1), float(self.c2), int(self.max_ls), cdt.str)
-            prog = _program_cache.get(key)
-            fresh = prog is None  # first dispatch pays trace + compile
-            if fresh:
-                prog = _build_chunk(f._agg_call.compiled, l2_t, self.m,
-                                    k, self.c1, self.c2, self.max_ls, cdt,
-                                    n_arrays=len(arrays))
-                _program_cache.put(key, prog)
-            return key, prog, fresh
+            return (key, *_program_cache.get_or_build(key, lambda: _build_chunk(
+                f._agg_call.compiled, l2_t, self.m, k, self.c1, self.c2,
+                self.max_ls, cdt, n_arrays=len(arrays))))
 
         key, prog, fresh = build(chunk)
+        S = np.zeros((self.m, n), dtype=cdt)
+        Y = np.zeros((self.m, n), dtype=cdt)
 
         if resume is not None:
             from cycloneml_tpu.ml.optim.lbfgs import _reopen
             state = _reopen(resume, self.max_iter)
-            S = np.zeros((self.m, n), dtype=cdt)
-            Y = np.zeros((self.m, n), dtype=cdt)
             hk = min(len(resume.hist_s), self.m)
             for i, (s_, y_) in enumerate(zip(resume.hist_s[-self.m:],
                                              resume.hist_y[-self.m:])):
@@ -348,8 +402,6 @@ class DeviceLBFGS(LBFGS):
             # fresh fit: f(x0) is computed INSIDE the first chunk dispatch;
             # the iteration-0 state is yielded when that chunk returns
             state = None
-            S = np.zeros((self.m, n), dtype=cdt)
-            Y = np.zeros((self.m, n), dtype=cdt)
             k_hist = 0
             first = True
             need_init = True
@@ -360,7 +412,6 @@ class DeviceLBFGS(LBFGS):
         S_d, Y_d = jnp.asarray(S), jnp.asarray(Y)
         k_d = jnp.int32(k_hist)
         guarded = False
-        pid = None
         while True:
             # one chunk turn is one `optim.iteration` span: argument tuple,
             # dispatch, readback and state build. It closes BEFORE the
@@ -368,14 +419,12 @@ class DeviceLBFGS(LBFGS):
             # be charged the consumer's time, and an abandoned generator
             # would leave it on the thread's span stack
             start = None
-            with tracing.span("phase", "optim.iteration",
-                              iteration=state.iteration
-                              if state is not None else 0):
+            base_iter = state.iteration if state is not None else 0
+            with tracing.span("phase", "optim.iteration", iteration=base_iter):
                 # big state (coef/S/Y/grad) stays ON DEVICE between chunks —
                 # only scalars and the per-iteration loss vector come back per
                 # dispatch; the full f64 state materializes on yield only when
                 # a consumer touches the arrays (np.asarray forces the copy)
-                base_iter = state.iteration if state is not None else 0
                 args = (*arrays, coef, S_d, Y_d, k_d, f_d, g_d,
                         np.bool_(first), cdt.type(f.weight_sum),
                         cdt.type(self.tol), cdt.type(self.grad_tol),
@@ -392,38 +441,12 @@ class DeviceLBFGS(LBFGS):
                     if new_fresh is not None:
                         fresh = new_fresh
                         self.effective_chunk = chunk
-                win = attribution.dispatch_window()
-                with win:
-                    with tracing.span("dispatch", "lbfgs.chunk") as dsp:
-                        if fresh:
-                            with tracing.span("compile", "lbfgs.chunk"):
-                                (coef_d, S_d, Y_d, k_d, f_d, g_d, losses_d,
-                                 it_d, evals_d, code_d, f0_d, g0_d) = \
-                                    prog(*args)
-                            fresh = False
-                        else:
-                            (coef_d, S_d, Y_d, k_d, f_d, g_d, losses_d, it_d,
-                             evals_d, code_d, f0_d, g0_d) = prog(*args)
-                        with tracing.span("transfer", "lbfgs.readback") as tsp:
-                            f_h, losses, it, evals, code, k_h, f0_h = \
-                                jax.device_get(
-                                    (f_d, losses_d, it_d, evals_d, code_d, k_d,
-                                     f0_d))
-                            tsp.annotate_bytes(
-                                (f_h, losses, it, evals, code, k_h, f0_h))
-                    dsp.annotate(evals=int(evals))
-                    # cost harvest only under a FULL tracer OR a live
-                    # attribution window: the flight-recorder ring records
-                    # spans and must not pay an AOT analyze, but a scoped fit
-                    # buys the FLOPs/bytes join (shared registry, one harvest
-                    # per program either way)
-                    tr = tracing.full_active()
-                    if (tr is not None or win.live) and pid is None:
-                        pid = costs.ensure("lbfgs.chunk", key, prog, args)
-                    win.annotate_program(pid)
-                    if tr is not None:
-                        dsp.annotate(program=pid)
-                        costs.note_execution(tr, pid)
+                ((coef_d, S_d, Y_d, k_d, f_d, g_d, *_, g0_d),
+                 (f_h, losses, it, evals, code, k_h, f0_h)) = dispatch_fused(
+                    "lbfgs.chunk", key, prog, args, fresh=fresh,
+                    transfer_name="lbfgs.readback", evals_at=3,
+                    readback=_chunk_scalars)
+                fresh = False
                 coef = coef_d
                 first = False
                 f.n_evals += int(evals)
@@ -452,16 +475,11 @@ class DeviceLBFGS(LBFGS):
                 # precedence matches host _converged: a budget stop outranks
                 # the value/gradient tests (the estimator's non-convergence
                 # warning keys off this reason)
-                if state.iteration >= self.max_iter:
+                budget_spent = state.iteration >= self.max_iter
+                if budget_spent or code:
                     state.converged = True
-                    state.converged_reason = "max iterations reached"
-                elif int(code) == 1:
-                    state.converged = True
-                    state.converged_reason = "function value converged"
-                elif int(code) == 2:
-                    state.converged = True
-                    state.converged_reason = "gradient converged"
-                if state.converged:
+                    state.converged_reason = _REASONS[
+                        0 if budget_spent else int(code)]
                     # terminal state: hand back host-f64 arrays as the host
                     # optimizer does
                     state.x = np.asarray(coef_d, np.float64)
@@ -510,32 +528,13 @@ def _build_stacked_chunk(compiled, m: int, K_iters: int, c1: float, c2: float,
 
     from cycloneml_tpu.ml.optim.loss import wolfe_search
 
-    def two_loop_one(S, Y, k, g):
-        idxs_bwd = jnp.arange(m - 1, -1, -1)
-
-        def bwd(q, i):
-            valid = i >= m - k
-            sy = jnp.dot(Y[i], S[i])
-            rho = jnp.where(valid, 1.0 / jnp.where(valid, sy, 1.0), 0.0)
-            a = rho * jnp.dot(S[i], q)
-            return q - a * Y[i], (a, rho)
-
-        q, (alphas, rhos) = jax.lax.scan(bwd, g, idxs_bwd)
-        last_sy = jnp.dot(S[m - 1], Y[m - 1])
-        last_yy = jnp.dot(Y[m - 1], Y[m - 1])
-        gamma = jnp.where(k > 0, last_sy / jnp.maximum(last_yy, 1e-300), 1.0)
-        r = gamma * q
-
-        def fwd(r, inp):
-            i, a, rho = inp
-            beta = rho * jnp.dot(Y[i], r)
-            return r + (a - beta) * S[i], None
-
-        r, _ = jax.lax.scan(
-            fwd, r, (idxs_bwd[::-1], alphas[::-1], rhos[::-1]))
-        return -r
-
-    two_loop = jax.vmap(two_loop_one)
+    two_loop = jax.vmap(lambda S, Y, k, g: _two_loop(S, Y, k, g, m))
+    descent_or_reset = jax.vmap(_descent_or_reset)
+    init_alpha_of = jax.vmap(_init_alpha, in_axes=(None, 0, 0))
+    push_pair = jax.vmap(lambda S, Y, k, s, y: _push_pair(S, Y, k, s, y, m))
+    convergence_code = jax.vmap(_convergence_code,
+                                in_axes=(0, 0, 0, 0, None, None))
+    row_dot = jax.vmap(jnp.dot)
 
     def lbfgs_stacked_chunk(*args):
         (arrays, coef0, S0, Y0, k0, f_in, g_in, first,
@@ -552,8 +551,7 @@ def _build_stacked_chunk(compiled, m: int, K_iters: int, c1: float, c2: float,
             # ``jnp.dot(beta, beta)`` (zero intercept products are exact),
             # so stacked and serial trajectories stay bit-aligned instead
             # of flipping iterations at the convergence-tol boundary.
-            loss = loss + 0.5 * reg * jax.vmap(jnp.dot)(coef * l2s[None, :],
-                                                        coef)
+            loss = loss + 0.5 * reg * row_dot(coef * l2s[None, :], coef)
             grad = grad + reg[:, None] * coef * l2s[None, :]
             return loss, grad
 
@@ -561,47 +559,29 @@ def _build_stacked_chunk(compiled, m: int, K_iters: int, c1: float, c2: float,
             (coef, S, Y, k, f, g, step, iters, ev_pm, ev_g, code,
              losses) = carry
             live = code == 0
-            d = two_loop(S, Y, k, g)
-            dg0 = jnp.sum(d * g, axis=1)
-            bad = dg0 >= 0
-            d = jnp.where(bad[:, None], -g, d)
-            k = jnp.where(bad, 0, k)
-            gg = jnp.sum(g * g, axis=1)
-            dg0 = jnp.where(bad, -gg, dg0)
-            gnorm = jnp.sqrt(jnp.maximum(gg, 1e-300))
-            init_alpha = jnp.where(
-                (first & (step == 0)) | bad,
-                jnp.minimum(1.0, 1.0 / gnorm), cdt.type(1.0)).astype(cdt)
+            d, k, dg0, bad = descent_or_reset(two_loop(S, Y, k, g), g, k)
+            init_alpha = init_alpha_of(first & (step == 0), bad, g)
 
             def phi(alpha):
                 v, grad = f_and_g(coef + alpha[:, None] * d)
-                return v, grad, jnp.sum(d * grad, axis=1)
+                return v, grad, row_dot(d, grad)
 
             alpha, f_new, g_new, ev = wolfe_search(
                 phi, jnp.zeros_like(g), f, dg0, init_alpha,
                 c1, c2, max_ls, cdt, active=live)
             s_vec = alpha[:, None] * d
-            y_vec = g_new - g
-            keep = live & (jnp.sum(s_vec * y_vec, axis=1)
-                           > 1e-10 * jnp.sum(y_vec * y_vec, axis=1))
-            S = jnp.where(keep[:, None, None],
-                          jnp.roll(S, -1, axis=1).at[:, -1].set(s_vec), S)
-            Y = jnp.where(keep[:, None, None],
-                          jnp.roll(Y, -1, axis=1).at[:, -1].set(y_vec), Y)
-            k = jnp.where(keep, jnp.minimum(k + 1, m), k)
-            denom = jnp.maximum(jnp.maximum(jnp.abs(f_new), jnp.abs(f)),
-                                1e-6)
-            f_conv = jnp.abs(f - f_new) <= tol * denom
-            gn = jnp.sqrt(jnp.maximum(jnp.sum(g_new * g_new, axis=1), 0.0))
-            xn = jnp.sqrt(jnp.maximum(
-                jnp.sum((coef + s_vec) ** 2, axis=1), 0.0))
-            g_conv = gn <= grad_tol * jnp.maximum(xn, 1.0)
-            code_new = jnp.where(f_conv, 1,
-                                 jnp.where(g_conv, 2, 0)).astype(jnp.int32)
+            x_new = coef + s_vec
+            S_new, Y_new, k_new = push_pair(S, Y, k, s_vec, g_new - g)
+            code_new = convergence_code(f, f_new, g_new, x_new,
+                                        tol, grad_tol)
             losses = losses.at[:, step].set(
                 jnp.where(live, f_new, jnp.nan).astype(cdt))
-            return (jnp.where(live[:, None], coef + s_vec, coef),
-                    S, Y, k,
+            # per-model freeze: a converged model's state is selected
+            # through unchanged
+            return (jnp.where(live[:, None], x_new, coef),
+                    jnp.where(live[:, None, None], S_new, S),
+                    jnp.where(live[:, None, None], Y_new, Y),
+                    jnp.where(live, k_new, k),
                     jnp.where(live, f_new, f),
                     jnp.where(live[:, None], g_new, g),
                     step + 1,
@@ -697,15 +677,10 @@ class StackedDeviceLBFGS:
             key = ("stacked_lbfgs_chunk", f._agg_call.compiled, self.m,
                    kc, float(self.c1), float(self.c2), int(self.max_ls),
                    cdt.str)
-            prog = _program_cache.get(key)
-            fresh = prog is None
-            if fresh:
-                prog = _build_stacked_chunk(f._agg_call.compiled, self.m,
-                                            kc, self.c1, self.c2,
-                                            self.max_ls, cdt,
-                                            n_arrays=len(arrays))
-                _program_cache.put(key, prog)
-            return key, prog, fresh
+            return (key, *_program_cache.get_or_build(
+                key, lambda: _build_stacked_chunk(
+                    f._agg_call.compiled, self.m, kc, self.c1, self.c2,
+                    self.max_ls, cdt, n_arrays=len(arrays))))
 
         key, prog, fresh = build(chunk)
 
@@ -718,67 +693,39 @@ class StackedDeviceLBFGS:
         reg_d = jnp.asarray(f.reg.astype(cdt))
         l2s = (f.l2_scale if f.l2_scale is not None else np.zeros(n))
         l2s_d = jnp.asarray(l2s.astype(cdt))
-        first, need_init = True, True
+        first = True  # this chunk evaluates f(x0) and scales its first step
         total_iter = 0
         iters_total = np.zeros(K, dtype=np.int64)
         evals_total = np.zeros(K, dtype=np.int64)
         histories: List[List[float]] = [[] for _ in range(K)]
         code_h = np.zeros(K, dtype=np.int64)
-        guarded = False
-        pid = None
         while True:
             args = (*arrays, coef, S_d, Y_d, k_d, f_d, g_d,
                     np.bool_(first), cdt.type(f.weight_sum), reg_d, l2s_d,
                     cdt.type(self.tol), cdt.type(self.grad_tol),
                     np.int32(max(self.max_iter - total_iter, 0)),
-                    np.bool_(need_init),
-                    code_h.astype(np.int32))
-            if not guarded:
-                guarded = True
+                    np.bool_(first), code_h.astype(np.int32))
+            if first:
                 chunk, key, prog, new_fresh = _budget_guarded_chunk(
                     "lbfgs.stacked_chunk", key, prog, args, chunk,
                     getattr(f, "_ctx", None), build)
                 if new_fresh is not None:
                     fresh = new_fresh
                     self.effective_chunk = chunk
-            win = attribution.dispatch_window()
-            with win:
-                with tracing.span("dispatch", "lbfgs.stacked_chunk",
-                                  n_models=K) as dsp:
-                    if fresh:
-                        with tracing.span("compile", "lbfgs.stacked_chunk"):
-                            (coef, S_d, Y_d, k_d, f_d, g_d, losses_d, step_d,
-                             it_d, ev_d, evg_d, code_d, f0_d) = prog(*args)
-                        fresh = False
-                    else:
-                        (coef, S_d, Y_d, k_d, f_d, g_d, losses_d, step_d,
-                         it_d, ev_d, evg_d, code_d, f0_d) = prog(*args)
-                    with tracing.span("transfer", "lbfgs.readback") as tsp:
-                        (losses, steps, iters, ev_pm, ev_g, code_h,
-                         f0_h) = jax.device_get(
-                            (losses_d, step_d, it_d, ev_d, evg_d, code_d,
-                             f0_d))
-                        tsp.annotate_bytes(
-                            (losses, steps, iters, ev_pm, ev_g, code_h, f0_h))
-                dsp.annotate(evals=int(ev_g))
-                # full tracer only: no AOT analyze under the flight ring —
-                # but a live attribution window buys the same one-time
-                # harvest for the scope's FLOPs/bytes join
-                tr = tracing.full_active()
-                if (tr is not None or win.live) and pid is None:
-                    pid = costs.ensure("lbfgs.stacked_chunk", key, prog,
-                                       args)
-                win.annotate_program(pid)
-                if tr is not None:
-                    dsp.annotate(program=pid)
-                    costs.note_execution(tr, pid)
+            # the (K, ·) state stays on the device; the rest comes back
+            ((coef, S_d, Y_d, k_d, f_d, g_d, *_),
+             (losses, steps, iters, ev_pm, ev_g, code_h, f0_h)) = \
+                dispatch_fused(
+                    "lbfgs.stacked_chunk", key, prog, args, fresh=fresh,
+                    transfer_name="lbfgs.readback", evals_at=4,
+                    readback=lambda out: out[6:], n_models=K)
+            fresh = False
             f.n_evals += int(ev_g)
             f.n_dispatches += 1
-            if need_init:
+            if first:
                 for kk in range(K):
                     histories[kk].append(float(f0_h[kk]))
-                need_init = False
-            first = False
+                first = False
             for kk in range(K):
                 for v in losses[kk, :int(steps)]:
                     if not np.isnan(v):
@@ -793,151 +740,25 @@ class StackedDeviceLBFGS:
                     "chunk_iterations": int(steps), "n_models": K})
             if (code_h != 0).all() or total_iter >= self.max_iter:
                 break
-        # budget stop outranks the value/gradient tests, as in the serial
-        # paths (the estimator's non-convergence warning keys off this)
-        reasons = []
-        for kk in range(K):
-            if code_h[kk] == 1:
-                reasons.append("function value converged")
-            elif code_h[kk] == 2:
-                reasons.append("gradient converged")
-            else:
-                reasons.append("max iterations reached")
+        # a model still live when the budget ran out stopped on the budget
+        # (the estimator's non-convergence warning keys off this reason)
         return StackedOptimResult(
             x=np.asarray(coef, dtype=np.float64),
             values=np.asarray(f_d, dtype=np.float64),
             iterations=iters_total,
-            converged_reasons=reasons,
+            converged_reasons=[_REASONS[int(c)] for c in code_h],
             loss_histories=histories,
             evals=evals_total)
 
 
 # -- streamed stacked L-BFGS: K host optimizers, one epoch per round ----------
 
-def _phi_eval(x, direction, alpha):
-    """One φ(α) evaluation as a sub-generator: yields the trial point,
-    receives ``(value, grad)`` from the driver's batched evaluation."""
-    v, g = yield x + alpha * direction
-    g = np.asarray(g, dtype=np.float64)
-    return float(v), g, float(np.dot(direction, g))
-
-
-def _zoom_gen(x, direction, value, d_dot_g0, lo, hi, v_lo, d_lo, v_hi,
-              c1, c2, max_evals):
-    # lbfgs._strong_wolfe's zoom, verbatim, with phi as a yield point
-    best = None
-    for _ in range(max_evals):
-        alpha = 0.5 * (lo + hi)
-        v, g, dg = yield from _phi_eval(x, direction, alpha)
-        if v > value + c1 * alpha * d_dot_g0 or v >= v_lo:
-            hi, v_hi = alpha, v
-        else:
-            if abs(dg) <= -c2 * d_dot_g0:
-                return alpha, v, g
-            if dg * (hi - lo) >= 0:
-                hi, v_hi = lo, v_lo
-            lo, v_lo, d_lo = alpha, v, dg
-        best = (alpha, v, g)
-        if abs(hi - lo) < 1e-12:
-            break
-    return best
-
-
-def _strong_wolfe_gen(x, value, grad, direction, init_alpha,
-                      c1=1e-4, c2=0.9, max_evals=30):
-    """Generator twin of ``lbfgs._strong_wolfe`` (bracket + bisection
-    zoom, identical branch structure and constants) with every φ(α)
-    evaluation a ``yield`` — so K concurrent searches can be serviced by
-    ONE batched objective evaluation per round. There is deliberately no
-    fused device path here: the streamed objective has none (each eval
-    is an epoch), which is exactly why the searches batch across models
-    instead."""
-    d_dot_g0 = float(np.dot(direction, grad))
-    if d_dot_g0 >= 0:
-        raise ValueError("direction is not a descent direction")
-    alpha_prev, v_prev, d_prev = 0.0, value, d_dot_g0
-    alpha = init_alpha
-    for i in range(max_evals):
-        v, g, dg = yield from _phi_eval(x, direction, alpha)
-        if v > value + c1 * alpha * d_dot_g0 or (i > 0 and v >= v_prev):
-            out = yield from _zoom_gen(x, direction, value, d_dot_g0,
-                                       alpha_prev, alpha, v_prev, d_prev, v,
-                                       c1, c2, max_evals)
-            if out is None:
-                break
-            return out
-        if abs(dg) <= -c2 * d_dot_g0:
-            return alpha, v, g
-        if dg >= 0:
-            out = yield from _zoom_gen(x, direction, value, d_dot_g0,
-                                       alpha, alpha_prev, v, dg, v_prev,
-                                       c1, c2, max_evals)
-            if out is None:
-                break
-            return out
-        alpha_prev, v_prev, d_prev = alpha, v, dg
-        alpha *= 2.0
-    v, g, _ = yield from _phi_eval(x, direction, alpha)
-    return alpha, v, g
-
-
-def _lbfgs_gen(x0, max_iter, m, tol, grad_tol, c1, c2, max_ls):
-    """One model's L-BFGS as a coroutine: mirrors ``lbfgs.LBFGS``
-    decision-for-decision (curvature condition, two-loop direction,
-    init-alpha rule, non-descent reset-and-retry, Breeze convergence
-    tests in the same precedence), with every loss/grad evaluation a
-    ``yield x`` answered by ``send((value, grad))``. Identical (v, g)
-    replies therefore reproduce the serial trajectory bit-for-bit —
-    the streamed-stacked parity test pins exactly this. Returns
-    ``(x, value, iterations, reason, loss_history)`` via StopIteration."""
-    from cycloneml_tpu.ml.optim.lbfgs import _History
-    x = np.asarray(x0, dtype=np.float64).copy()
-    v, g = yield x
-    value = float(v)
-    grad = np.asarray(g, dtype=np.float64)
-    loss_history = [value]
-    hist = _History(m)
-    iteration = 0
-    while True:
-        d = hist.direction(grad)
-        init_alpha = 1.0 if iteration > 0 else \
-            min(1.0, 1.0 / max(float(np.linalg.norm(grad)), 1e-12))
-        try:
-            alpha, v_new, g_new = yield from _strong_wolfe_gen(
-                x, value, grad, d, init_alpha, c1, c2, max_ls)
-        except ValueError:
-            hist = _History(m)  # reset on non-descent (Breeze retries)
-            d = -grad
-            alpha, v_new, g_new = yield from _strong_wolfe_gen(
-                x, value, grad, d,
-                min(1.0, 1.0 / max(float(np.linalg.norm(grad)), 1e-12)),
-                c1, c2, max_ls)
-        x_new = x + alpha * d
-        g_new = np.asarray(g_new, dtype=np.float64)
-        hist.update(x_new - x, g_new - grad)
-        f_old = value
-        x, value, grad = x_new, float(v_new), g_new
-        iteration += 1
-        loss_history.append(value)
-        # LBFGS._converged, same precedence: budget, then value, then grad
-        if iteration >= max_iter:
-            return x, value, iteration, "max iterations reached", \
-                loss_history
-        denom = max(abs(value), abs(f_old), 1e-6)
-        if abs(f_old - value) <= tol * denom:
-            return x, value, iteration, "function value converged", \
-                loss_history
-        gnorm = float(np.linalg.norm(grad))
-        if gnorm <= grad_tol * max(float(np.linalg.norm(x)), 1.0):
-            return x, value, iteration, "gradient converged", loss_history
-
-
 class StackedHostLBFGS:
     """Host-driven L-BFGS over a stack of K models whose objective is
     EXPENSIVE per evaluation and cheap per model — the streamed regime,
     where one evaluation is a whole double-buffered epoch.
 
-    K serial optimizers run as coroutines (:func:`_lbfgs_gen`); each
+    K serial optimizers run as coroutines (``LBFGS._minimize_co``); each
     round stacks their pending trial points into one ``(K, n)`` matrix
     and makes ONE call to the stacked objective
     (``StackedStreamingLossFunction`` — one epoch serves every model),
@@ -964,11 +785,11 @@ class StackedHostLBFGS:
         loss/grad (the ``StackedStreamingLossFunction`` contract)."""
         x0 = np.asarray(x0, dtype=np.float64)
         K, n = x0.shape
-        gens = [_lbfgs_gen(x0[kk], self.max_iter, self.m, self.tol,
-                           self.grad_tol, self.c1, self.c2, self.max_ls)
+        opt = LBFGS(self.max_iter, self.m, self.tol, self.grad_tol)
+        gens = [opt._minimize_co(x0[kk], self.c1, self.c2, self.max_ls)
                 for kk in range(K)]
         pending = np.zeros((K, n))
-        done: List[Optional[tuple]] = [None] * K
+        done: List[Optional[OptimState]] = [None] * K
         evals = np.zeros(K, dtype=np.int64)
         for kk, gen in enumerate(gens):
             pending[kk] = next(gen)  # prime: first yield is the start point
@@ -988,11 +809,12 @@ class StackedHostLBFGS:
                         (float(L[kk]), np.asarray(G[kk], dtype=np.float64)))
                 except StopIteration as fin:
                     done[kk] = fin.value
-                    pending[kk] = fin.value[0]  # terminal point rides along
+                    pending[kk] = fin.value.x  # terminal point rides along
         return StackedOptimResult(
-            x=np.stack([d[0] for d in done]),
-            values=np.asarray([d[1] for d in done], dtype=np.float64),
-            iterations=np.asarray([d[2] for d in done], dtype=np.int64),
-            converged_reasons=[d[3] for d in done],
-            loss_histories=[list(d[4]) for d in done],
+            x=np.stack([d.x for d in done]),
+            values=np.asarray([d.value for d in done], dtype=np.float64),
+            iterations=np.asarray([d.iteration for d in done],
+                                  dtype=np.int64),
+            converged_reasons=[d.converged_reason for d in done],
+            loss_histories=[list(d.loss_history) for d in done],
             evals=evals)

@@ -20,6 +20,14 @@ curvature test on it cannot be met near the optimum.
 The loss/grad callable is typically the jit-compiled mesh aggregation
 (psum over ICI); optimizer state stays on the host in float64 — exactly the
 reference's driver-side Breeze arrangement (SURVEY §3.3).
+
+Written once, here: the strong-Wolfe search is the coroutine
+``_wolfe_search`` (``_strong_wolfe`` drives it with a callable, after
+trying the objective's fused ``device_line_search``); the decisions of one
+L-BFGS turn are ``LBFGS._direction`` / ``_advance`` / ``_converged``, which
+both ``LBFGS.iterations`` and the coroutine ``LBFGS._minimize_co``
+(``device_lbfgs.StackedHostLBFGS`` runs K of them on one batched objective)
+use; and which optimizer serves an objective is ``optimizer_for``'s to say.
 """
 
 from __future__ import annotations
@@ -109,6 +117,9 @@ class _History:
                 self.s.pop(0)
                 self.y.pop(0)
 
+    def reset(self) -> None:
+        self.s, self.y = [], []
+
     def direction(self, grad: np.ndarray) -> np.ndarray:
         q = grad.copy()
         k = len(self.s)
@@ -127,71 +138,105 @@ class _History:
         return -q
 
 
+def _descent_slope(direction: np.ndarray, grad: np.ndarray) -> float:
+    """φ'(0) = d·g of a search along ``direction``; a search needs it
+    negative."""
+    slope = float(np.dot(direction, grad))
+    if slope >= 0:
+        raise ValueError("direction is not a descent direction")
+    return slope
+
+
+def _phi_eval(x, direction, alpha):
+    """One φ(α) evaluation of a search coroutine: yields the trial point,
+    receives ``(value, grad)`` from whoever drives it."""
+    v, g = yield x + alpha * direction
+    g = np.asarray(g, dtype=np.float64)
+    return float(v), g, float(np.dot(direction, g))
+
+
+def _zoom(x, direction, value, slope, lo, hi, v_lo, c1, c2, max_evals):
+    """Nocedal & Wright alg. 3.6 by bisection (Breeze interpolates;
+    bisection keeps the same Wolfe guarantees and is deterministic).
+    Returns the last point evaluated when the budget or the bracket runs
+    out before a Wolfe point is found."""
+    best = None
+    for _ in range(max_evals):
+        alpha = 0.5 * (lo + hi)
+        v, g, dg = yield from _phi_eval(x, direction, alpha)
+        if v > value + c1 * alpha * slope or v >= v_lo:
+            hi = alpha
+        else:
+            if abs(dg) <= -c2 * slope:
+                return alpha, v, g
+            if dg * (hi - lo) >= 0:
+                hi = lo
+            lo, v_lo = alpha, v
+        best = (alpha, v, g)
+        if abs(hi - lo) < 1e-12:
+            break
+    return best
+
+
+def _wolfe_search(x, value, slope, direction, init_alpha,
+                  c1=1e-4, c2=0.9, max_evals=30):
+    """THE host strong-Wolfe search (Nocedal & Wright alg. 3.5/3.6 — the
+    scheme Breeze's StrongWolfeLineSearch follows) as a coroutine: every
+    φ(α) is a ``yield`` of the trial point, answered with
+    ``send((value, grad))``. ``_strong_wolfe`` drives one with a callable;
+    ``StackedHostLBFGS`` drives K of them with one batched evaluation a
+    round. Returns ``(alpha, f(x+αd), g)`` via StopIteration."""
+    alpha_prev, v_prev = 0.0, value
+    alpha = init_alpha
+    for i in range(max_evals):
+        v, g, dg = yield from _phi_eval(x, direction, alpha)
+        if v > value + c1 * alpha * slope or (i > 0 and v >= v_prev):
+            return (yield from _zoom(x, direction, value, slope,
+                                     alpha_prev, alpha, v_prev,
+                                     c1, c2, max_evals))
+        if abs(dg) <= -c2 * slope:
+            return alpha, v, g
+        if dg >= 0:
+            return (yield from _zoom(x, direction, value, slope,
+                                     alpha, alpha_prev, v,
+                                     c1, c2, max_evals))
+        alpha_prev, v_prev = alpha, v
+        alpha *= 2.0
+    # fall back to the last evaluated point if Wolfe could not be satisfied
+    v, g, _ = yield from _phi_eval(x, direction, alpha)
+    return alpha, v, g
+
+
 def _strong_wolfe(f: LossGrad, x: np.ndarray, value: float, grad: np.ndarray,
                   direction: np.ndarray, init_alpha: float = 1.0,
                   c1: float = 1e-4, c2: float = 0.9,
                   max_evals: int = 30) -> Tuple[float, float, np.ndarray]:
-    """Strong-Wolfe line search (Nocedal & Wright alg. 3.5/3.6 — the scheme
-    Breeze's StrongWolfeLineSearch follows). Returns (alpha, f(x+αd), g)."""
-
-    d_dot_g0 = float(np.dot(direction, grad))
-    if d_dot_g0 >= 0:
-        raise ValueError("direction is not a descent direction")
+    """Strong-Wolfe line search along ``direction`` with a callable
+    objective. Returns (alpha, f(x+αd), g)."""
+    slope = _descent_slope(direction, grad)
 
     # fused path: a DistributedLossFunction runs the whole bracket+zoom
     # search in ONE device dispatch (vs one dispatch per phi eval here)
     fused = getattr(f, "device_line_search", None)
     if fused is not None:
-        out = fused(x, direction, value, d_dot_g0, init_alpha,
+        out = fused(x, direction, value, slope, init_alpha,
                     c1, c2, max_evals)
         if out is not None:
             return out
 
-    def phi(alpha: float):
-        v, g = f(x + alpha * direction)
-        return v, g, float(np.dot(direction, g))
+    search = _wolfe_search(x, value, slope, direction, init_alpha,
+                           c1, c2, max_evals)
+    try:
+        trial = next(search)
+        while True:
+            trial = search.send(f(trial))
+    except StopIteration as fin:
+        return fin.value
 
-    def zoom(lo, hi, v_lo, d_lo, v_hi):
-        best = None
-        for _ in range(max_evals):
-            # cubic-safe bisection (Breeze uses interpolation; bisection keeps
-            # the same Wolfe guarantees and is deterministic)
-            alpha = 0.5 * (lo + hi)
-            v, g, dg = phi(alpha)
-            if v > value + c1 * alpha * d_dot_g0 or v >= v_lo:
-                hi, v_hi = alpha, v
-            else:
-                if abs(dg) <= -c2 * d_dot_g0:
-                    return alpha, v, g
-                if dg * (hi - lo) >= 0:
-                    hi, v_hi = lo, v_lo
-                lo, v_lo, d_lo = alpha, v, dg
-            best = (alpha, v, g)
-            if abs(hi - lo) < 1e-12:
-                break
-        return best
 
-    alpha_prev, v_prev, d_prev = 0.0, value, d_dot_g0
-    alpha = init_alpha
-    for i in range(max_evals):
-        v, g, dg = phi(alpha)
-        if v > value + c1 * alpha * d_dot_g0 or (i > 0 and v >= v_prev):
-            out = zoom(alpha_prev, alpha, v_prev, d_prev, v)
-            if out is None:
-                break
-            return out
-        if abs(dg) <= -c2 * d_dot_g0:
-            return alpha, v, g
-        if dg >= 0:
-            out = zoom(alpha, alpha_prev, v, dg, v_prev)
-            if out is None:
-                break
-            return out
-        alpha_prev, v_prev, d_prev = alpha, v, dg
-        alpha *= 2.0
-    # fall back to the last evaluated point if Wolfe could not be satisfied
-    v, g, _ = phi(alpha)
-    return alpha, v, g
+def _steepest_alpha(grad: np.ndarray) -> float:
+    """First trial step along ``-grad``: min(1, 1/‖g‖)."""
+    return min(1.0, 1.0 / max(float(np.linalg.norm(grad)), 1e-12))
 
 
 def _reopen(resume: OptimState, max_iter: int) -> OptimState:
@@ -232,6 +277,46 @@ class LBFGS:
             return "gradient converged"
         return None
 
+    # -- the decisions of one turn, shared by both loops below ---------------
+    @staticmethod
+    def _start(x0: np.ndarray, value: float, grad: np.ndarray) -> OptimState:
+        value = float(value)
+        return OptimState(x=x0, value=value,
+                          grad=np.asarray(grad, dtype=np.float64),
+                          loss_history=[value])
+
+    @staticmethod
+    def _direction(hist: _History, state: OptimState
+                   ) -> Tuple[np.ndarray, float]:
+        """Search direction and first trial step of the turn after
+        ``state``. The two-loop direction starts at α = 1; steepest descent
+        — the very first turn, and the retry after a non-descent direction
+        reset the curvature memory (Breeze retries) — at min(1, 1/‖g‖)."""
+        grad = state.grad
+        d = hist.direction(grad)
+        if float(np.dot(d, grad)) >= 0:
+            hist.reset()
+            return -grad, _steepest_alpha(grad)
+        return d, (1.0 if state.iteration > 0 else _steepest_alpha(grad))
+
+    def _advance(self, hist: _History, state: OptimState, d: np.ndarray,
+                 alpha: float, v_new: float, g_new: np.ndarray) -> OptimState:
+        """The state after the step ``alpha·d``: curvature pair, history,
+        convergence test."""
+        x_new = state.x + alpha * d
+        g_new = np.asarray(g_new, dtype=np.float64)
+        hist.update(x_new - state.x, g_new - state.grad)
+        new = OptimState(
+            x=x_new, value=float(v_new), grad=g_new,
+            iteration=state.iteration + 1,
+            loss_history=state.loss_history + [float(v_new)],
+            hist_s=list(hist.s), hist_y=list(hist.y))
+        reason = self._converged(new, state.value)
+        if reason is not None:
+            new.converged = True
+            new.converged_reason = reason
+        return new
+
     def iterations(self, f: LossGrad, x0: np.ndarray,
                    resume: Optional[OptimState] = None):
         """Generator of OptimState per iteration (like Breeze .iterations).
@@ -245,45 +330,37 @@ class LBFGS:
         else:
             with _turn(0):
                 x = np.asarray(x0, dtype=np.float64).copy()
-                value, grad = f(x)
-                state = OptimState(x=x, value=float(value),
-                                   grad=np.asarray(grad, dtype=np.float64))
-                state.loss_history.append(state.value)
+                state = self._start(x, *f(x))
         yield state
         if state.converged:
             return  # resumed from a finished checkpoint: nothing to do
         while True:
             with _turn(state.iteration + 1):
-                d = hist.direction(state.grad)
-                init_alpha = 1.0 if state.iteration > 0 else min(
-                    1.0, 1.0 / max(float(np.linalg.norm(state.grad)), 1e-12))
-                try:
-                    alpha, v_new, g_new = _strong_wolfe(
-                        f, state.x, state.value, state.grad, d, init_alpha)
-                except ValueError:
-                    # reset on non-descent (Breeze retries)
-                    hist = _History(self.m)
-                    d = -state.grad
-                    alpha, v_new, g_new = _strong_wolfe(
-                        f, state.x, state.value, state.grad, d,
-                        min(1.0, 1.0 / max(
-                            float(np.linalg.norm(state.grad)), 1e-12)))
-                x_new = state.x + alpha * d
-                g_new = np.asarray(g_new, dtype=np.float64)
-                hist.update(x_new - state.x, g_new - state.grad)
-                f_old = state.value
-                state = OptimState(
-                    x=x_new, value=float(v_new), grad=g_new,
-                    iteration=state.iteration + 1,
-                    loss_history=state.loss_history + [float(v_new)],
-                    hist_s=list(hist.s), hist_y=list(hist.y))
-                reason = self._converged(state, f_old)
-                if reason is not None:
-                    state.converged = True
-                    state.converged_reason = reason
+                d, init_alpha = self._direction(hist, state)
+                alpha, v_new, g_new = _strong_wolfe(
+                    f, state.x, state.value, state.grad, d, init_alpha)
+                state = self._advance(hist, state, d, alpha, v_new, g_new)
             yield state
             if state.converged:
                 return
+
+    def _minimize_co(self, x0: np.ndarray, c1: float = 1e-4,
+                     c2: float = 0.9, max_ls: int = 30):
+        """:meth:`minimize` as a coroutine: every loss/grad evaluation is a
+        ``yield x`` answered by ``send((value, grad))``, so K of these can
+        share one batched evaluation a round (``StackedHostLBFGS``). Same
+        decisions as :meth:`iterations`, so identical replies give the
+        identical trajectory. Returns the terminal state via StopIteration."""
+        hist = _History(self.m)
+        x = np.asarray(x0, dtype=np.float64).copy()
+        state = self._start(x, *(yield x))
+        while not state.converged:
+            d, init_alpha = self._direction(hist, state)
+            alpha, v_new, g_new = yield from _wolfe_search(
+                state.x, state.value, _descent_slope(d, state.grad), d,
+                init_alpha, c1, c2, max_ls)
+            state = self._advance(hist, state, d, alpha, v_new, g_new)
+        return state
 
     def minimize(self, f: LossGrad, x0: np.ndarray,
                  resume: Optional[OptimState] = None) -> OptimState:
@@ -383,17 +460,13 @@ class LBFGSB(LBFGS):
                     return float(v), np.asarray(g, dtype=np.float64)
 
                 init_alpha = 1.0 if state.iteration > 0 else \
-                    min(1.0, 1.0 / max(float(np.linalg.norm(state.grad)), 1e-12))
-                try:
-                    alpha, v_new, g_new = _strong_wolfe(
-                        f_boxed, state.x, state.value, state.grad, d, init_alpha)
-                except ValueError:
-                    hist = _History(self.m)
-                    d = -state.grad
-                    alpha, v_new, g_new = _strong_wolfe(
-                        f_boxed, state.x, state.value, state.grad, d,
-                        min(1.0, 1.0 / max(float(np.linalg.norm(state.grad)),
-                                           1e-12)))
+                    _steepest_alpha(state.grad)
+                if float(np.dot(d, state.grad)) >= 0:
+                    # non-descent: reset and retry along steepest descent
+                    hist.reset()
+                    d, init_alpha = -state.grad, _steepest_alpha(state.grad)
+                alpha, v_new, g_new = _strong_wolfe(
+                    f_boxed, state.x, state.value, state.grad, d, init_alpha)
                 x_new = self._clip(state.x + alpha * d)
                 raw_grad_new = np.asarray(g_new, dtype=np.float64)
                 pg_new = self._projected_grad(x_new, raw_grad_new)
@@ -544,8 +617,7 @@ class OWLQN(LBFGS):
                 if not np.any(d):
                     d = -state.grad
                 orthant = np.where(x != 0, np.sign(x), -np.sign(state.grad))
-                steepest_alpha = min(1.0, 1.0 / max(
-                    float(np.linalg.norm(state.grad)), 1e-12))
+                steepest_alpha = _steepest_alpha(state.grad)
                 try:
                     alpha, x_new, v_new, raw_grad_new, evals, outcome = \
                         self._search(
@@ -578,3 +650,31 @@ class OWLQN(LBFGS):
 
     def _has_l1(self) -> bool:
         return bool(np.any(np.asarray(self.l1_reg) > 0))
+
+
+def optimizer_for(max_iter: int, tol: float, n_coef: int, *,
+                  bounds: Optional[Tuple[np.ndarray, np.ndarray]] = None,
+                  l1: float = 0.0, n_penalized: int = 0,
+                  penalty_std: Optional[np.ndarray] = None) -> LBFGS:
+    """The optimizer that serves an objective over ``n_coef`` coordinates
+    (ref createOptimizer, LogisticRegression.scala:777-814): ``(lower,
+    upper)`` bounds → :class:`LBFGSB`; an L1 share → :class:`OWLQN`; else
+    :class:`LBFGS`.
+
+    The L1 penalty is ``l1`` on the first ``n_penalized`` coordinates and 0
+    on the rest (intercepts are never penalised). ``penalty_std`` — σ per
+    penalised coordinate — asks for the penalty in the ORIGINAL feature
+    space (``standardization=False``) while the coordinates live in the
+    standardized one: ``l1/σ``, and 0 where σ = 0."""
+    if bounds is not None:
+        return LBFGSB(*bounds, max_iter=max_iter, tol=tol)
+    if l1 > 0:
+        l1_vec = np.zeros(n_coef)
+        if penalty_std is None:
+            l1_vec[:n_penalized] = l1
+        else:
+            std = np.asarray(penalty_std, dtype=np.float64)
+            l1_vec[:n_penalized] = np.where(
+                std > 0, l1 / np.where(std > 0, std, 1.0), 0.0)
+        return OWLQN(max_iter=max_iter, tol=tol, l1_reg=l1_vec)
+    return LBFGS(max_iter=max_iter, tol=tol)

@@ -16,7 +16,7 @@ from typing import Callable, Optional, Tuple
 import numpy as np
 
 from cycloneml_tpu.dataset.dataset import InstanceDataset
-from cycloneml_tpu.observe import attribution, tracing
+from cycloneml_tpu.observe import tracing
 from cycloneml_tpu.parallel import collectives
 
 
@@ -117,8 +117,6 @@ class DistributedLossFunction:
         if self.l2_reg_fn is not None and \
                 not hasattr(self.l2_reg_fn, "traceable"):
             return None
-        import jax
-
         from cycloneml_tpu.parallel import faults
 
         # the fused program dispatches the aggregation from INSIDE one XLA
@@ -147,52 +145,27 @@ class DistributedLossFunction:
         # argument for the same reason — baking it in would fork the cache.
         key = (self._agg_call.compiled, l2_t, float(c1), float(c2),
                int(max_evals), cdt.str)
-        fn = _ls_program_cache.get(key)
-        fresh = fn is None
-        if fresh:
-            fn = _build_line_search(self._agg_call.compiled, l2_t,
-                                    c1, c2, max_evals, cdt)
-            # bounded: standardization=False fits key on a fresh l2 fn per
-            # fit and would otherwise grow this without limit
-            _ls_program_cache.put(key, fn)
+        # (bounded: standardization=False fits key on a fresh l2 fn per fit
+        # and would otherwise grow it without limit)
+        fn, fresh = _ls_program_cache.get_or_build(
+            key, lambda: _build_line_search(self._agg_call.compiled, l2_t,
+                                            c1, c2, max_evals, cdt))
         args = (*arrays,
                 np.asarray(x, dtype=cdt),
                 np.asarray(direction, dtype=cdt),
                 cdt.type(value), cdt.type(dg0),
                 cdt.type(init_alpha),
                 cdt.type(self.weight_sum))
-        pid = None
-        # full tracer only: the flight-recorder ring must not trigger the
-        # AOT cost analyze / budget check. A live attribution window buys
-        # the harvest too (scoped fits join FLOPs/bytes on the program id).
-        win = attribution.dispatch_window()
-        tr = tracing.full_active()
-        if tr is not None or win.live:
-            # cost harvest BEFORE the dispatch (registry-cached once per
-            # program identity): a raise-mode budget guard must fire before
-            # the oversized program executes, and the AOT analyze must not
-            # land inside the dispatch/compile spans
+        if fresh and tracing.full_active() is not None:
+            # a raise-mode budget guard must fire before the oversized
+            # program executes (the harvest is registry-cached: the
+            # dispatch below finds it done)
             from cycloneml_tpu.observe import costs
-            pid = costs.ensure("lbfgs.line_search", key, fn, args)
-            if fresh and tr is not None:
-                costs.check_budget(pid)
-        win.annotate_program(pid)
-        with win:
-            with tracing.span("dispatch", "lbfgs.line_search") as dsp:
-                if fresh:
-                    with tracing.span("compile", "lbfgs.line_search"):
-                        res = fn(*args)
-                else:
-                    res = fn(*args)
-                with tracing.span("transfer", "line_search.readback") as tsp:
-                    out = jax.device_get(res)
-                    tsp.annotate_bytes(out)
-        alpha, v, g, evals = out
-        dsp.annotate(evals=int(evals))
-        if tr is not None:
-            from cycloneml_tpu.observe import costs
-            dsp.annotate(program=pid)
-            costs.note_execution(tr, pid)
+            costs.check_budget(
+                costs.ensure("lbfgs.line_search", key, fn, args))
+        _, (alpha, v, g, evals) = collectives.dispatch_fused(
+            "lbfgs.line_search", key, fn, args, fresh=fresh,
+            transfer_name="line_search.readback", evals_at=3)
         self.n_evals += int(evals)
         self.n_dispatches += 1
         loss = float(v)
@@ -352,7 +325,7 @@ def wolfe_search(phi, g_zero, value0, dg0, init_alpha,
                  c1: float, c2: float, max_evals: int, cdt, active=None):
     """Traced strong-Wolfe bracket+zoom (Nocedal-Wright alg 3.5/3.6) as a
     ``lax.while_loop`` state machine — the device-resident twin of the host
-    search in ``lbfgs._strong_wolfe``.
+    search, ``lbfgs._wolfe_search``.
 
     ``phi(alpha) -> (value, grad_pytree, dg)``; ``g_zero`` is a zero pytree
     matching the gradient structure (any sharding — the feature-sharded

@@ -298,7 +298,7 @@ class WeightedLeastSquares:
 
     def _quasi_newton(self, ata, atb, a_bar, b_bar, bb_bar, a_std,
                       eff_l1, d: int):
-        from cycloneml_tpu.ml.optim.lbfgs import LBFGS, OWLQN
+        from cycloneml_tpu.ml.optim.lbfgs import optimizer_for
 
         k = ata.shape[0]
 
@@ -315,16 +315,9 @@ class WeightedLeastSquares:
         x0 = np.zeros(k)
         if self.fit_intercept:
             x0[d] = b_bar
-        if eff_l1:
-            l1_vec = np.zeros(k)
-            for i in range(d):
-                if self.standardize_features:
-                    l1_vec[i] = eff_l1
-                else:
-                    l1_vec[i] = eff_l1 / a_std[i] if a_std[i] != 0 else 0.0
-            opt = OWLQN(max_iter=self.max_iter, tol=self.tol, l1_reg=l1_vec)
-        else:
-            opt = LBFGS(max_iter=self.max_iter, tol=self.tol)
+        opt = optimizer_for(
+            self.max_iter, self.tol, k, l1=eff_l1 or 0.0, n_penalized=d,
+            penalty_std=None if self.standardize_features else a_std)
         state = None
         for state in opt.iterations(f, x0):
             pass

@@ -28,7 +28,8 @@ from cycloneml_tpu.dataset.dataset import InstanceDataset
 from cycloneml_tpu.dataset.frame import MLFrame
 from cycloneml_tpu.linalg.vectors import DenseVector, Vectors
 from cycloneml_tpu.ml.base import PredictionModel, Predictor
-from cycloneml_tpu.ml.optim import LBFGS, OWLQN, aggregators
+from cycloneml_tpu.ml.optim import aggregators
+from cycloneml_tpu.ml.optim.lbfgs import optimizer_for
 from cycloneml_tpu.ml.optim.loss import DistributedLossFunction, l2_regularization
 from cycloneml_tpu.ml.shared import (
     HasAggregationDepth, HasElasticNetParam, HasFitIntercept, HasLabelCol,
@@ -301,14 +302,9 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
                                                   stats.weight_sum,
                                                   extra_args=extras)
 
-            if l1 > 0:
-                l1_vec = np.full(d, l1)
-                if not standardize:
-                    l1_vec = np.where(x_std > 0, l1 / np.where(x_std > 0, x_std, 1.0), 0.0)
-                opt = OWLQN(max_iter=self.get("maxIter"), tol=self.get("tol"),
-                            l1_reg=l1_vec)
-            else:
-                opt = LBFGS(max_iter=self.get("maxIter"), tol=self.get("tol"))
+            opt = optimizer_for(
+                self.get("maxIter"), self.get("tol"), d, l1=l1, n_penalized=d,
+                penalty_std=None if standardize else x_std)
         with tracing.span("phase", "fit.optimize",
                           optimizer=type(opt).__name__):
             state = opt.minimize(loss_fn, np.zeros(d))

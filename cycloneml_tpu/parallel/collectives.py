@@ -129,6 +129,17 @@ class BoundedProgramCache:
         while len(self._d) > self._max:
             self._d.popitem(last=False)
 
+    def get_or_build(self, key, build: Callable):
+        """``(program, fresh)``: the cached program of ``key``, or
+        ``build()`` cached — ``fresh`` then says that its first dispatch
+        pays trace + XLA compile."""
+        prog = self.get(key)
+        fresh = prog is None
+        if fresh:
+            prog = build()
+            self.put(key, prog)
+        return prog, fresh
+
     def clear(self) -> None:
         self._d.clear()
 
@@ -262,6 +273,51 @@ def _instrument_dispatch(jitted, name: str = "tree_aggregate", key=None,
 
     dispatch.__wrapped__ = jitted
     return dispatch
+
+
+def dispatch_fused(name: str, key, prog, args: tuple, *, fresh: bool,
+                   transfer_name: str, evals_at: int,
+                   readback: Callable = lambda out: out, **span_attrs):
+    """One dispatch of a FUSED optimizer program — a whole line search or a
+    chunk of L-BFGS iterations, which inline the aggregation and so never
+    pass :func:`_instrument_dispatch` — and its one small readback.
+
+    Opens ``dispatch <name>`` (attrs ``span_attrs``) ⊃ ``compile <name>``
+    when ``fresh`` (the dispatch that pays trace + XLA compile) and ⊃
+    ``transfer <transfer_name>`` around the ``device_get`` of
+    ``readback(outputs)``, all inside an attribution window; host value
+    ``evals_at`` is the evaluation count the dispatch span reports as
+    ``evals``. Under a FULL tracer or a live window — never the
+    flight-recorder ring, which records spans and must not pay an AOT
+    analyze — the program's costs are harvested (once per program identity
+    ``key``) BEFORE the dispatch, so the analyze stays out of the
+    dispatch/compile spans, and the dispatch span carries a ``program``
+    attr so FitProfile can join executions onto costs.
+
+    Returns ``(outputs, host values)``."""
+    import jax
+
+    win = attribution.dispatch_window()
+    tr = tracing.full_active()
+    pid = None
+    if tr is not None or win.live:
+        pid = costs.ensure(name, key, prog, args)
+    win.annotate_program(pid)
+    with win:
+        with tracing.span("dispatch", name, **span_attrs) as dsp:
+            if fresh:
+                with tracing.span("compile", name):
+                    out = prog(*args)
+            else:
+                out = prog(*args)
+            with tracing.span("transfer", transfer_name) as tsp:
+                host = jax.device_get(readback(out))
+                tsp.annotate_bytes(host)
+    dsp.annotate(evals=int(host[evals_at]))
+    if tr is not None:
+        dsp.annotate(program=pid)
+        costs.note_execution(tr, pid)
+    return out, host
 
 
 # (fn, mesh, n_sharded, auto_psum, with_state) -> jitted program

@@ -34,6 +34,7 @@ import numpy as np
 from cycloneml_tpu.mesh import DATA_AXIS, MODEL_AXIS, REPLICA_AXIS, MeshRuntime
 from cycloneml_tpu.observe import tracing
 from cycloneml_tpu.parallel.collectives import (BoundedProgramCache,
+                                                dispatch_fused,
                                                 psum_over_mesh,
                                                 shard_map_compat)
 
@@ -250,34 +251,23 @@ class FeatureShardedLossFunction:
         if self.l2_reg_fn is not None and \
                 not getattr(self.l2_reg_fn, "is_standardized", False):
             return None
-        import jax
         reg = (getattr(self.l2_reg_fn, "reg_param", 0.0)
                if self.l2_reg_fn is not None else 0.0)
         cdt = np.dtype(self._x.dtype)
         key = ("tp_ls", self._rt.mesh, float(c1), float(c2),
                int(max_evals), cdt.str)
-        prog = _cache_get(key)
-        fresh = prog is None
-        if fresh:
-            prog = _build_tp_line_search(self._rt, c1, c2, max_evals, cdt)
-            _cache_put(key, prog)
+        prog, fresh = _program_cache.get_or_build(
+            key, lambda: _build_tp_line_search(self._rt, c1, c2, max_evals,
+                                               cdt))
         beta0, b0 = self._split(x, cdt)
         dbeta, db0 = self._split(direction, cdt)
         args = (self._x, self._y, self._w, beta0, b0, dbeta, db0,
                 cdt.type(value), cdt.type(dg0), cdt.type(init_alpha),
                 cdt.type(self.weight_sum), cdt.type(reg),
                 self._inv_std, self._scaled_mean)
-        with tracing.span("dispatch", "tp.line_search") as dsp:
-            if fresh:
-                with tracing.span("compile", "tp.line_search"):
-                    res = prog(*args)
-            else:
-                res = prog(*args)
-            with tracing.span("transfer", "tp.line_search.readback") as tsp:
-                out = jax.device_get(res)
-                tsp.annotate_bytes(out)
-        alpha, v, gb, gb0, evals = out
-        dsp.annotate(evals=int(evals))
+        _, (alpha, v, gb, gb0, evals) = dispatch_fused(
+            "tp.line_search", key, prog, args, fresh=fresh,
+            transfer_name="tp.line_search.readback", evals_at=4)
         self.n_evals += int(evals)
         self.n_dispatches += 1
         self.n_fused_searches += 1

@@ -72,6 +72,123 @@ def test_owlqn_lasso_vs_sklearn():
     assert set(np.nonzero(np.abs(st.x) > 1e-8)[0]) == set(np.nonzero(np.abs(sk.coef_) > 1e-8)[0])
 
 
+def _quad1(x):
+    return 0.5 * float(x @ x), x.copy()
+
+
+def _line1(x):
+    return -float(x[0]), np.array([-1.0])
+
+
+def _kink1(x):
+    return float(np.abs(x).sum()), np.sign(x)
+
+
+def _rosen_fg(x):
+    a, b = x
+    return (float((1 - a) ** 2 + 100 * (b - a * a) ** 2),
+            np.array([-2 * (1 - a) - 400 * a * (b - a * a),
+                      200 * (b - a * a)]))
+
+
+@pytest.mark.parametrize("f, x, d, init_alpha, cap, alpha, evals", [
+    # (alpha, evals) as the pre-coroutine _strong_wolfe returned them
+    pytest.param(_quad1, [1.0], [-1.0], 1.0, 30, 1.0, 1,
+                 id="first_trial_accepted"),
+    pytest.param(_quad1, [1.0], [-1.0], 4.0, 30, 1.0, 3,
+                 id="armijo_fails_then_zoom"),
+    pytest.param(_quad1, [1.0], [-1.0], 1.95, 30, 0.975, 2,
+                 id="positive_slope_then_zoom"),
+    pytest.param(_rosen_fg, [-1.2, 1.0], None, 1.0, 30, 0.0009765625, 11,
+                 id="rosenbrock_long_zoom"),
+    pytest.param(_line1, [0.0], [1.0], 1.0, 5, 32.0, 6,
+                 id="bracket_cap_exhausted"),
+    pytest.param(_kink1, [1.0], [-1.0], 3.0, 8, 0.99609375, 9,
+                 id="zoom_cap_exhausted"),
+    pytest.param(_quad1, [1.0], [1.0], 1.0, 30, None, 0,
+                 id="non_descent_raises"),
+])
+def test_strong_wolfe_search_branches(f, x, d, init_alpha, cap, alpha, evals):
+    """The one host search (the coroutine, driven here by a callable) on a
+    case for each of its branches."""
+    from cycloneml_tpu.ml.optim.lbfgs import _strong_wolfe
+    x = np.array(x)
+    value, grad = f(x)
+    d = -grad if d is None else np.array(d)
+    seen = []
+
+    def counted(z):
+        seen.append(z)
+        return f(z)
+
+    if alpha is None:
+        with pytest.raises(ValueError, match="not a descent direction"):
+            _strong_wolfe(counted, x, value, grad, d, init_alpha)
+        assert not seen
+        return
+    a, v, g = _strong_wolfe(counted, x, value, grad, d, init_alpha,
+                            max_evals=cap)
+    assert (a, len(seen)) == (alpha, evals)
+    # what comes back is the last point evaluated
+    v_ref, g_ref = f(x + a * d)
+    assert v == v_ref
+    np.testing.assert_array_equal(g, g_ref)
+
+
+def _host_logistic(seed=0, n=60, d=5, reg=0.05):
+    rng = np.random.RandomState(seed)
+    X = rng.randn(n, d)
+    y = (X @ rng.randn(d) + 0.3 * rng.randn(n) > 0).astype(np.float64)
+
+    def f(w):
+        z = X @ w
+        loss = float(np.mean(np.logaddexp(0.0, z) - y * z)
+                     + 0.5 * reg * (w @ w))
+        return loss, X.T @ (1.0 / (1.0 + np.exp(-z)) - y) / n + reg * w
+    return f, d
+
+
+@pytest.mark.parametrize("objective", ["quadratic", "logistic"])
+def test_stacked_host_lbfgs_of_one_model_is_lbfgs(objective):
+    """StackedHostLBFGS drives the SAME turn decisions and the same search
+    as LBFGS.iterations: one model, same replies, the same floats."""
+    from cycloneml_tpu.ml.optim.device_lbfgs import StackedHostLBFGS
+    if objective == "quadratic":
+        rng = np.random.RandomState(3)
+        A = rng.randn(8, 8)
+        A = A @ A.T + np.eye(8)
+        b = rng.randn(8)
+        d = 8
+
+        def f(x):
+            return 0.5 * float(x @ A @ x) - float(b @ x), A @ x - b
+    else:
+        f, d = _host_logistic()
+    x0 = np.full(d, 0.5)
+    calls = []
+
+    def counted(x):
+        calls.append(x)
+        return f(x)
+
+    serial = LBFGS(max_iter=40, tol=1e-9).minimize(counted, x0)
+
+    def stacked_f(xs):
+        out = [f(x) for x in xs]
+        return (np.array([v for v, _ in out]),
+                np.stack([g for _, g in out]))
+
+    res = StackedHostLBFGS(max_iter=40, tol=1e-9).minimize(
+        stacked_f, x0[None, :])
+    assert serial.iteration > 3
+    np.testing.assert_array_equal(res.x[0], serial.x)
+    assert res.values[0] == serial.value
+    assert int(res.iterations[0]) == serial.iteration
+    assert res.converged_reasons[0] == serial.converged_reason
+    assert res.loss_histories[0] == serial.loss_history
+    assert int(res.evals[0]) == len(calls)
+
+
 def test_owlqn_zero_l1_equals_lbfgs():
     rng = np.random.RandomState(3)
     h = np.diag(rng.uniform(1, 3, 5))

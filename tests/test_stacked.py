@@ -315,6 +315,42 @@ class TestConvergenceMasks:
                                    cut.loss_histories[early], rtol=0)
 
 
+    def test_one_model_stack_walks_the_serial_chunk(self, ctx):
+        """The stacked chunk is ``vmap`` of the parts the serial chunk is
+        built from: at K = 1, in float64, it takes the serial chunk's
+        iterations and evaluations and sees its losses."""
+        import jax.numpy as jnp
+
+        from cycloneml_tpu.ml.optim import aggregators
+        from cycloneml_tpu.ml.optim.device_lbfgs import (
+            DeviceLBFGS, StackedDeviceLBFGS)
+        from cycloneml_tpu.ml.optim.loss import (
+            DistributedLossFunction, l2_regularization)
+
+        stacked_f, d = self._stacked_loss(ctx, np.array([0.1]))
+        stacked = StackedDeviceLBFGS(max_iter=100, tol=1e-6, chunk=8) \
+            .minimize(stacked_f, np.zeros((1, d + 1)))
+
+        ds = _binary_frame(ctx, seed=40).to_instance_dataset(
+            "features", "label", None)
+        serial_f = DistributedLossFunction(
+            ds, aggregators.binary_logistic_scaled(d, True),
+            l2_regularization(0.1, d, True, standardize=True),
+            stacked_f.weight_sum,
+            extra_args=tuple(stacked_f._agg_call.arrays()[-2:]))
+        serial = DeviceLBFGS(max_iter=100, tol=1e-6, chunk=8).minimize(
+            serial_f, np.zeros(d + 1))
+
+        assert serial.iteration > 3
+        assert int(stacked.iterations[0]) == serial.iteration
+        assert int(stacked.evals[0]) == serial_f.n_evals
+        assert stacked.converged_reasons[0] == serial.converged_reason
+        np.testing.assert_allclose(stacked.loss_histories[0],
+                                   serial.loss_history, rtol=1e-12)
+        np.testing.assert_allclose(stacked.x[0], serial.x, rtol=1e-9,
+                                   atol=1e-12)
+
+
 class TestStackedGradientDescent:
     def test_matches_serial_per_model(self, ctx):
         from cycloneml_tpu.ml.optim import aggregators
