@@ -464,7 +464,7 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
                     f"fit_stacked requires binary {{0, 1}} labels; the "
                     f"shard set carries {len(hist)} classes")
             n_models = len(reg_params)
-            pos = np.full(n_models, sds.y_moments()[0])
+            pos = np.full(n_models, stats.label_sum)
         else:
             y_stack = np.asarray(y_stack)
             n_models = y_stack.shape[0]

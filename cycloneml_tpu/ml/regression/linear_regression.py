@@ -92,9 +92,6 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
         return self._fit_dataset(ds)
 
     def _fit_dataset(self, ds: InstanceDataset) -> "LinearRegressionModel":
-        import jax
-        import jax.numpy as jnp
-
         from cycloneml_tpu.oocore import StreamingDataset, streaming_mode
         streamed = isinstance(ds, StreamingDataset)
         force = not streamed and \
@@ -138,19 +135,12 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
             x_mean, x_std = stats.mean, stats.std
             w_sum = stats.weight_sum
 
-            # label moments: one psum pass in-core; already harvested in the
-            # shard write pass for streamed datasets
-            if streamed:
-                s1y, s2y, w2y = ds.y_moments()
-                ymom = {"s1": s1y, "s2": s2y, "w2": w2y}
-            else:
-                ymom = ds.tree_aggregate_fn(
-                    lambda x, y, w: {"s1": jnp.sum(w * y),
-                                     "s2": jnp.sum(w * y * y),
-                                     "w2": jnp.sum(w * w)})()
-            y_mean = float(ymom["s1"]) / w_sum
-            denom = w_sum - float(ymom["w2"]) / w_sum
-            y_var = max((float(ymom["s2"]) - w_sum * y_mean ** 2) / denom, 0.0) if denom > 0 else 0.0
+            # label moments: harvested by the pass that made ``stats`` (the
+            # Summarizer's, cached on the dataset; the shard write pass for
+            # a streamed one) — nothing is traced, launched or read back here
+            y_mean = stats.label_sum / w_sum
+            denom = w_sum - stats.weight_sq_sum / w_sum
+            y_var = max((stats.label_sq_sum - w_sum * y_mean ** 2) / denom, 0.0) if denom > 0 else 0.0
             y_std = float(np.sqrt(y_var))
             if y_std == 0.0:
                 # constant label (ref LinearRegression.scala:388-414, mirroring

@@ -5,8 +5,10 @@ metrics list :84, treeAggregate paths :214,232; also
 mllib/stat/MultivariateOnlineSummarizer): one jit-compiled psum pass computes
 all weighted moments simultaneously — mean, variance (unbiased, weighted, the
 reference's formula), count, numNonzeros, max, min, normL1, normL2, sum,
-weightSum. Padding rows (w=0) are neutral in every statistic, including
-max/min which mask by weight.
+weightSum — and the label's weighted sums beside them, as the reference's
+``Summarizer.getRegressionSummarizers`` summarises features and label in
+ONE treeAggregate. Padding rows (w=0) are neutral in every statistic,
+including max/min which mask by weight.
 """
 
 from __future__ import annotations
@@ -31,6 +33,12 @@ class SummaryStats:
     norm_l2: np.ndarray
     sum: np.ndarray
     weight_sum: float
+    # the label's side of the same pass (Σ w·y, Σ w·y², Σ w²): what
+    # LinearRegression standardises the label by. None on a summary whose
+    # builder harvested no label (reading one then raises, not a silent 0)
+    label_sum: Optional[float] = None
+    label_sq_sum: Optional[float] = None
+    weight_sq_sum: Optional[float] = None
 
     @property
     def std(self) -> np.ndarray:
@@ -96,6 +104,10 @@ def _moments(x, y, w):
         "s2": s2,
         "w": jnp.sum(w),
         "w2": jnp.sum(w * w),
+        # y rides the accumulator dtype like w (zeros where a dataset has
+        # no label): two scalar sums more in a pass that reads all of X
+        "ys1": jnp.sum(w * y),
+        "ys2": jnp.sum(w * y * y),
         "cnt": jnp.sum(present.astype(acc)),
         "nnz": jnp.sum((present & (x != 0)).astype(acc), axis=0),
         "mx": jnp.max(jnp.where(present, x, neg_inf), axis=0),
@@ -168,7 +180,8 @@ def _finalize(out, dataset: InstanceDataset) -> SummaryStats:
     mean = s1 / w
     # unbiased weighted variance — the reference's formula
     # (MultivariateOnlineSummarizer.variance): (s2 - w*mean^2) * w/(w - w2/w)
-    denom = w - float(out["w2"]) / w
+    w2 = float(out["w2"])
+    denom = w - w2 / w
     if denom > 0:
         variance = np.maximum((s2 - w * mean * mean) / denom, 0.0)
     else:
@@ -184,4 +197,7 @@ def _finalize(out, dataset: InstanceDataset) -> SummaryStats:
         norm_l2=np.sqrt(s2),
         sum=s1,
         weight_sum=w,
+        label_sum=float(out["ys1"]),
+        label_sq_sum=float(out["ys2"]),
+        weight_sq_sum=w2,
     )

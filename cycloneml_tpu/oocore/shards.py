@@ -384,7 +384,8 @@ class StreamingDataset:
             mean=mean, variance=variance, count=int(round(m.cnt)),
             num_nonzeros=m.nnz.copy(), max=m.mx.copy(), min=m.mn.copy(),
             norm_l1=m.l1.copy(), norm_l2=np.sqrt(np.maximum(m.s2, 0.0)),
-            sum=m.s1.copy(), weight_sum=m.w)
+            sum=m.s1.copy(), weight_sum=m.w,
+            label_sum=m.s1y, label_sq_sum=m.s2y, weight_sq_sum=m.w2)
 
     def label_histogram(self) -> np.ndarray:
         """Weighted class histogram (f64) when labels are class indices;
@@ -399,12 +400,6 @@ class StreamingDataset:
     def num_classes(self) -> int:
         return max(len(self._moments.histogram), 2) \
             if self._moments.integral_labels else 0
-
-    def y_moments(self):
-        """``(Σwy, Σwy², Σw²)`` — what the LinearRegression label-std pass
-        computes in-core with one psum."""
-        m = self._moments
-        return m.s1y, m.s2y, m.w2
 
     # -- shard access (the stream's supplier) ---------------------------------
     def load_shard(self, i: int):

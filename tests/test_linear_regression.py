@@ -124,3 +124,65 @@ def test_save_load(ctx, tmp_path):
     np.testing.assert_allclose(back.coefficients.to_array(),
                                m.coefficients.to_array())
     assert back.intercept == m.intercept
+
+
+def test_warm_fit_prepare_builds_and_launches_nothing(ctx):
+    """The label moments ride the Summarizer's cached pass: once a dataset
+    has its summary, ``fit.prepare`` traces, compiles, launches and reads
+    back nothing, and a re-fit adds no program to the cache."""
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    from cycloneml_tpu.ml.stat import Summarizer
+    from cycloneml_tpu.observe import tracing
+    from cycloneml_tpu.parallel import collectives
+    rng = np.random.RandomState(28)
+    n, d = 4096, 24
+    x = rng.randn(n, d) * rng.uniform(0.5, 4.0, d)[None, :]
+    y = x @ rng.randn(d) + 3.0 + 0.5 * rng.randn(n)
+    ds = InstanceDataset.from_numpy(ctx, x, y)
+    est = LinearRegression(maxIter=40, regParam=0.05, elasticNetParam=0.5)
+    tracing.disable()
+    tracer = tracing.enable(max_spans=50_000)
+    try:
+        fits = []
+        for _ in range(3):
+            tracer.clear()
+            model = est.fit(ds)
+            fits.append((model, tracer.snapshot(),
+                         len(collectives._program_cache)))
+    finally:
+        tracing.disable()
+
+    first, built, size = fits[0]
+    assert [s for s in built if s.kind == "compile"]   # the cold fit did
+    for model, spans, cache_size in fits[1:]:
+        assert cache_size == size
+        assert not [s for s in spans if s.kind == "compile"]
+        stats, = [s for s in spans if s.name == "fit.stats"]
+        assert stats.attrs == {"cached": True}
+        prepares = {s.span_id for s in spans if s.name == "fit.prepare"}
+        assert len(prepares) == 2
+        by_id = {s.span_id: s for s in spans}
+
+        def in_prepare(s):
+            while s is not None and s.span_id not in prepares:
+                s = by_id.get(s.parent_id)
+            return s is not None
+        assert not [(s.kind, s.name) for s in spans if in_prepare(s)
+                    and s.kind in ("compile", "dispatch", "transfer",
+                                   "collective")]
+        assert not [s for s in spans if s.name == "cache.miss"]
+        np.testing.assert_array_equal(model.coefficients.to_array(),
+                                      first.coefficients.to_array())
+        assert model.intercept == first.intercept
+        assert model.summary.objective_history == \
+            first.summary.objective_history
+
+    # the same fit with the label's mean and std from float64 numpy
+    y_mean, y_std = float(y.mean()), float(y.std(ddof=1))
+    coef, icpt, state, _, _ = est._solve_quasi_newton(
+        ds, Summarizer.summarize(ds), y_mean, y_std, 0.05 / y_std, 0.5)
+    np.testing.assert_allclose(first.coefficients.to_array(), coef,
+                               rtol=1e-9, atol=1e-12)
+    assert first.intercept == pytest.approx(icpt, rel=1e-9)
+    np.testing.assert_allclose(first.summary.objective_history,
+                               state.loss_history, rtol=1e-9)

@@ -662,7 +662,11 @@ def test_fit_profile_phase_seconds_are_the_hosts_side(ctx, which):
         "optim.iteration"}
     assert all(v >= 0.0 for v in prof.phase_seconds.values())
     host = prof.wall_seconds - prof.dispatch_seconds
-    assert sum(prof.phase_seconds.values()) == pytest.approx(host, rel=0.05)
+    # what no phase covers is the estimator's entry code (0.2-0.3 ms): a
+    # twentieth of the elastic net's host side since its fit.prepare
+    # re-traces nothing (4 ms a fit here, 80 ms before)
+    assert sum(prof.phase_seconds.values()) == pytest.approx(
+        host, rel=0.05, abs=1e-3)
     # fit.optimize's own time is what is left outside its turns
     assert prof.phase_seconds["fit.optimize"] < \
         0.5 * sum(s.duration_s for s in spans if s.name == "fit.optimize")
