@@ -5,8 +5,9 @@ One process, every attached TPU device, no arguments, nothing read from the
 environment. It drives the main path through the public entry points —
 ``CycloneContext`` (default ``master="tpu"``), ``generate_classification``,
 ``LogisticRegression.fit`` — at full width, then a host-fed numpy → ``MLFrame``
-→ ``fit`` leg, then compiles and checks every Pallas kernel natively at small
-n. Every leg asserts WHICH path ran (platform, data dtype, Mosaic custom call,
+→ ``fit`` leg, a small binomial ``GeneralizedLinearRegression`` fit (IRLS over
+the weighted branch of the moment Gramian), then compiles and checks every
+Pallas kernel natively at small n. Every leg asserts WHICH path ran (platform, data dtype, Mosaic custom call,
 one in-core dispatch) and that what came out is right (finite non-increasing
 objective, agreement with the XLA twin and with a float64 reference).
 
@@ -31,6 +32,8 @@ import numpy as np
 N_ROWS = 2_000_000      # the r05 shape: 5.12 GB of bf16 X on one chip
 N_COLS = 1_280
 N_HOST_ROWS = 100_000   # host-fed leg: numpy -> MLFrame -> fit
+N_GLR_ROWS = 131_072    # binomial GLR leg: IRLS from a device-resident dataset
+N_GLR_COLS = 256
 MAX_ITER = 25
 REG = 0.01
 
@@ -270,6 +273,70 @@ def host_leg(ctx, n: int, d: int, devices) -> dict:
     }
 
 
+def glr_leg(ctx, n: int, d: int, devices) -> dict:
+    """Leg 3: a binomial ``GeneralizedLinearRegression`` fit from a
+    device-resident dataset — IRLS, whose working weights are no 0/1 mask,
+    so every pass takes the WEIGHTED branch of the moment Gramian on the
+    chip — against a float64 Newton iteration over the same stored values."""
+    import jax.numpy as jnp
+    from cycloneml_tpu.dataset.random import generate_classification
+    from cycloneml_tpu.ml.regression import GeneralizedLinearRegression
+    from cycloneml_tpu.ml.regression import glm
+    from cycloneml_tpu.ops import kernels
+
+    # noise at half the signal's deviation (beta ~ N(0, 1): |x.beta| ~
+    # sqrt(d)): coefficients of a few tenths, as a table's are. At the
+    # generator's default the classes all but separate, the coefficients
+    # pass 1 and float32 cannot resolve IRLS's ABSOLUTE tol of 1e-6
+    ds = generate_classification(ctx, n, d, seed=1, noise=0.5 * d ** 0.5)
+    t0 = time.perf_counter()
+    model = GeneralizedLinearRegression(family="binomial").fit(ds)
+    cold_s = time.perf_counter() - t0
+    s = model.summary
+    se = np.asarray(s.coefficient_standard_errors, np.float64)
+    check(str(ds.x.dtype) == "bfloat16", f"glr leg: data tier {ds.x.dtype}")
+    check(2 <= s.num_iterations < 25 and s.total_passes == s.num_iterations
+          and s.total_dispatches == s.num_iterations + 1,
+          f"glr leg: {s.num_iterations} iterations, {s.total_passes} passes, "
+          f"{s.total_dispatches} dispatches")
+    call = ds.tree_aggregate_fn(glm.irls_aggregator(
+        glm.Binomial(), glm.Logit(), kernels.stored_feature_major(ds.x),
+        False))
+    text = call.compiled.__wrapped__.lower(
+        *call.arrays(), jnp.zeros(d + 2, jnp.float32)).as_text()
+    check("tpu_custom_call" in text,
+          "glr leg: no Mosaic custom call in the IRLS program")
+
+    x, y, _ = ds.to_numpy()
+    xa = np.hstack([np.asarray(x, np.float64), np.ones((len(y), 1))])
+    y = np.asarray(y, np.float64)
+    b = np.zeros(d + 1)
+    for _ in range(25):
+        mu = 1.0 / (1.0 + np.exp(-(xa @ b)))
+        h = xa.T @ (xa * (mu * (1.0 - mu))[:, None])
+        step = np.linalg.solve(h, xa.T @ (y - mu))
+        b += step
+        if np.max(np.abs(step)) < 1e-12:
+            break
+    got = np.append(np.asarray(model.coefficients, np.float64),
+                    model.intercept)
+    gap = float(np.linalg.norm(got - b) / np.linalg.norm(b))
+    se_gap = rel_err(se, np.sqrt(np.diag(np.linalg.inv(h))))
+    mu = 1.0 / (1.0 + np.exp(-(xa @ got)))
+    dev = -2.0 * float(np.sum(y * np.log(mu) + (1 - y) * np.log1p(-mu)))
+    check(gap < 1e-4, f"glr leg: coefficients {gap:.3e} off float64 Newton")
+    check(se_gap < 1e-3, f"glr leg: standard errors {se_gap:.3e} off")
+    check(abs(s.deviance - dev) < 1e-5 * dev,
+          f"glr leg: deviance {s.deviance} against {dev}")
+    return {"n": n, "d": d, "orientation":
+            "feature_major" if kernels.stored_feature_major(ds.x)
+            else "row_major",
+            "iterations": s.num_iterations, "passes": s.total_passes,
+            "dispatches": s.total_dispatches, "deviance": s.deviance,
+            "coef_gap_vs_f64": gap, "se_gap_vs_f64": se_gap,
+            "cold_fit_s": round(cold_s, 3)}
+
+
 def rel_err(got, want) -> float:
     got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
     return float(np.max(np.abs(got - want)) / max(np.max(np.abs(want)), 1e-30))
@@ -499,6 +566,8 @@ def main() -> int:
     print(f"chip_smoke: device leg ok {device}", file=sys.stderr)
     host = host_leg(ctx, N_HOST_ROWS, N_COLS, devices)
     print(f"chip_smoke: host leg ok {host}", file=sys.stderr)
+    glr = glr_leg(ctx, N_GLR_ROWS, N_GLR_COLS, devices)
+    print(f"chip_smoke: glr leg ok {glr}", file=sys.stderr)
     kernels_ok = kernel_matrix()
     print(f"chip_smoke: kernel matrix ok {kernels_ok}", file=sys.stderr)
     ctx.stop()
@@ -514,6 +583,7 @@ def main() -> int:
                      "libtpu": version("libtpu")},
         "fit": device,
         "host_fit": host,
+        "glr_fit": glr,
         "kernel_matrix_max_rel_err": kernels_ok,
         "compile_cache": {
             "dir": cache_dir, "entries_before": entries_before,

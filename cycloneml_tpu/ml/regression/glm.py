@@ -2,22 +2,28 @@
 
 Re-design of the reference estimator (ref: ml/regression/
 GeneralizedLinearRegression.scala:246 — families/links at :557-990,
-IRLS driver at ml/optim/IterativelyReweightedLeastSquares.scala): each IRLS
-iteration is ONE fused device pass — eta/mu/working-response/working-weights
-and the weighted Gramian are computed per block on the MXU and psum'd over
-the mesh; the (d+1)×(d+1) augmented normal system is solved on the driver.
-The reference instead re-runs a WeightedLeastSquares treeAggregate per
-iteration over reweighted instances; collapsing reweight+Gramian into one
-jit program removes a full dataset pass per iteration.
+IRLS driver at ml/optim/IterativelyReweightedLeastSquares.scala) on the
+package's normal aggregation path: the dataset is a device-resident
+``InstanceDataset`` (``fit(ds)``; a frame builds one and takes the same
+loop), and each IRLS iteration is ONE ``tree_aggregate`` program
+(``irls_aggregator``: eta, mu, the working response ``z`` and the working
+weights ``omega`` from one sweep of X at storage width, then the weighted
+moments of ``(X, z, omega)`` through ``ops/kernels.moment_sums`` — the
+Gramian the normal equations of ``LinearRegression`` run) followed by
+``WeightedLeastSquares.solve`` on the driver, as the reference's IRLS hands
+every reweighted problem to its WeightedLeastSquares. Nothing of X, y or w
+comes to the host; the standard errors are the last solve's
+``diag_inv_atwa`` and every other summary statistic is a device reduction,
+most of them lazy.
 
 Families: gaussian, binomial, poisson, gamma, tweedie(variancePower).
 Links: identity, log, logit, inverse, sqrt, probit, cloglog, power(p).
-Offset support packs the offset as column 0 of the device block (sliced off
-inside the aggregation program) — dense blocks stay the physical unit.
+The offset column (frame path) rides as a fourth row-sharded vector.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -32,6 +38,7 @@ from cycloneml_tpu.ml.shared import (
     HasRegParam, HasSolver, HasTol,
 )
 from cycloneml_tpu.ml.util_io import MLReadable, MLWritable, load_arrays, save_arrays
+from cycloneml_tpu.observe import tracing
 from cycloneml_tpu.util.logging import get_logger
 
 logger = get_logger(__name__)
@@ -64,15 +71,33 @@ class Family:
         import jax.numpy as jnp
         return jnp.sum(w * self.unit_deviance(y, mu))
 
-    def aic(self, y, mu, w, w_sum, deviance, rank):  # driver-side, numpy
+    def aic(self, rows: dict, n: int, w_sum: float, deviance: float,
+            rank: int) -> float:
+        """Akaike's criterion from the device-resident ``rows`` (``y``,
+        ``mu``, ``w`` and ``valid``, the mask of real rows): jnp
+        reductions, only scalars come to the driver."""
         return float("nan")
 
     def clean_mu(self, mu):
         return mu
 
-    def validate_label(self, y_host: np.ndarray) -> None:
-        """Driver-side label-domain check before training (no-op for
-        most families; Tweedie enforces the reference's require()s)."""
+    def label_floor(self):
+        """``(bound, strict)`` the labels must stay above (Tweedie
+        enforces the reference's require()s), or None: no check, and no
+        reduction over the labels is launched for it."""
+        return None
+
+    # value identity: the cached aggregator factories key on the family
+    # and the link, so every fit of one configuration asks tree_aggregate
+    # for the same function and gets the same program
+    def _key(self):
+        return (type(self).__name__, getattr(self, "variance_power", None))
+
+    def __eq__(self, other):
+        return isinstance(other, Family) and self._key() == other._key()
+
+    def __hash__(self):
+        return hash(self._key())
 
 
 class Tweedie(Family):
@@ -87,20 +112,13 @@ class Tweedie(Family):
             return jnp.maximum(y, 0.1)
         return y
 
-    def validate_label(self, y_host: np.ndarray) -> None:
+    def label_floor(self):
         # label-domain validation (ref Tweedie.initialize:624-632): the
         # compound-Poisson band allows y=0; p>=2 needs strictly positive
         # labels — without this, y=0 at p>2 silently NaNs the deviance.
-        # Driver-side on the HOST labels (initialize runs inside jit)
+        # (initialize runs inside jit, so the check is a reduction of its own)
         p = self.variance_power
-        if 1.0 <= p < 2.0:
-            if np.any(y_host < 0):
-                raise ValueError(
-                    f"tweedie({p}) labels must be non-negative")
-        elif p >= 2.0:
-            if np.any(y_host <= 0):
-                raise ValueError(
-                    f"tweedie({p}) labels must be positive")
+        return None if p < 1.0 else (0.0, p >= 2.0)
 
     def variance(self, mu):
         import jax.numpy as jnp
@@ -140,14 +158,15 @@ class Gaussian(Tweedie):
     def unit_deviance(self, y, mu):
         return (y - mu) ** 2
 
-    def aic(self, y, mu, w, w_sum, deviance, rank):
+    def aic(self, rows, n, w_sum, deviance, rank):
         # ref :704-711 (+ summary's 2·rank): numInstances (row COUNT, not
         # weight sum) scales the log-likelihood term, and Σlog w subtracts
         # — R's weighted-gaussian aic
-        n = float(len(np.atleast_1d(y)))
+        import jax.numpy as jnp
+        log_w = jnp.sum(jnp.where(
+            rows["valid"], jnp.log(jnp.maximum(rows["w"], _EPS)), 0.0))
         return (n * (math.log(deviance / n * 2.0 * math.pi) + 1.0) + 2.0
-                - float(np.sum(np.log(np.maximum(w, _EPS))))
-                + 2.0 * rank)
+                - float(log_w) + 2.0 * rank)
 
     def clean_mu(self, mu):
         return mu
@@ -170,22 +189,28 @@ class Binomial(Family):
             return jnp.where(yy > 0, yy * jnp.log(jnp.maximum(yy / m, _EPS)), 0.0)
         return 2.0 * (ylogy(y, mu) + ylogy(1.0 - y, 1.0 - mu))
 
-    def aic(self, y, mu, w, w_sum, deviance, rank):
+    def aic(self, rows, n, w_sum, deviance, rank):
         # ref :745-759 — wt=round(w) trials, but successes round y*w with
         # the RAW weight (y=0.7, w=0.7: round(0.49)=0 successes of 1
         # trial, not round(0.7·1)=1)
-        from scipy import stats as sps
+        import jax.numpy as jnp
+        from jax.scipy import stats as jsps
+        y, mu, w = rows["y"], rows["mu"], rows["w"]
         # Java math.round = floor(x + 0.5) (half-UP), not numpy's
         # half-even — they diverge on exact .5 trials/successes
-        wt = np.floor(w + 0.5).astype(np.int64)
-        ok = wt > 0
-        ll = sps.binom.logpmf(np.floor(y[ok] * w[ok] + 0.5), wt[ok],
-                              np.clip(mu[ok], _EPS, 1 - _EPS))
-        return -2.0 * float(ll.sum()) + 2.0 * rank
+        wt = jnp.floor(w + 0.5)
+        ok = rows["valid"] & (wt > 0)
+        ll = jsps.binom.logpmf(jnp.floor(y * w + 0.5),
+                               jnp.where(ok, wt, 1.0), self.clean_mu(mu))
+        return -2.0 * float(jnp.sum(jnp.where(ok, ll, 0.0))) + 2.0 * rank
 
     def clean_mu(self, mu):
+        # the clip has to survive the accumulator's width: 1 - 1e-16 is
+        # 1.0 in float32, and mu = 1 makes the working weight of a
+        # saturated row 1e16 instead of ~0
         import jax.numpy as jnp
-        return jnp.clip(mu, _EPS, 1.0 - _EPS)
+        eps = max(_EPS, float(jnp.finfo(mu.dtype).eps) / 2)
+        return jnp.clip(mu, eps, 1.0 - eps)
 
 
 class Poisson(Tweedie):
@@ -206,10 +231,12 @@ class Poisson(Tweedie):
         t = jnp.where(y > 0, y * jnp.log(jnp.maximum(y, _EPS) / mu), 0.0)
         return 2.0 * (t - (y - mu))
 
-    def aic(self, y, mu, w, w_sum, deviance, rank):
-        from scipy import stats as sps
-        ll = w * sps.poisson.logpmf(np.round(y), mu)
-        return -2.0 * float(ll.sum()) + 2.0 * rank
+    def aic(self, rows, n, w_sum, deviance, rank):
+        import jax.numpy as jnp
+        from jax.scipy import stats as jsps
+        ll = rows["w"] * jsps.poisson.logpmf(jnp.round(rows["y"]), rows["mu"])
+        return -2.0 * float(jnp.sum(jnp.where(rows["valid"], ll, 0.0))) \
+            + 2.0 * rank
 
 
 class Gamma(Tweedie):
@@ -229,11 +256,17 @@ class Gamma(Tweedie):
         import jax.numpy as jnp
         return -2.0 * (jnp.log(jnp.maximum(y, _EPS) / mu) - (y - mu) / mu)
 
-    def aic(self, y, mu, w, w_sum, deviance, rank):
-        from scipy import stats as sps
+    def aic(self, rows, n, w_sum, deviance, rank):
+        import jax.numpy as jnp
+        from jax.scipy import stats as jsps
         disp = deviance / w_sum
-        ll = (w * sps.gamma.logpdf(y, 1.0 / disp, scale=mu * disp)).sum()
-        return -2.0 * float(ll) + 2.0 * rank + 2.0  # +2 for estimated dispersion
+        valid = rows["valid"]
+        ll = rows["w"] * jsps.gamma.logpdf(
+            jnp.where(valid, rows["y"], 1.0), 1.0 / disp,
+            scale=rows["mu"] * disp)
+        # +2 for the estimated dispersion
+        return -2.0 * float(jnp.sum(jnp.where(valid, ll, 0.0))) \
+            + 2.0 * rank + 2.0
 
 
 def _make_family(name: str, variance_power: float) -> Family:
@@ -269,6 +302,15 @@ class Link:
     def deriv(self, mu):
         """d eta / d mu."""
         raise NotImplementedError
+
+    def _key(self):
+        return (type(self).__name__, getattr(self, "p", None))
+
+    def __eq__(self, other):
+        return isinstance(other, Link) and self._key() == other._key()
+
+    def __hash__(self):
+        return hash(self._key())
 
 
 class Identity(Link):
@@ -501,200 +543,251 @@ class GeneralizedLinearRegression(Predictor, _GLRParams, MLWritable, MLReadable)
         return fam, link
 
     def _fit(self, frame: MLFrame) -> "GeneralizedLinearRegressionModel":
-        x = np.asarray(frame[self.get("featuresCol")], dtype=np.float64)
-        y = np.asarray(frame[self.get("labelCol")], dtype=np.float64)
-        wcol = self.get("weightCol")
-        w = np.asarray(frame[wcol], dtype=np.float64) if wcol else np.ones(len(y))
+        """A frame becomes the device-resident dataset every estimator
+        trains on (cached on the frame) and takes the loop ``fit(ds)``
+        takes; the offset column is placed beside it as a fourth
+        row-sharded vector."""
+        ds = frame.to_instance_dataset(
+            self.get("featuresCol"), self.get("labelCol"),
+            self.get("weightCol") or None)
         ocol = self.get("offsetCol")
-        offset = np.asarray(frame[ocol], dtype=np.float64) if ocol else None
-        return self._fit_arrays(x, y, w, offset)
+        offset = None
+        if ocol:
+            if isinstance(frame, InstanceDataset):
+                raise ValueError("offsetCol names a frame column; an "
+                                 "InstanceDataset carries none")
+            ofs = np.zeros(ds.y.shape[0], dtype=str(ds.y.dtype))
+            ofs[ds.valid_indices()] = np.asarray(frame[ocol])
+            offset = ds.ctx.mesh_runtime.device_put_sharded_rows(ofs)
+        return self._fit_dataset(ds, offset)
 
-    def _fit_arrays(self, x, y, w, offset=None) -> "GeneralizedLinearRegressionModel":
+    def _fit_dataset(self, ds: InstanceDataset, offset=None
+                     ) -> "GeneralizedLinearRegressionModel":
+        """IRLS over a device-resident dataset: per iteration one
+        aggregation program (dispatch + readback of the ``(d+1)``-sized
+        moments) and one ``WeightedLeastSquares.solve`` on the driver —
+        exactly what the reference's IterativelyReweightedLeastSquares
+        does with its reweighted instances — then one margin-only pass for
+        the returned model's deviance."""
         import jax
         import jax.numpy as jnp
-        from cycloneml_tpu.context import CycloneContext
+        from cycloneml_tpu.ml.optim.wls import AUTO, WeightedLeastSquares
+        from cycloneml_tpu.ops.kernels import stored_feature_major
+        from cycloneml_tpu.parallel import collectives
 
-        fam, link = self._family_link()
-        fam.validate_label(np.asarray(y, dtype=np.float64))
-        n, d = x.shape
-        if d > self.MAX_FEATURES:
-            raise ValueError(f"GLM supports at most {self.MAX_FEATURES} features")
-        fit_icpt = self.get("fitIntercept")
-        reg = self.get("regParam")
-        tol = self.get("tol")
-        max_iter = self.get("maxIter")
+        with tracing.span("phase", "fit.prepare"):
+            fam, link = self._family_link()
+            d = ds.n_features
+            if d > self.MAX_FEATURES:
+                raise ValueError(
+                    f"GLM supports at most {self.MAX_FEATURES} features")
+            fit_icpt = self.get("fitIntercept")
+            tol = self.get("tol")
+            rt = ds.ctx.mesh_runtime
+            rows = (ds.x, ds.y, ds.w) + (() if offset is None else (offset,))
+            _check_labels(fam, ds)
+            irls = collectives.tree_aggregate(
+                irls_aggregator(fam, link, stored_feature_major(ds.x),
+                                offset is not None), rt, *rows)
+            # ref IterativelyReweightedLeastSquares.scala: every reweighted
+            # problem goes to WeightedLeastSquares(fitIntercept, regParam,
+            # elasticNetParam = 0, standardizeFeatures = false,
+            # standardizeLabel = false) — regParam is the plain
+            # 0.5·regParam·|β|² of the estimator's documentation
+            wls = WeightedLeastSquares(
+                fit_intercept=fit_icpt, reg_param=self.get("regParam"),
+                elastic_net_param=0.0, standardize_features=False,
+                standardize_label=False, solver_type=AUTO)
 
-        has_offset = offset is not None
-        # offset rides as column 0 of the device block (see module docstring)
-        x_dev = np.concatenate([offset[:, None], x], axis=1) if has_offset else x
-        ctx = CycloneContext.get_or_create()
-        ds = InstanceDataset.from_numpy(ctx, x_dev, y, w)
+        n_dispatches = 0
 
-        fam_init = fam.initialize
-        fam_var = fam.variance
-        link_fn, unlink_fn, deriv_fn = link.link, link.unlink, link.deriv
-        clean = fam.clean_mu
+        def dispatch(program, name, coef, icpt, first):
+            """One launch and its one readback; ``coef``, the intercept
+            and the first-pass flag travel as ONE replicated vector."""
+            nonlocal n_dispatches
+            n_dispatches += 1
+            params = jnp.asarray(np.concatenate([coef, [icpt, first]]))
+            with tracing.span("dispatch", f"irls.{name}", passes=1):
+                out_dev = program(*rows, params)    # 'collective' inside
+                with tracing.span("transfer", "irls.readback") as tsp:
+                    out = jax.device_get(out_dev)
+                    tsp.annotate_bytes(out)
+            if hasattr(ds.ctx, "record_step"):
+                ds.ctx.record_step({"irls_passes": 1.0})
+            return out
 
-        def irls_pass(x_blk, y_blk, w_blk, beta, icpt, first):
-            ofs = x_blk[:, 0] if has_offset else 0.0
-            xf = x_blk[:, 1:] if has_offset else x_blk
-            eta_lin = jnp.dot(xf, beta, precision=jax.lax.Precision.HIGHEST) + icpt
-            mu0 = clean(fam_init(y_blk, jnp.maximum(w_blk, _EPS)))
-            eta = jnp.where(first > 0, link_fn(mu0), eta_lin + ofs)
-            mu = clean(unlink_fn(eta))
-            g = deriv_fn(mu)
-            z = (eta - ofs) + (y_blk - mu) * g
-            wi = w_blk / jnp.maximum(g * g * fam_var(mu), _EPS)
-            xw = xf * wi[:, None]
-            return {
-                "xtx": jnp.dot(xw.T, xf, precision=jax.lax.Precision.HIGHEST),
-                "xty": jnp.dot(xw.T, z, precision=jax.lax.Precision.HIGHEST),
-                "xsum": jnp.sum(xw, axis=0),
-                "xsq": jnp.sum(xw * xf, axis=0),
-                "wsum": jnp.sum(wi),
-                "zsum": jnp.sum(wi * z),
-                "dev": fam.deviance(y_blk, mu, w_blk),
-            }
-
-        agg = ds.tree_aggregate_fn(irls_pass)
-
-        beta = np.zeros(d)
-        icpt = 0.0
-        history = []
-        w_sum = float(w.sum())
-        for it in range(max(max_iter, 1)):
-            # one transfer for the whole IRLS stat pytree — this loop was
-            # paying NINE separate device->host round trips per iteration
-            # (graftlint JX001)
-            out = jax.device_get(agg(jnp.asarray(beta), jnp.asarray(icpt),
-                                     jnp.asarray(1.0 if it == 0 else 0.0)))
-            xtx = np.asarray(out["xtx"], dtype=np.float64)
-            xty = np.asarray(out["xty"], dtype=np.float64)
-            if fit_icpt:
-                a = np.zeros((d + 1, d + 1))
-                a[:d, :d] = xtx
-                a[:d, d] = a[d, :d] = np.asarray(out["xsum"], dtype=np.float64)
-                a[d, d] = float(out["wsum"])
-                b = np.concatenate([xty, [float(out["zsum"])]])
-            else:
-                a, b = xtx, xty
-            if reg > 0:
-                # ref: each IRLS step runs WeightedLeastSquares with
-                # standardizeFeatures=standardizeLabel=true, so the effective
-                # original-space penalty is reg · Σwᵢ · σ_j² under the
-                # CURRENT working weights (label-std factors cancel, same
-                # derivation as LinearRegression._solve_normal)
-                ws = float(out["wsum"])
-                xm = np.asarray(out["xsum"], dtype=np.float64) / ws
-                var_j = np.asarray(out["xsq"], dtype=np.float64) / ws - xm * xm
-                idx = np.arange(d)
-                a[idx, idx] += reg * ws * np.clip(var_j, 0.0, None)
-            try:
-                sol = np.linalg.solve(a, b)
-            except np.linalg.LinAlgError:
-                sol = np.linalg.lstsq(a, b, rcond=None)[0]
-            new_beta = sol[:d]
-            new_icpt = float(sol[d]) if fit_icpt else 0.0
-            old = np.concatenate([beta, [icpt]])
-            new = np.concatenate([new_beta, [new_icpt]])
-            # ref IRLS convergence: max relative coefficient change
-            delta = float(np.max(np.abs(new - old) / np.maximum(np.abs(old), 1e-6)))
-            beta, icpt = new_beta, new_icpt
-            history.append(float(out["dev"]))
+        coef, icpt = np.zeros(d), 0.0
+        history, wm = [], None
+        for it in range(max(self.get("maxIter"), 1)):
+            with tracing.span("phase", "irls.iteration", iteration=it) as isp:
+                out = dispatch(irls, "pass", coef, icpt, float(it == 0))
+                with tracing.span("phase", "fit.solve"):
+                    wm = wls.solve(out, d)
+                old = np.append(coef, icpt)
+                coef, icpt = wm.coefficients, float(wm.intercept)
+                # ref IRLS convergence (IterativelyReweightedLeastSquares
+                # .scala:105-114): the largest ABSOLUTE change of a
+                # coefficient or of the intercept
+                delta = float(np.max(np.abs(np.append(coef, icpt) - old)))
+                history.append(float(out["dev"]))
+                isp.annotate(delta=delta, deviance=history[-1])
             if it > 0 and delta < tol:
                 break
 
-        model = GeneralizedLinearRegressionModel(beta, icpt, uid=self.uid)
-        self._copy_values(model)
-        model._set_parent(self)
-        model.summary = self._summarize(model, x, y, w, offset, fam, link,
-                                        len(history))
-        return model
+        with tracing.span("phase", "fit.finish"):
+            # the deviance the passes report is the PREVIOUS model's: the
+            # returned one gets a margin-only pass of its own
+            last = dispatch(
+                collectives.tree_aggregate(
+                    deviance_aggregator(fam, link, offset is not None),
+                    rt, *rows), "deviance", coef, icpt, 0.0)
+            model = GeneralizedLinearRegressionModel(coef, icpt, uid=self.uid)
+            self._copy_values(model)
+            model._set_parent(self)
+            model.summary = GLMTrainingSummary(
+                model, ds, offset, fam, link, wm, fit_icpt,
+                deviance=float(last["dev"]), pearson=float(last["pearson"]),
+                deviance_history=history, total_dispatches=n_dispatches)
+            return model
 
-    def _summarize(self, model, x, y, w, offset, fam: Family, link: Link,
-                   n_iter: int):
+
+# -- the device programs of a fit ---------------------------------------------
+
+def _working_point(fam: Family, link: Link, eta, y, w, ofs):
+    """``(mu, z, omega)`` at the linear predictor ``eta`` (offset
+    included): the mean, the working response (offset taken out again) and
+    the working weight of every row (ref FamilyAndLink.reweightFunc)."""
+    import jax.numpy as jnp
+    mu = fam.clean_mu(link.unlink(eta))
+    g = link.deriv(mu)
+    z = (eta - ofs) + (y - mu) * g
+    omega = w / jnp.maximum(g * g * fam.variance(mu), _EPS)
+    # a row that carries no weight (padding, w = 0) must not carry a NaN
+    # into a sum either: 0 · NaN is NaN
+    return mu, jnp.where(omega > 0, z, 0.0), omega
+
+
+def _starting_eta(fam: Family, link: Link, y, w):
+    """The linear predictor IRLS starts from: the link of the family's
+    ``mu0`` (ref FamilyAndLink.initialize)."""
+    import jax.numpy as jnp
+    return link.link(fam.clean_mu(fam.initialize(y, jnp.maximum(w, _EPS))))
+
+
+def _margins(x, params, ofs, dtype):
+    from cycloneml_tpu.ops.kernels import storage_matvec
+    d = x.shape[1]
+    return storage_matvec(x, params[:d]).astype(dtype) + params[d] + ofs
+
+
+@functools.lru_cache(maxsize=None)
+def irls_aggregator(fam: Family, link: Link, feature_major: bool = False,
+                    has_offset: bool = False):
+    """One IRLS pass over a shard, ``irls_pass(x, y, w, [offset], params)``
+    with ``params = [beta | intercept | first]``: the linear predictor from
+    one sweep of X at storage width (the FIRST pass starts from the
+    family's ``mu0`` instead and skips the sweep), the working point, then
+    the weighted moments ``{w_sum, b_sum, bb_sum, a_sum, ab_sum, aa_sum}``
+    of ``(X, z, omega)`` by ``ops/kernels.moment_sums`` — no ``(rows, d)``
+    value is ever formed — and the deviance at the pass's ``beta``. Cached
+    by value of family and link, so every fit of one configuration asks
+    ``tree_aggregate`` for the same function and gets the same program
+    (``jit_tree_aggregate__irls_pass`` in a device capture);
+    ``feature_major`` is the caller's observation of how X is stored."""
+    def irls_pass(x, y, w, *rest):
+        import jax
+        from cycloneml_tpu.ops.kernels import moment_sums
+        ofs = rest[0] if has_offset else 0.0
+        params = rest[-1]
+
+        eta = jax.lax.cond(params[-1] > 0,
+                           lambda: _starting_eta(fam, link, y, w),
+                           lambda: _margins(x, params, ofs, y.dtype))
+        mu, z, omega = _working_point(fam, link, eta, y, w, ofs)
+        out = dict(moment_sums(x, z, omega, feature_major=feature_major))
+        out["dev"] = fam.deviance(y, mu, w)
+        return out
+    return irls_pass
+
+
+@functools.lru_cache(maxsize=None)
+def deviance_aggregator(fam: Family, link: Link, has_offset: bool = False):
+    """The margin-only pass for a given model (same arguments as the IRLS
+    pass): its deviance and its Pearson chi-square."""
+    def irls_deviance(x, y, w, *rest):
         import jax.numpy as jnp
+        ofs = rest[0] if has_offset else 0.0
+        mu = fam.clean_mu(link.unlink(_margins(x, rest[-1], ofs, y.dtype)))
+        return {"dev": fam.deviance(y, mu, w),
+                "pearson": jnp.sum(w * (y - mu) ** 2
+                                   / jnp.maximum(fam.variance(mu), _EPS))}
+    return irls_deviance
 
-        n, d = x.shape
-        fit_icpt = self.get("fitIntercept")
-        eta = x @ model._coef + model._icpt + (offset if offset is not None else 0.0)
-        mu = np.asarray(fam.clean_mu(link.unlink(jnp.asarray(eta))))
-        w_sum = float(w.sum())
-        dev = float(fam.deviance(jnp.asarray(y), jnp.asarray(mu), jnp.asarray(w)))
 
-        # null model: intercept-only (with offset if present)
-        if fit_icpt:
-            null_dev = self._fit_null(y, w, offset, fam, link)
+@functools.lru_cache(maxsize=None)
+def _label_min():
+    import jax
+    import jax.numpy as jnp
+    return jax.jit(lambda y, w: jnp.min(jnp.where(w > 0, y, jnp.inf)))
+
+
+def _check_labels(fam: Family, ds: InstanceDataset) -> None:
+    """The family's label domain, by one device reduction over the rows
+    that carry weight (families with no bound launch nothing)."""
+    floor = fam.label_floor()
+    if floor is None:
+        return
+    bound, strict = floor
+    low = float(_label_min()(ds.y, ds.w))
+    if low < bound or (strict and low <= bound):
+        raise ValueError(
+            f"{fam.name}({fam.variance_power}) labels must be "
+            f"{'positive' if strict else 'non-negative'}")
+
+
+@functools.lru_cache(maxsize=None)
+def _row_program(fam: Family, link: Link):
+    """``(x, y, offset, params) -> mu`` row by row, at the labels' width
+    (the summary's lazy statistics start from it)."""
+    import jax
+    return jax.jit(lambda x, y, ofs, params: fam.clean_mu(
+        link.unlink(_margins(x, params, ofs, y.dtype))))
+
+
+@functools.lru_cache(maxsize=None)
+def _null_program(fam: Family, link: Link, fit_intercept: bool,
+                  has_offset: bool):
+    """``(y, w, offset) -> deviance`` of the null model (ref nullDeviance):
+    no intercept — eta is the offset alone; an intercept and no offset —
+    the closed form, mu = the weighted mean of the labels; both — the
+    intercept-only refit, scalar IRLS on the device."""
+    import jax
+    import jax.numpy as jnp
+
+    def intercept_only(y, w, ofs):
+        def step(state):
+            k, icpt, eta, _ = state
+            _, z, omega = _working_point(fam, link, eta, y, w, ofs)
+            new = jnp.sum(omega * z) / jnp.maximum(jnp.sum(omega), _EPS)
+            done = jnp.abs(new - icpt) < 1e-10 * jnp.maximum(jnp.abs(icpt),
+                                                             1.0)
+            return k + 1, new, new + ofs, done
+
+        _, icpt, _, _ = jax.lax.while_loop(
+            lambda s: (s[0] < 50) & ~s[3], step,
+            (0, jnp.zeros((), y.dtype), _starting_eta(fam, link, y, w),
+             False))
+        return link.unlink(icpt + ofs)
+
+    def null_model_deviance(y, w, ofs):
+        if not fit_intercept:
+            mu = link.unlink(ofs + jnp.zeros_like(y))
+        elif not has_offset:
+            mu = jnp.sum(w * y) / jnp.sum(w) + jnp.zeros_like(y)
         else:
-            eta0 = (offset if offset is not None else np.zeros(n))
-            mu0 = np.asarray(fam.clean_mu(link.unlink(jnp.asarray(eta0))))
-            null_dev = float(fam.deviance(jnp.asarray(y), jnp.asarray(mu0),
-                                          jnp.asarray(w)))
-
-        rank = d + (1 if fit_icpt else 0)
-        dof_resid = n - rank
-        if fam.name in ("gaussian", "gamma") or (isinstance(fam, Tweedie)
-                                                 and fam.name == "tweedie"):
-            g = np.asarray(link.deriv(jnp.asarray(mu)))
-            var = np.asarray(fam.variance(jnp.asarray(mu)))
-            pearson = float((w * (y - mu) ** 2 / np.maximum(var, _EPS)).sum())
-            dispersion = pearson / max(dof_resid, 1)
-        else:
-            dispersion = 1.0
-        aic = fam.aic(y, mu, w, w_sum, dev, rank)
-
-        # standard errors from (XᵀWX)⁻¹·φ at the converged weights
-        g = np.asarray(link.deriv(jnp.asarray(mu)))
-        var = np.asarray(fam.variance(jnp.asarray(mu)))
-        wi = w / np.maximum(g * g * var, _EPS)
-        xa = np.concatenate([x, np.ones((n, 1))], axis=1) if fit_icpt else x
-        xtwx = xa.T @ (xa * wi[:, None])
-        try:
-            cov = np.linalg.inv(xtwx) * dispersion
-            se = np.sqrt(np.clip(np.diag(cov), 0, None))
-        except np.linalg.LinAlgError:
-            se = np.full(rank, float("nan"))
-        coefs = np.concatenate([model._coef, [model._icpt]]) if fit_icpt \
-            else model._coef
-        tvals = coefs / np.maximum(se, _EPS)
-        from scipy import stats as sps
-        if fam.name in ("binomial", "poisson"):
-            pvals = 2.0 * sps.norm.sf(np.abs(tvals))
-        else:
-            pvals = 2.0 * sps.t.sf(np.abs(tvals), max(dof_resid, 1))
-
-        return GLMTrainingSummary(
-            deviance=dev, null_deviance=null_dev, dispersion=dispersion,
-            aic=aic, num_iterations=n_iter, rank=rank,
-            degrees_of_freedom=n - 1 if fit_icpt else n,
-            residual_degree_of_freedom=dof_resid,
-            coefficient_standard_errors=se, t_values=tvals, p_values=pvals,
-            prediction_mean=mu, label=y, weights=w, family_obj=fam,
-            link_obj=link)
-
-    def _fit_null(self, y, w, offset, fam: Family, link: Link) -> float:
-        """Deviance of the intercept-only model (scalar IRLS on the driver)."""
-        import jax.numpy as jnp
-        mu = np.asarray(fam.initialize(jnp.asarray(y), jnp.asarray(w)))
-        mu = np.asarray(fam.clean_mu(jnp.asarray(mu)))
-        icpt = 0.0
-        ofs = offset if offset is not None else 0.0
-        eta = np.asarray(link.link(jnp.asarray(mu)))
-        for _ in range(50):
-            mu = np.asarray(fam.clean_mu(link.unlink(jnp.asarray(eta))))
-            g = np.asarray(link.deriv(jnp.asarray(mu)))
-            z = (eta - ofs) + (y - mu) * g
-            wi = w / np.maximum(g * g * np.asarray(fam.variance(jnp.asarray(mu))), _EPS)
-            new_icpt = float((wi * z).sum() / max(wi.sum(), _EPS))
-            if abs(new_icpt - icpt) < 1e-10 * max(abs(icpt), 1.0):
-                icpt = new_icpt
-                break
-            icpt = new_icpt
-            eta = icpt + ofs
-        mu = np.asarray(fam.clean_mu(link.unlink(jnp.asarray(icpt + ofs))))
-        if np.isscalar(mu) or mu.ndim == 0:
-            mu = np.full_like(y, float(mu))
-        return float(fam.deviance(jnp.asarray(y), jnp.asarray(mu), jnp.asarray(w)))
+            mu = intercept_only(y, w, ofs)
+        return fam.deviance(y, fam.clean_mu(mu), w)
+    return jax.jit(null_model_deviance)
 
 
 class GeneralizedLinearRegressionModel(PredictionModel, _GLRParams,
@@ -757,41 +850,117 @@ class GeneralizedLinearRegressionModel(PredictionModel, _GLRParams,
 
 
 class GLMTrainingSummary:
-    """ref GeneralizedLinearRegressionTrainingSummary."""
+    """ref GeneralizedLinearRegressionTrainingSummary. What the fit
+    already paid for is here when the fit returns — the returned model's
+    ``deviance`` (one margin-only pass), ``dispersion`` (its Pearson
+    chi-square from the same pass), the counters and ``deviance_history``
+    (the deviance each IRLS pass saw: the previous model's). Everything
+    else is lazy, as in the reference: ``coefficient_standard_errors`` /
+    ``t_values`` / ``p_values`` take the LAST solve's ``diag_inv_atwa``
+    (the reference's ``diagInvAtWA``; LAPACK ``potri`` on the driver's
+    factor, no second Gramian), ``null_deviance`` / ``aic`` /
+    ``residuals`` / ``prediction_mean`` are device reductions or maps over
+    the dataset the summary keeps — nothing row-sized is held on the host.
 
-    def __init__(self, **kw):
-        self.deviance = kw["deviance"]
-        self.null_deviance = kw["null_deviance"]
-        self.dispersion = kw["dispersion"]
-        self.aic = kw["aic"]
-        self.num_iterations = kw["num_iterations"]
-        self.rank = kw["rank"]
-        self.degrees_of_freedom = kw["degrees_of_freedom"]
-        self.residual_degree_of_freedom = kw["residual_degree_of_freedom"]
-        self.coefficient_standard_errors = kw["coefficient_standard_errors"]
-        self.t_values = kw["t_values"]
-        self.p_values = kw["p_values"]
-        self._mu = kw["prediction_mean"]
-        self._y = kw["label"]
-        self._w = kw["weights"]
-        self._fam: Family = kw["family_obj"]
-        self._link: Link = kw["link_obj"]
-        self.family = self._fam.name
-        self.link = self._link.name
+    ``total_passes`` counts weighted-Gramian passes over X (one an
+    iteration), ``total_dispatches`` every launch of the fit (the passes
+    and the deviance pass)."""
+
+    def __init__(self, model, ds, offset, fam: Family, link: Link,
+                 wls_model, fit_intercept: bool, *, deviance: float,
+                 pearson: float, deviance_history, total_dispatches: int):
+        self._model, self._ds, self._offset = model, ds, offset
+        self._fam, self._link, self._wls_model = fam, link, wls_model
+        self._fit_intercept = fit_intercept
+        self.family, self.link = fam.name, link.name
+        self.deviance = deviance
+        self.deviance_history = list(deviance_history)
+        self.num_iterations = self.total_passes = len(self.deviance_history)
+        self.total_dispatches = total_dispatches
+        n = ds.n_rows
+        self.rank = ds.n_features + (1 if fit_intercept else 0)
+        self.degrees_of_freedom = n - 1 if fit_intercept else n
+        self.residual_degree_of_freedom = n - self.rank
+        estimated = fam.name in ("gaussian", "gamma", "tweedie")
+        self.dispersion = pearson / max(self.residual_degree_of_freedom, 1) \
+            if estimated else 1.0
+
+    # -- from the last solve -------------------------------------------
+    @functools.cached_property
+    def coefficient_standard_errors(self) -> np.ndarray:
+        """sqrt(diag((AᵀΩA)⁻¹)·φ) at the last pass's working weights, in
+        the order coefficients, intercept; NaN where the last solve has
+        no inverse to offer (a singular system went to quasi-Newton)."""
+        with tracing.span("phase", "fit.solve", part="diag_inv_atwa"):
+            # the solver's lazy half: potri over the last factor
+            diag = np.asarray(self._wls_model.diag_inv_atwa, np.float64)
+        if diag.shape != (self.rank,):
+            return np.full(self.rank, float("nan"))
+        return np.sqrt(np.clip(diag * self.dispersion, 0, None))
+
+    @functools.cached_property
+    def t_values(self) -> np.ndarray:
+        coefs = np.append(self._model._coef, self._model._icpt) \
+            if self._fit_intercept else self._model._coef
+        return coefs / np.maximum(self.coefficient_standard_errors, _EPS)
+
+    @functools.cached_property
+    def p_values(self) -> np.ndarray:
+        from scipy import stats as sps
+        if self._fam.name in ("binomial", "poisson"):
+            return 2.0 * sps.norm.sf(np.abs(self.t_values))
+        return 2.0 * sps.t.sf(np.abs(self.t_values),
+                              max(self.residual_degree_of_freedom, 1))
+
+    # -- from the device, when read ------------------------------------
+    def _ofs(self):
+        return 0.0 if self._offset is None else self._offset
+
+    @functools.cached_property
+    def prediction_mean(self):
+        """The fitted means as a device array in the dataset's padded,
+        row-sharded row space (``ds.unpad`` trims a host copy)."""
+        import jax.numpy as jnp
+        ds, m = self._ds, self._model
+        params = jnp.asarray(np.concatenate([m._coef, [m._icpt, 0.0]]))
+        return _row_program(self._fam, self._link)(
+            ds.x, ds.y, self._ofs(), params)
+
+    @functools.cached_property
+    def null_deviance(self) -> float:
+        import jax
+        ds = self._ds
+        program = _null_program(self._fam, self._link, self._fit_intercept,
+                                self._offset is not None)
+        return float(jax.device_get(program(ds.y, ds.w, self._ofs())))
+
+    @functools.cached_property
+    def aic(self) -> float:
+        import jax.numpy as jnp
+        ds = self._ds
+        valid = np.zeros(ds.y.shape[0], bool)
+        valid[ds.valid_indices()] = True
+        rows = {"y": ds.y, "w": ds.w, "mu": self.prediction_mean,
+                "valid": ds.ctx.mesh_runtime.device_put_sharded_rows(valid)}
+        return self._fam.aic(rows, float(ds.n_rows), float(jnp.sum(ds.w)),
+                             self.deviance, self.rank)
 
     def residuals(self, residuals_type: str = "deviance") -> np.ndarray:
+        """One residual per real row, computed on the device and brought
+        home as the n-vector the caller asked for."""
         import jax.numpy as jnp
-        y, mu, w = self._y, self._mu, self._w
+        ds = self._ds
+        y, mu, w = ds.y, self.prediction_mean, ds.w
         if residuals_type == "response":
-            return y - mu
-        if residuals_type == "working":
-            g = np.asarray(self._link.deriv(jnp.asarray(mu)))
-            return (y - mu) * g
-        if residuals_type == "pearson":
-            var = np.asarray(self._fam.variance(jnp.asarray(mu)))
-            return (y - mu) * np.sqrt(w) / np.sqrt(np.maximum(var, _EPS))
-        if residuals_type == "deviance":
-            dev_i = w * np.asarray(self._fam.unit_deviance(jnp.asarray(y),
-                                                           jnp.asarray(mu)))
-            return np.sign(y - mu) * np.sqrt(np.clip(dev_i, 0, None))
-        raise ValueError(residuals_type)
+            r = y - mu
+        elif residuals_type == "working":
+            r = (y - mu) * self._link.deriv(mu)
+        elif residuals_type == "pearson":
+            r = (y - mu) * jnp.sqrt(w) / jnp.sqrt(
+                jnp.maximum(self._fam.variance(mu), _EPS))
+        elif residuals_type == "deviance":
+            r = jnp.sign(y - mu) * jnp.sqrt(jnp.clip(
+                w * self._fam.unit_deviance(y, mu), 0, None))
+        else:
+            raise ValueError(residuals_type)
+        return ds.unpad(np.asarray(r, dtype=np.float64))

@@ -738,6 +738,43 @@ def _split3(v):
     return hi, mid, lo
 
 
+def _split3_rounded(v):
+    """:func:`_split3` for code XLA compiles (outside a Mosaic kernel):
+    the pieces are rounded by ``reduce_precision``, which the compiler may
+    not take back. A ``convert`` pair it may: XLA:TPU keeps a bf16 value
+    of a fusion at f32 (excess precision), so ``v - bf16(v)`` came out 0
+    on the v5e while the stored piece WAS rounded — the two low pieces
+    were lost (PR 33: margins 2e-3 off). Every piece is representable in
+    bfloat16, so the final convert is exact however it is done."""
+    def round_bf16(a):
+        return jax.lax.reduce_precision(a, exponent_bits=8, mantissa_bits=7)
+    hi = round_bf16(v)
+    rest = v - hi
+    mid = round_bf16(rest)
+    return tuple(p.astype(jnp.bfloat16) for p in (hi, mid, rest - mid))
+
+
+def storage_matvec(x, beta):
+    """``x @ beta`` for a replicated ``beta`` at the accumulator's width,
+    reading X at storage width. A bfloat16 X meets ``beta`` as its three
+    bfloat16 pieces (:func:`_split3_rounded`) in ONE ``(n, d) x (d, 3)``
+    contraction — bf16 x bf16 products are exact in the f32 accumulator,
+    so the margins are f32-faithful with no widened copy of X and no
+    multi-pass ``highest`` product of an f32 operand (which read 3.8e-6
+    low on the v5e, PR 29). Every other storage takes XLA's contraction
+    at ``highest``."""
+    if x.dtype == jnp.bfloat16:
+        pieces = jnp.stack(
+            _split3_rounded(jnp.asarray(beta, jnp.float32)), axis=1)
+        return jnp.sum(jnp.dot(x, pieces,
+                               preferred_element_type=jnp.float32), axis=1)
+    from cycloneml_tpu.dataset.instance import is_narrow_dtype
+    acc = jnp.float32 if is_narrow_dtype(x.dtype) else x.dtype
+    return jnp.dot(x, jnp.asarray(beta, acc),
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=acc)
+
+
 def moment_gramian_tile(rows: int, d: int, dtype, feature_major: bool):
     """Lane tile of :func:`fused_moment_gramian` — how many rows of X one
     grid step takes — for a shard of ``rows`` x ``d`` stored as ``dtype``

@@ -209,3 +209,60 @@ def test_moment_program_where_no_kernel_fits_widens_no_copy_of_x(topo):
     text = compiled.as_text()
     assert "tpu_custom_call" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# -- GeneralizedLinearRegression's IRLS programs ------------------------------------
+
+def _compile_irls(topo, n, d, which):
+    """The two programs a binomial ``GeneralizedLinearRegression`` fit
+    dispatches — the IRLS pass and the margin-only deviance pass — under
+    psum for one described v5e chip, with the replicated ``[beta |
+    intercept | first]`` vector as their one extra argument."""
+    import jax
+    import jax.numpy as jnp
+    from unittest.mock import patch
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from cycloneml_tpu.ml.regression import glm
+    from cycloneml_tpu.ops import kernels
+    fam, link = glm.Binomial(), glm.Logit()
+    agg = glm.irls_aggregator(fam, link, True, False) if which == "pass" \
+        else glm.deviance_aggregator(fam, link, False)
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    rows, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    args = [jax.ShapeDtypeStruct((n, d), jnp.bfloat16, sharding=rows),
+            jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rows),
+            jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rows),
+            jax.ShapeDtypeStruct((d + 2,), jnp.float32, sharding=rep)]
+
+    def program(*a):
+        local = lambda *b: jax.tree_util.tree_map(
+            lambda t: jax.lax.psum(t, "data"), agg(*b))
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(P("data"),) * 3 + (P(),),
+                             out_specs=P(), check_vma=False)(*a)
+
+    with jax.enable_x64(False), \
+            patch.object(kernels, "pallas_available", lambda: True):
+        return jax.jit(program).lower(*args).compile()
+
+
+@pytest.mark.parametrize("which", ["pass", "deviance"])
+def test_irls_programs_at_the_cells_shape_read_x_as_stored(topo, which):
+    """``glr_binomial_irls_fit``: 2,000,000 x 2,000 bf16 on one chip. X
+    arrives ``{0,1}``; the pass is the margins' contraction over the
+    stored X (bf16 x the three bf16 pieces of beta) and the Mosaic moment
+    Gramian (one a branch of the weights' cond) over 8.0 GB of arguments,
+    the deviance pass the contraction alone; neither holds an f32 value of
+    X's shape, a pad or a layout copy of X, and their temporaries are the
+    row vectors (eta, z, omega) and a few (d, d) blocks."""
+    n, d = 2_000_000, 2000
+    compiled = _compile_irls(topo, n, d, which)
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert _entry_layout_of_x(text) == "0,1"
+    assert text.count("tpu_custom_call") >= (2 if which == "pass" else 0)
+    assert 8.0e9 <= mem.argument_size_in_bytes <= 8.1e9
+    assert mem.temp_size_in_bytes < 96 << 20
+    assert _x_ops(text, n) == [] and _wide_x(text, n, d) == []
+    # the margins: ONE contraction of the stored X with beta's three pieces
+    assert len(re.findall(rf"= f32\[{n},3\]\S* convolution\(", text)) == 1
