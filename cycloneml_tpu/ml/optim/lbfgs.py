@@ -67,7 +67,9 @@ class OptimState:
     loss_history: List[float] = field(default_factory=list)
     # curvature memory, carried so training can checkpoint/resume EXACTLY
     # (the reference has no mid-training checkpointing at all — SURVEY §5.4
-    # flags step-level checkpoint as the required improvement)
+    # flags step-level checkpoint as the required improvement). Lists, oldest
+    # pair first; a DeviceLBFGS state carries a read-on-demand Sequence that
+    # reads like one (device_lbfgs._HistoryView)
     hist_s: List[np.ndarray] = field(default_factory=list)
     hist_y: List[np.ndarray] = field(default_factory=list)
     raw_grad: Optional[np.ndarray] = None  # OWLQN: grad before pseudo-grad

@@ -1,5 +1,7 @@
 """Chunked device L-BFGS: trajectory parity with the host optimizer."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -8,6 +10,7 @@ from cycloneml_tpu.ml.optim import LBFGS, aggregators
 from cycloneml_tpu.ml.optim.device_lbfgs import DeviceLBFGS
 from cycloneml_tpu.ml.optim.loss import (DistributedLossFunction,
                                          l2_regularization)
+from cycloneml_tpu.observe import tracing
 
 
 def _loss(ctx, n=400, d=12, seed=0, reg=0.0):
@@ -67,6 +70,120 @@ def test_device_chunk_resume_exact(ctx):
     f3, _ = _loss(ctx, seed=9, reg=0.02)
     resumed = opt.minimize(f3, np.zeros(d + 1), resume=mid)
     np.testing.assert_allclose(resumed.x, full.x, rtol=1e-8, atol=1e-10)
+
+
+@pytest.fixture
+def tracer():
+    tracing.disable()
+    t = tracing.enable(max_spans=50_000)
+    yield t
+    tracing.disable()
+
+
+def _history_reads(tracer):
+    return [s for s in tracer.snapshot()
+            if s.kind == "instant" and s.name == "optim.history.read"]
+
+
+def _count_device_indexing(monkeypatch):
+    """Every ``device_array[...]`` from Python is a launch of its own
+    (``jit_dynamic_slice`` / ``jit_squeeze`` on the chip): count them."""
+    from jax._src.array import ArrayImpl
+    calls = []
+    index = ArrayImpl.__getitem__
+
+    def counted(self, idx):
+        calls.append((self.shape, idx))
+        return index(self, idx)
+
+    monkeypatch.setattr(ArrayImpl, "__getitem__", counted)
+    return calls
+
+
+def _buffers(state):
+    """Host copies of what a device state's history view stands on, taken
+    without reading the view: the rows it must yield, oldest first."""
+    hist = state.hist_s._hist
+    S, Y = hist._bufs
+    return S.shape, np.asarray(S)[hist._lo:], np.asarray(Y)[hist._lo:]
+
+
+@pytest.mark.parametrize("chunk", [2, 8])
+def test_history_leaves_the_turn_as_a_view(ctx, tracer, monkeypatch, chunk):
+    """A turn launches nothing for the L-BFGS history: its state carries a
+    view of the chunk's ring buffers, cut into rows only when read. chunk=8
+    is the benchmark's fit (one terminal turn: the view IS the buffers);
+    chunk=2 has turns that another dispatch follows — those DONATE S/Y, so
+    each parts with them by one slice a buffer, and the state it yielded
+    stays readable afterwards."""
+    m, iters = 10, 6
+    f, d = _loss(ctx, seed=11, reg=0.02)
+    indexed = _count_device_indexing(monkeypatch)
+    states = list(DeviceLBFGS(max_iter=iters, tol=0.0, chunk=chunk)
+                  .iterations(f, np.zeros(d + 1)))
+    turns = [s.attrs["history_launches"] for s in tracer.snapshot()
+             if s.kind == "phase" and s.name == "optim.iteration"]
+    # (a) nobody read the history: no read instant, and the only device
+    # indexing of the run is what the non-terminal turns reported
+    assert turns == ([0] if chunk == 8 else [2, 2, 0])
+    assert len(indexed) == sum(turns)
+    assert not _history_reads(tracer)
+    assert states[-1].converged and states[0].hist_s == []
+
+    host_f, _ = _loss(ctx, seed=11, reg=0.02)
+    host = {s.iteration: s for s in LBFGS(max_iter=iters, tol=0.0)
+            .iterations(host_f, np.zeros(d + 1))}
+    for k, state in enumerate(states[1:], 1):
+        hk = min(state.iteration, m)
+        shape, want_s, want_y = _buffers(state)
+        # a terminal turn's view stands on the (m, n) ring buffers
+        # themselves, an earlier one's on its own slice of the live rows
+        assert shape == ((m, d + 1) if state.converged else (hk, d + 1))
+        assert len(state.hist_s) == len(state.hist_y) == hk
+        assert not _history_reads(tracer)[k - 1:]   # len() reads nothing
+        # (b) one read instant a state, whatever is read after the first
+        tree = state.to_pytree()
+        assert state.hist_s[-1] is tree["hist_s"][-1]
+        assert all(a is b for a, b in zip(state.hist_y[-m:],
+                                          tree["hist_y"], strict=True))
+        reads = _history_reads(tracer)
+        assert len(reads) == k and reads[-1].attrs["rows"] == hk
+        # (c) the rows of the buffers, bit for bit, oldest first — and the
+        # host optimizer's pairs at the same iteration (f64: same machine)
+        for got, want, ref in ((tree["hist_s"], want_s, host[state.iteration].hist_s),
+                               (tree["hist_y"], want_y, host[state.iteration].hist_y)):
+            got = np.stack([np.asarray(r) for r in got])
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(got, want)
+            np.testing.assert_allclose(got, np.stack(ref), rtol=1e-6,
+                                       atol=1e-12)
+
+
+@pytest.mark.parametrize("onto", ["device", "host"])
+def test_resume_from_the_view_equals_resume_from_lists(ctx, onto):
+    """A state whose history is the view resumes — on the device chunk, or
+    on the host L-BFGS a fit falls back to — exactly as one that carries
+    the materialised lists: same iterations, same coefficients."""
+    def fresh():
+        return _loss(ctx, seed=9, reg=0.02)[0]
+    f, d = _loss(ctx, seed=9, reg=0.02)
+    opt = DeviceLBFGS(max_iter=24, tol=1e-12, chunk=4)
+    full = opt.minimize(f, np.zeros(d + 1))
+    it = opt.iterations(fresh(), np.zeros(d + 1))
+    next(it)
+    mid = next(it)                       # not terminal: 4 of 24 iterations
+    assert not mid.converged and not isinstance(mid.hist_s, list)
+    _, rows_s, rows_y = _buffers(mid)
+    listed = dataclasses.replace(mid, hist_s=list(rows_s),
+                                 hist_y=list(rows_y))
+    next(it)                             # the view outlives a dispatch
+    to = opt if onto == "device" else LBFGS(max_iter=24, tol=1e-12)
+    from_view = to.minimize(fresh(), np.zeros(d + 1), resume=mid)
+    from_lists = to.minimize(fresh(), np.zeros(d + 1), resume=listed)
+    assert from_view.iteration == from_lists.iteration
+    assert from_view.loss_history == from_lists.loss_history
+    np.testing.assert_array_equal(from_view.x, from_lists.x)
+    np.testing.assert_allclose(from_view.x, full.x, rtol=1e-8, atol=1e-10)
 
 
 def test_lr_estimator_uses_device_chunk(ctx):
