@@ -6,7 +6,9 @@ environment. It drives the main path through the public entry points —
 ``CycloneContext`` (default ``master="tpu"``), ``generate_classification``,
 ``LogisticRegression.fit`` — at full width, then a host-fed numpy → ``MLFrame``
 → ``fit`` leg, a small binomial ``GeneralizedLinearRegression`` fit (IRLS over
-the weighted branch of the moment Gramian), then compiles and checks every
+the weighted branch of the moment Gramian), a small ten-class
+``LogisticRegression`` fit (the fused multinomial sweep under the
+device-resident L-BFGS), then compiles and checks every
 Pallas kernel natively at small n. Every leg asserts WHICH path ran (platform, data dtype, Mosaic custom call,
 one in-core dispatch) and that what came out is right (finite non-increasing
 objective, agreement with the XLA twin and with a float64 reference).
@@ -34,6 +36,9 @@ N_COLS = 1_280
 N_HOST_ROWS = 100_000   # host-fed leg: numpy -> MLFrame -> fit
 N_GLR_ROWS = 131_072    # binomial GLR leg: IRLS from a device-resident dataset
 N_GLR_COLS = 256
+N_SOFTMAX_ROWS = 65_536  # multinomial leg: mnist8m's width and classes
+N_SOFTMAX_COLS = 784
+N_CLASSES = 10
 MAX_ITER = 25
 REG = 0.01
 
@@ -337,6 +342,86 @@ def glr_leg(ctx, n: int, d: int, devices) -> dict:
             "cold_fit_s": round(cold_s, 3)}
 
 
+def multinomial_leg(ctx, n: int, d: int, k: int, devices) -> dict:
+    """Leg 4: ``LogisticRegression`` with ``k`` label classes from a
+    device-resident bf16 dataset: ``family="auto"`` turns multinomial and
+    takes the fused K-class sweep (Mosaic, both products on the MXU) under
+    ``DeviceLBFGS`` — checked against the float64 objective and gradient
+    at the model it returns, over the same stored values."""
+    import jax
+    import jax.numpy as jnp
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    from cycloneml_tpu.dataset.random import generate_classification
+    from cycloneml_tpu.ml.classification import LogisticRegression
+    from cycloneml_tpu.ml.optim import aggregators
+    from cycloneml_tpu.ops import kernels
+
+    base = generate_classification(ctx, n, d, seed=2)
+    rng = np.random.RandomState(2)
+    # a class a row by a noisy linear score: learnable, not separable
+    score = jnp.asarray(rng.randn(d, k) / np.sqrt(d), jnp.bfloat16)
+    labels = jax.jit(lambda x, e: jnp.argmax(
+        jnp.dot(x, score, preferred_element_type=jnp.float32) + e,
+        axis=1).astype(jnp.float32))(
+        base.x, ctx.mesh_runtime.device_put_sharded_rows(
+            (0.5 * rng.randn(n, k)).astype(np.float32)))
+    ds = InstanceDataset(ctx, base.x, labels, base.w, n, d)
+    t0 = time.perf_counter()
+    model = LogisticRegression(maxIter=100, regParam=REG).fit(ds)
+    cold_s = time.perf_counter() - t0
+    s = model.summary
+    stored = "feature_major" if kernels.stored_feature_major(ds.x) \
+        else "row_major"
+    check(str(ds.x.dtype) == "bfloat16", f"softmax leg: tier {ds.x.dtype}")
+    check(s.num_classes == model.num_classes == k,
+          f"softmax leg: {s.num_classes} classes of {k}")
+    check(s.orientation == stored,
+          f"softmax leg: sweep {s.orientation!r}, X is stored {stored}")
+    check(not s.streamed and s.total_dispatches < s.total_evals
+          <= 2 * s.total_iterations,
+          f"softmax leg: {s.total_evals} evaluations, {s.total_iterations} "
+          f"iterations, {s.total_dispatches} dispatches")
+    call = ds.tree_aggregate_fn(aggregators.multinomial_logistic_pallas_scaled(
+        d, k, True, feature_major=stored == "feature_major"))
+    v = jnp.zeros(d, jnp.float32)
+    text = call.compiled.__wrapped__.lower(
+        *call.arrays(), v, v, jnp.zeros(d * k + k, jnp.float32)).as_text()
+    check("tpu_custom_call" in text and "glm_sweep_multinomial" in text,
+          "softmax leg: no Mosaic K-class sweep in the aggregation program")
+
+    x, y, _ = ds.to_numpy()
+    x, yi = np.asarray(x, np.float64), np.asarray(y).astype(int)
+    mean, std = x.mean(axis=0), x.std(axis=0, ddof=1)
+    wmat = model.coefficient_matrix.to_array() * std[None, :]
+    icpt = model.intercept_vector.to_array()
+    check(abs(icpt.sum()) < 1e-6, f"softmax leg: intercepts sum {icpt.sum()}")
+    xh = (x - mean) / std
+    m = xh @ wmat.T + (icpt + (wmat / std) @ mean)
+    top = m.max(axis=1, keepdims=True)
+    lse = top[:, 0] + np.log(np.exp(m - top).sum(axis=1))
+    objective = float(np.mean(lse - m[np.arange(n), yi])
+                      + 0.5 * REG * np.sum(wmat * wmat))
+    r = np.exp(m - lse[:, None])
+    r[np.arange(n), yi] -= 1.0
+    grad = r.T @ xh / n + REG * wmat
+    at_zero = np.zeros((n, k))
+    at_zero[np.arange(n), yi] = -1.0
+    first = np.linalg.norm((at_zero + 1.0 / k).T @ xh / n)
+    gap = abs(s.objective_history[-1] - objective) / objective
+    shrink = float(np.linalg.norm(grad) / first)
+    check(gap < 1e-5, f"softmax leg: objective {s.objective_history[-1]} "
+                      f"against float64 {objective}")
+    check(shrink < 1e-2, f"softmax leg: the float64 gradient at the model "
+                         f"is {shrink:.3e} of the one at zero")
+    return {"n": n, "d": d, "classes": k, "orientation": s.orientation,
+            "iterations": s.total_iterations, "evals": s.total_evals,
+            "dispatches": s.total_dispatches,
+            "objective": s.objective_history[-1],
+            "objective_gap_vs_f64": gap, "gradient_left_vs_f64": shrink,
+            "accuracy": float(np.mean(np.argmax(m, axis=1) == yi)),
+            "cold_fit_s": round(cold_s, 3)}
+
+
 def rel_err(got, want) -> float:
     got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
     return float(np.max(np.abs(got - want)) / max(np.max(np.abs(want)), 1e-30))
@@ -568,6 +653,9 @@ def main() -> int:
     print(f"chip_smoke: host leg ok {host}", file=sys.stderr)
     glr = glr_leg(ctx, N_GLR_ROWS, N_GLR_COLS, devices)
     print(f"chip_smoke: glr leg ok {glr}", file=sys.stderr)
+    softmax = multinomial_leg(ctx, N_SOFTMAX_ROWS, N_SOFTMAX_COLS, N_CLASSES,
+                              devices)
+    print(f"chip_smoke: softmax leg ok {softmax}", file=sys.stderr)
     kernels_ok = kernel_matrix()
     print(f"chip_smoke: kernel matrix ok {kernels_ok}", file=sys.stderr)
     ctx.stop()
@@ -584,6 +672,7 @@ def main() -> int:
         "fit": device,
         "host_fit": host,
         "glr_fit": glr,
+        "softmax_fit": softmax,
         "kernel_matrix_max_rel_err": kernels_ok,
         "compile_cache": {
             "dir": cache_dir, "entries_before": entries_before,

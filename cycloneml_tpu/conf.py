@@ -360,7 +360,8 @@ LBFGS_DEVICE_CHUNK = (
 USE_PALLAS_KERNELS = (
     ConfigBuilder("cyclone.ml.usePallasKernels")
     .doc("Route the eligible dense sweeps — binomial LogisticRegression "
-         "(serial AND stacked), the LinearRegression l-bfgs objective, "
+         "(serial AND stacked), multinomial LogisticRegression over a "
+         "resident bf16 X, the LinearRegression l-bfgs objective, "
          "the RowMatrix Gramian and the KMeans assignment step — through "
          "the hand-written fused Pallas kernels (ops/kernels.py) instead "
          "of the XLA-fused jnp aggregators. 'auto' (default) makes the "

@@ -457,6 +457,8 @@ class InstanceDataset:
         # host copy already exists: no blocking device→host readback
         # (its cost on the chip: not measured)
         self._yw_host: Optional[Tuple[np.ndarray, np.ndarray]] = None
+        # weighted class histogram of the labels (label_histogram), once read
+        self._label_histogram: Optional[np.ndarray] = None
         # real-row mask when padding is interleaved per shard (chunked
         # loaders); None means padding sits at the global tail ([:n_rows])
         self._valid_mask: Optional[np.ndarray] = valid_mask
@@ -532,6 +534,7 @@ class InstanceDataset:
         (generators, chunked loaders) to install the cache ``from_numpy``
         sets internally."""
         self._yw_host = (y, w)
+        self._label_histogram = None
         return self
 
     def to_instance_dataset(self, features_col=None, label_col=None,
@@ -560,6 +563,18 @@ class InstanceDataset:
         if self._yw_host is not None:
             return self._yw_host[1]
         return np.asarray(self.w)
+
+    def label_histogram(self) -> np.ndarray:
+        """Weighted histogram (f64) of the labels read as class indices,
+        one entry a class up to the largest label — what a streamed
+        dataset's ``label_histogram`` answers from its write pass. Datasets
+        are immutable, so it is a property of the object: the host pass
+        over ``n`` labels (98 ms at 8,100,000 rows: the v5e's host, PR 35)
+        is paid by the first fit that asks, not by every one."""
+        if self._label_histogram is None:
+            self._label_histogram = np.bincount(
+                self.y_host().astype(np.int64), weights=self.w_host())
+        return self._label_histogram.copy()
 
     def _restore_device(self) -> None:
         restored = False

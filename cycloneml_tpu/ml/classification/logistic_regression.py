@@ -695,15 +695,12 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
             features_std = stats.std
             weight_sum = stats.weight_sum
 
-            # label histogram via one psum pass (≈ the summary treeAggregate at
-            # LogisticRegression.scala:515 area)
-            if streamed:
-                hist = ds.label_histogram()
-                num_classes = max(len(hist), 2) if ds.n_rows else 2
-            else:
-                y_host = ds.y_host()
-                w_host = ds.w_host()
-                num_classes = int(y_host.max()) + 1 if ds.n_rows else 2
+            # weighted class histogram (≈ the summary treeAggregate at
+            # LogisticRegression.scala:515 area): a streamed dataset's from
+            # its shard write pass, an in-core one's from its host labels,
+            # cached on the immutable dataset either way
+            hist = ds.label_histogram()
+            num_classes = max(len(hist), 2) if ds.n_rows else 2
             family = self.get("family")
             if family == "auto":
                 is_multinomial = num_classes > 2
@@ -714,12 +711,8 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
                         f"Binomial family requires <= 2 label classes, found "
                         f"{num_classes} (the reference rejects this too)")
                 num_classes = max(num_classes, 2)
-            if streamed:
-                histogram = np.zeros(num_classes)
-                histogram[:len(hist)] = hist[:num_classes]
-            else:
-                histogram = np.bincount(y_host.astype(np.int64), weights=w_host,
-                                        minlength=num_classes)[:num_classes]
+            # one entry a class: a dataset of one label still has two
+            histogram = np.append(hist, np.zeros(num_classes - len(hist)))
 
             fit_intercept = self.get("fitIntercept")
             standardize = self.get("standardization")
@@ -748,7 +741,7 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
             # evaluation, bf16 blocks read at storage width with fp32 in-kernel
             # accumulation; the XLA-fused jnp aggregator stays as the fallback
             # (and the only path on CPU, where the interpreter is for tests)
-            use_pallas = (not is_multinomial) and use_fused_kernels(ds.ctx)
+            use_pallas = use_fused_kernels(ds.ctx)
             orientation = None  # tiling of the fused sweep, if it runs
             # EVERY fit path folds standardization (and fitWithMean centering)
             # INTO the aggregator read — no standardized copy exists anywhere:
@@ -769,10 +762,22 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
                 else inv_std
 
             if is_multinomial:
-                # always the scaled aggregator: the TP/pallas alternatives are
-                # binomial-only, so use_scaled cannot be False here
-                agg = aggregators.multinomial_logistic_scaled(
-                    d, num_classes, fit_intercept)
+                # the fused K-class sweep where X's storage admits one (a
+                # resident bf16 X: kernels.multinomial_sweep_tile), in the
+                # tiling its layout dictates; the XLA aggregator otherwise
+                # (always scaled: the TP alternative is binomial-only)
+                if use_pallas and not streamed:
+                    from cycloneml_tpu.ops.kernels import \
+                        multinomial_sweep_orientation
+                    orientation = multinomial_sweep_orientation(
+                        ds.x, num_classes)
+                if orientation is not None:
+                    agg = aggregators.multinomial_logistic_pallas_scaled(
+                        d, num_classes, fit_intercept,
+                        feature_major=orientation == "feature_major")
+                else:
+                    agg = aggregators.multinomial_logistic_scaled(
+                        d, num_classes, fit_intercept)
                 n_coef = d * num_classes + (num_classes if fit_intercept else 0)
                 x0 = np.zeros(n_coef)
                 if fit_intercept and histogram.min() > 0:
@@ -947,6 +952,7 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
                 total_evals=loss_fn.n_evals,
                 total_dispatches=loss_fn.n_dispatches,
                 streamed=streamed, orientation=orientation,
+                num_classes=num_classes,
                 search_evals=list(state.search_evals) or None)
             return model
 
@@ -1070,7 +1076,8 @@ class LogisticRegressionTrainingSummary:
 
     def __init__(self, objective_history, total_iterations,
                  total_evals=None, total_dispatches=None, n_models=1,
-                 streamed=False, orientation=None, search_evals=None):
+                 streamed=False, orientation=None, search_evals=None,
+                 num_classes=2):
         self.objective_history = objective_history
         self.total_iterations = total_iterations
         # optimizer-path telemetry: loss/grad evaluations and host->device
@@ -1093,6 +1100,9 @@ class LogisticRegressionTrainingSummary:
         # "row_major", ops/kernels.glm_sweep_orientation); None when the
         # sweep was not the fused kernel
         self.orientation = orientation
+        # classes the fit's objective ran over (2: a binomial fit): with
+        # ``orientation`` it says which fused sweep that was
+        self.num_classes = num_classes
 
 
 class BinaryLogisticRegressionSummary:

@@ -463,6 +463,34 @@ def _binary_logistic_pallas_scaled(d: int, fit_intercept: bool,
     return agg
 
 
+def multinomial_logistic_pallas_scaled(d: int, k: int,
+                                       fit_intercept: bool = True,
+                                       feature_major=None) -> Agg:
+    """Pallas twin of :func:`multinomial_logistic_scaled`
+    (ops/kernels.fused_multinomial_logistic_scaled): one read of a bf16 X
+    an evaluation, both products on the MXU with the f32 operand of each
+    in three bf16 pieces — the coefficient matrix is NOT rounded to the
+    data tier, which the XLA twin's ``_tier_dot`` does. For the shapes
+    ``kernels.multinomial_sweep_tile`` admits; the caller asks it first.
+    ``feature_major``: as :func:`binary_logistic_pallas_scaled`."""
+    return _multinomial_logistic_pallas_scaled(
+        d, k, fit_intercept, _feature_major(d, feature_major))
+
+
+@functools.lru_cache(maxsize=None)
+def _multinomial_logistic_pallas_scaled(d: int, k: int, fit_intercept: bool,
+                                        feature_major: bool) -> Agg:
+    from cycloneml_tpu.ops.kernels import fused_multinomial_logistic_scaled
+
+    @_named("multinomial_logistic_pallas_scaled")
+    def agg(x, y, w, inv_std, scaled_mean, coef):
+        return fused_multinomial_logistic_scaled(
+            x, y, w, inv_std, scaled_mean, coef, d, k, fit_intercept,
+            feature_major=feature_major)
+
+    return agg
+
+
 def least_squares_pallas_scaled(d: int, feature_major=None) -> Agg:
     """Pallas twin of :func:`least_squares_scaled`: the residual sweep
     (margin → err → loss/mult/grad) runs as one VMEM-resident row pass

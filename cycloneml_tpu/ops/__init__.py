@@ -12,10 +12,12 @@ from cycloneml_tpu.ops.kernels import (fused_binary_logistic,
                                        fused_binary_logistic_scaled,
                                        fused_kmeans_assign,
                                        fused_least_squares_scaled,
-                                       fused_moment_gramian, moment_sums,
-                                       pallas_available, use_fused_kernels)
+                                       fused_moment_gramian,
+                                       fused_multinomial_logistic_scaled,
+                                       moment_sums, pallas_available,
+                                       use_fused_kernels)
 
 __all__ = ["fused_binary_logistic", "fused_binary_logistic_scaled",
            "fused_kmeans_assign", "fused_least_squares_scaled",
-           "fused_moment_gramian", "moment_sums", "pallas_available",
-           "use_fused_kernels"]
+           "fused_moment_gramian", "fused_multinomial_logistic_scaled",
+           "moment_sums", "pallas_available", "use_fused_kernels"]

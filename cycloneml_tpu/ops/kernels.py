@@ -9,6 +9,10 @@ output blocks — the standard Pallas reduction pattern):
   transpose gemv :130) as margin→softplus-loss→multiplier→grad in one kernel.
 - ``fused_least_squares_scaled``: the LinearRegression l-bfgs residual sweep
   on the same row pass.
+- ``fused_multinomial_logistic_scaled``: the K-class sweep (ref:
+  MultinomialLogisticBlockAggregator) — margins, softmax, loss and gradient
+  from one read of a bfloat16 X, both products on the MXU with the f32
+  operand of each as three bf16 pieces; tiled as the GLM sweep is.
 - ``fused_kmeans_assign``: the KMeans distance+argmin inner loop (ref:
   DistanceMeasure.findClosest:123) as ‖x‖²−2x·c+‖c‖² with a fused argmin.
 - ``fused_moment_gramian``: the augmented Gramian ``[1|y|X]'W[1|y|X]`` —
@@ -432,6 +436,29 @@ def fused_least_squares_scaled(x, y, w, inv_std, scaled_mean, y_pars, coef,
     return {"loss": loss, "grad": g, "count": count}
 
 
+def _kahan_add(acc, comp, v):
+    """``acc += v`` across the (sequential) grid with the running
+    compensation ``comp``: a plain f32 ``+=`` over thousands of row tiles
+    drifts ~n_tiles ulps, which is enough to break the strong-Wolfe
+    first-try acceptance when a sweep feeds the chunked device L-BFGS
+    (measured: 46 line-search evals vs 10 for the tree-reducing XLA path
+    at n=2M×d=1280). The compensation keeps the total at ~1 ulp — cheaper
+    than the XLA tree and exact enough for the Wolfe tests."""
+    yk = v - comp[:]
+    t = acc[:] + yk
+    comp[:] = (t - acc[:]) - yk
+    acc[:] = t
+
+
+def _lane_sums(v, tile: int):
+    """``(r, tile) -> (r, 128)`` lane-wise partial sums by whole-vreg VPU
+    adds; the one cross-lane reduction is left to the caller."""
+    out = v[:, :LANE]
+    for c in range(1, tile // LANE):
+        out = out + v[:, c * LANE:(c + 1) * LANE]
+    return out
+
+
 def _run_glm(x, y, w, beta_p, b0, ys, *, kind, tile, width, grid,
              interpret, scale=None, feature_major=False):
     """Shared one-pass GLM sweep: margin → per-row loss/multiplier → grad,
@@ -481,20 +508,6 @@ def _run_glm(x, y, w, beta_p, b0, ys, *, kind, tile, width, grid,
             [jnp.sum(mult)[None], jnp.sum(wv)[None]]).reshape(1, 2)
         return mult, v_loss, v_aux
 
-    def kahan_add(pairs):
-        # Kahan-compensated accumulation across the (sequential) grid: a
-        # plain f32 `+=` over thousands of row tiles drifts ~n_tiles ulps,
-        # which is enough to break the strong-Wolfe first-try acceptance
-        # when this kernel feeds the chunked device L-BFGS (measured: 46
-        # line-search evals vs 10 for the tree-reducing XLA path at
-        # n=2M×d=1280). The running compensation keeps the total at ~1 ulp
-        # — cheaper than the XLA tree and exact enough for the Wolfe tests.
-        for acc, comp, v in pairs:
-            yk = v - comp[:]
-            t = acc[:] + yk
-            comp[:] = (t - acc[:]) - yk
-            acc[:] = t
-
     def glm_sweep(*refs):
         if has_scale:
             (b0_ref, ys_ref, x_ref, y_ref, w_ref, beta_ref, s_ref,
@@ -543,15 +556,13 @@ def _run_glm(x, y, w, beta_p, b0, ys, *, kind, tile, width, grid,
             mult, v_loss, v_aux = link(margin, yv, wv, ys_ref)
             gx = mult * xv
             if feature_major:
-                # lane-chunk adds (whole vregs, VPU): (d, T) → (d, 128)
-                v_grad = gx[:, :LANE]
-                for c in range(1, tile // LANE):
-                    v_grad = v_grad + gx[:, c * LANE:(c + 1) * LANE]
+                v_grad = _lane_sums(gx, tile)      # (d, T) → (d, 128)
             else:
                 v_grad = jnp.sum(gx, axis=0, keepdims=True)
-            kahan_add(((loss_ref, closs_ref, v_loss),
-                       (grad_ref, cgrad_ref, v_grad),
-                       (aux_ref, caux_ref, v_aux)))
+            for acc, comp, v in ((loss_ref, closs_ref, v_loss),
+                                 (grad_ref, cgrad_ref, v_grad),
+                                 (aux_ref, caux_ref, v_aux)):
+                _kahan_add(acc, comp, v)
 
         if tail == 0:
             tile_sums()
@@ -621,6 +632,237 @@ def _run_glm(x, y, w, beta_p, b0, ys, *, kind, tile, width, grid,
     with jax.named_scope("glm.sweep"):
         outs = sweep(*args)
     return outs[:3]
+
+
+# -- fused multinomial (softmax) loss + gradient -------------------------------
+
+#: classes ride the sublanes in whole packed bf16 groups, so that the three
+#: pieces of an operand stack (and come apart) on tile boundaries
+CLASS_GROUP = 16
+#: bf16 pieces an f32 operand of either product is split into (8 + 8 + 8
+#: mantissa bits: the sum of the pieces is the f32 value to its last bit)
+SOFTMAX_PIECES = 3
+
+_NN = (((1,), (0,)), ((), ()))     # (m, k) x (k, n)
+
+
+def multinomial_sweep_tile(rows: int, d: int, k: int, dtype,
+                           feature_major: bool):
+    """Rows of X one grid step of :func:`fused_multinomial_logistic_scaled`
+    takes, or None where the kernel cannot be built and the XLA aggregator
+    is the path: storage other than bfloat16 (an f32 X would need its own
+    pieces on both products, fp8 its scale), a width that does not end on
+    a packed sublane group (feature-major: d % 16) or on a lane (row-major:
+    d % 128), fewer than 128 rows, or a ``(d, k)`` whose working set passes
+    the VMEM budget. At k ≤ 16 that admits d ≤ 5,600 or so; the class pad
+    grows in sixteens and the budget holds k·d ≲ 1.0e5 (k = 100 at d = 784,
+    k = 40 at d = 2,000)."""
+    if np.dtype(dtype) != np.dtype(jnp.bfloat16) or rows < LANE or k < 2:
+        return None
+    if d % (CLASS_GROUP if feature_major else LANE):
+        return None
+    kp = _pad_to(k, CLASS_GROUP)
+    d_lanes = _pad_to(d, LANE)
+    # resident: the coefficient pieces, gradient and compensation (out
+    # blocks count twice), one tile's (pieces, d) f32 product
+    fixed = kp * d_lanes * (2 * SOFTMAX_PIECES * 2 + 3 * 4
+                            + SOFTMAX_PIECES * 4)
+    # per row of X: the double-buffered storage block (and its masked copy
+    # in the last step), the (pieces·kp) f32 margins and bf16 multipliers,
+    # a dozen (kp, T) f32 softmax temporaries
+    per_row = 3 * 2 * d + kp * (SOFTMAX_PIECES * 6 + 12 * 4)
+    # the MXU sums a tile's rows in f32: 1,024 terms an entry at most, as
+    # the moment Gramian's
+    for t in (1024, 512, 256, 128):
+        if t <= rows and fixed + t * per_row <= _VMEM_BUDGET:
+            return t
+    return None
+
+
+def multinomial_sweep_orientation(x, k: int):
+    """The tiling the fused multinomial sweep takes for the concrete X of
+    a fit — ``"feature_major"`` / ``"row_major"`` by the layout X has, as
+    :func:`glm_sweep_orientation` — or None where a shard of it admits no
+    tile (:func:`multinomial_sweep_tile`) and the fit is the XLA
+    aggregator's."""
+    feature_major = stored_feature_major(x)
+    rows, d = x.sharding.shard_shape(x.shape)
+    if multinomial_sweep_tile(rows, d, k, x.dtype, feature_major) is None:
+        return None
+    return "feature_major" if feature_major else "row_major"
+
+
+def fused_multinomial_logistic_scaled(x, y, w, inv_std, scaled_mean, coef,
+                                      d: int, k: int,
+                                      fit_intercept: bool = True,
+                                      interpret: bool = False,
+                                      feature_major: bool = False,
+                                      tile: int = None
+                                      ) -> Dict[str, jnp.ndarray]:
+    """Softmax cross-entropy over ``k`` classes and its gradient from ONE
+    read of a bfloat16 X at storage width — the kernel twin of
+    ``aggregators.multinomial_logistic_scaled`` (ref
+    MultinomialLogisticBlockAggregator: all k coefficient vectors kept),
+    standardization folded around the row pass as in
+    :func:`fused_binary_logistic_scaled`:
+
+      margins = x·(W∘inv_std)ᵀ + (b − W·scaled_mean)
+      grad_Ŵ  = inv_std∘(multᵀ·x) − Σmult ⊗ scaled_mean
+
+    Both products run on the MXU and stay f32-faithful: X is bfloat16 and
+    exact, and the f32 operand of each product — the scaled coefficient
+    matrix going in, the multipliers ``w·(p − 1[y])`` coming out — is split
+    into three bfloat16 pieces whose sum is the f32 value, stacked on the
+    class axis (bf16 x bf16 products are exact in the f32 accumulator).
+    The coefficient pieces are made here, in code XLA compiles, so by
+    ``reduce_precision`` (:func:`_split3_rounded`); the multipliers' inside
+    the kernel (:func:`_split3`).
+
+    ``feature_major`` (static) is the caller's observation of how X is
+    stored and picks the tiling, never the result; ``tile`` overrides the
+    rows a grid step takes (tests). Which ``(d, k)`` fit:
+    :func:`multinomial_sweep_tile`, which the caller asks first."""
+    n = x.shape[0]
+    if tile is None:
+        tile = multinomial_sweep_tile(n, d, k, x.dtype, feature_major)
+    if tile is None:
+        raise ValueError(
+            f"no multinomial sweep for a {x.dtype} X of {n} x {d}, "
+            f"{k} classes, feature_major={feature_major}: ask "
+            f"multinomial_sweep_tile first and take the XLA aggregator")
+    f32 = jnp.float32
+    kp = _pad_to(k, CLASS_GROUP)
+    coef = jnp.asarray(coef, f32)
+    inv_std = jnp.asarray(inv_std, f32)
+    scaled_mean = jnp.asarray(scaled_mean, f32)
+    wmat = coef[: d * k].reshape(k, d)
+    b = coef[d * k:] if fit_intercept else jnp.zeros((k,), f32)
+    with jax.named_scope("glm.prepare_vectors"):
+        bias = b - jnp.dot(wmat, scaled_mean,
+                           precision=jax.lax.Precision.HIGHEST)
+        scaled = jnp.pad(wmat * inv_std[None, :], ((0, kp - k), (0, 0)))
+        pieces = jnp.concatenate(_split3_rounded(scaled), axis=0)
+        bias = jnp.pad(bias, (0, kp - k)).reshape(kp, 1)
+    _note_sweep("multinomial",
+                "feature_major" if feature_major else "row_major",
+                classes=k, class_pad=kp, pieces=SOFTMAX_PIECES,
+                pad_cols=0, tail_rows=n % tile,
+                **{"lane_tile" if feature_major else "row_tile": tile})
+    loss, raw, msum, count = _run_multinomial(
+        x.T if feature_major else x, jnp.asarray(y, f32), jnp.asarray(w, f32),
+        pieces, bias, k=k, tile=tile, feature_major=feature_major,
+        interpret=interpret)
+    msum = jnp.sum(msum[:k], axis=1)
+    gw = raw[:k] * inv_std[None, :] - msum[:, None] * scaled_mean[None, :]
+    grad = jnp.concatenate([gw.reshape(-1), msum]) if fit_intercept \
+        else gw.reshape(-1)
+    return {"loss": jnp.sum(loss), "grad": grad, "count": jnp.sum(count)}
+
+
+def _run_multinomial(x, y, w, pieces, bias, *, k, tile, feature_major,
+                     interpret):
+    """The K-class GLM sweep: per grid step the margins of ``tile`` rows
+    (MXU), their softmax, loss and multipliers with the classes on the
+    sublanes and the rows on the lanes (VPU, a ``(class_pad, tile)``
+    block), and the multipliers' product with the same X tile (MXU), Kahan-
+    added across the sequential grid as :func:`_run_glm`'s sums are.
+
+    ``x`` is the ``(d, n)`` view (``feature_major``: blocks ``(d, tile)``)
+    or the ``(n, d)`` array (blocks ``(tile, d)``); either way X meets the
+    MXU as it is stored, y and w ride as lane-dense ``(1, n)`` rows, and
+    nothing is padded: the rows of the last tile past n are selected out in
+    that grid step alone (Pallas leaves them undefined, and 0 · NaN is NaN).
+    Returns ``(loss (1, 128), Σ mult·x (class_pad, d), Σ mult (class_pad,
+    128), Σ w (1, 128))`` with the lane-wise partial sums left to the
+    caller."""
+    kp = pieces.shape[0] // SOFTMAX_PIECES
+    d = pieces.shape[1]
+    n = x.shape[1] if feature_major else x.shape[0]
+    steps = pl.cdiv(n, tile)
+    tail = n % tile
+    # which operand's lanes each product contracts: the stored tile is the
+    # right operand of both
+    margins_dims, grad_dims = (_NN, _NT) if feature_major else (_NT, _NN)
+
+    def glm_sweep_multinomial(x_ref, y_ref, w_ref, p_ref, b_ref,
+                              loss_ref, grad_ref, msum_ref, count_ref,
+                              closs_ref, cgrad_ref, cmsum_ref, ccount_ref):
+        i = pl.program_id(0)
+        sums = ((loss_ref, closs_ref), (grad_ref, cgrad_ref),
+                (msum_ref, cmsum_ref), (count_ref, ccount_ref))
+
+        @pl.when(i == 0)
+        def _():
+            for acc, comp in sums:
+                acc[:] = jnp.zeros_like(acc)
+                comp[:] = jnp.zeros_like(comp)
+
+        def tile_sums(live=None):
+            xv = x_ref[:]
+            stacked = jax.lax.dot_general(
+                p_ref[:], xv, margins_dims,
+                preferred_element_type=jnp.float32)
+            margins = b_ref[:] + sum(
+                stacked[j * kp:(j + 1) * kp] for j in range(SOFTMAX_PIECES))
+            klass = jax.lax.broadcasted_iota(jnp.int32, (kp, tile), 0)
+            if k < kp:
+                margins = jnp.where(klass < k, margins, -jnp.inf)
+            yv, wv = y_ref[:], w_ref[:]
+            top = jnp.max(margins, axis=0, keepdims=True)
+            e = jnp.exp(margins - top)
+            z = jnp.sum(e, axis=0, keepdims=True)
+            hit = klass == yv.astype(jnp.int32)
+            picked = jnp.sum(jnp.where(hit, margins, 0.0), axis=0,
+                             keepdims=True)
+            v_loss = wv * (top + jnp.log(z) - picked)
+            mult = wv * (e / z - hit.astype(jnp.float32))
+            if live is not None:
+                v_loss = jnp.where(live, v_loss, 0.0)
+                mult = jnp.where(live, mult, 0.0)
+                wv = jnp.where(live, wv, 0.0)
+                # the multipliers' product contracts the rows: X's own
+                # dead rows go too
+                rows = live if feature_major else jax.lax.broadcasted_iota(
+                    jnp.int32, (tile, 1), 0) < tail
+                xv = jnp.where(rows, xv, jnp.zeros((), xv.dtype))
+            v_grad = jax.lax.dot_general(
+                jnp.concatenate(_split3(mult), axis=0), xv, grad_dims,
+                preferred_element_type=jnp.float32)
+            v_grad = sum(v_grad[j * kp:(j + 1) * kp]
+                         for j in range(SOFTMAX_PIECES))
+            for (acc, comp), v in zip(sums, (
+                    _lane_sums(v_loss, tile), v_grad,
+                    _lane_sums(mult, tile), _lane_sums(wv, tile))):
+                _kahan_add(acc, comp, v)
+
+        if tail == 0:
+            tile_sums()
+        else:
+            pl.when(i < steps - 1)(tile_sums)
+            pl.when(i == steps - 1)(lambda: tile_sums(
+                jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1) < tail))
+
+    x_spec = pl.BlockSpec((d, tile), lambda i: (0, i)) if feature_major \
+        else pl.BlockSpec((tile, d), lambda i: (i, 0))
+    vec_spec = pl.BlockSpec((1, tile), lambda i: (0, i))
+    shapes = [(1, LANE), (kp, d), (kp, LANE), (1, LANE)]
+    with jax.named_scope("glm.prepare_vectors"):
+        args = (x, y.reshape(1, n), w.reshape(1, n), pieces, bias)
+    sweep = pl.pallas_call(
+        glm_sweep_multinomial,
+        name="glm_sweep_multinomial",
+        grid=(steps,),
+        in_specs=[x_spec, vec_spec, vec_spec,
+                  pl.BlockSpec(pieces.shape, lambda i: (0, 0)),
+                  pl.BlockSpec((kp, 1), lambda i: (0, 0))],
+        out_specs=[pl.BlockSpec(s, lambda i: (0, 0)) for s in shapes],
+        out_shape=[jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes],
+        scratch_shapes=[pltpu.VMEM(s, jnp.float32) for s in shapes],
+        compiler_params=_compiler_params("arbitrary"),
+        interpret=interpret,
+    )
+    with jax.named_scope("glm.sweep"):
+        return sweep(*args)
 
 
 # -- fused KMeans assignment ----------------------------------------------------

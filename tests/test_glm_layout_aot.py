@@ -24,6 +24,10 @@ def topo():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
+#: classes of the multinomial programs compiled here (the mnist8m cell's)
+N_CLASSES = 10
+
+
 def _aggregator(kind, d, feature_major):
     """(the scaled Pallas aggregator the estimators use, its extras)"""
     import jax.numpy as jnp
@@ -33,6 +37,10 @@ def _aggregator(kind, d, feature_major):
         return (aggregators.binary_logistic_pallas_scaled(
             d, True, feature_major=feature_major),
             [v, v, ((d + 1,), jnp.float32)])
+    if kind == "multinomial":
+        return (aggregators.multinomial_logistic_pallas_scaled(
+            d, N_CLASSES, True, feature_major=feature_major),
+            [v, v, ((d * N_CLASSES + N_CLASSES,), jnp.float32)])
     return (aggregators.least_squares_pallas_scaled(
         d, feature_major=feature_major), [v, v, ((2,), jnp.float32), v])
 
@@ -119,6 +127,27 @@ def test_feature_major_program_on_four_chips(topo):
     assert "tpu_custom_call" in text and "all-reduce" in text
     assert _x_ops(text, n // 4) == [] and _x_ops(text, n) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("n,d,feature_major,layout", [
+    (8_100_000, 784, True, "0,1"), (2_000_000, 1280, False, "1,0")])
+def test_multinomial_program_reads_x_as_stored(topo, n, d, feature_major,
+                                               layout):
+    """``lr_multinomial_mnist8m_fit``'s shard — 8,100,000 x 784 bf16 on one
+    chip, stored ``{0,1}``, 12.7 GB of arguments — and the row-major side at
+    a lane-aligned width: the aggregation program is ONE Mosaic call
+    (``glm_sweep_multinomial``; the last tile's rows past n are masked in
+    the kernel) with no f32 value of X's shape, no pad or copy of X, and
+    temporaries (the ``(1, n)`` rows of y and w) far under 1 GB."""
+    compiled = _compile(topo, "multinomial", n, d, feature_major)
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert _entry_layout_of_x(text) == layout
+    assert text.count("tpu_custom_call") == 1
+    assert "glm_sweep_multinomial" in text
+    assert n * d * 2 <= mem.argument_size_in_bytes <= n * d * 2 + (1 << 27)
+    assert mem.temp_size_in_bytes < 1 << 30
+    assert _x_ops(text, n) == [] and _wide_x(text, n, d) == []
 
 
 # -- the normal equations' moment program (WeightedLeastSquares) ---------------
