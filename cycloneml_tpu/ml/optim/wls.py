@@ -9,6 +9,23 @@ psum over the mesh) producing {wSum, bSum, bbSum, aSum, abSum, aaSum}; the
 (d+1)-sized standardized normal-equation solve then runs on the driver in
 f64, exactly where the reference solves after its aggregate.
 
+What the driver factors. The standardised system is a diagonal congruence
+of the moments: with ``S = [[aaSum, aSum], [aSum', wSum]]`` and ``E =
+diag(1/aStd, 1)`` it is ``E (S / wSum) E + diag(lam, 0)``, and where every
+feature varies (``aStd > 0``) that is ``E (S' / wSum) E`` for ``S' = S +
+wSum · diag(lam · aVar, 0)``. Cholesky's backward error does not depend
+on a diagonal scaling of the matrix (Higham, *Accuracy and Stability of
+Numerical Algorithms*, §10.1), so the Cholesky solver factors ``S'`` — the
+block as the device delivered it, widened ONCE into the column-major
+float64 array LAPACK works in — and the standardisation and its inverse
+act on O(d) vectors, where they cancel: ``coef = bStd · t[:d]`` for ``S' t
+= wSum · [abBar / bStd ; bBar]``. The O(d²) rescaling is built only
+where it is needed: a feature that does not vary (its raw column is
+collinear with the intercept, its standardised one is zero), the
+quasi-Newton solver (the L1 penalty lives in standardised coordinates and
+OWL-QN is not scale-invariant), and auto's fallback to it.
+``WeightedLeastSquaresModel.system`` says which was solved.
+
 Distinctions that matter for golden parity (and differ from the
 LinearRegression l-bfgs path):
 
@@ -46,14 +63,19 @@ class WeightedLeastSquaresModel:
     """``diag_inv_atwa`` — the diagonal of ``(AᵀWA)⁻¹`` the reference's
     summaries take standard errors from — may be handed over as a
     callable: the Cholesky solver's costs a second pass over its factor
-    (LAPACK ``potri``), which a fit that never reads it does not pay."""
+    (LAPACK ``potri``), which a fit that never reads it does not pay.
+    ``system`` names the matrix the solver was handed: ``"moments"`` (the
+    moment block itself), ``"standardised"`` (its O(d²) rescaling), or
+    None where a constant label needed no solve."""
 
     def __init__(self, coefficients: np.ndarray, intercept: float,
-                 diag_inv_atwa, objective_history):
+                 diag_inv_atwa, objective_history,
+                 system: Optional[str] = None):
         self.coefficients = coefficients
         self.intercept = intercept
         self._diag_inv_atwa = diag_inv_atwa
         self.objective_history = list(objective_history)
+        self.system = system
 
     @property
     def diag_inv_atwa(self) -> np.ndarray:
@@ -86,6 +108,31 @@ def _array_moments():
     IRLS-sized systems handed numpy): one jitted call, no mesh."""
     import jax
     return jax.jit(moments_aggregator(False))
+
+
+def _normal_block(aa_sum, ridge, border, corner, scale=None):
+    """The ONE column-major ``(k, k)`` float64 block LAPACK factors in
+    place: ``[[aa_sum + diag(ridge), border], [border', corner]]`` (no
+    ``border``: the core alone). ``aa_sum`` is symmetric, so its
+    transpose is the same matrix in the layout of the target and the
+    widening copy is contiguous; nothing rides in that copy (a ``multiply``
+    of mixed widths goes through the ufunc's casting buffer, the slow
+    way to widen). ``scale = (rows, columns)`` is the standardisation, two
+    more passes over the core: ``aa_sum ⊙ rows columns'``."""
+    d = aa_sum.shape[0]
+    k = d if border is None else d + 1
+    ata = np.empty((k, k), order="F")
+    core = ata[:d, :d]
+    if scale is None:
+        core[...] = aa_sum.T
+    else:
+        np.multiply(aa_sum.T, scale[0][:, None], out=core)
+        core *= scale[1][None, :]
+    core[np.arange(d), np.arange(d)] += ridge
+    if border is not None:
+        ata[:d, d] = ata[d, :d] = border
+        ata[d, d] = corner
+    return ata
 
 
 class WeightedLeastSquares:
@@ -161,14 +208,37 @@ class WeightedLeastSquares:
         return out
 
     # -- the reference algorithm -----------------------------------------
-    def solve(self, m: dict, d: int) -> WeightedLeastSquaresModel:
+    def solve(self, m: dict, d: int, *, _standardise: bool = False
+              ) -> WeightedLeastSquaresModel:
         """The driver's half (ref WeightedLeastSquares.fit after its
-        treeAggregate): standardise the moments, solve the (d+1)-sized
-        system in float64, map back."""
+        treeAggregate): the (d+1)-sized system in float64, and the model
+        in the data's own coordinates.
+
+        The Cholesky solver factors the moment block it was handed, ``S'
+        = [[aa_sum + w_sum·diag(lam·a_var), a_sum], [a_sum', w_sum]]``:
+        the standardised system is ``E (S' / w_sum) E`` with ``E =
+        diag(1/a_std, 1)``, a diagonal congruence that moves neither the
+        solution nor Cholesky's backward error (module docstring), so for
+        ``S' t = w_sum · [ab_bar / b_std ; b_bar]`` the standardised
+        solution is ``E⁻¹ t`` and mapping it back cancels ``E⁻¹``: ``coef
+        = b_std · t[:d]``; ``diag_inv_atwa`` is ``diag(S'⁻¹)`` as it
+        stands; the objective is the standardised quadratic with ONE
+        ``1 / w_sum`` (``_cholesky``'s ``scale``). The block is filled by
+        one widening copy: at d = 2,000 every pass over a (d, d) float64
+        array is 32 MB of host memory traffic, and the chip idles through
+        each. The O(d²) rescaling is built where standardised coordinates
+        are needed: a feature with ``a_std == 0`` (``E`` is singular:
+        the column gets coefficient 0 through its zero standardised
+        column), the quasi-Newton solver, and auto's fallback to it after
+        a failed ``potrf``, which rebuilds from ``m``. The returned
+        model's ``system`` names the form; ``_standardise`` forces the
+        rescaled one (the tests' twin of the moment form, no option of
+        the component)."""
         w_sum = float(m["w_sum"])
         if w_sum <= 0:
             raise ValueError("sum of weights must be positive")
-        raw_b_bar = float(m["b_sum"]) / w_sum
+        b_sum = float(m["b_sum"])
+        raw_b_bar = b_sum / w_sum
         raw_bb_bar = float(m["bb_sum"]) / w_sum
         raw_b_std = float(np.sqrt(max(raw_bb_bar - raw_b_bar ** 2, 0.0)))
 
@@ -186,8 +256,10 @@ class WeightedLeastSquares:
         b_bar = float(raw_b_bar) / b_std
         bb_bar = float(raw_bb_bar) / (b_std * b_std)
 
-        raw_a_bar = np.asarray(m["a_sum"], np.float64) / w_sum
-        raw_ab_bar = np.asarray(m["ab_sum"], np.float64) / w_sum
+        a_sum = np.asarray(m["a_sum"], np.float64)
+        ab_sum = np.asarray(m["ab_sum"], np.float64)
+        raw_a_bar = a_sum / w_sum
+        raw_ab_bar = ab_sum / w_sum
         aa_sum = np.asarray(m["aa_sum"])
         a_var = np.maximum(
             np.diagonal(aa_sum).astype(np.float64) / w_sum - raw_a_bar ** 2,
@@ -210,69 +282,77 @@ class WeightedLeastSquares:
         if not self.standardize_label:
             lam = lam * b_std
 
-        # the standardized system, built ONCE in the column-major block
-        # LAPACK factors in place: at d = 2,000 every pass over a (d, d)
-        # float64 array is 32 MB of host memory traffic, and a fit waits
-        # for each (aa_sum is symmetric: its transpose is the same matrix
-        # in the layout of the target, so the widening pass is contiguous).
-        # The intercept rides as an appended bias column (getAtA, ref :312)
-        k = d + 1 if self.fit_intercept else d
-        ata = np.empty((k, k), order="F")
-        core = ata[:d, :d]
-        np.multiply(aa_sum.T, (inv_std / w_sum)[:, None], out=core)
-        core *= inv_std[None, :]
-        core[np.arange(d), np.arange(d)] += lam
-        if self.fit_intercept:
-            ata[:d, d] = ata[d, :d] = a_bar
-            ata[d, d] = 1.0
-            atb = np.concatenate([ab_bar, [b_bar]])
-        else:
-            atb = ab_bar
-
         use_qn = (self.solver_type == QUASI_NEWTON
                   or (self.solver_type == AUTO
                       and self.elastic_net_param != 0.0
                       and self.reg_param != 0.0))
+        icpt = self.fit_intercept
+        k = d + 1 if icpt else d
+
+        def system(moments: bool):
+            """Block and right-hand side of either form; the intercept
+            rides as an appended bias column (getAtA, ref :312)."""
+            if moments:
+                ata = _normal_block(aa_sum, w_sum * lam * a_var,
+                                    a_sum if icpt else None, w_sum)
+                return ata, np.append(ab_sum, b_sum)[:k] / b_std
+            ata = _normal_block(aa_sum, lam, a_bar if icpt else None, 1.0,
+                                scale=(inv_std / w_sum, inv_std))
+            return ata, np.append(ab_bar, b_bar)[:k]
+
+        moments = not (use_qn or _standardise) and bool(live.all())
+        ata, atb = system(moments)
         if use_qn:
             sol, history, aa_inv = self._quasi_newton(
                 ata, atb, a_bar, b_bar, bb_bar, a_std, eff_l1, d)
         else:
             try:
-                sol, history, aa_inv = self._cholesky(ata, atb, bb_bar)
+                sol, history, aa_inv = self._cholesky(
+                    ata, atb, bb_bar, 1.0 / w_sum if moments else 1.0)
             except np.linalg.LinAlgError:
                 if self.solver_type != AUTO:
                     raise
-                # ref :266-273: auto falls back to QN on singular AtA
+                # ref :266-273: auto falls back to QN on singular AtA —
+                # in standardised coordinates, rebuilt from the moments
+                if moments:
+                    moments = False
+                    ata, atb = system(False)
                 sol, history, aa_inv = self._quasi_newton(
                     ata, atb, a_bar, b_bar, bb_bar, a_std, None, d)
 
-        if self.fit_intercept:
-            coef_std, intercept = sol[:d], float(sol[d]) * b_std
-        else:
-            coef_std, intercept = sol, 0.0
-        coef = coef_std * np.where(live, b_std * inv_std, 0.0)
+        # back to the data's coordinates: E⁻¹ has cancelled in the moment
+        # form; a dead feature's inv_std is 0, and so is its coefficient
+        coef = sol[:d] * (b_std if moments else b_std * inv_std)
+        intercept = float(sol[d]) * b_std if icpt else 0.0
 
-        if aa_inv is not None:
-            mult = np.concatenate([a_var, [1.0]]) if self.fit_intercept \
-                else a_var
+        if aa_inv is None:
+            diag = np.zeros(1)
+        elif moments:
+            diag = aa_inv               # diag(S'⁻¹): nothing to rescale
+        else:
+            mult = np.append(a_var, 1.0)[:k]
 
             def diag():
                 with np.errstate(divide="ignore"):
                     return np.where(mult > 0, aa_inv() / (w_sum * mult),
                                     np.inf)
-        else:
-            diag = np.zeros(1)
-        return WeightedLeastSquaresModel(coef, intercept, diag, history)
+        return WeightedLeastSquaresModel(
+            coef, intercept, diag, history,
+            system="moments" if moments else "standardised")
 
-    def _cholesky(self, ata, atb, bb_bar):
+    def _cholesky(self, ata, atb, bb_bar, scale=1.0):
         """LAPACK's symmetric positive-definite routines on the ONE
         column-major block (``potrf`` / ``potrs``, and ``potri`` when the
         inverse's diagonal is asked for; ref CholeskySolver's ``dppsv`` +
         ``dpptri``), in place: the factor overwrites the lower triangle,
         the strict upper triangle keeps the matrix, so the objective's
         ``sol'A sol`` is a ``symv`` over the upper half with the saved
-        diagonal put back — no second (d, d) array. A matrix that is not
-        positive definite raises LinAlgError (the reference's
+        diagonal put back — no second (d, d) array. ``ata`` is whichever
+        block ``solve`` built; ``scale`` is what takes its quadratic to
+        the standardised one (``1 / w_sum`` for the moment block, whose
+        solution and right-hand side carry the rest of the congruence;
+        1 for the standardised block). A matrix that is not positive
+        definite raises LinAlgError (the reference's
         SingularMatrixException analog) with ``ata`` restored."""
         from scipy.linalg import blas, lapack
         diag = ata.diagonal().copy()
@@ -289,7 +369,8 @@ class WeightedLeastSquares:
         # _quasi_newton's cost function evaluates, penalty included
         a_sol = blas.dsymv(1.0, chol, sol, lower=0) \
             + (diag - chol.diagonal()) * sol
-        loss = 0.5 * bb_bar - float(atb @ sol) + 0.5 * float(sol @ a_sol)
+        loss = 0.5 * bb_bar - scale * float(atb @ sol) \
+            + 0.5 * scale * float(sol @ a_sol)
 
         def inv_diag():
             return lapack.dpotri(chol, lower=1, overwrite_c=1)[0] \

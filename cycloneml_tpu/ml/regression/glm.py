@@ -621,8 +621,9 @@ class GeneralizedLinearRegression(Predictor, _GLRParams, MLWritable, MLReadable)
         for it in range(max(self.get("maxIter"), 1)):
             with tracing.span("phase", "irls.iteration", iteration=it) as isp:
                 out = dispatch(irls, "pass", coef, icpt, float(it == 0))
-                with tracing.span("phase", "fit.solve"):
+                with tracing.span("phase", "fit.solve") as ssp:
                     wm = wls.solve(out, d)
+                    ssp.annotate(system=wm.system)
                 old = np.append(coef, icpt)
                 coef, icpt = wm.coefficients, float(wm.intercept)
                 # ref IRLS convergence (IterativelyReweightedLeastSquares

@@ -211,8 +211,9 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
                 standardize_label=True, solver_type=AUTO,
                 max_iter=self.get("maxIter"), tol=self.get("tol"))
         moments = wls.moments(ds)
-        with tracing.span("phase", "fit.solve"):
+        with tracing.span("phase", "fit.solve") as ssp:
             wm = wls.solve(moments, ds.n_features)
+            ssp.annotate(system=wm.system)
         with tracing.span("phase", "fit.finish"):
             model = LinearRegressionModel(wm.coefficients, wm.intercept,
                                           uid=self.uid)

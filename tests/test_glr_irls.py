@@ -231,8 +231,9 @@ def test_warm_fit_builds_and_launches_nothing_outside_its_passes(ctx):
 
 def test_spans_and_counters_of_a_fit(ctx):
     """One ``irls.iteration`` a pass, each around its ``dispatch irls.pass``
-    ⊃ ``transfer irls.readback`` and its ``fit.solve``; the summary's
-    counters are the spans counted."""
+    ⊃ ``transfer irls.readback`` and its ``fit.solve``, which says that it
+    factored the moment block as delivered; the summary's counters are the
+    spans counted."""
     from cycloneml_tpu.dataset.dataset import InstanceDataset
     from cycloneml_tpu.ml.regression import GeneralizedLinearRegression
     x, y = _case(38, 2048, 16)
@@ -258,6 +259,7 @@ def test_spans_and_counters_of_a_fit(ctx):
         solve, = [sp for sp in spans if sp.name == "fit.solve"
                   and sp.parent_id == it.span_id]
         assert solve.t0 >= dsp.t1
+        assert solve.attrs["system"] == "moments"
     finish, = [sp for sp in spans if sp.name == "fit.finish"]
     assert dispatches[-1].parent_id == finish.span_id
     assert [sp.name for sp in spans if sp.kind == "phase"

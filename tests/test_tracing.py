@@ -857,7 +857,8 @@ def test_linear_regression_summary_counts_what_the_step_counter_counts(ctx):
 def test_normal_solver_fit_is_a_traced_counted_fit(ctx):
     """A normal-equation fit under the tracer: ``fit.prepare``, the moment
     program's dispatch with its collective and its readback inside,
-    ``fit.solve``, ``fit.finish`` — all under the job span, the phases
+    ``fit.solve`` (of the moment block as delivered: ``system``),
+    ``fit.finish`` — all under the job span, the phases
     disjoint from each other and from the dispatch (what the idle readers
     partition by), together covering the job — and one ``kernel.
     wls_moments`` instant for the program the first fit built."""
@@ -895,6 +896,7 @@ def test_normal_solver_fit_is_a_traced_counted_fit(ctx):
     assert {s.name for s in spans if s.kind == "phase"} == {
         "fit.prepare", "fit.solve", "fit.finish"}
     assert len([s for s in spans if s.kind == "dispatch"]) == 1
+    assert named[("phase", "fit.solve")].attrs["system"] == "moments"
     at, covered = job.t0, 0.0
     for key in order:
         s = named[key]

@@ -316,3 +316,133 @@ def test_arrays_and_dataset_take_the_same_pass(ctx):
     assert a.intercept == pytest.approx(b.intercept, rel=1e-9)
     assert a.objective_history == pytest.approx(b.objective_history)
     np.testing.assert_allclose(a.diag_inv_atwa, b.diag_inv_atwa, rtol=1e-7)
+
+
+# -- which system the driver factors -------------------------------------------
+
+def _f32_moments(x, y, w):
+    """The six sums as the device delivers them: float32 arrays."""
+    return {k: np.asarray(v, np.float32)
+            for k, v in _moment_reference(x, y, w).items()}
+
+
+def _rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.max(np.abs(got - want) / np.abs(want)))
+
+
+@pytest.mark.parametrize("unit_weights", [True, False])
+@pytest.mark.parametrize("reg_param", [0.0, 0.01])
+@pytest.mark.parametrize("standardize_label", [True, False])
+@pytest.mark.parametrize("standardize_features", [True, False])
+@pytest.mark.parametrize("fit_intercept", [True, False])
+def test_the_moment_block_solves_the_standardised_system(
+        fit_intercept, standardize_features, standardize_label, reg_param,
+        unit_weights):
+    """``solve`` factors the moment block it was handed (one widening
+    copy, the standardisation on O(d) vectors) and returns what the
+    standardised (d+1)² block returns — coefficients, intercept, the
+    standardised quadratic it reports, and the inverse's diagonal that a
+    GLR fit's standard errors are (``standardize_* = False`` is IRLS's
+    setting) — on the same float32 moments: the congruence is exact, so
+    1e-10 is the float64 solves' room (1e-13 observed), not a tolerance
+    of the method. Columns of unlike scale and offset, so a dropped
+    ``a_std`` or a misplaced ``w_sum`` shows."""
+    from cycloneml_tpu.ml.optim.wls import WeightedLeastSquares
+    rng = np.random.RandomState(21)
+    n, d = 3000, 12
+    x = rng.randn(n, d) * rng.uniform(0.2, 5.0, d) + rng.randn(d)
+    y = x @ rng.randn(d) / np.sqrt(d) + 0.5 * rng.randn(n) + 0.3
+    w = np.ones(n) if unit_weights else rng.uniform(0.5, 2.0, n)
+    m = _f32_moments(x, y, w)
+    wls = WeightedLeastSquares(
+        fit_intercept, reg_param=reg_param,
+        standardize_features=standardize_features,
+        standardize_label=standardize_label)
+    got, want = wls.solve(m, d), wls.solve(m, d, _standardise=True)
+    assert (got.system, want.system) == ("moments", "standardised")
+    assert _rel(got.coefficients, want.coefficients) <= 1e-10
+    assert got.intercept == pytest.approx(want.intercept, rel=1e-10)
+    assert (got.intercept != 0.0) == fit_intercept
+    assert _rel(got.objective_history, want.objective_history) <= 1e-10
+    assert got.diag_inv_atwa.shape == (d + fit_intercept,)
+    assert _rel(got.diag_inv_atwa, want.diag_inv_atwa) <= 1e-10
+
+
+def _singular_case():
+    """Two identical ±1 columns over 64 unit-weight rows: every entry of
+    the moment block is a small integer and its first pivot a perfect
+    square (64), so the second pivot is 0 EXACTLY and ``potrf`` fails on
+    either form, whatever LAPACK's blocking."""
+    rng = np.random.RandomState(22)
+    x = np.where(rng.rand(64, 3) > 0.5, 1.0, -1.0)
+    x[:, 0] = np.tile([1.0, -1.0], 32)
+    x[:, 1] = x[:, 0]
+    y = x[:, 0] + 0.5 * x[:, 2] + 0.25 * rng.randn(64)
+    return x, y, np.ones(64)
+
+
+@pytest.mark.parametrize("form", ["constant_column", "elastic_net",
+                                  "singular_fallback"])
+def test_the_forms_that_keep_the_standardised_block(form):
+    """Where standardised coordinates are needed the O(d²) rescaling is
+    still built, and the answer is the one the standardised block gives:
+    a column that does not vary (``E`` is singular; coefficient exactly
+    0), the quasi-Newton solver (L1 is not scale-invariant), and auto's
+    fallback from a ``potrf`` that failed on the moment block."""
+    from cycloneml_tpu.ml.optim.wls import AUTO, WeightedLeastSquares
+    kw = dict(fit_intercept=True, reg_param=0.01, solver_type=AUTO,
+              max_iter=200, tol=1e-10)
+    if form == "singular_fallback":
+        x, y, w = _singular_case()
+        kw["reg_param"] = 0.0
+    else:
+        x, y = _case(23, 2000, 8,
+                     constant_col=5 if form == "constant_column" else None)
+        w = np.random.RandomState(24).uniform(0.5, 2.0, 2000)
+    if form == "elastic_net":
+        kw["elastic_net_param"] = 0.5
+    m = _moment_reference(x, y, w)
+    wls = WeightedLeastSquares(**kw)
+    got, want = wls.solve(m, x.shape[1]), \
+        wls.solve(m, x.shape[1], _standardise=True)
+    assert got.system == want.system == "standardised"
+    # the same block through the same solver: not close, equal
+    np.testing.assert_array_equal(got.coefficients, want.coefficients)
+    assert got.intercept == want.intercept
+    assert got.objective_history == want.objective_history
+    if form == "constant_column":
+        assert got.coefficients[5] == 0.0
+        assert np.isinf(got.diag_inv_atwa[5])
+    elif form == "elastic_net":
+        assert len(got.objective_history) > 1        # OWL-QN iterated
+    else:
+        # quasi-Newton from zero splits the twin columns' weight evenly
+        assert len(got.objective_history) > 1
+        assert got.coefficients[0] == pytest.approx(got.coefficients[1],
+                                                    rel=1e-8)
+        fitted = x @ got.coefficients + got.intercept
+        ref = np.linalg.lstsq(np.c_[x[:, [0, 2]], np.ones(64)], y,
+                              rcond=None)[0]
+        np.testing.assert_allclose(
+            fitted, np.c_[x[:, [0, 2]], np.ones(64)] @ ref, atol=1e-6)
+
+
+def test_a_failed_potrf_restores_the_block():
+    """``_cholesky`` works in place; when ``potrf`` stops (here at the
+    second pivot, on the moment block of the singular case) it puts the
+    matrix back before raising, and a forced Cholesky solver raises where
+    auto falls back."""
+    from cycloneml_tpu.ml.optim.wls import (CHOLESKY, WeightedLeastSquares,
+                                            _normal_block)
+    x, y, w = _singular_case()
+    m = _moment_reference(x, y, w)
+    block = _normal_block(m["aa_sum"], np.zeros(3), m["a_sum"], m["w_sum"])
+    assert block.flags.f_contiguous and block.dtype == np.float64
+    before = block.copy()
+    wls = WeightedLeastSquares(True, solver_type=CHOLESKY)
+    with pytest.raises(np.linalg.LinAlgError, match="potrf info=2"):
+        wls._cholesky(block, np.append(m["ab_sum"], m["b_sum"]), 1.0)
+    np.testing.assert_array_equal(block, before)
+    with pytest.raises(np.linalg.LinAlgError):
+        wls.solve(m, 3)
