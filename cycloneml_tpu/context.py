@@ -40,6 +40,24 @@ logger = get_logger(__name__)
 
 _active_lock = threading.Lock()
 _active_context: Optional["CycloneContext"] = None
+_staging_listeners_registered = False
+
+
+def _register_staging_listeners() -> None:
+    """Hand ``observe/tracing.py``'s listeners to ``jax.monitoring``, once a
+    process (jax keeps no set: a second registration would fire twice). They
+    stay for the process's life and read ``tracing.active()`` per event."""
+    global _staging_listeners_registered
+    with _active_lock:
+        if _staging_listeners_registered:
+            return
+        _staging_listeners_registered = True
+    import jax.monitoring as monitoring
+    monitoring.register_scalar_listener(_tracing.on_staging_start)
+    monitoring.register_event_time_span_listener(_tracing.on_staging_span)
+    monitoring.register_event_listener(_tracing.on_cache_event)
+    monitoring.register_event_duration_secs_listener(
+        _tracing.on_cache_duration)
 
 
 def active_context() -> Optional["CycloneContext"]:
@@ -108,6 +126,9 @@ class CycloneContext:
     def __init__(self, conf: Optional[CycloneConf] = None,
                  master: Optional[str] = None, app_name: Optional[str] = None):
         global _active_context
+        # the start's own spans are recorded at the end, once a tracer is
+        # installed (it is installed half-way down)
+        t_start = time.perf_counter()
         with _active_lock:
             if _active_context is not None and not _active_context._stopped:
                 raise RuntimeError(
@@ -150,8 +171,10 @@ class CycloneContext:
         if self.conf.get(MULTIHOST_MODEL_PARALLELISM) > 1:
             mesh_kw["model_parallelism"] = \
                 self.conf.get(MULTIHOST_MODEL_PARALLELISM)
+        t_mesh = time.perf_counter()
         self.mesh_runtime = mesh_mod.get_or_create(self.conf.get(MASTER),
                                                    **mesh_kw)
+        t_services = time.perf_counter()
 
         # context-owned storage tiers (BlockManager analog): every
         # persisted/cached numeric dataset registers here, so conf budgets
@@ -270,6 +293,10 @@ class CycloneContext:
         if tracer is not None:
             import jax
             tracer.annotation = jax.profiler.TraceAnnotation
+        # and what jax stages beneath the program's spans becomes ``staging``
+        # spans of whichever tracer is active then (observe/tracing.py): a
+        # tracer enabled after this context is served too
+        _register_staging_listeners()
 
         # distributed-trace adoption + span shipping (observe/collect.py):
         # a deploy-launched app joins the submitting process's trace
@@ -347,6 +374,18 @@ class CycloneContext:
         with _active_lock:
             _active_context = self
         atexit.register(self.stop)
+        if tracer is not None:
+            # context.mesh holds the backend's initialisation when this
+            # context is the first to touch jax; context.services the bus,
+            # metrics, reporters and plug-ins. In the order they closed
+            t_end = time.perf_counter()
+            start_id = tracer.reserve_span_id()
+            tracer.record_span("phase", "context.mesh", t0=t_mesh,
+                               t1=t_services, parent=start_id)
+            tracer.record_span("phase", "context.services", t0=t_services,
+                               t1=t_end, parent=start_id)
+            tracer.record_span("phase", "context.start", t0=t_start,
+                               t1=t_end, span_id=start_id)
 
     # -- factories -------------------------------------------------------------
     @classmethod
@@ -394,10 +433,14 @@ class CycloneContext:
             else None
         sid = ""
         mark = 0
+        staged = None
         if job_span is not None:
             mark = tracer.mark()  # rollup scans only this job's spans
             job_span.__enter__()
             sid = job_span.span_id
+            # what jax stages beneath this job, kept as it happens: the
+            # line below and the profile read it, nothing scans the ring
+            staged = tracer.open_staging_account()
         # usage attribution bracket: an un-scoped job gets an automatic
         # "job-{id}" scope (a caller's explicit attribution.scope wins),
         # and the scope row's delta across the fit lands on the profile
@@ -433,6 +476,16 @@ class CycloneContext:
                 job_scope.__exit__(None, None, None)
             if job_span is not None:
                 job_span.__exit__(None, None, None)
+                tracer.close_staging_account(staged)
+                if staged["programs"] or staged["slowest_s"]:
+                    logger.info(
+                        "job %d (%s) staged %d programs: trace %.3f s, "
+                        "lower %.3f s, compile %.3f s; persistent cache "
+                        "%d hits / %d misses; slowest %s (%.3f s)",
+                        jid, description, staged["programs"],
+                        staged["trace"], staged["lower"], staged["compile"],
+                        staged["cache_hit"], staged["cache_miss"],
+                        staged["slowest_fun"], staged["slowest_s"])
             if job_span is not None and tracer.full:
                 # profile rollups are a FULL-tracing feature: the flight
                 # ring records the job span for post-hoc dumps but must
@@ -441,6 +494,13 @@ class CycloneContext:
                     prof = tracer.profile_for(sid, since=mark)
                     prof.job_id = jid
                     prof.description = description
+                    prof.staged_programs = staged["programs"]
+                    prof.staging_seconds = {
+                        step: staged[step]
+                        for step in ("trace", "lower", "compile")}
+                    prof.staging_cache_hits = staged["cache_hit"]
+                    prof.staging_cache_misses = staged["cache_miss"]
+                    prof.staging_slowest_fun = staged["slowest_fun"]
                     if usage_before is not None:
                         prof.job_usage = _attribution.usage_delta(
                             usage_before, led.row(usage_key))

@@ -263,25 +263,22 @@ def _build_chunk(compiled, l2_t, m: int, K: int, c1: float, c2: float,
 
         def body(carry):
             (coef, S, Y, k, f, g, it, evals, done, losses) = carry
-            with jax.named_scope("lbfgs.direction"):
-                d, k, dg0, bad = _descent_or_reset(_two_loop(S, Y, k, g, m),
-                                                   g, k)
-                init_alpha = _init_alpha(first & (it == 0), bad, g)
+            d, k, dg0, bad = _descent_or_reset(_two_loop(S, Y, k, g, m),
+                                               g, k)
+            init_alpha = _init_alpha(first & (it == 0), bad, g)
 
             def phi(alpha):
                 v, grad = f_and_g(coef + alpha * d)
                 return v, grad, jnp.dot(d, grad)
 
-            with jax.named_scope("lbfgs.line_search"):
-                alpha, f_new, g_new, ev = wolfe_search(
-                    phi, jnp.zeros_like(g), f, dg0, init_alpha,
-                    c1, c2, max_ls, cdt)
-            with jax.named_scope("lbfgs.update"):
-                s = alpha * d
-                S, Y, k = _push_pair(S, Y, k, s, g_new - g, m)
-                code = _convergence_code(f, f_new, g_new, coef + s,
-                                         tol, grad_tol)
-                losses = losses.at[it].set(f_new)
+            alpha, f_new, g_new, ev = wolfe_search(
+                phi, jnp.zeros_like(g), f, dg0, init_alpha,
+                c1, c2, max_ls, cdt)
+            s = alpha * d
+            S, Y, k = _push_pair(S, Y, k, s, g_new - g, m)
+            code = _convergence_code(f, f_new, g_new, coef + s,
+                                     tol, grad_tol)
+            losses = losses.at[it].set(f_new)
             return (coef + s, S, Y, k, f_new, g_new, it + 1,
                     evals + ev, code, losses)
 

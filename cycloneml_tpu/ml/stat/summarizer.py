@@ -134,8 +134,7 @@ def _psum_parts(moments):
     from cycloneml_tpu.mesh import DATA_AXIS, REPLICA_AXIS
 
     def summarizer_moments(x, y, w):
-        with jax.named_scope("summarizer.moments"):
-            parts = moments(x, y, w)
+        parts = moments(x, y, w)
         summed = {}
         for k, v in parts.items():
             if k == "mx":

@@ -58,7 +58,9 @@ MAX_KEPT_DUMPS = 16
 
 class FlightTracer(tracing.Tracer):
     """The always-on ring: a Tracer that records spans and nothing else
-    (``full = False`` — no metrics bridge, no cost harvest, no rollups)."""
+    (``full = False`` — no metrics bridge, no cost harvest, no rollups).
+    It keeps ``Tracer.totals()`` like any tracer: they are what a ring of
+    2,048 spans forgets (the context's start, the first fit)."""
 
     full = False
 

@@ -31,6 +31,10 @@ class FitProfile:
     / ``fit.finish`` / ``optim.iteration``) — a phase's duration less the
     ``phase`` and ``dispatch`` spans directly inside it — so the values sum
     to the phases' union less the dispatch time under them.
+    ``staged_programs`` / ``staging_seconds`` / ``staging_cache_hits`` /
+    ``staging_cache_misses`` / ``staging_slowest_fun`` are what jax reported
+    staging beneath the job (``compile_seconds`` is the program's bracket:
+    first dispatches of new program objects, execution included).
     ``n_models`` is the model-axis width of the fit's dispatches (stacked
     fits — ``n_models`` > 1 — amortize every compile in this profile over
     that many models; see docs/multi-model.md).
@@ -82,6 +86,16 @@ class FitProfile:
     n_models: int = 1
     phase_seconds: Dict[str, float] = dataclasses.field(
         default_factory=dict)
+    # what jax staged beneath the job (``staging`` spans; run_job fills
+    # these from the tracer's per-thread account, the numbers its INFO line
+    # prints): compile steps reported, seconds of the outermost events by
+    # step, the persistent cache's answers, the slowest event's function
+    staged_programs: int = 0
+    staging_seconds: Dict[str, float] = dataclasses.field(
+        default_factory=dict)
+    staging_cache_hits: int = 0
+    staging_cache_misses: int = 0
+    staging_slowest_fun: str = ""
     # fp8 tier fallbacks during this fit: the envelope probe (or a
     # non-finite fp8 solution) re-routed the fit to bf16 storage — see
     # docs/mixed-precision.md and the PrecisionFallback event

@@ -27,11 +27,18 @@ kind           opened around
 ``compile``    the FIRST dispatch of a freshly built program — the call that
                pays tracing + XLA compilation (program-cache misses)
 ``transfer``   a blocking ``jax.device_get`` readback; ``bytes`` attr
+``staging``    what jax staged, as jax reports it (``jax.monitoring``):
+               ``trace`` (jaxpr tracing), ``lower`` (jaxpr → MLIR; Pallas
+               kernels lower to Mosaic here), ``compile`` (XLA compile, or
+               the persistent cache's retrieval and load). Attrs ``fun``,
+               ``nested``; on ``compile`` also ``cache`` and ``retrieval_s``
 ``phase``      host work of a fit between its dispatches: ``fit.stats`` /
                ``fit.prepare`` / ``fit.optimize`` / ``fit.finish`` in the
                estimator, ``optim.iteration`` per turn of an optimizer's
                host loop (its self time — duration less its ``dispatch``
-               children — is the host optimizer's own work)
+               children — is the host optimizer's own work);
+               ``context.start`` ⊃ ``context.mesh`` / ``context.services``
+               around ``CycloneContext.__init__``
 ``checkpoint`` ``TrainingCheckpointer`` save / commit / restore
 ``rebuild``    a ``MeshSupervisor.recover`` mesh rebuild
 ``instant``    zero-duration annotations: injected faults, step retries,
@@ -51,6 +58,26 @@ profiler session is open. :func:`instant`, :func:`counter` and
 :meth:`Tracer.record_span` emit nothing there: an annotation brackets a live
 region on one thread, and those are points or regions that already ended
 (possibly on another thread).
+
+Staging: ``compile`` is this program's bracket — the first dispatch of a
+program object the program cache did not hold — and cannot tell a cache
+load from a compile, nor see what jit re-stages beneath an old program
+object. jax reports each staging step where it happens; the ``on_staging_*``
+/ ``on_cache_*`` functions below are the listeners ``CycloneContext``
+registers for them (plain functions of jax's ``(event, value, **kw)``: this
+module stays jax-free). Only the OUTERMOST staging event on a thread becomes
+a span (an outer trace holds hundreds of one-primitive traces, and an eager
+constant inside it a whole trace/lower/compile of its own: all are counted in
+``nested`` and are the outer step's time already), opened at jax's entry
+event and closed at its exit, so it nests under the thread's open span and is
+mirrored into a profiler capture like any other. Staging on a thread with no
+open span is not the program's (a caller's own ``jit``): it is added to the
+``staging.outside`` total and opens no span.
+
+Totals: every closed span also updates ``{n, seconds, first_s, max_s}`` for
+its ``kind.name`` (:meth:`Tracer.totals`), which outlive the ring: the first
+fit of a process (``first_s`` of ``job.<Estimator>.fit``) and the context's
+start are long gone from a 2,048-span ring when anyone reads.
 
 Off by default with near-zero disabled cost: every instrumentation site
 performs ONE module-global read (the same pattern ``faults.inject`` uses)
@@ -78,7 +105,8 @@ from typing import Any, Dict, List, Optional, Tuple
 __all__ = [
     "Span", "Tracer", "enable", "disable", "active", "full_active",
     "install_if_absent", "span", "instant", "counter", "current_span_id",
-    "nbytes",
+    "nbytes", "on_staging_start", "on_staging_span", "on_cache_event",
+    "on_cache_duration",
 ]
 
 
@@ -138,6 +166,47 @@ NOOP_SPAN = _NoopSpan()
 #: what every span's event in a ``jax.profiler`` capture starts with (a
 #: benchmark finds ITS spans by its own prefix: this one is the program's)
 ANNOTATION_PREFIX = "cyclone."
+
+#: the ``jax.monitoring`` events that bracket a staging step (an entry
+#: scalar, then a duration and a time span at exit, each with ``fun_name``)
+STAGING_STEPS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+#: what the persistent cache says inside a ``compile`` step (a miss is
+#: reported when the new executable is written; jax says nothing of a compile
+#: it neither found nor kept: ``cache`` stays ``off``)
+CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: distinct ``kind.name`` totals kept; past it a new name joins ``<kind>.*``
+#: (the totals sit beside a bounded ring and must not outgrow it)
+MAX_TOTAL_NAMES = 1024
+
+
+def _new_totals() -> Dict[Tuple[str, str], List[float]]:
+    # totals that no span carries are there from the start: 0 cache misses
+    # is a reading, not a missing one
+    return {("staging", name): [0, 0.0, 0.0, 0.0]
+            for name in ("cache_hit", "cache_miss", "outside")}
+
+
+class _ThreadStaging:
+    """One thread's open staging steps (``Tracer._local.staging``)."""
+
+    __slots__ = ("open", "live", "nested", "cache", "retrieval_s",
+                 "accounts")
+
+    def __init__(self):
+        self.open: List[str] = []     # steps jax has entered and not left
+        self.live: Optional[_LiveSpan] = None   # the outermost one's span
+        self.nested = 0               # events folded into the outermost
+        self.cache = "off"            # of the compile step in flight
+        self.retrieval_s: Optional[float] = None
+        self.accounts: List[Dict[str, Any]] = []   # one per open job
 
 
 class _LiveSpan:
@@ -244,6 +313,8 @@ class Tracer:
         self._lock = threading.Lock()
         self._local = threading.local()
         self._tid_names: Dict[int, str] = {}
+        # (kind, name) -> [n, seconds, first_s, max_s]; outlives the ring
+        self._totals = _new_totals()
 
     @property
     def wall_base(self) -> float:
@@ -303,8 +374,16 @@ class Tracer:
         s.t0 = s.t1 = time.perf_counter()
         self._record(s)
 
+    def reserve_span_id(self) -> str:
+        """An id for a span recorded later (``record_span(span_id=...)``):
+        a retroactive parent is recorded AFTER its children, since a
+        thread's spans are recorded in the order they close, and the
+        children must name it."""
+        return f"s{next(self._ids)}"
+
     def record_span(self, kind: str, name: str = "", t0: float = 0.0,
-                    t1: float = 0.0, parent: str = "", **attrs) -> Span:
+                    t1: float = 0.0, parent: str = "", span_id: str = "",
+                    **attrs) -> Span:
         """Record an already-timed span retroactively (``t0``/``t1`` are
         ``perf_counter`` readings). For producers whose phases span
         threads — the serving batcher times a request's queue phase on
@@ -313,8 +392,8 @@ class Tracer:
         could not bracket that lifetime. For the same reason it is not
         mirrored into a profiler capture: the region is over, and not this
         thread's."""
-        s = Span(f"s{next(self._ids)}", parent, kind, name or kind,
-                 threading.get_ident(), attrs)
+        s = Span(span_id or f"s{next(self._ids)}", parent, kind,
+                 name or kind, threading.get_ident(), attrs)
         s.t0, s.t1 = t0, t1
         self._record(s)
         return s
@@ -338,6 +417,8 @@ class Tracer:
                 self._spans.popleft()
                 self._base += 1
                 self.dropped += 1
+            if s.kind != "instant" and s.kind != "counter":
+                self._add_total((s.kind, s.name), s.duration_s)
             if s.tid not in self._tid_names:
                 # _record always runs on the thread whose ident stamps the
                 # span (context-manager exit / instant / retroactive
@@ -356,7 +437,130 @@ class Tracer:
             except Exception:
                 pass  # a broken metrics bridge must not kill the step
 
+    def _add_total(self, key: Tuple[str, str], seconds: float) -> None:
+        # callers hold self._lock
+        t = self._totals.get(key)
+        if t is None:
+            if len(self._totals) >= MAX_TOTAL_NAMES:
+                key = (key[0], "*")
+            t = self._totals.setdefault(key, [0, 0.0, 0.0, 0.0])
+        if t[0] == 0:
+            t[2] = seconds
+        t[0] += 1
+        t[1] += seconds
+        if seconds > t[3]:
+            t[3] = seconds
+
+    # -- staging (jax.monitoring's events; see the module docstring) -----------
+    def _staging(self) -> _ThreadStaging:
+        st = getattr(self._local, "staging", None)
+        if st is None:
+            st = self._local.staging = _ThreadStaging()
+        return st
+
+    def staging_enter(self, step: str, fun: str) -> None:
+        """jax announced the start of ``step`` on this thread."""
+        st = self._staging()
+        if st.open:
+            st.nested += 1
+        else:
+            st.nested = 0
+            # a thread with no open span is not running the program
+            st.live = self.span("staging", step, fun=fun) \
+                if self._stack() else None
+            if st.live is not None:
+                st.live.__enter__()
+        st.open.append(step)
+        if step == "compile":
+            st.cache, st.retrieval_s = "off", None
+
+    def staging_cache(self, cache: Optional[str] = None,
+                      retrieval_s: Optional[float] = None) -> None:
+        """The persistent cache's word on the compile step in flight."""
+        st = self._staging()
+        if cache is not None:
+            st.cache = cache
+        if retrieval_s is not None:
+            st.retrieval_s = retrieval_s
+
+    def staging_exit(self, step: str, fun: str, wall_t0: float,
+                     wall_t1: float) -> None:
+        """jax reported the end of ``step`` with its wall-clock window."""
+        st = self._staging()
+        if step not in st.open:
+            # an exit whose entry this tracer never saw (it was installed
+            # mid-step): nothing was opened, nothing is recorded
+            return
+        # steps entered above this one lost their exits: they go with it
+        while st.open.pop() != step:
+            pass
+        live = st.live
+        if step == "compile" and live is not None:
+            # every executable built or loaded beneath the program's spans
+            # counts, folded into an outer step or not
+            for acc in st.accounts:
+                acc["programs"] += 1
+            if st.cache != "off":
+                answer = "cache_" + st.cache
+                for acc in st.accounts:
+                    acc[answer] += 1
+                with self._lock:
+                    self._add_total(("staging", answer), 0.0)
+        if st.open:
+            return
+        st.live = None
+        if live is None:
+            with self._lock:
+                self._add_total(("staging", "outside"), wall_t1 - wall_t0)
+            return
+        attrs = live.span.attrs
+        attrs["nested"] = st.nested
+        if step == "compile":
+            attrs["cache"] = st.cache
+            if st.cache == "hit" and st.retrieval_s is not None:
+                attrs["retrieval_s"] = st.retrieval_s
+        live.__exit__(None, None, None)
+        took = live.span.duration_s
+        for acc in st.accounts:
+            acc[step] += took
+            if took > acc["slowest_s"]:
+                acc["slowest_fun"], acc["slowest_s"] = fun, took
+
+    def open_staging_account(self) -> Dict[str, Any]:
+        """What this thread stages from here to the matching
+        :meth:`close_staging_account`, kept as the staging exits happen:
+        ``run_job`` reads it instead of scanning the ring."""
+        acc = {"programs": 0, "trace": 0.0, "lower": 0.0, "compile": 0.0,
+               "cache_hit": 0, "cache_miss": 0, "slowest_fun": "",
+               "slowest_s": 0.0}
+        self._staging().accounts.append(acc)
+        return acc
+
+    def close_staging_account(self, acc: Dict[str, Any]) -> None:
+        accounts = self._staging().accounts
+        # by identity: two fresh accounts are equal
+        accounts[:] = [a for a in accounts if a is not acc]
+        # one entry a job, staged or not: ``first_s`` is what the first
+        # job of the process staged, which no whole-process total says
+        with self._lock:
+            self._add_total(("staging", "job"),
+                            acc["trace"] + acc["lower"] + acc["compile"])
+
     # -- reading ---------------------------------------------------------------
+    def totals(self) -> Dict[str, Dict[str, float]]:
+        """``kind.name -> {n, seconds, first_s, max_s}`` over every span
+        this tracer closed since construction or :meth:`clear` (instants
+        and counter samples excluded), whether or not the ring still holds
+        it; plus ``staging.cache_hit`` / ``staging.cache_miss`` (``n``: the
+        persistent cache's answers beneath program spans),
+        ``staging.outside`` (staging on threads with no open span) and
+        ``staging.job`` (one entry a closed job: the seconds of the
+        ``staging`` spans beneath it, so ``first_s`` is the first job's)."""
+        with self._lock:
+            return {f"{kind}.{name}": {"n": t[0], "seconds": t[1],
+                                       "first_s": t[2], "max_s": t[3]}
+                    for (kind, name), t in self._totals.items()}
+
     def _window(self, since: int) -> List[Span]:
         # callers hold self._lock
         start = max(0, since - self._base)
@@ -397,6 +601,7 @@ class Tracer:
             self._base += len(self._spans)
             self._spans.clear()
             self.dropped = 0
+            self._totals = _new_totals()
 
     def profile_for(self, root_id: Optional[str] = None, since: int = 0):
         """A :class:`FitProfile` over the spans descending from ``root_id``
@@ -497,6 +702,51 @@ def current_span_id() -> str:
     if t is None:
         return ""
     return t.current_span_id()
+
+
+# -- jax.monitoring listeners (registered by CycloneContext, once a process) -----
+# Plain functions of jax's listener signatures; each reads the module global
+# once and returns when no tracer is installed. A warm call fires none.
+
+def on_staging_start(event: str, value: float = 0.0, **kw) -> None:
+    """Scalar listener: jax announces a staging step's start."""
+    t = _tracer
+    if t is None:
+        return
+    step = STAGING_STEPS.get(event)
+    if step is not None:
+        t.staging_enter(step, str(kw.get("fun_name", "")))
+
+
+def on_staging_span(event: str, start_time: float, end_time: float,
+                    **kw) -> None:
+    """Time-span listener: a staging step's end, with its wall window."""
+    t = _tracer
+    if t is None:
+        return
+    step = STAGING_STEPS.get(event)
+    if step is not None:
+        t.staging_exit(step, str(kw.get("fun_name", "")), start_time,
+                       end_time)
+
+
+def on_cache_event(event: str, **kw) -> None:
+    """Event listener: the persistent cache used, hit or missed."""
+    t = _tracer
+    if t is None:
+        return
+    cache = CACHE_EVENTS.get(event)
+    if cache is not None:
+        t.staging_cache(cache=cache)
+
+
+def on_cache_duration(event: str, duration_secs: float, **kw) -> None:
+    """Duration listener: what a persistent-cache hit took to retrieve."""
+    t = _tracer
+    if t is None:
+        return
+    if event == CACHE_RETRIEVAL_EVENT:
+        t.staging_cache(retrieval_s=float(duration_secs))
 
 
 def nbytes(tree: Any) -> int:

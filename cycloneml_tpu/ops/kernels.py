@@ -260,11 +260,9 @@ def _pad_rows_cols(x, y, w, row_tile: int):
                               fixed_bytes=8 * 4 * d_pad)
     n_pad = _pad_to(max(n, row_tile), row_tile)
     if n_pad != n or d_pad != d:
-        with jax.named_scope("glm.prepare_x"):
-            x = jnp.pad(x, ((0, n_pad - n), (0, d_pad - d)))
-        with jax.named_scope("glm.prepare_vectors"):
-            y = jnp.pad(y, (0, n_pad - n))
-            w = jnp.pad(w, (0, n_pad - n))
+        x = jnp.pad(x, ((0, n_pad - n), (0, d_pad - d)))
+        y = jnp.pad(y, (0, n_pad - n))
+        w = jnp.pad(w, (0, n_pad - n))
     return x, y, w, n_pad, d_pad, row_tile
 
 
@@ -283,8 +281,7 @@ def _glm_sums(x, y, w, beta, b0, ys, *, kind: str, d: int, row_tile: int,
         if feature_major else None
     if lane_tile is None:
         x, y, w, n_pad, d_pad, row_tile = _pad_rows_cols(x, y, w, row_tile)
-        with jax.named_scope("glm.prepare_vectors"):
-            beta_p = jnp.pad(beta, (0, d_pad - d)).reshape(1, d_pad)
+        beta_p = jnp.pad(beta, (0, d_pad - d)).reshape(1, d_pad)
         _note_sweep(kind, "row_major", row_tile=row_tile,
                     pad_cols=d_pad - d, tail_rows=n_pad - n)
         loss, grad_row, aux = _run_glm(
@@ -590,13 +587,12 @@ def _run_glm(x, y, w, beta_p, b0, ys, *, kind, tile, width, grid,
         vec_spec,
         pl.BlockSpec(col_shape, lambda i: (0, 0)),       # beta
     ]
-    with jax.named_scope("glm.prepare_vectors"):
-        # Mosaic rejects 1-D blocks (see glm_sweep). (n,) -> (n, 1) is a
-        # relayout pass over the vector into a lane-sparse column on the
-        # chip; (n,) -> (1, n) keeps it lane-dense
-        vec_shape = (1, -1) if feature_major else (-1, 1)
-        args = [b0.reshape(1, 1), ys.reshape(1, 1), x,
-                y.reshape(vec_shape), w.reshape(vec_shape), beta_p]
+    # Mosaic rejects 1-D blocks (see glm_sweep). (n,) -> (n, 1) is a
+    # relayout pass over the vector into a lane-sparse column on the
+    # chip; (n,) -> (1, n) keeps it lane-dense
+    vec_shape = (1, -1) if feature_major else (-1, 1)
+    args = [b0.reshape(1, 1), ys.reshape(1, 1), x,
+            y.reshape(vec_shape), w.reshape(vec_shape), beta_p]
     if has_scale:
         in_specs.append(pl.BlockSpec(col_shape, lambda i: (0, 0)))
         args.append(scale)
@@ -629,9 +625,7 @@ def _run_glm(x, y, w, beta_p, b0, ys, *, kind, tile, width, grid,
         compiler_params=_compiler_params("arbitrary"),
         interpret=interpret,
     )
-    with jax.named_scope("glm.sweep"):
-        outs = sweep(*args)
-    return outs[:3]
+    return sweep(*args)[:3]
 
 
 # -- fused multinomial (softmax) loss + gradient -------------------------------
@@ -737,12 +731,11 @@ def fused_multinomial_logistic_scaled(x, y, w, inv_std, scaled_mean, coef,
     scaled_mean = jnp.asarray(scaled_mean, f32)
     wmat = coef[: d * k].reshape(k, d)
     b = coef[d * k:] if fit_intercept else jnp.zeros((k,), f32)
-    with jax.named_scope("glm.prepare_vectors"):
-        bias = b - jnp.dot(wmat, scaled_mean,
-                           precision=jax.lax.Precision.HIGHEST)
-        scaled = jnp.pad(wmat * inv_std[None, :], ((0, kp - k), (0, 0)))
-        pieces = jnp.concatenate(_split3_rounded(scaled), axis=0)
-        bias = jnp.pad(bias, (0, kp - k)).reshape(kp, 1)
+    bias = b - jnp.dot(wmat, scaled_mean,
+                       precision=jax.lax.Precision.HIGHEST)
+    scaled = jnp.pad(wmat * inv_std[None, :], ((0, kp - k), (0, 0)))
+    pieces = jnp.concatenate(_split3_rounded(scaled), axis=0)
+    bias = jnp.pad(bias, (0, kp - k)).reshape(kp, 1)
     _note_sweep("multinomial",
                 "feature_major" if feature_major else "row_major",
                 classes=k, class_pad=kp, pieces=SOFTMAX_PIECES,
@@ -846,8 +839,7 @@ def _run_multinomial(x, y, w, pieces, bias, *, k, tile, feature_major,
         else pl.BlockSpec((tile, d), lambda i: (i, 0))
     vec_spec = pl.BlockSpec((1, tile), lambda i: (0, i))
     shapes = [(1, LANE), (kp, d), (kp, LANE), (1, LANE)]
-    with jax.named_scope("glm.prepare_vectors"):
-        args = (x, y.reshape(1, n), w.reshape(1, n), pieces, bias)
+    args = (x, y.reshape(1, n), w.reshape(1, n), pieces, bias)
     sweep = pl.pallas_call(
         glm_sweep_multinomial,
         name="glm_sweep_multinomial",
@@ -861,8 +853,7 @@ def _run_multinomial(x, y, w, pieces, bias, *, k, tile, feature_major,
         compiler_params=_compiler_params("arbitrary"),
         interpret=interpret,
     )
-    with jax.named_scope("glm.sweep"):
-        return sweep(*args)
+    return sweep(*args)
 
 
 # -- fused KMeans assignment ----------------------------------------------------

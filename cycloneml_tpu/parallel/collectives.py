@@ -400,10 +400,9 @@ def tree_aggregate(fn: Callable, runtime: MeshRuntime, *arrays,
         if not auto_psum:
             # fn performs its own collectives (e.g. pmax/pmin stats)
             return partial
-        with jax.named_scope("tree_aggregate.psum"):
-            return jax.tree_util.tree_map(
-                lambda t: psum_over_mesh(t, (DATA_AXIS, REPLICA_AXIS),
-                                         depth=depth), partial)
+        return jax.tree_util.tree_map(
+            lambda t: psum_over_mesh(t, (DATA_AXIS, REPLICA_AXIS),
+                                     depth=depth), partial)
 
     def program(*all_args):
         def local(*a):

@@ -655,18 +655,43 @@ def test_phase_spans_cover_the_fit(ctx, which):
 
 @pytest.mark.parametrize("which", ["logistic", "linreg_enet"])
 def test_fit_profile_phase_seconds_are_the_hosts_side(ctx, which):
-    _, job, spans, _ = _traced_fit(ctx, which)
+    """Held by the spans' own arithmetic, so that a loaded machine cannot
+    fail it (a wall-clock tolerance of 1 ms did, with six workers on the
+    cores): every instant of the job outside its dispatch spans lies in a
+    phase, but for the estimator's entry code — the part of the job span
+    that no ``fit.*`` phase covers."""
+    _, job, spans, by_id = _traced_fit(ctx, which)
     prof = FitProfile.from_spans(spans, root_id=job.span_id)
     assert set(prof.phase_seconds) == {
         "fit.stats", "fit.prepare", "fit.optimize", "fit.finish",
         "optim.iteration"}
     assert all(v >= 0.0 for v in prof.phase_seconds.values())
+    # the fit.* phases are the job's children, in order and disjoint ...
+    top = sorted((s for s in spans if s.kind == "phase"
+                  and by_id.get(s.parent_id) is job), key=lambda s: s.t0)
+    assert {s.name for s in top} == {"fit.stats", "fit.prepare",
+                                     "fit.optimize", "fit.finish"}
+    # (and the job's ONLY children: nothing the tracer times runs in the
+    # gaps between them, so the entry code is untimed host code alone)
+    assert [s for s in spans if by_id.get(s.parent_id) is job
+            and s.kind != "phase"] == []
+    at = job.t0
+    for s in top:
+        assert at <= s.t0 <= s.t1 <= job.t1, (s, at)
+        at = s.t1
+    # ... every dispatch lies in a phase, none inside another dispatch ...
+    dispatches = [s for s in spans if s.kind == "dispatch"]
+    assert len(dispatches) == prof.dispatch_count >= 1
+    for d in dispatches:
+        above = [a.kind for a in _ancestors(d, by_id)]
+        assert "phase" in above and "dispatch" not in above, (d, above)
+    # ... so the host's side of the job is the phases' self time plus what
+    # the job span holds before, between and after them: the entry code
+    entry_code = job.duration_s - sum(s.duration_s for s in top)
+    assert entry_code >= 0.0
     host = prof.wall_seconds - prof.dispatch_seconds
-    # what no phase covers is the estimator's entry code (0.2-0.3 ms): a
-    # twentieth of the elastic net's host side since its fit.prepare
-    # re-traces nothing (4 ms a fit here, 80 ms before)
     assert sum(prof.phase_seconds.values()) == pytest.approx(
-        host, rel=0.05, abs=1e-3)
+        host - entry_code, rel=1e-9, abs=1e-9)
     # fit.optimize's own time is what is left outside its turns
     assert prof.phase_seconds["fit.optimize"] < \
         0.5 * sum(s.duration_s for s in spans if s.name == "fit.optimize")
@@ -762,13 +787,11 @@ def test_aggregation_program_is_named_after_its_aggregator(ctx):
     text = call.compiled.__wrapped__.lower(
         *call.arrays(), *extras).as_text(debug_info=True)
     assert "jit_tree_aggregate__binary_logistic_scaled" in text
-    assert "tree_aggregate.psum" in text
     assert "jit_sharded" not in text and ".sharded" not in text
     moments = ds.tree_aggregate_fn(_get_moments_fn(), auto_psum=False)
     text = moments.compiled.__wrapped__.lower(
         *moments.arrays()).as_text(debug_info=True)
     assert "jit_tree_aggregate__summarizer_moments" in text
-    assert "summarizer.moments" in text
 
 
 @pytest.mark.parametrize("kind,name", [
@@ -776,8 +799,8 @@ def test_aggregation_program_is_named_after_its_aggregator(ctx):
     ("squared", "glm_sweep_least_squares")])
 def test_glm_kernel_and_scopes_are_named_in_the_tpu_lowering(kind, name):
     """Lowered FOR the TPU from here (no chip): the Mosaic call carries the
-    kernel's name, and the pad of X, the vector reshapes and the sweep sit
-    under their scopes."""
+    kernel's name. (The ``jax.named_scope``s this test once looked for are
+    gone: the v5e profiler keeps no stat that carries one, PERF.md §3.)"""
     import jax
     import jax.numpy as jnp
     from cycloneml_tpu.ml.optim import aggregators
@@ -793,8 +816,6 @@ def test_glm_kernel_and_scopes_are_named_in_the_tpu_lowering(kind, name):
     text = jax.jit(agg).trace(*args).lower(
         lowering_platforms=("tpu",)).as_text(debug_info=True)
     assert "tpu_custom_call" in text and name in text
-    for scope in ("glm.prepare_x", "glm.prepare_vectors", "glm.sweep"):
-        assert scope in text, scope
     assert "kern" not in text.replace("kernel", "")
 
 
@@ -821,9 +842,6 @@ def test_chunk_and_line_search_programs_are_named(ctx):
                        np.bool_(True), one, one, one, np.int32(2),
                        np.bool_(True)).as_text(debug_info=True)
     assert "jit_lbfgs_chunk" in text
-    for scope in ("lbfgs.direction", "lbfgs.line_search", "lbfgs.update",
-                  "tree_aggregate.psum"):
-        assert scope in text, scope
     search = _build_line_search(call.compiled, None, 1e-4, 0.9, 5, cdt)
     text = search.lower(*arrays, c, c, one, one, one, one).as_text()
     assert "jit_lbfgs_line_search" in text
@@ -913,3 +931,439 @@ def test_normal_solver_fit_is_a_traced_counted_fit(ctx):
     assert by_id[collective.parent_id] is dispatch
     assert collective.t1 <= readback.t0 + 1e-9
     assert readback.attrs["bytes"] >= 4 * 24 * 24
+
+
+# -- staging: what jax stages, under the program's spans -------------------------
+# All counts and structure: a CPU time says nothing of the chip's.
+
+@pytest.fixture
+def ring():
+    """The default tracer of a context: the flight ring. It harvests no
+    costs, so nothing is staged ahead of a program's first dispatch (a FULL
+    tracer's AOT analysis traces, lowers and compiles each new program once
+    more, before its ``compile`` span opens)."""
+    from cycloneml_tpu.observe.flight import FlightTracer
+    tracing.disable()
+    t = tracing.install_if_absent(FlightTracer(max_spans=50_000))
+    yield t
+    tracing.disable()
+
+
+def _staging(spans):
+    return [s for s in spans if s.kind == "staging"]
+
+
+def _under(s, root, by_id):
+    return s is root or root in _ancestors(s, by_id)
+
+
+def test_fresh_jit_under_a_span_yields_one_span_a_step(ctx, tracer):
+    """Also the alarm if a later jax stops emitting the events: the ``ctx``
+    fixture registered the listeners, the rest is jax's."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def inner(x):
+        return jnp.sin(x) + 1.0
+
+    def staged_here(x):
+        return inner(x) * 2.0 + jnp.cos(x).sum()
+
+    f = jax.jit(staged_here)
+    x = jnp.ones(8)
+    outside = tracer.totals()["staging.outside"]["n"]
+    jax.jit(lambda v: v * 3.5)(x)          # no span open: not the program's
+    assert tracer.totals()["staging.outside"]["n"] == outside + 3
+    assert not _staging(tracer.snapshot())
+
+    with tracer.span("dispatch", "fresh") as sp:
+        f(x).block_until_ready()
+    staged = _staging(tracer.snapshot())
+    assert [s.name for s in staged] == ["trace", "lower", "compile"]
+    assert all(s.parent_id == sp.span_id for s in staged)
+    trace, lower, compiled = staged
+    assert trace.attrs["fun"] == "staged_here"
+    assert "staged_here" in lower.attrs["fun"]
+    assert "staged_here" in compiled.attrs["fun"]
+    # inner, sin, add, multiply, cos, the sum, add: folded in, not recorded
+    assert trace.attrs["nested"] >= 6
+    assert lower.attrs["nested"] == compiled.attrs["nested"] == 0
+    assert compiled.attrs["cache"] in ("off", "hit", "miss")
+    assert trace.t0 <= trace.t1 <= lower.t0 <= lower.t1 <= compiled.t0
+    totals = tracer.totals()
+    for name in ("trace", "lower", "compile"):
+        assert totals[f"staging.{name}"]["n"] == 1
+    assert totals["staging.trace"]["seconds"] == trace.duration_s
+    with tracer.span("dispatch", "warm"):
+        f(x).block_until_ready()            # a warm call fires no listener
+    assert len(_staging(tracer.snapshot())) == 3
+
+
+def test_a_trace_that_raises_leaves_the_span_stack_as_it_was(ctx, tracer):
+    import jax
+    import jax.numpy as jnp
+
+    def refuses(x):
+        jnp.sin(x)
+        raise ValueError("not traceable")
+
+    x = jnp.ones(3)              # staged here, outside the program's spans
+    with tracer.span("job", "raises") as job:
+        before = list(tracer._stack())
+        with pytest.raises(ValueError, match="not traceable"):
+            jax.jit(refuses)(x)
+        assert tracer._stack() == before
+        assert not tracer._staging().open and tracer._staging().live is None
+        assert tracing.current_span_id() == job.span_id
+    assert tracer._stack() == []
+    failed, = _staging(tracer.snapshot())
+    assert (failed.name, failed.attrs["fun"]) == ("trace", "refuses")
+    assert failed.parent_id == job.span_id and failed.t1 >= failed.t0 > 0
+
+
+def test_an_exit_without_its_entry_is_ignored(tracer):
+    """A tracer installed mid-step sees the exit alone: nothing was opened,
+    so nothing is recorded, inside a span or outside, and the thread's
+    stack is untouched either way."""
+    import time
+    event = "/jax/core/compile/backend_compile_duration"
+    now = time.time()
+    tracing.on_staging_span(event, now - 0.5, now, fun_name="jit(late)")
+    with tracer.span("dispatch", "mid") as sp:
+        before = list(tracer._stack())
+        tracing.on_staging_span(event, now - 0.25, now, fun_name="jit(late)")
+        assert tracer._stack() == before
+        assert tracing.current_span_id() == sp.span_id
+        # under an announced step of another kind it is that step's already
+        tracing.on_staging_start("/jax/core/compile/jaxpr_trace_duration",
+                                 0.0, fun_name="outer")
+        tracing.on_staging_span(event, now - 0.25, now, fun_name="jit(late)")
+        assert tracer._staging().open == ["trace"]
+        tracing.on_staging_span("/jax/core/compile/jaxpr_trace_duration",
+                                now - 0.25, now, fun_name="outer")
+    outer, = _staging(tracer.snapshot())
+    assert (outer.name, outer.attrs) == ("trace", {"fun": "outer",
+                                                   "nested": 0})
+    assert tracer._stack() == [] and not tracer._staging().open
+    totals = tracer.totals()
+    assert totals["staging.outside"]["n"] == 0
+    assert "staging.compile" not in totals
+    # other events of jax's are not staging
+    tracing.on_staging_start("/jax/some/other_scalar", 1.0)
+    tracing.on_cache_event("/jax/compilation_cache/tasks_using_cache")
+    assert not tracer._staging().open
+
+
+def test_persistent_cache_answers_ride_the_compile_span(tracer):
+    """The listeners by hand, as jax calls them on a hit and on a miss."""
+    step = "/jax/core/compile/backend_compile_duration"
+
+    def compile_step(*cache_events, retrieval=None):
+        tracing.on_staging_start(step, 0.0, fun_name="jit(f)")
+        for e in cache_events:
+            tracing.on_cache_event("/jax/compilation_cache/" + e)
+        if retrieval is not None:
+            tracing.on_cache_duration(
+                "/jax/compilation_cache/cache_retrieval_time_sec", retrieval)
+        tracing.on_staging_span(step, 0.0, 1.0, fun_name="jit(f)")
+
+    with tracer.span("job", "fit"):
+        acc = tracer.open_staging_account()
+        compile_step("compile_requests_use_cache", "cache_hits",
+                     retrieval=0.125)
+        compile_step("compile_requests_use_cache", "cache_misses")
+        compile_step()
+        tracer.close_staging_account(acc)
+        compile_step("cache_misses")            # after the account closed
+    hit, miss, off, _ = _staging(tracer.snapshot())
+    assert hit.attrs == {"fun": "jit(f)", "nested": 0, "cache": "hit",
+                         "retrieval_s": 0.125}
+    assert miss.attrs == {"fun": "jit(f)", "nested": 0, "cache": "miss"}
+    assert off.attrs["cache"] == "off"
+    totals = tracer.totals()
+    assert totals["staging.cache_hit"]["n"] == 1
+    assert totals["staging.cache_miss"]["n"] == 2
+    assert totals["staging.compile"]["n"] == 4
+    assert (acc["programs"], acc["cache_hit"], acc["cache_miss"]) == (3, 1, 1)
+    assert acc["compile"] == sum(s.duration_s for s in (hit, miss, off))
+    assert acc["slowest_fun"] == "jit(f)"
+    # the account's seconds are the job's entry among the totals
+    assert totals["staging.job"] == {
+        "n": 1, "seconds": acc["compile"], "first_s": acc["compile"],
+        "max_s": acc["compile"]}
+    tracer.clear()
+    assert tracer.totals() == {
+        k: {"n": 0, "seconds": 0.0, "first_s": 0.0, "max_s": 0.0}
+        for k in ("staging.cache_hit", "staging.cache_miss",
+                  "staging.outside")}
+
+
+def test_totals_outlive_the_flight_ring(ctx):
+    """A private context beside the session's, started under a ring of eight
+    spans: after two fits the ring holds neither the context's start nor the
+    first fit; the totals hold both."""
+    from cycloneml_tpu import context as ctx_mod
+    from cycloneml_tpu.context import CycloneContext
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    from cycloneml_tpu.ml.regression import LinearRegression
+    from cycloneml_tpu.observe.flight import FlightTracer
+    tracing.disable()
+    ring = tracing.install_if_absent(FlightTracer(max_spans=8))
+    with ctx_mod._active_lock:
+        old = ctx_mod._active_context
+        ctx_mod._active_context = None
+    try:
+        private = CycloneContext(master="local-mesh[8]", app_name="ring")
+        try:
+            assert tracing.active() is ring
+            rng = np.random.RandomState(4)
+            x = rng.randn(512, 6)
+            ds = InstanceDataset.from_numpy(private, x, x @ rng.randn(6))
+            est = LinearRegression(regParam=0.01)
+            est.fit(ds)
+            est.fit(ds)
+        finally:
+            private.stop()
+    finally:
+        with ctx_mod._active_lock:
+            ctx_mod._active_context = old
+        tracing.disable()
+    assert ring.dropped > 0 and len(ring.snapshot()) == 8
+    assert not [s for s in ring.snapshot() if s.name == "context.start"]
+    totals = ring.totals()
+    start = totals["phase.context.start"]
+    assert start["n"] == 1 and start["seconds"] > 0.0
+    assert totals["phase.context.mesh"]["seconds"] \
+        + totals["phase.context.services"]["seconds"] <= start["seconds"]
+    job = totals["job.LinearRegression.fit"]
+    assert job["n"] == 2
+    # the first fit of the process: it paid the staging, the second did not
+    assert job["first_s"] == job["max_s"] > job["seconds"] - job["first_s"]
+    assert totals["staging.compile"]["n"] >= 1
+    # all of it beneath the first job: what the jobs staged is what the
+    # process staged, and the second job's share of it is nothing
+    staged = totals["staging.job"]
+    assert staged["n"] == 2 and staged["first_s"] == staged["seconds"] > 0.0
+    assert staged["seconds"] == pytest.approx(sum(
+        totals[f"staging.{step}"]["seconds"]
+        for step in ("trace", "lower", "compile")), rel=1e-9)
+    assert sum(t["n"] for t in totals.values()) > 8
+
+
+def _cell_case(ctx, which):
+    """The five cells' estimators (``BENCHMARK.json``) at a tiny shape."""
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    from cycloneml_tpu.ml.classification import LogisticRegression
+    from cycloneml_tpu.ml.regression import (GeneralizedLinearRegression,
+                                             LinearRegression)
+    rng = np.random.RandomState(11)
+    # a shape of its own each: jax keeps lowered and compiled programs by
+    # their jaxpr, and a shape another test has used would find them there
+    n, d = 1048, 13 + ["lr_epsilon", "linreg_enet", "linreg_ridge_normal",
+                       "glr_binomial", "lr_multinomial",
+                       "capture"].index(which)
+    x = rng.randn(n, d)
+    margin = x @ rng.randn(d)
+    if which == "lr_epsilon":
+        y, est = (margin + 0.5 * rng.randn(n) > 0).astype(np.float64), \
+            LogisticRegression(maxIter=100, regParam=0.01)
+    elif which == "linreg_enet":
+        y, est = margin + 0.5 * rng.randn(n), \
+            LinearRegression(regParam=0.01, elasticNetParam=0.5)
+    elif which in ("linreg_ridge_normal", "capture"):
+        y, est = margin + 0.5 * rng.randn(n), LinearRegression(regParam=0.01)
+    elif which == "glr_binomial":
+        y, est = (margin + 0.5 * rng.randn(n) > 0).astype(np.float64), \
+            GeneralizedLinearRegression(family="binomial")
+    else:
+        scores = x @ rng.randn(d, 4) + 0.5 * rng.randn(n, 4)
+        y, est = np.argmax(scores, axis=1).astype(np.float64), \
+            LogisticRegression(maxIter=100, regParam=0.01)
+    return InstanceDataset.from_numpy(ctx, x, y), est
+
+
+@pytest.mark.parametrize("which", [
+    "lr_epsilon", "linreg_enet", "linreg_ridge_normal", "glr_binomial",
+    "lr_multinomial"])
+def test_first_fit_stages_beneath_its_compile_spans_second_fit_nothing(
+        ctx, ring, which):
+    from cycloneml_tpu.parallel import collectives
+    ds, est = _cell_case(ctx, which)
+    collectives.clear_program_cache()
+    est.fit(ds)
+    first = ring.snapshot()
+    ring.clear()
+    est.fit(ds)
+    second = ring.snapshot()
+    totals = ring.totals()
+    by_id = {s.span_id: s for s in first}
+    job, = [s for s in first if s.kind == "job"]
+    compiles = [s for s in first if s.kind == "compile"]
+    assert compiles
+    for c in compiles:
+        steps = [s.name for s in _staging(first) if c in _ancestors(s, by_id)]
+        # the first dispatch of a new program object traces, lowers and
+        # compiles it (and whatever eager operation it meets first)
+        assert {"trace", "lower", "compile"} <= set(steps), (c, steps)
+    # staging is the program's only beneath the program's spans
+    assert all(_under(s, job, by_id) and s.attrs["fun"]
+               for s in _staging(first))
+    # a warm fit stages nothing: a staging span here would be a re-trace,
+    # named by its function
+    assert [(s.name, s.attrs["fun"]) for s in _staging(second)] == []
+    assert not [s for s in second if s.kind == "compile"]
+    assert not [k for k in totals if k.startswith("staging.")
+                and k != "staging.job" and totals[k]["n"]]
+    assert (totals["staging.job"]["n"], totals["staging.job"]["seconds"]) \
+        == (1, 0.0)
+
+
+def test_staging_spans_lie_inside_their_compile_event_in_a_capture(
+        ctx, ring, tmp_path):
+    """Live spans: a cold fit's ``cyclone.staging.*`` events sit inside a
+    ``cyclone.compile.*`` event on the profiler's clock; a warm fit's
+    capture holds none."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    from cycloneml_tpu.parallel import collectives
+    ds, est = _cell_case(ctx, "capture")
+    collectives.clear_program_cache()
+    ring.annotation = jax.profiler.TraceAnnotation
+
+    def captured(where):
+        with ctx.profile(str(where)):
+            est.fit(ds)
+        capture, = glob.glob(str(where / "plugins" / "profile" / "*"
+                                 / "*.xplane.pb"))
+        return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                for plane in ProfileData.from_file(capture).planes
+                if plane.name.startswith("/host:")
+                for line in plane.lines for ev in line.events
+                if ev.name.startswith("cyclone.")]
+
+    cold = captured(tmp_path / "cold")
+    compiles = [(s, e) for name, s, e in cold
+                if name.startswith("cyclone.compile.")]
+    staged = [(name, s, e) for name, s, e in cold
+              if name.startswith("cyclone.staging.")]
+    assert compiles and {name for name, _, _ in staged} == {
+        "cyclone.staging.trace", "cyclone.staging.lower",
+        "cyclone.staging.compile"}
+    inside = [ev for ev in staged
+              if any(s <= ev[1] and ev[2] <= e for s, e in compiles)]
+    assert {name for name, _, _ in inside} == {name for name, _, _ in staged}
+    warm = captured(tmp_path / "warm")
+    assert warm and not [name for name, _, _ in warm
+                         if name.startswith(("cyclone.staging.",
+                                             "cyclone.compile."))]
+
+
+def test_a_fresh_lambda_per_fit_shows_as_staging_under_fit_prepare(ctx, ring):
+    """PR 32's bug in its direct form: the program cache keys on the
+    aggregator's function object, so a ``lambda`` built per fit is traced,
+    lowered and compiled per fit — and the spans name it. A module-level
+    aggregator is staged once."""
+    import jax.numpy as jnp
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    rng = np.random.RandomState(6)
+    ds = InstanceDataset.from_numpy(ctx, rng.randn(128, 3), rng.randn(128))
+
+    def fit(aggregator):
+        with tracing.span("job", "Sketch.fit") as job:
+            with tracing.span("phase", "fit.prepare"):
+                ds.tree_aggregate_fn(aggregator or (
+                    lambda x, y, w: {"wy": jnp.sum(w * y)}))()
+        return job.span_id
+
+    def staged_under_prepare(job_id):
+        spans = ring.snapshot()
+        by_id = {s.span_id: s for s in spans}
+        prepare, = [s for s in spans if s.name == "fit.prepare"
+                    and s.parent_id == job_id]
+        return [(s.name, s.attrs["fun"]) for s in _staging(spans)
+                if prepare in _ancestors(s, by_id)]
+
+    fit(None)
+    second = staged_under_prepare(fit(None))
+    assert [name for name, _ in second] == ["trace", "lower", "compile"]
+    assert all("lambda" in fun for _, fun in second), second
+
+    def label_moment(x, y, w):
+        return {"wy": jnp.sum(w * y)}
+    assert staged_under_prepare(fit(label_moment))
+    assert staged_under_prepare(fit(label_moment)) == []
+
+
+def test_a_two_chunk_device_lbfgs_fit_compiles_its_chunk_twice(ctx, ring):
+    """PERF.md §7, found by hand with ``jax_log_compiles`` in PR 34: the
+    first turn's operands are uncommitted host values, later turns pass the
+    program's own committed outputs, so jit stages ``lbfgs_chunk`` again
+    beneath the SAME program object (one ``compile`` span, the program's
+    bracket, sees none of it). Pinned as found: when this reads 1 the
+    second compile is gone — correct PERF.md §7."""
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    from cycloneml_tpu.ml.optim import aggregators
+    from cycloneml_tpu.ml.optim.device_lbfgs import DeviceLBFGS
+    from cycloneml_tpu.ml.optim.loss import DistributedLossFunction
+    rng = np.random.RandomState(8)
+    x = rng.randn(512, 5)
+    y = (x @ rng.randn(5) + rng.randn(512) > 0).astype(np.float64)
+    f = DistributedLossFunction(
+        InstanceDataset.from_numpy(ctx, x, y),
+        aggregators.binary_logistic(5, fit_intercept=False))
+    with tracing.span("job", "two_chunks"):
+        states = list(DeviceLBFGS(max_iter=4, chunk=2, tol=0.0)
+                      .iterations(f, np.zeros(5)))
+    assert states[-1].iteration == 4
+    spans = ring.snapshot()
+    chunks = [s for s in spans if (s.kind, s.name) == ("dispatch",
+                                                       "lbfgs.chunk")]
+    assert len(chunks) == 2
+    assert len([s for s in spans if (s.kind, s.name) == (
+        "compile", "lbfgs.chunk")]) == 1
+    compiled = [s for s in _staging(spans) if s.name == "compile"
+                and "lbfgs_chunk" in s.attrs["fun"]]
+    assert len(compiled) == 2
+    by_id = {s.span_id: s for s in spans}
+    assert [chunks.index(next(a for a in _ancestors(s, by_id)
+                              if a in chunks)) for s in compiled] == [0, 1]
+
+
+def test_run_job_logs_one_line_and_the_profile_carries_its_numbers(
+        ctx, caplog):
+    import logging
+    from cycloneml_tpu.parallel import collectives
+    ds, est = _cell_case(ctx, "linreg_ridge_normal")
+    collectives.clear_program_cache()
+    profiles = []
+    listener = (lambda e: profiles.append(e.profile)
+                if type(e).__name__ == "FitProfileCompleted" else None)
+    ctx.listener_bus.add_listener(listener)
+    tracing.disable()
+    tracing.enable(max_spans=50_000)
+    try:
+        with caplog.at_level(logging.INFO, logger="cycloneml_tpu.context"):
+            est.fit(ds)
+            est.fit(ds)
+        ctx.listener_bus.wait_until_empty()
+    finally:
+        tracing.disable()
+        ctx.listener_bus.remove_listener(listener)
+    lines = [r.getMessage() for r in caplog.records if " staged " in
+             r.getMessage()]
+    assert len(lines) == 1, lines           # the warm fit staged nothing
+    cold, warm = [FitProfile.from_dict(p) for p in profiles[-2:]]
+    assert cold.staged_programs >= 1
+    assert f"staged {cold.staged_programs} programs" in lines[0]
+    assert cold.staging_slowest_fun and cold.staging_slowest_fun in lines[0]
+    assert set(cold.staging_seconds) == {"trace", "lower", "compile"}
+    assert all(v > 0.0 for v in cold.staging_seconds.values())
+    assert cold.staging_cache_hits + cold.staging_cache_misses \
+        <= cold.staged_programs
+    assert sum(cold.staging_seconds.values()) <= cold.wall_seconds
+    assert (warm.staged_programs, warm.staging_slowest_fun) == (0, "")
+    assert not any(warm.staging_seconds.values())
+    assert FitProfile.from_dict(cold.to_dict()) == cold
