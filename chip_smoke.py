@@ -6,7 +6,7 @@ environment. It drives the main path through the public entry points —
 ``CycloneContext`` (default ``master="tpu"``), ``generate_classification``,
 ``LogisticRegression.fit`` — at full width, then a host-fed numpy → ``MLFrame``
 → ``fit`` leg, a small binomial ``GeneralizedLinearRegression`` fit (IRLS over
-the weighted branch of the moment Gramian), a small ten-class
+both forms of the moment Gramian: one MXU pass, then three), a small ten-class
 ``LogisticRegression`` fit (the fused multinomial sweep under the
 device-resident L-BFGS), then compiles and checks every
 Pallas kernel natively at small n. Every leg asserts WHICH path ran (platform, data dtype, Mosaic custom call,
@@ -280,9 +280,11 @@ def host_leg(ctx, n: int, d: int, devices) -> dict:
 
 def glr_leg(ctx, n: int, d: int, devices) -> dict:
     """Leg 3: a binomial ``GeneralizedLinearRegression`` fit from a
-    device-resident dataset — IRLS, whose working weights are no 0/1 mask,
-    so every pass takes the WEIGHTED branch of the moment Gramian on the
-    chip — against a float64 Newton iteration over the same stored values."""
+    device-resident dataset — IRLS: the first pass's working weights hold
+    one value (``mu0 (1 - mu0)``) and take ONE MXU pass of the moment
+    Gramian, every later pass its three-piece form, and the fit's
+    ``mxu_passes`` must say so — against a float64 Newton iteration over
+    the same stored values."""
     import jax.numpy as jnp
     from cycloneml_tpu.dataset.random import generate_classification
     from cycloneml_tpu.ml.regression import GeneralizedLinearRegression
@@ -304,6 +306,8 @@ def glr_leg(ctx, n: int, d: int, devices) -> dict:
           and s.total_dispatches == s.num_iterations + 1,
           f"glr leg: {s.num_iterations} iterations, {s.total_passes} passes, "
           f"{s.total_dispatches} dispatches")
+    check(s.mxu_passes == [1] + [3] * (s.num_iterations - 1),
+          f"glr leg: MXU passes of the Gramians {s.mxu_passes}")
     call = ds.tree_aggregate_fn(glm.irls_aggregator(
         glm.Binomial(), glm.Logit(), kernels.stored_feature_major(ds.x),
         False))
@@ -337,7 +341,8 @@ def glr_leg(ctx, n: int, d: int, devices) -> dict:
             "feature_major" if kernels.stored_feature_major(ds.x)
             else "row_major",
             "iterations": s.num_iterations, "passes": s.total_passes,
-            "dispatches": s.total_dispatches, "deviance": s.deviance,
+            "dispatches": s.total_dispatches, "mxu_passes": s.mxu_passes,
+            "deviance": s.deviance,
             "coef_gap_vs_f64": gap, "se_gap_vs_f64": se_gap,
             "cold_fit_s": round(cold_s, 3)}
 
@@ -506,15 +511,18 @@ def kernel_matrix() -> dict:
             if tier == "bfloat16":
                 # the moment Gramian claims the bf16 tier only (every
                 # other storage takes XLA's contraction): row-major tile
-                # at this width, one MXU pass under a presence mask and
-                # three under weights that are not 0/1
-                for kind, wm in (("mask", (rng.random(n) > 0.2)),
-                                 ("weights", 0.5 + rng.random(n))):
+                # at this width, one MXU pass under a presence mask or
+                # any ONE live value, three under weights that hold more
+                mask = rng.random(n) > 0.2
+                for kind, wm, passes in (
+                        ("mask", mask, 1.0), ("one_value", 0.1875 * mask, 1.0),
+                        ("weights", 0.5 + rng.random(n), 3.0)):
                     wm = wm.astype(np.float32)
                     record(f"moment_gramian/{kind}/n={n}",
                            lambda x, wm=wm: kernels.moment_sums(
                                x, yr, wm, feature_major=False),
-                           (xs,), moment_reference(xv, yr, wm))
+                           (xs,), dict(moment_reference(xv, yr, wm),
+                                       mxu_passes=passes))
 
     # the feature-major tiling: arrays XLA:TPU stores with the rows on the
     # lanes (a width that is no multiple of 128, and enough rows that
@@ -561,7 +569,8 @@ def kernel_matrix() -> dict:
                 record(f"moment_gramian_feature_major/n={n},d={d_odd}",
                        lambda x: kernels.moment_sums(
                            x, yr, w, feature_major=True),
-                       (xs,), moment_reference(xv, yr, w))
+                       (xs,), dict(moment_reference(xv, yr, w),
+                                   mxu_passes=3.0))
 
     # stacked fits vmap the GLM kernel (labels on axis 1 of an (n, K) bf16
     # stack, coefficients on axis 0)

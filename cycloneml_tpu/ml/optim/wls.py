@@ -187,15 +187,20 @@ class WeightedLeastSquares:
                 f"WeightedLeastSquares supports at most {MAX_NUM_FEATURES} "
                 f"features, got {d}")
         if y is None:
-            from cycloneml_tpu.ops.kernels import stored_feature_major
+            from cycloneml_tpu.ops.kernels import (mean_mxu_passes,
+                                                   stored_feature_major)
             ds = x
             call = ds.tree_aggregate_fn(
                 moments_aggregator(stored_feature_major(ds.x)))
-            with tracing.span("dispatch", "wls.moments", passes=1):
+            with tracing.span("dispatch", "wls.moments", passes=1) as dsp:
                 out_dev = call()            # 'collective' span inside
                 with tracing.span("transfer", "wls.readback") as tsp:
                     out = jax.device_get(out_dev)
                     tsp.annotate_bytes(out)
+                # which form of the Gramian the weights took: chosen on
+                # the device, so known with the moments (None: XLA's)
+                dsp.annotate(mxu_passes=mean_mxu_passes(
+                    out, ds.ctx.mesh_runtime.data_parallelism))
             if hasattr(ds.ctx, "record_step"):
                 ds.ctx.record_step({"wls_passes": 1.0})
         else:

@@ -572,7 +572,8 @@ class GeneralizedLinearRegression(Predictor, _GLRParams, MLWritable, MLReadable)
         import jax
         import jax.numpy as jnp
         from cycloneml_tpu.ml.optim.wls import AUTO, WeightedLeastSquares
-        from cycloneml_tpu.ops.kernels import stored_feature_major
+        from cycloneml_tpu.ops.kernels import (mean_mxu_passes,
+                                               stored_feature_major)
         from cycloneml_tpu.parallel import collectives
 
         with tracing.span("phase", "fit.prepare"):
@@ -600,6 +601,7 @@ class GeneralizedLinearRegression(Predictor, _GLRParams, MLWritable, MLReadable)
                 standardize_label=False, solver_type=AUTO)
 
         n_dispatches = 0
+        mxu_passes = []
 
         def dispatch(program, name, coef, icpt, first):
             """One launch and its one readback; ``coef``, the intercept
@@ -607,11 +609,18 @@ class GeneralizedLinearRegression(Predictor, _GLRParams, MLWritable, MLReadable)
             nonlocal n_dispatches
             n_dispatches += 1
             params = jnp.asarray(np.concatenate([coef, [icpt, first]]))
-            with tracing.span("dispatch", f"irls.{name}", passes=1):
+            with tracing.span("dispatch", f"irls.{name}", passes=1) as dsp:
                 out_dev = program(*rows, params)    # 'collective' inside
                 with tracing.span("transfer", "irls.readback") as tsp:
                     out = jax.device_get(out_dev)
                     tsp.annotate_bytes(out)
+                if name == "pass":
+                    # which form of the Gramian this pass's working
+                    # weights took: chosen on the device, so known now
+                    # (None: XLA's contraction)
+                    mxu_passes.append(
+                        mean_mxu_passes(out, rt.data_parallelism))
+                    dsp.annotate(mxu_passes=mxu_passes[-1])
             if hasattr(ds.ctx, "record_step"):
                 ds.ctx.record_step({"irls_passes": 1.0})
             return out
@@ -648,18 +657,24 @@ class GeneralizedLinearRegression(Predictor, _GLRParams, MLWritable, MLReadable)
             model.summary = GLMTrainingSummary(
                 model, ds, offset, fam, link, wm, fit_icpt,
                 deviance=float(last["dev"]), pearson=float(last["pearson"]),
-                deviance_history=history, total_dispatches=n_dispatches)
+                deviance_history=history, total_dispatches=n_dispatches,
+                mxu_passes=mxu_passes)
             return model
 
 
 # -- the device programs of a fit ---------------------------------------------
 
-def _working_point(fam: Family, link: Link, eta, y, w, ofs):
+def _working_point(fam: Family, link: Link, eta, y, w, ofs, mu=None):
     """``(mu, z, omega)`` at the linear predictor ``eta`` (offset
     included): the mean, the working response (offset taken out again) and
-    the working weight of every row (ref FamilyAndLink.reweightFunc)."""
+    the working weight of every row (ref FamilyAndLink.reweightFunc).
+    ``mu`` is the mean where the caller HAS it (the starting point: the
+    family's ``mu0``, of which ``eta`` is the link) — ``unlink(link(mu0))``
+    is the identity on paper and an ulp or more off in float32, which
+    gives the rows of one ``mu0`` several working weights."""
     import jax.numpy as jnp
-    mu = fam.clean_mu(link.unlink(eta))
+    if mu is None:
+        mu = fam.clean_mu(link.unlink(eta))
     g = link.deriv(mu)
     z = (eta - ofs) + (y - mu) * g
     omega = w / jnp.maximum(g * g * fam.variance(mu), _EPS)
@@ -668,11 +683,16 @@ def _working_point(fam: Family, link: Link, eta, y, w, ofs):
     return mu, jnp.where(omega > 0, z, 0.0), omega
 
 
-def _starting_eta(fam: Family, link: Link, y, w):
-    """The linear predictor IRLS starts from: the link of the family's
-    ``mu0`` (ref FamilyAndLink.initialize)."""
+def _starting_mu(fam: Family, y, w):
+    """The mean IRLS starts from: the family's ``mu0`` (ref
+    FamilyAndLink.initialize; R's ``mustart``)."""
     import jax.numpy as jnp
-    return link.link(fam.clean_mu(fam.initialize(y, jnp.maximum(w, _EPS))))
+    return fam.clean_mu(fam.initialize(y, jnp.maximum(w, _EPS)))
+
+
+def _starting_eta(fam: Family, link: Link, y, w):
+    """The linear predictor IRLS starts from: the link of ``mu0``."""
+    return link.link(_starting_mu(fam, y, w))
 
 
 def _margins(x, params, ofs, dtype):
@@ -701,10 +721,17 @@ def irls_aggregator(fam: Family, link: Link, feature_major: bool = False,
         ofs = rest[0] if has_offset else 0.0
         params = rest[-1]
 
-        eta = jax.lax.cond(params[-1] > 0,
-                           lambda: _starting_eta(fam, link, y, w),
-                           lambda: _margins(x, params, ofs, y.dtype))
-        mu, z, omega = _working_point(fam, link, eta, y, w, ofs)
+        def start():
+            # the working point AT mu0: a binomial-logit fit of 0/1 labels
+            # and equal weights then has ONE working weight, which the
+            # moment pass observes (one MXU pass for three)
+            mu = _starting_mu(fam, y, w)
+            return _working_point(fam, link, link.link(mu), y, w, ofs, mu)
+
+        mu, z, omega = jax.lax.cond(
+            params[-1] > 0, start,
+            lambda: _working_point(
+                fam, link, _margins(x, params, ofs, y.dtype), y, w, ofs))
         out = dict(moment_sums(x, z, omega, feature_major=feature_major))
         out["dev"] = fam.deviance(y, mu, w)
         return out
@@ -865,11 +892,14 @@ class GLMTrainingSummary:
 
     ``total_passes`` counts weighted-Gramian passes over X (one an
     iteration), ``total_dispatches`` every launch of the fit (the passes
-    and the deviance pass)."""
+    and the deviance pass), ``mxu_passes`` the MXU passes each of those
+    Gramians took for its working weights (1: one live value, 3: more;
+    None where XLA's contraction ran — ``kernels.moment_sums``)."""
 
     def __init__(self, model, ds, offset, fam: Family, link: Link,
                  wls_model, fit_intercept: bool, *, deviance: float,
-                 pearson: float, deviance_history, total_dispatches: int):
+                 pearson: float, deviance_history, total_dispatches: int,
+                 mxu_passes=()):
         self._model, self._ds, self._offset = model, ds, offset
         self._fam, self._link, self._wls_model = fam, link, wls_model
         self._fit_intercept = fit_intercept
@@ -878,6 +908,7 @@ class GLMTrainingSummary:
         self.deviance_history = list(deviance_history)
         self.num_iterations = self.total_passes = len(self.deviance_history)
         self.total_dispatches = total_dispatches
+        self.mxu_passes = list(mxu_passes)
         n = ds.n_rows
         self.rank = ds.n_features + (1 if fit_intercept else 0)
         self.degrees_of_freedom = n - 1 if fit_intercept else n

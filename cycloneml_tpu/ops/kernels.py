@@ -956,6 +956,15 @@ GRAM_BLOCK = 128
 #: 128 MiB a v5e core has
 GRAM_VMEM_LIMIT_BYTES = 100 << 20
 _GRAM_VMEM_BUDGET = 88 << 20
+#: lane tile of the three-piece form. The three bf16 pieces of a 128-row
+#: block are 48 vector registers at 256 lanes and stay in the file of 64
+#: while the block's products read them; at 1,024 lanes they are 192,
+#: spilled and filled around every product, and the MXU waits. Measured on
+#: the v5e at 2,000,000 x 2,000 (PERF.md §6, PR 38): 161.7 ms a pass at
+#: 1,024, 146.4 at 512, 140.8 at 256 = three times the mask form's 46.9.
+#: The mask form has one piece a block and keeps its 1,024 (48.0 ms; 48.5
+#: at 512, 58.2 at 2,048).
+GRAM_WEIGHTED_TILE = 256
 
 _NT = (((1,), (1,)), ((), ()))     # contract the lanes of two (rows, T) tiles
 
@@ -1053,7 +1062,9 @@ def fused_moment_gramian(x, y, w, *, feature_major: bool, lane_tile: int,
     (rows with w = 0, and the lanes of the last tile past n, are selected
     out of the tile): one MXU pass. With ``weighted=True`` the left
     operand is ``Z·w`` in f32, split into three bf16 pieces per VMEM
-    block: three passes, f32-faithful, still no copy of X.
+    block: three passes, f32-faithful, still no copy of X
+    (:func:`moment_sums` picks the form from the weights it is handed, and
+    gives this one a lane tile of ``GRAM_WEIGHTED_TILE``).
 
     Accumulation: one tile's products are summed by the MXU in f32
     (T ≤ 1,024 terms an entry), the tiles are added with Kahan
@@ -1144,15 +1155,24 @@ def moment_sums(x, y, w, *, feature_major: bool = False,
     Which form runs is read off the array, not off a conf key: where
     :func:`moment_gramian_tile` finds a tile (bfloat16 X on a backend
     that lowers Mosaic) it is :func:`fused_moment_gramian`, and INSIDE the
-    program the weights pick its pass count — all 0/1 (a presence mask:
-    every default-weight fit) one MXU pass, anything else three. Every
-    other X (the host platform, f32/f64/fp8 storage, widths the kernel's
-    tile or VMEM cannot take) is XLA's own contraction at ``highest``
-    with the weight folded into one operand: on the TPU an operand fusion
-    of the convolution, so no weighted or widened copy of X there either
-    (sandbox AOT at 2,000,000 x 2,000: 0 B of temporaries). ``feature_
-    major`` is the caller's observation (:func:`stored_feature_major`)
-    and picks the kernel's tiling, never the result."""
+    program the weights pick its pass count. A shard whose weights hold
+    ONE live value ``c = max(w)`` — every weight 0 or c, c finite: a
+    presence mask (c = 1, every default-weight fit), a constant weight
+    column, the first IRLS pass of a binomial-logit fit — takes ONE MXU
+    pass under the mask ``w > 0`` and scales the block by c (``Σ c z_i z_j
+    = c Σ z_i z_j``: one rounding an entry on top of the mask form's
+    sums, none at c = 1); a second live value, a NaN or an infinite
+    weight takes three. The count a shard took rides home with its
+    moments as ``mxu_passes`` (1.0 or 3.0: the psum over shards adds
+    them, :func:`mean_mxu_passes` divides). Every other X (the host
+    platform, f32/f64/fp8 storage, widths the kernel's tile or VMEM
+    cannot take) is XLA's own contraction at ``highest`` with the weight
+    folded into one operand — no ``mxu_passes`` there: on the TPU an
+    operand fusion of the convolution, so no weighted or widened copy of
+    X there either (sandbox AOT at 2,000,000 x 2,000: 0 B of
+    temporaries). ``feature_major`` is the caller's observation
+    (:func:`stored_feature_major`) and picks the kernel's tiling, never
+    the result."""
     from cycloneml_tpu.observe import tracing
     n, d = x.shape
     tile = moment_gramian_tile(n, d, x.dtype, feature_major) \
@@ -1176,25 +1196,48 @@ def moment_sums(x, y, w, *, feature_major: bool = False,
                                   preferred_element_type=acc),
                 "aa_sum": jnp.einsum("bi,bj->ij", xw, x, precision=hi,
                                      preferred_element_type=acc)}
+    tile_weighted = min(tile, GRAM_WEIGHTED_TILE)
     tracing.instant(
         "kernel.wls_moments",
         orientation="feature_major" if feature_major else "row_major",
-        lane_tile=tile, block=GRAM_BLOCK, mxu_passes=1,
-        mxu_passes_weighted=3, pad_cols=0, tail_rows=n % tile)
+        lane_tile=tile, lane_tile_weighted=tile_weighted, block=GRAM_BLOCK,
+        mxu_passes="one_value:1|else:3", pad_cols=0, tail_rows=n % tile)
     w = jnp.asarray(w, jnp.float32)
 
     def run(weighted):
         return lambda: fused_moment_gramian(
-            x, y, w, feature_major=feature_major, lane_tile=tile,
-            weighted=weighted, interpret=interpret)
+            x, y, w, feature_major=feature_major, weighted=weighted,
+            lane_tile=tile_weighted if weighted else tile,
+            interpret=interpret)
 
-    upper = jax.lax.cond(jnp.all((w == 0) | (w == 1)), run(False), run(True))
+    # c >= 0 and not c > 0: a shard of padding alone (c = 0) is a mask
+    c = jnp.max(w)
+    one_value = jnp.isfinite(c) & (c >= 0) & jnp.all((w == 0) | (w == c))
+    upper = jax.lax.cond(one_value, run(False), run(True))
     k = MOMENT_ROWS
     y_rows, gram = upper[1:4], upper[k:, k:]
-    return {"w_sum": upper[0, 0], "b_sum": jnp.sum(upper[0, 1:4]),
+    sums = {"w_sum": upper[0, 0], "b_sum": jnp.sum(upper[0, 1:4]),
             # (y_hi + y_mid + y_lo)^2 from the pieces' upper triangle
             "bb_sum": jnp.sum(jnp.triu(y_rows[:, 1:4])
                               + jnp.triu(y_rows[:, 1:4], 1)),
             "a_sum": upper[0, k:], "ab_sum": jnp.sum(y_rows[:, k:], axis=0),
             # the lower half is the upper's mirror: symmetric to the bit
             "aa_sum": jnp.triu(gram) + jnp.triu(gram, 1).T}
+    # the mask form's sums times c, AFTER the unpacking (a multiply of the
+    # cond's own output made XLA keep the kernel's 16 MB block in VMEM and
+    # the kernel 1 % slower: PERF.md §6, PR 38); 1.0 · x is x to the bit
+    scale = jnp.where(one_value, c, 1.0)
+    return {"mxu_passes": jnp.where(one_value, 1.0, 3.0),
+            **{name: scale * v for name, v in sums.items()}}
+
+
+def mean_mxu_passes(moments, shards: int):
+    """MXU passes a shard's Gramian took, from the psum'd ``mxu_passes``
+    of :func:`moment_sums` over ``shards`` row shards: 1 or 3 where they
+    agree, their mean where they do not, None where XLA's contraction ran
+    (no such count)."""
+    total = moments.get("mxu_passes")
+    if total is None:
+        return None
+    mean = float(total) / shards
+    return int(mean) if mean.is_integer() else mean

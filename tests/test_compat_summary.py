@@ -33,7 +33,9 @@ def test_spark_session_builder(ctx):
     assert getActiveSession() is spark
 
 
-def test_compat_functions_and_window():
+def test_compat_functions_and_window(ctx):
+    # ``ctx``: getOrCreate reuses the active context; with none (this test
+    # first on its xdist worker) it would build the default master, ``tpu``
     from cycloneml_tpu.compat import SparkSession, Window, col, functions as F
     spark = SparkSession.builder.getOrCreate()
     df = spark.createDataFrame({"k": ["a", "a", "b"], "v": [1.0, 2.0, 3.0]})

@@ -86,6 +86,30 @@ def _assert_equals_reference(model, ref):
                                rtol=SE_RTOL)
 
 
+import contextlib
+
+
+@contextlib.contextmanager
+def _moments_on_the_interpreter(monkeypatch, feature_major, tile=384):
+    """The moment pass on the Pallas kernel: the test routes its
+    pallas_call through the interpreter and says what the chip would
+    (Mosaic lowers, X is stored this way, a tile of ``tile`` rows)."""
+    from cycloneml_tpu.parallel import collectives
+    native_call = kernels.pl.pallas_call
+    monkeypatch.setattr(
+        kernels.pl, "pallas_call",
+        lambda *a, **kw: native_call(*a, **{**kw, "interpret": True}))
+    monkeypatch.setattr(kernels, "pallas_available", lambda: True)
+    monkeypatch.setattr(kernels, "stored_feature_major",
+                        lambda a: feature_major)
+    monkeypatch.setattr(kernels, "moment_gramian_tile", lambda *a: tile)
+    collectives.clear_program_cache()
+    try:
+        yield
+    finally:
+        collectives.clear_program_cache()
+
+
 # -- (a) whole fits against the plain reference, and the frame path -----------
 
 @pytest.mark.parametrize("dtype,d", [("float32", 200), ("bfloat16", 200),
@@ -99,7 +123,7 @@ def test_binomial_fit_equals_the_plain_reference(ctx, dtype, d):
     xs = _stored(x, dtype)
     model, _ = _fit(ctx, xs, y, dtype, **BINOMIAL)
     s = model.summary
-    assert 4 <= s.num_iterations < 25
+    assert s.num_iterations == 5        # as before PR 38's first-pass mu0
     assert s.total_passes == s.num_iterations
     assert s.total_dispatches == s.num_iterations + 1
     assert len(s.deviance_history) == s.num_iterations
@@ -116,25 +140,15 @@ def test_binomial_fit_on_the_kernel_equals_the_plain_reference(
     """The same fit with the reweighted moments on the Pallas kernel (the
     test routes its pallas_call through the interpreter and says what the
     chip would: Mosaic lowers, X is stored this way): the working weights
-    are no 0/1 mask, so every pass takes the kernel's weighted branch —
-    both tilings, rows that do not fill the last tile."""
-    from cycloneml_tpu.parallel import collectives
+    past the first pass are no one-value mask, so those passes take the
+    kernel's three-piece form — both tilings, rows that do not fill the
+    last tile."""
     d = 48 if path == "feature_major" else 128
     x, y = _case(34, 4096, d)
     xs = _stored(x, "bfloat16")
-    native_call = kernels.pl.pallas_call
-    monkeypatch.setattr(
-        kernels.pl, "pallas_call",
-        lambda *a, **kw: native_call(*a, **{**kw, "interpret": True}))
-    monkeypatch.setattr(kernels, "pallas_available", lambda: True)
-    monkeypatch.setattr(kernels, "stored_feature_major",
-                        lambda a: path == "feature_major")
-    monkeypatch.setattr(kernels, "moment_gramian_tile", lambda *a: 384)
-    collectives.clear_program_cache()
-    try:
+    with _moments_on_the_interpreter(monkeypatch, path == "feature_major"):
         model, _ = _fit(ctx, xs, y, "bfloat16", **BINOMIAL)
-    finally:
-        collectives.clear_program_cache()
+    assert model.summary.num_iterations == 5
     _assert_equals_reference(model, _reference(ctx, xs, y))
 
 
@@ -151,7 +165,7 @@ def test_frame_and_dataset_take_the_same_loop(ctx):
                                   by_ds.coefficients.to_array())
     assert by_frame.intercept == by_ds.intercept
     a, b = by_frame.summary, by_ds.summary
-    assert a.deviance == b.deviance and a.num_iterations == b.num_iterations
+    assert a.deviance == b.deviance and a.num_iterations == b.num_iterations == 5
     np.testing.assert_array_equal(a.coefficient_standard_errors,
                                   b.coefficient_standard_errors)
     assert a.null_deviance == b.null_deviance and a.aic == b.aic
@@ -213,6 +227,7 @@ def test_warm_fit_builds_and_launches_nothing_outside_its_passes(ctx):
     collectives.clear_program_cache()       # the first fit builds its own
     fits = _traced_fits(ctx, GeneralizedLinearRegression(**BINOMIAL), ds, 3)
     first, built, size = fits[0]
+    assert first.summary.num_iterations == 4
     assert [s for s in built if s.kind == "compile"]   # the cold fit did
     for model, spans, cache_size in fits[1:]:
         assert cache_size == size
@@ -242,7 +257,7 @@ def test_spans_and_counters_of_a_fit(ctx):
         ctx, GeneralizedLinearRegression(**BINOMIAL), ds, 1)
     s = model.summary
     iterations = [sp for sp in spans if sp.name == "irls.iteration"]
-    assert len(iterations) == s.num_iterations == s.total_passes
+    assert len(iterations) == s.num_iterations == s.total_passes == 5
     assert [sp.attrs["iteration"] for sp in iterations] == \
         list(range(s.num_iterations))
     assert [sp.attrs["deviance"] for sp in iterations] == s.deviance_history
@@ -266,6 +281,107 @@ def test_spans_and_counters_of_a_fit(ctx):
             and sp.name.startswith("fit.")][0] == "fit.prepare"
 
 
+# -- (d') the pass count the working weights ask for --------------------------
+
+def test_first_pass_has_one_working_weight(ctx):
+    """Binomial / logit, 0/1 labels, unit weights: ``mu0`` is 0.75 or 0.25
+    and the working weight ``mu0 (1 - mu0)`` 0.1875 for every row — IF the
+    working point is taken at ``mu0`` itself. Through ``unlink(link(mu0))``
+    (a ``log`` and a ``sigmoid``) float32 gives the two labels weights an
+    ulp apart, and the moment pass sees two live values."""
+    import jax.numpy as jnp
+    from unittest.mock import patch
+    from cycloneml_tpu.ml.regression import glm
+    rng = np.random.RandomState(43)
+    n, d = 512, 8
+    x = jnp.asarray(rng.randn(n, d), jnp.float32)
+    y = jnp.asarray(rng.rand(n) > 0.4, jnp.float32)
+    w = jnp.ones(n, jnp.float32).at[-40:].set(0.0)     # a shard's padding
+    params = jnp.asarray(np.append(np.zeros(d + 1), 1.0), jnp.float32)
+    seen, moment_sums = {}, kernels.moment_sums
+
+    def spy(x_, z, omega, **kw):
+        seen["omega"], seen["z"] = np.asarray(omega), np.asarray(z)
+        return moment_sums(x_, z, omega, **kw)
+
+    with patch.object(kernels, "moment_sums", spy):
+        glm.irls_aggregator(glm.Binomial(), glm.Logit())(x, y, w, params)
+    live = np.unique(seen["omega"][seen["omega"] != 0])
+    assert len(live) == 1, [float(v).hex() for v in live]
+    assert live[0] == pytest.approx(0.1875, rel=1e-6)
+    assert np.all(seen["omega"][-40:] == 0) and np.all(seen["z"][-40:] == 0)
+    # the working response is eta0 + (y - mu0) g: one value a label
+    assert len(np.unique(seen["z"][:-40])) == 2
+
+
+def _pass_spans(tracer):
+    return [s for s in tracer.snapshot()
+            if s.kind == "dispatch" and s.name == "irls.pass"]
+
+
+@pytest.fixture
+def ring():
+    """The default tracer of a context: the flight ring."""
+    from cycloneml_tpu.observe import tracing
+    from cycloneml_tpu.observe.flight import FlightTracer
+    tracing.disable()
+    t = tracing.install_if_absent(FlightTracer(max_spans=50_000))
+    yield t
+    tracing.disable()
+
+
+@pytest.mark.parametrize("path", ["feature_major", "row_major"])
+def test_dispatch_spans_say_which_form_of_the_gramian_ran(
+        ctx, monkeypatch, ring, path):
+    """The form is chosen on the device, so the host learns it with the
+    moments: every ``irls.pass`` dispatch span carries ``mxu_passes`` and
+    the summary the same list — a binomial-logit fit of 0/1 labels and
+    unit weights takes ONE pass for its first Gramian (one working weight
+    on every shard of the mesh) and three for each later one."""
+    d = 48 if path == "feature_major" else 128
+    x, y = _case(44, 4096, d)
+    xs = _stored(x, "bfloat16")
+    with _moments_on_the_interpreter(monkeypatch, path == "feature_major"):
+        model, _ = _fit(ctx, xs, y, "bfloat16", **BINOMIAL)
+    s = model.summary
+    assert s.num_iterations == 5
+    assert s.mxu_passes == [1, 3, 3, 3, 3]
+    assert [sp.attrs["mxu_passes"] for sp in _pass_spans(ring)] == \
+        s.mxu_passes
+    _assert_equals_reference(model, _reference(ctx, xs, y))
+
+
+def test_poisson_log_takes_three_passes_from_the_first(ctx, monkeypatch,
+                                                       ring):
+    """poisson / log: ``mu0`` follows the label, so the first pass's
+    working weights already hold many values — the rule observes, it is
+    told nothing."""
+    rng = np.random.RandomState(45)
+    x = rng.randn(4096, 48) * 0.2
+    xs = _stored(x, "bfloat16")
+    y = rng.poisson(np.exp(xs @ (rng.randn(48) * 0.5) + 0.3)).astype(
+        np.float64)
+    with _moments_on_the_interpreter(monkeypatch, True):
+        model, _ = _fit(ctx, xs, y, "bfloat16", family="poisson")
+    s = model.summary
+    assert s.mxu_passes == [3] * s.num_iterations and s.num_iterations >= 3
+    assert [sp.attrs["mxu_passes"] for sp in _pass_spans(ring)] == \
+        s.mxu_passes
+    mu = np.exp(xs @ model.coefficients.to_array() + model.intercept)
+    score = np.append(xs.T @ (y - mu), np.sum(y - mu))
+    assert np.max(np.abs(score)) < 1e-4 * np.sum(y)
+
+
+def test_xla_moments_carry_no_pass_count(ctx, ring):
+    """Where XLA's contraction is the Gramian (the host platform here)
+    there is no such count: spans and summary say ``None``."""
+    x, y = _case(46, 1024, 8)
+    model, _ = _fit(ctx, x, y, "float64", **BINOMIAL)
+    assert model.summary.mxu_passes == [None] * model.summary.num_iterations
+    assert [sp.attrs["mxu_passes"] for sp in _pass_spans(ring)] == \
+        model.summary.mxu_passes
+
+
 # -- (e) the other families through the same program -------------------------
 
 def test_poisson_log_fit_through_the_dataset_path(ctx):
@@ -282,7 +398,8 @@ def test_poisson_log_fit_through_the_dataset_path(ctx):
     assert np.max(np.abs(score)) < 1e-6 * np.sum(w * y)
     s = model.summary
     assert s.family == "poisson" and s.link == "log"
-    assert s.total_passes == s.num_iterations < 25
+    assert s.total_passes == s.num_iterations == 6
+    assert s.mxu_passes == [None] * 6       # float64 X: XLA's contraction
     dev = 2 * np.sum(w * (np.where(y > 0, y * np.log(np.maximum(y, 1e-300)
                                                      / mu), 0.0) - (y - mu)))
     assert s.deviance == pytest.approx(dev, rel=1e-10)
@@ -329,6 +446,7 @@ def test_offset_rides_as_a_fourth_row_vector(ctx):
     est = GeneralizedLinearRegression(family="poisson", offsetCol="off",
                                       tol=1e-10)
     model = est.fit(frame)
+    assert model.summary.num_iterations == 6
     mu = np.exp(x @ model.coefficients.to_array() + model.intercept + off)
     assert np.max(np.abs(x.T @ (y - mu))) < 1e-6 * np.sum(y)
     assert np.isfinite(model.summary.null_deviance)
@@ -347,6 +465,7 @@ def test_reg_param_is_the_plain_ridge_of_the_reweighted_problem(ctx):
     w = rng.uniform(0.5, 2.0, 2048)
     reg = 0.3
     model, _ = _fit(ctx, x, y, "float64", w, family="gaussian", regParam=reg)
+    assert model.summary.num_iterations == 2
     xm, ym = np.average(x, axis=0, weights=w), np.average(y, weights=w)
     xc, yc = x - xm, y - ym
     a = xc.T @ (xc * w[:, None]) / w.sum() + reg * np.eye(6)

@@ -144,6 +144,259 @@ def test_moment_sums_reads_the_array_not_a_conf_key(ctx):
     _assert_moments(got, _moment_reference(x, y, w), 3e-6)
 
 
+# -- the pass count the weights ask for ---------------------------------------
+
+def _block(x, y, w, feature_major, tile, weighted):
+    """One form of the kernel, called directly: the packed upper block."""
+    return np.asarray(kernels.fused_moment_gramian(
+        x, y, w, feature_major=feature_major, lane_tile=tile,
+        weighted=weighted, interpret=True))
+
+
+def _entries(upper, d):
+    """The moments that ARE entries of the block (the label's are sums of
+    three): what ``moment_sums`` returns of them."""
+    k = kernels.MOMENT_ROWS
+    gram = upper[k:, k:]
+    return {"w_sum": upper[0, 0], "a_sum": upper[0, k:],
+            "aa_sum": np.triu(gram) + np.triu(gram, 1).T}
+
+
+def _same_bits(got, want):
+    for name, v in want.items():
+        np.testing.assert_array_equal(
+            np.asarray(got[name]).view(np.uint32),
+            np.asarray(v, np.float32).view(np.uint32), err_msg=name)
+
+
+def _one_value_case(feature_major, c, seed=8):
+    """A ragged last tile (n % tile = 52), every seventh row weightless."""
+    rng = np.random.RandomState(seed)
+    d, tile = (48, 128) if feature_major else (128, 128)
+    n = 3 * tile + 52
+    x = _bf16(rng.randn(n, d))
+    y = rng.randn(n).astype(np.float32)
+    w = np.full(n, c, np.float32)
+    w[::7] = 0.0
+    return x, y, w, tile
+
+
+@pytest.mark.parametrize("c", [0.1875, 0.3, 2.5, 1.0])
+@pytest.mark.parametrize("feature_major", [True, False])
+def test_one_value_weights_take_one_mxu_pass(ctx, feature_major, c):
+    """Weights that are all 0 or ONE value c (a constant weight column, the
+    first IRLS pass of a binomial-logit fit: 0.1875) take the presence-mask
+    form and scale its block by c — bit for bit ``c x`` what the mask form
+    returns, so one rounding an entry on top of its exact-product sums:
+    inside the stated bound against float64 — and say so: ``mxu_passes``
+    1. At c = 1 that is today's 0/1 branch to the bit."""
+    x, y, w, tile = _one_value_case(feature_major, c)
+    got = _kernel_moments(x, y, w, feature_major, tile)
+    assert float(got["mxu_passes"]) == 1.0
+    mask = _block(x, y, (w > 0).astype(np.float32), feature_major, tile,
+                  weighted=False)
+    _same_bits(got, _entries(np.float32(c) * mask, x.shape[1]))
+    want = _moment_reference(x, y, w)
+    _assert_moments(got, want, 3e-6)
+    x64 = np.abs(np.asarray(x, np.float64)) * np.sqrt(np.asarray(w))[:, None]
+    bound = (tile + 5) * 2.0 ** -24 * (x64.T @ x64)
+    err = np.abs(np.asarray(got["aa_sum"], np.float64) - want["aa_sum"])
+    assert np.all(err <= bound), float(np.max(err / bound))
+
+
+@pytest.mark.parametrize("second", [0.25, float("nan"), float("inf"), -0.5])
+@pytest.mark.parametrize("feature_major", [True, False])
+def test_a_second_live_value_takes_three_mxu_passes(ctx, feature_major,
+                                                    second):
+    """One row with another weight — a second live value, a NaN, an
+    infinity, a negative weight — and the shard takes the three-piece form:
+    the bits ``fused_moment_gramian(weighted=True)`` returns, and
+    ``mxu_passes`` 3."""
+    x, y, w, tile = _one_value_case(feature_major, 0.1875, seed=9)
+    w[5] = second
+    got = _kernel_moments(x, y, w, feature_major, tile)
+    assert float(got["mxu_passes"]) == 3.0
+    _same_bits(got, _entries(
+        _block(x, y, w, feature_major, tile, weighted=True), x.shape[1]))
+
+
+def test_weightless_and_all_infinite_shards(ctx):
+    """The rule's edges: a shard of padding alone (every weight 0: c = 0)
+    is a mask and returns zeros in one pass; a shard whose every weight is
+    infinite has one value and is NOT one pass (c must be finite)."""
+    x, y, w, tile = _one_value_case(True, 1.0, seed=10)
+    got = _kernel_moments(x, y, np.zeros_like(w), True, tile)
+    assert float(got["mxu_passes"]) == 1.0
+    assert float(got["w_sum"]) == 0.0 and not np.any(np.asarray(got["aa_sum"]))
+    got = _kernel_moments(x, y, np.full_like(w, np.inf), True, tile)
+    assert float(got["mxu_passes"]) == 3.0
+
+
+def test_mean_mxu_passes_over_shards():
+    """The host's reading of the psum'd count: 1 or 3 where the shards
+    agree, their mean where they do not, None for XLA's contraction."""
+    assert kernels.mean_mxu_passes({"mxu_passes": np.float32(8.0)}, 8) == 1
+    assert kernels.mean_mxu_passes({"mxu_passes": np.float32(24.0)}, 8) == 3
+    assert kernels.mean_mxu_passes({"mxu_passes": np.float32(10.0)}, 4) == 2.5
+    assert kernels.mean_mxu_passes({"w_sum": 1.0}, 8) is None
+
+
+# -- the weighted form against the body it replaced ---------------------------
+
+def _parent_weighted_gramian(x, y, w, *, feature_major, lane_tile):
+    """A straight transcription of the weighted branch as it stood before
+    PR 38 (each row block split at the head of its own products, the
+    pieces compiler temporaries): kept HERE, not in the package, as the
+    proof that the package's body moved the schedule and nothing else."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    n, d = x.shape
+    rows, block = kernels.MOMENT_ROWS, kernels.GRAM_BLOCK
+    big = rows + d
+    edges = list(range(0, big, block)) + [big]
+    spans = list(zip(edges[:-1], edges[1:]))
+    tile = lane_tile
+    nt = (((1,), (1,)), ((), ()))
+
+    def split3(v):
+        hi = v.astype(jnp.bfloat16)
+        rest = v - hi.astype(jnp.float32)
+        mid = rest.astype(jnp.bfloat16)
+        lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+        return hi, mid, lo
+
+    def moment_gramian(x_ref, y_ref, w_ref, acc_ref, comp_ref, z_ref):
+        i = pl.program_id(0)
+
+        @pl.when(i == 0)
+        def _():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+            comp_ref[:] = jnp.zeros_like(comp_ref)
+
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1) + i * tile
+        valid = lane < n
+        wv = jnp.where(valid, w_ref[:], 0.0)
+        y_hi, y_mid, y_lo = (p.astype(jnp.float32) for p in split3(
+            jnp.where(valid, y_ref[:], 0.0)))
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 0)
+        moments = jnp.where(
+            row == 0, valid.astype(jnp.float32),
+            jnp.where(row == 1, y_hi, jnp.where(
+                row == 2, y_mid, jnp.where(row == 3, y_lo, 0.0))))
+        z_ref[0:rows, :] = moments.astype(jnp.bfloat16)
+        xv = x_ref[:] if feature_major else x_ref[:].T
+        z_ref[rows:big, :] = jnp.where(valid, xv, jnp.zeros((), xv.dtype))
+        for a, (i0, i1) in enumerate(spans):
+            left = split3(z_ref[i0:i1, :].astype(jnp.float32) * wv)
+            for j0, j1 in spans[a:]:
+                right = z_ref[j0:j1, :]
+                v = sum(jax.lax.dot_general(
+                    piece, right, nt, preferred_element_type=jnp.float32)
+                    for piece in left)
+                yk = v - comp_ref[i0:i1, j0:j1]
+                t = acc_ref[i0:i1, j0:j1] + yk
+                comp_ref[i0:i1, j0:j1] = (t - acc_ref[i0:i1, j0:j1]) - yk
+                acc_ref[i0:i1, j0:j1] = t
+
+    if feature_major:
+        x_arg, x_spec = x.T, pl.BlockSpec((d, tile), lambda i: (0, i))
+    else:
+        x_arg, x_spec = x, pl.BlockSpec((tile, d), lambda i: (i, 0))
+    vec_spec = pl.BlockSpec((1, tile), lambda i: (0, i))
+    return np.asarray(pl.pallas_call(
+        moment_gramian, grid=(pl.cdiv(n, tile),),
+        in_specs=[x_spec, vec_spec, vec_spec],
+        out_specs=pl.BlockSpec((big, big), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((big, big), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((big, big), jnp.float32),
+                        pltpu.VMEM((big, tile), jnp.bfloat16)],
+        interpret=True,
+    )(x_arg, jnp.asarray(y, jnp.float32).reshape(1, n),
+      jnp.asarray(w, jnp.float32).reshape(1, n)))
+
+
+def _weights(kind, rng, n):
+    if kind == "logistic":      # mu (1 - mu) of a fit under way
+        mu = 1.0 / (1.0 + np.exp(-1.5 * rng.randn(n)))
+        return mu * (1.0 - mu)
+    if kind == "signed":
+        return rng.randn(n)
+    if kind == "tiny":          # down to where the low pieces go subnormal
+        return rng.rand(n) * 10.0 ** rng.uniform(-36, -20, n)
+    return np.asarray(_bf16(0.25 + rng.rand(n)), np.float64)   # exact bf16
+
+
+@pytest.mark.parametrize("kind", ["logistic", "signed", "tiny", "bf16"])
+@pytest.mark.parametrize("feature_major", [True, False])
+def test_weighted_form_is_the_parents_to_the_bit(ctx, feature_major, kind):
+    """At one lane tile the three-piece form returns the parent's block
+    bit for bit — the same pieces, products and order of accumulation:
+    PR 38 moved the tile the form is GIVEN (``GRAM_WEIGHTED_TILE``), not
+    what it does with one. Several blocks of the triangle, a ragged last
+    tile, a last row block that is not whole."""
+    rng = np.random.RandomState(11)
+    d, tile = (272, 256) if feature_major else (256, 128)
+    n = 2 * tile + 77
+    x = _bf16(rng.randn(n, d))
+    y = rng.randn(n).astype(np.float32)
+    w = _weights(kind, rng, n).astype(np.float32)
+    got = _block(x, y, w, feature_major, tile, weighted=True)
+    want = _parent_weighted_gramian(x, y, w, feature_major=feature_major,
+                                    lane_tile=tile)
+    assert np.any(np.triu(want)[kernels.MOMENT_ROWS:] != 0)
+    np.testing.assert_array_equal(np.triu(got).view(np.uint32),
+                                  np.triu(want).view(np.uint32))
+
+
+def test_each_form_gets_its_lane_tile(ctx):
+    """The three-piece form runs at ``GRAM_WEIGHTED_TILE`` lanes (its
+    three pieces of a row block then stay in the vector registers), the
+    mask form at the tile the rule finds; a shard with fewer rows than
+    either runs both at what it has."""
+    seen = []
+    native = kernels.fused_moment_gramian
+
+    def spy(*a, **kw):
+        seen.append((kw["weighted"], kw["lane_tile"]))
+        return native(*a, **kw)
+
+    rng = np.random.RandomState(12)
+    n, d = 1100, 32
+    x = _bf16(rng.randn(n, d))
+    y = rng.randn(n).astype(np.float32)
+    w = (0.25 + rng.rand(n)).astype(np.float32)
+    with patch.object(kernels, "fused_moment_gramian", spy):
+        got = _kernel_moments(x, y, w, True, 1024)
+        _kernel_moments(x[:300], y[:300], w[:300], True, 128)
+    assert sorted(seen) == [(False, 128), (False, 1024), (True, 128),
+                            (True, kernels.GRAM_WEIGHTED_TILE)]
+    assert kernels.GRAM_WEIGHTED_TILE == 256
+    _assert_moments(got, _moment_reference(x, y, w), 3e-6)
+
+
+def test_weighted_form_accumulation_bound(ctx):
+    """The stated bound on the three-piece form at its own tile: ``|err_ij|
+    <= (T + 4) 2^-24 sum_r w_r |z_ri z_rj|`` with T = 256, over 79 grid
+    steps of same-sign products and weights like a logistic fit's; the
+    test holds a twentieth of the worst case, as the mask form's does."""
+    rng = np.random.RandomState(13)
+    n, d, tile = 20000, 16, kernels.GRAM_WEIGHTED_TILE
+    x = _bf16(np.abs(rng.randn(n, d)) + 0.5)
+    y = np.abs(rng.randn(n)).astype(np.float32)
+    mu = 1.0 / (1.0 + np.exp(-1.5 * rng.randn(n)))
+    w = (mu * (1.0 - mu)).astype(np.float32)
+    got = _kernel_moments(x, y, w, True, 1024)
+    assert float(got["mxu_passes"]) == 3.0
+    want = _moment_reference(x, y, w)
+    x64 = np.abs(np.asarray(x, np.float64)) * np.sqrt(
+        np.asarray(w, np.float64))[:, None]
+    bound = (tile + 4) * 2.0 ** -24 * (x64.T @ x64)
+    err = np.abs(np.asarray(got["aa_sum"], np.float64) - want["aa_sum"])
+    assert np.all(err <= bound / 20), float(np.max(err / bound))
+
+
 # -- whole fits against the plain reference ------------------------------------
 
 ROW_AXES = ("replica", "data")
