@@ -8,7 +8,8 @@ environment. It drives the main path through the public entry points —
 → ``fit`` leg, a small binomial ``GeneralizedLinearRegression`` fit (IRLS over
 both forms of the moment Gramian: one MXU pass, then three), a small ten-class
 ``LogisticRegression`` fit (the fused multinomial sweep under the
-device-resident L-BFGS), then compiles and checks every
+device-resident L-BFGS), a ``KMeans`` fit from a stated starting set (the fused
+Lloyd step), then compiles and checks every
 Pallas kernel natively at small n. Every leg asserts WHICH path ran (platform, data dtype, Mosaic custom call,
 one in-core dispatch) and that what came out is right (finite non-increasing
 objective, agreement with the XLA twin and with a float64 reference).
@@ -39,6 +40,9 @@ N_GLR_COLS = 256
 N_SOFTMAX_ROWS = 65_536  # multinomial leg: mnist8m's width and classes
 N_SOFTMAX_COLS = 784
 N_CLASSES = 10
+N_KMEANS_ROWS = 2_000_000  # k-means leg: the first row-major width, k = 1,000
+N_KMEANS_COLS = 128
+N_CENTRES = 1_000
 MAX_ITER = 25
 REG = 0.01
 
@@ -427,6 +431,72 @@ def multinomial_leg(ctx, n: int, d: int, k: int, devices) -> dict:
             "cold_fit_s": round(cold_s, 3)}
 
 
+def kmeans_leg(ctx, n: int, d: int, k: int, devices) -> dict:
+    """Leg 5: ``KMeans(k, initialModel=...)`` on a device-resident bf16
+    mixture at a row-major width: every step is the Mosaic kernel
+    ``kmeans_lloyd``; one step's assignments equal float32 nearest centres
+    (XLA, ``highest``, row chunks) and its sums a float64 host reduction
+    over the same stored values."""
+    import jax
+    import jax.numpy as jnp
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    from cycloneml_tpu.dataset.random import generate_classification
+    from cycloneml_tpu.ml.clustering import KMeans, kmeans
+
+    base = generate_classification(ctx, n, d, seed=3)
+    rng = np.random.RandomState(3)
+    mu = rng.randn(k, d).astype(np.float32)
+    pts = jax.jit(lambda x, m: (
+        x.astype(jnp.float32)
+        + jnp.take(m, (jnp.arange(n) * 7919) % k, axis=0)
+    ).astype(jnp.bfloat16), out_shardings=base.x.sharding)(base.x, mu)
+    ds = InstanceDataset(ctx, pts, base.y, base.w, n, d)
+    start = (mu + 0.05 * rng.randn(k, d)).astype(np.float64)
+    t0 = time.perf_counter()
+    model = KMeans(k=k, maxIter=3, initialModel=start).fit(ds)
+    cold_s = time.perf_counter() - t0
+    s = model.summary
+    check(str(ds.x.dtype) == "bfloat16", f"kmeans leg: tier {ds.x.dtype}")
+    check(s.orientation == "row_major" and s.pieces == 3,
+          f"kmeans leg: step {s.orientation!r}, {s.pieces} pieces")
+    check(s.total_dispatches <= s.total_steps + 1,
+          f"kmeans leg: {s.total_dispatches} dispatches, {s.total_steps} steps")
+    call = ds.tree_aggregate_fn(kmeans.lloyd_aggregator(True, True))
+    c32 = jnp.asarray(start, jnp.float32)
+    text = call.compiled.__wrapped__.lower(*call.arrays(), c32).as_text()
+    check("tpu_custom_call" in text and "kmeans_lloyd" in text,
+          "kmeans leg: no Mosaic Lloyd step in the aggregation program")
+    out = jax.device_get(call(c32))
+    check(float(out["kernel_shards"]) == len(devices),
+          f"kmeans leg: {out['kernel_shards']} shards took the kernel")
+
+    chunk = 50_000
+
+    def nearest(x, c):
+        with jax.default_matmul_precision("highest"):
+            def block(xb):
+                xf = xb.astype(jnp.float32)
+                return jnp.argmin(
+                    jnp.sum(xf * xf, 1)[:, None] - 2.0 * xf @ c.T
+                    + jnp.sum(c * c, 1)[None], axis=1)
+            return jax.lax.map(block, x.reshape(n // chunk, chunk, d))
+
+    assign = np.asarray(jax.jit(nearest)(ds.x, c32)).ravel()
+    x64 = np.asarray(ds.to_numpy()[0], np.float64)
+    order = np.argsort(assign, kind="stable")
+    counts = np.bincount(assign, minlength=k)
+    check(counts.min() > 0, "kmeans leg: an empty cluster in the check")
+    sums = np.add.reduceat(x64[order], np.cumsum(counts) - counts, axis=0)
+    check(np.array_equal(np.asarray(out["counts"]), counts),
+          "kmeans leg: assignments differ from float32 nearest centres")
+    gap = rel_err(out["sums"], sums)
+    check(gap < 1e-6, f"kmeans leg: sums {gap:.3e} off the float64 reduction")
+    return {"n": n, "d": d, "k": k, "orientation": s.orientation,
+            "steps": s.total_steps, "dispatches": s.total_dispatches,
+            "training_cost": s.training_cost, "sums_vs_f64": gap,
+            "cold_fit_s": round(cold_s, 3)}
+
+
 def rel_err(got, want) -> float:
     got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
     return float(np.max(np.abs(got - want)) / max(np.max(np.abs(want)), 1e-30))
@@ -665,6 +735,8 @@ def main() -> int:
     softmax = multinomial_leg(ctx, N_SOFTMAX_ROWS, N_SOFTMAX_COLS, N_CLASSES,
                               devices)
     print(f"chip_smoke: softmax leg ok {softmax}", file=sys.stderr)
+    lloyd = kmeans_leg(ctx, N_KMEANS_ROWS, N_KMEANS_COLS, N_CENTRES, devices)
+    print(f"chip_smoke: kmeans leg ok {lloyd}", file=sys.stderr)
     kernels_ok = kernel_matrix()
     print(f"chip_smoke: kernel matrix ok {kernels_ok}", file=sys.stderr)
     ctx.stop()
@@ -682,6 +754,7 @@ def main() -> int:
         "host_fit": host,
         "glr_fit": glr,
         "softmax_fit": softmax,
+        "kmeans_fit": lloyd,
         "kernel_matrix_max_rel_err": kernels_ok,
         "compile_cache": {
             "dir": cache_dir, "entries_before": entries_before,

@@ -5,22 +5,44 @@ with per-partition center sums then collectAsMap :240-311; the ml wrapper
 delegates to it, ml/clustering/KMeans.scala:336; DistanceMeasure.scala:28
 with euclidean/cosine). TPU-first formulation:
 
-- distances: ‖x‖² + ‖c‖² − 2x·cᵀ as ONE (n,k) MXU matmul per step — the
-  reference's per-row ``findClosest`` with triangle-inequality pruning
-  (DistanceMeasure.scala:123) exists to avoid flops on a CPU; the MXU makes
-  the dense matmul faster than any pruning.
-- center update: one-hot(assign)ᵀ @ X — a second MXU matmul — psum'd over
-  the mesh; this IS the per-partition sum + global merge of the reference.
-- whole Lloyd iteration = one jit-compiled SPMD program; driver only checks
-  movement against tol.
-- init: "random" or "k-means||" (Bahmani et al., ref KMeans.scala
-  initKMeansParallel) with distributed cost pass + driver-side weighted
-  k-means++ refinement, exactly the reference's scheme.
+- one Lloyd step = ONE ``tree_aggregate`` program
+  (``jit_tree_aggregate__kmeans_lloyd_step``) returning ``{sums (k, d),
+  counts (k,), cost}`` psum'd over the mesh — the per-partition sum + global
+  merge of the reference. No ``(n, k)`` value exists on any path: on a TPU,
+  for a bfloat16 X whose width is a multiple of 128 (at least 128 rows a
+  shard, ``k·d`` within the kernel's VMEM budget, weights that hold one
+  live value), the step is the Mosaic kernel ``kmeans_lloyd``
+  (``ops/kmeans_lloyd.py``: scores on the MXU with the float32 centres as
+  three bf16 pieces, argmin with the lowest index on a tie, the one-hot
+  update product, X read once at storage width); everywhere else its
+  row-blocked XLA twin. ``cyclone.ml.usePallasKernels``: ``auto`` takes the
+  kernel where it exists, ``false`` forces the twin. The reference's
+  per-row ``findClosest`` with triangle-inequality pruning
+  (DistanceMeasure.scala:123) exists to avoid flops on a CPU; here the
+  dense product on the MXU is the faster search.
+- the loop stays on the host: one dispatch and one readback a step, the
+  centre update in float64 (``sums / counts``; an empty cluster keeps its
+  centre), stop when the largest centre move is under ``tol`` or at
+  ``maxIter``.
+- start: ``initialModel`` (ref mllib KMeans.setInitialModel: a stated
+  starting set, bypassing the random start), else ``initMode`` "random" or
+  "k-means||" (Bahmani et al., ref KMeans.scala initKMeansParallel) with a
+  distributed cost pass + driver-side weighted k-means++ refinement.
+- ``summary.training_cost`` is the cost at the RETURNED centres (one
+  assignment-only pass in ``fit.finish``, skipped where the last step moved
+  nothing). MLlib reports the last step's cost, taken at the centres BEFORE
+  their update: a departure, made so that the reported cost belongs to the
+  reported model.
+
+Measured on one TPU v5e at 25,000,000 x 128 bf16, k = 1,000: PERF.md §5
+(cell ``kmeans_k1000_lloyd_fit``).
 """
 
 from __future__ import annotations
 
-from typing import Optional
+import dataclasses
+import functools
+from typing import List, Optional
 
 import numpy as np
 
@@ -34,6 +56,7 @@ from cycloneml_tpu.ml.shared import (
     HasFeaturesCol, HasMaxIter, HasPredictionCol, HasSeed, HasTol, HasWeightCol,
 )
 from cycloneml_tpu.ml.util_io import MLReadable, MLWritable, load_arrays, save_arrays
+from cycloneml_tpu.observe import tracing
 from cycloneml_tpu.util.logging import get_logger
 
 logger = get_logger(__name__)
@@ -59,12 +82,78 @@ class _KMeansParams(HasFeaturesCol, HasPredictionCol, HasMaxIter, HasSeed,
             V.in_array(["euclidean", "cosine"]), default="euclidean")
 
 
+@functools.lru_cache(maxsize=None)
+def _normalizer():
+    import jax
+    import jax.numpy as jnp
+    return jax.jit(lambda x: normalize_rows(jnp, x))
+
+
+@functools.lru_cache(maxsize=None)
+def lloyd_aggregator(fused: bool, update: bool):
+    """One Lloyd step over a shard, ``kmeans_lloyd_step(x, y, w, centres) ->
+    {sums (k, d), counts (k,), cost, kernel_shards}`` (``update=False``:
+    ``kmeans_lloyd_cost``, the assignment-only pass: ``cost`` alone) by
+    ``ops/kmeans_lloyd.lloyd_step``. Cached by VALUE, so every fit asks
+    ``tree_aggregate`` for the same function and gets the same program
+    (``jit_tree_aggregate__kmeans_lloyd_step`` in a device capture): a
+    closure built per fit would be re-traced per fit. ``fused`` is the
+    caller's word that the kernel exists for its X."""
+    def kmeans_lloyd_step(x, y, w, centres):
+        from cycloneml_tpu.ops.kmeans_lloyd import lloyd_step
+        return lloyd_step(x, w, centres, fused=fused, update=update)
+
+    if not update:
+        kmeans_lloyd_step.__name__ = "kmeans_lloyd_cost"
+    return kmeans_lloyd_step
+
+
+@dataclasses.dataclass
+class KMeansSummary:
+    """What a fit reports (ref ml/clustering/KMeansSummary: ``k``,
+    ``num_iter``, ``training_cost``, ``cluster_sizes``), plus what the
+    program did: ``total_steps`` (passes over X that updated the centres),
+    ``total_dispatches`` (those and the assignment-only pass),
+    ``orientation`` (``row_major``: every step ran the Mosaic kernel;
+    ``xla``: the row-blocked twin) and ``pieces`` (bf16 pieces of a centre
+    in the scores: 3 on a bfloat16 X, None where X is wider and the product
+    is ``highest``). ``training_cost`` is the cost AT the returned centres
+    (MLlib: at the centres before the last update) and ``cluster_sizes``
+    the last step's assignment counts (weighted)."""
+
+    k: int
+    num_iter: int
+    training_cost: float
+    cluster_sizes: List[float]
+    total_steps: int
+    total_dispatches: int
+    orientation: str
+    pieces: Optional[int]
+
+
 class KMeans(Estimator, _KMeansParams, MLWritable, MLReadable):
     def __init__(self, uid=None, **kwargs):
         super().__init__(uid)
         self._declare_kmeans_params()
+        # the estimator's alone: a model does not carry its starting set
+        self.initialModel = self._param(
+            "initialModel", "the starting centres, a (k, d) array or a "
+            "KMeansModel (ref mllib KMeans.setInitialModel): bypasses "
+            "initMode; its k must match")
         for key, v in kwargs.items():
             self.set(key, v)
+
+    def set(self, param, value):
+        name = param if isinstance(param, str) else param.name
+        if name == "initialModel" and value is not None:
+            # held as a float64 array: what copy, save and load carry
+            if isinstance(value, KMeansModel):
+                value = value.cluster_centers_matrix().to_array()
+            value = np.array(value, dtype=np.float64)
+            if value.ndim != 2:
+                raise ValueError(f"initialModel wants (k, d) centres, got "
+                                 f"an array of shape {value.shape}")
+        return super().set(param, value)
 
     def set_k(self, v):
         return self.set("k", v)
@@ -75,93 +164,117 @@ class KMeans(Estimator, _KMeansParams, MLWritable, MLReadable):
     def set_seed(self, v):
         return self.set("seed", v)
 
+    def set_initial_model(self, v):
+        return self.set("initialModel", v)
+
     def _fit(self, frame: MLFrame) -> "KMeansModel":
         ds = frame.to_instance_dataset(
             self.get("featuresCol"), label_col=None,
             weight_col=self.get("weightCol") or None)
         return self._fit_dataset(ds)
 
+    def _starting_centers(self, ds: InstanceDataset, k: int) -> np.ndarray:
+        if not self.is_set(self.initialModel):
+            return self._init_centers(ds, k)
+        start = np.asarray(self.get("initialModel"), np.float64)
+        if start.shape != (k, ds.n_features):
+            raise ValueError(
+                f"initialModel holds centres of shape {start.shape}; k is "
+                f"{k} and the data's width {ds.n_features}")
+        return start
+
     def _fit_dataset(self, ds: InstanceDataset) -> "KMeansModel":
+        """Lloyd's iterations over a device-resident dataset: per step one
+        aggregation program (dispatch + readback of ``{sums, counts,
+        cost}``) and the float64 centre update on the driver, then — where
+        the last step still moved a centre — one assignment-only pass for
+        the returned centres' cost."""
         import jax
         import jax.numpy as jnp
-
-        k = self.get("k")
-        cosine = self.get("distanceMeasure") == "cosine"
-        # centers are a replicated (k, d) vector set — they ride the
-        # ACCUMULATOR tier (f32/f64) even when X stores bf16; distances
-        # upcast X per tile inside the kernels, never in HBM
         from cycloneml_tpu.dataset.instance import compute_dtype
-        dtype = compute_dtype()
+        from cycloneml_tpu.ops.kernels import use_fused_kernels
+        from cycloneml_tpu.ops.kmeans_lloyd import CENTRE_PIECES, lloyd_tile
 
-        if cosine:
-            # cosine distance clusters on the unit sphere: normalize once
-            norm = jax.jit(lambda x: normalize_rows(jnp, x))
-            ds = ds.derive(x=norm(ds.x))
+        with tracing.span("phase", "fit.prepare"):
+            k = self.get("k")
+            cosine = self.get("distanceMeasure") == "cosine"
+            if cosine:
+                # cosine distance clusters on the unit sphere: normalize once
+                ds = ds.derive(x=_normalizer()(ds.x))
+            centers = self._starting_centers(ds, k)
+            if cosine and self.is_set(self.initialModel):
+                centers = normalize_rows(np, centers)
+            # centers are a replicated (k, d) set on the ACCUMULATOR tier
+            # (f32/f64) even when X stores bf16: the step reads X at
+            # storage width and never rounds a centre to it
+            dtype = compute_dtype()
+            rows = ds.x.sharding.shard_shape(ds.x.shape)[0]
+            fused = use_fused_kernels(ds.ctx) and lloyd_tile(
+                rows, ds.n_features, k, ds.x.dtype) is not None
+            step = ds.tree_aggregate_fn(lloyd_aggregator(fused, True))
 
-        centers = self._init_centers(ds, k)
+        n_dispatches = 0
+        shards = ds.ctx.mesh_runtime.data_parallelism
+        kernel_shards = []
 
-        hi = jax.lax.Precision.HIGHEST
-        from cycloneml_tpu.conf import USE_PALLAS_KERNELS
-        # explicit opt-in only: the assignment kernel has no measured win
-        # over XLA (builder run, rounds 3-5, record deleted in PR 21; not
-        # measured on the current machine), so 'auto' keeps the XLA path
-        use_pallas = (hasattr(ds.ctx, "conf") and
-                      str(ds.ctx.conf.get(USE_PALLAS_KERNELS)).lower()
-                      == "true")
+        def dispatch(program, name, at):
+            """One launch of ``program`` at the centres ``at`` and its one
+            readback."""
+            nonlocal n_dispatches
+            n_dispatches += 1
+            with tracing.span("dispatch", f"kmeans.{name}", passes=1):
+                out_dev = program(jnp.asarray(at.astype(dtype)))
+                with tracing.span("transfer", "kmeans.readback") as tsp:
+                    out = jax.device_get(out_dev)
+                    tsp.annotate_bytes(out)
+            kernel_shards.append(float(out["kernel_shards"]))
+            return out
 
-        if use_pallas:
-            from cycloneml_tpu.ops.kernels import fused_kmeans_assign
-
-            def lloyd_step(x, y, w, c):
-                # fused distance+argmin kernel (the (T, k) tile never
-                # leaves VMEM; bf16 X read at storage width with f32
-                # distance accumulation), then segment-sum center updates —
-                # w stays in its accumulator dtype so the sums do too
-                best, dist = fused_kmeans_assign(x, c)
-                wv = w
-                sums = jax.ops.segment_sum(x * wv[:, None], best,
-                                           num_segments=k)
-                counts = jax.ops.segment_sum(wv, best, num_segments=k)
-                cost = jnp.sum(wv * dist.astype(wv.dtype))
-                return {"sums": sums, "counts": counts, "cost": cost}
-        else:
-            def lloyd_step(x, y, w, c):
-                # (b,k) squared distances via the MXU
-                d2 = pairwise_sq_dists(jnp, x, c, precision=hi)
-                assign = jnp.argmin(d2, axis=1)
-                onehot = jax.nn.one_hot(assign, k, dtype=w.dtype) * w[:, None]
-                sums = jnp.dot(onehot.T, x, precision=hi)    # (k,d) center sums
-                counts = jnp.sum(onehot, axis=0)              # (k,)
-                cost = jnp.sum(w * jnp.maximum(jnp.min(d2, axis=1), 0.0))
-                return {"sums": sums, "counts": counts, "cost": cost}
-
-        step = ds.tree_aggregate_fn(lloyd_step)
         tol = self.get("tol")
-        cost = float("inf")
+        cost, moved, counts = float("inf"), float("inf"), np.zeros(k)
         it = 0
         for it in range(1, self.get("maxIter") + 1):
-            # one transfer per Lloyd step, not three (graftlint JX001)
-            out = jax.device_get(step(centers.astype(dtype)))
-            counts = np.asarray(out["counts"], dtype=np.float64)
-            sums = np.asarray(out["sums"], dtype=np.float64)
-            cost = float(out["cost"])
-            # empty clusters keep their previous center (ref behavior)
-            new_centers = np.where(counts[:, None] > 0,
-                                   sums / np.maximum(counts[:, None], 1e-300),
-                                   centers)
-            if cosine:
-                norms = np.linalg.norm(new_centers, axis=1, keepdims=True)
-                new_centers = new_centers / np.maximum(norms, 1e-12)
-            moved = np.linalg.norm(new_centers - centers, axis=1).max()
-            centers = new_centers
+            with tracing.span("phase", "lloyd.iteration",
+                              iteration=it) as isp:
+                out = dispatch(step, "step", centers)
+                if hasattr(ds.ctx, "record_step"):
+                    ds.ctx.record_step({"lloyd_steps": 1.0})
+                counts = np.asarray(out["counts"], dtype=np.float64)
+                sums = np.asarray(out["sums"], dtype=np.float64)
+                cost = float(out["cost"])
+                # empty clusters keep their previous center (ref behavior)
+                new_centers = np.where(
+                    counts[:, None] > 0,
+                    sums / np.maximum(counts[:, None], 1e-300), centers)
+                if cosine:
+                    norms = np.linalg.norm(new_centers, axis=1, keepdims=True)
+                    new_centers = new_centers / np.maximum(norms, 1e-12)
+                moved = float(
+                    np.linalg.norm(new_centers - centers, axis=1).max())
+                centers = new_centers
+                isp.annotate(moved=moved, cost=cost)
             if moved < tol:
                 break
 
-        model = KMeansModel(centers, training_cost=cost, uid=self.uid)
-        self._copy_values(model)
-        model._set_parent(self)
-        model.num_iterations = it
-        return model
+        with tracing.span("phase", "fit.finish"):
+            if moved > 0.0:
+                # the steps report the cost at the centres they were GIVEN:
+                # the returned ones get an assignment-only pass of their own
+                cost = float(dispatch(
+                    ds.tree_aggregate_fn(lloyd_aggregator(fused, False)),
+                    "cost", centers)["cost"])
+            on_kernel = fused and all(s == shards for s in kernel_shards)
+            model = KMeansModel(centers, training_cost=cost, uid=self.uid)
+            self._copy_values(model)
+            model._set_parent(self)
+            model.summary = KMeansSummary(
+                k=k, num_iter=it, training_cost=cost,
+                cluster_sizes=[float(c) for c in counts],
+                total_steps=it, total_dispatches=n_dispatches,
+                orientation="row_major" if on_kernel else "xla",
+                pieces=CENTRE_PIECES if str(ds.x.dtype) == "bfloat16"
+                else None)
+            return model
 
     # -- initialization --------------------------------------------------------
     def _init_centers(self, ds: InstanceDataset, k: int) -> np.ndarray:
@@ -271,7 +384,12 @@ class KMeansModel(Model, _KMeansParams, MLWritable, MLReadable):
         self._declare_kmeans_params()
         self._centers = np.asarray(centers) if centers is not None else None
         self.training_cost = training_cost
-        self.num_iterations = 0
+        self.summary: Optional[KMeansSummary] = None
+
+    @property
+    def num_iterations(self) -> int:
+        """Alias of ``summary.num_iter`` (0 for a model that was loaded)."""
+        return self.summary.num_iter if self.summary is not None else 0
 
     @property
     def cluster_centers(self):

@@ -295,3 +295,75 @@ def test_irls_programs_at_the_cells_shape_read_x_as_stored(topo, which):
     assert _x_ops(text, n) == [] and _wide_x(text, n, d) == []
     # the margins: ONE contraction of the stored X with beta's three pieces
     assert len(re.findall(rf"= f32\[{n},3\]\S* convolution\(", text)) == 1
+
+
+# -- KMeans' Lloyd step ---------------------------------------------------------
+
+def _compile_lloyd(topo, n, d, k, update, n_chips=1):
+    """The program one Lloyd step of ``KMeans`` dispatches —
+    ``kmeans.lloyd_aggregator(fused=True, update)`` under psum — with the
+    replicated float32 centres as its one extra argument."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from cycloneml_tpu.ml.clustering import kmeans
+    agg = kmeans.lloyd_aggregator(True, update)
+    mesh = Mesh(np.array(topo.devices[:n_chips]), ("data",))
+    rows, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    args = [jax.ShapeDtypeStruct((n, d), jnp.bfloat16, sharding=rows),
+            jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rows),
+            jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rows),
+            jax.ShapeDtypeStruct((k, d), jnp.float32, sharding=rep)]
+
+    def program(*a):
+        local = lambda *b: jax.tree_util.tree_map(
+            lambda t: jax.lax.psum(t, "data"), agg(*b))
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(P("data"),) * 3 + (P(),),
+                             out_specs=P(), check_vma=False)(*a)
+
+    with jax.enable_x64(False):
+        return jax.jit(program).lower(*args).compile()
+
+
+def _score_values(text, rows, k):
+    """Instructions that hold a value of any type with X's rows and k (or
+    the padded k) columns: a distance or one-hot matrix."""
+    pads = {k, -(-k // 16) * 16, -(-k // 128) * 128}
+    return [line.strip() for line in text.splitlines()
+            if re.search(rf"= \w+\[{rows},(?:{'|'.join(map(str, pads))})\]",
+                         line)]
+
+
+@pytest.mark.parametrize("update,name", [(True, "kmeans_lloyd"),
+                                         (False, "kmeans_lloyd_cost")])
+def test_lloyd_step_program_at_the_cells_shape(topo, update, name):
+    """``kmeans_k1000_lloyd_fit``: 25,000,000 x 128 bf16 on one chip, k =
+    1,000. X arrives ``{1,0}`` (a width of 128: the first row-major cell);
+    the step is ONE Mosaic call (the kernel branch of the weights' cond;
+    the other branch is the row-blocked twin) over 6.4 GB of arguments, and
+    holds no ``(rows, k)`` value of any type, no f32 value of X's shape and
+    no pad or copy of X; its temporaries are the ``(1, n)`` row of w and
+    one chunk of the twin."""
+    n, d, k = 25_000_000, 128, 1000
+    compiled = _compile_lloyd(topo, n, d, k, update)
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert _entry_layout_of_x(text) == "1,0"
+    assert text.count("tpu_custom_call") == 1 and name in text
+    assert n * d * 2 <= mem.argument_size_in_bytes <= n * d * 2 + (1 << 28)
+    assert mem.temp_size_in_bytes < 1 << 30
+    assert _x_ops(text, n) == [] and _wide_x(text, n, d) == []
+    assert _score_values(text, n, k) == []
+
+
+def test_lloyd_step_program_on_four_chips(topo):
+    """The stated deployment: 100,000,000 x 128 over the 2x2 host, every
+    chip's 25,000,000-row shard read as it lies, the ``(k, d)`` sums
+    all-reduced."""
+    n, d, k = 100_000_000, 128, 1000
+    text = _compile_lloyd(topo, n, d, k, True, n_chips=4).as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+    for rows in (n, n // 4):
+        assert _x_ops(text, rows) == [] and _wide_x(text, rows, d) == []
+        assert _score_values(text, rows, k) == []

@@ -1,0 +1,357 @@
+"""Lloyd's steps from a stated starting set (``KMeans(initialModel=...)``):
+the fused assign-and-update kernel in the Pallas interpreter against its
+row-blocked XLA twin and float64 numpy, the precision its three bf16 pieces
+of a centre buy, the estimator against the benchmark's plain reference
+(``perfbench/reference/kmeans_lloyd.py``), and the spans, counters and
+summary of a fit. The program compiled for a described v5e is in
+``tests/test_glm_layout_aot.py`` (every such compile lives there)."""
+
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+from cycloneml_tpu.ml.clustering import KMeans, KMeansModel
+from cycloneml_tpu.ops import kernels
+from cycloneml_tpu.ops import kmeans_lloyd as kl
+
+ROW_AXES = ("replica", "data")
+
+
+def _mixture(n, d, k, seed, spread=1.0):
+    rng = np.random.RandomState(seed)
+    mu = rng.randn(k, d)
+    x = mu[rng.randint(0, k, n)] + spread * rng.randn(n, d)
+    xb = jnp.asarray(x, jnp.bfloat16)
+    return xb, np.asarray(xb.astype(jnp.float32), np.float64), mu
+
+
+def _lloyd_numpy(x64, w, c64):
+    """``(sums, counts, cost, gap)`` in float64: ``gap`` is every row's
+    distance between its best two centres."""
+    d2 = ((x64[:, None, :] - c64[None]) ** 2).sum(-1)
+    a = d2.argmin(1)
+    k = c64.shape[0]
+    sums = np.zeros_like(c64)
+    np.add.at(sums, a, w[:, None] * x64)
+    two = np.sort(d2, axis=1)[:, :2]
+    return (sums, np.bincount(a, weights=w, minlength=k),
+            float((w * d2.min(1)).sum()), two[:, 1] - two[:, 0])
+
+
+# -- the kernel ----------------------------------------------------------------
+
+@pytest.mark.parametrize("n,k,tile", [(1000, 24, 256), (1024, 32, 512),
+                                      (640, 1000, 128)])
+def test_kernel_against_the_twin_and_float64(n, k, tile):
+    """Same sums, counts and cost from the kernel (interpreted), the twin
+    and float64 numpy: the rows of the last tile past n are masked
+    (``tail_rows`` = 232 and 0), rows of weight 0 count for nothing, a
+    padded centre (k = 24 -> 32, 1,000 -> 1,008; none at k = 32) is never
+    chosen, and duplicate centres give every row to the LOWER index."""
+    d = 128
+    xb, x64, mu = _mixture(n, d, k, seed=k)
+    rng = np.random.RandomState(1)
+    c = (mu + 0.5 * rng.randn(k, d)).astype(np.float32)
+    c[5] = c[3]
+    w = np.ones(n, np.float32)
+    w[::7] = 0.0
+    sums, counts, cost, gap = _lloyd_numpy(x64, w.astype(np.float64),
+                                           c.astype(np.float64))
+    assert gap[gap > 0].min() > 1e-3       # no near-tie but the planted
+    got = kl.fused_lloyd_step(xb, w, jnp.asarray(c), interpret=True,
+                              tile=tile)
+    twin = kl.blocked_lloyd_step(xb, w, jnp.asarray(c), chunk=300)
+    for out in (got, twin):
+        np.testing.assert_array_equal(np.asarray(out["counts"]), counts)
+        assert out["sums"].shape == (k, d) and out["counts"].shape == (k,)
+        np.testing.assert_allclose(np.asarray(out["sums"]), sums, rtol=1e-6,
+                                   atol=1e-4)
+        assert float(out["cost"]) == pytest.approx(cost, rel=1e-6)
+    assert counts[5] == 0 and counts.sum() == w.sum()
+    only = kl.fused_lloyd_step(xb, w, jnp.asarray(c), interpret=True,
+                               tile=tile, update=False)
+    assert set(only) == {"cost"}
+    assert float(only["cost"]) == pytest.approx(float(got["cost"]), rel=1e-7)
+
+
+def test_one_piece_centres_move_assignments_three_pieces_keep():
+    """The stated precision, tested: centres that differ in their low bits
+    alone. Float32 scores (three bf16 pieces of a centre) assign every row
+    as float64 does wherever the best two distances differ by more than
+    the float32 bound; ONE piece (the centre rounded to bfloat16 in the
+    product) does not."""
+    n, d, k = 512, 128, 16
+    rng = np.random.RandomState(5)
+    base = rng.randn(d)
+    c = (base[None, :] * (1.0 + 2e-3 * rng.randn(k, d))).astype(np.float32)
+    xb = jnp.asarray(base[None, :] + 0.05 * rng.randn(8 * n, d),
+                     jnp.bfloat16)
+    x64 = np.asarray(xb.astype(jnp.float32), np.float64)
+    gap = _lloyd_numpy(x64, np.ones(8 * n), c.astype(np.float64))[3]
+    # the float32 bound on a score is a few ulps of |x|^2 ~ 130: keep the
+    # rows whose best two centres lie 1e-3 apart or more
+    keep = np.nonzero(gap > 1e-3)[0][:n]
+    assert len(keep) == n
+    xb, x64 = xb[keep], x64[keep]
+    w = np.ones(n, np.float32)
+    _, counts, _, gap = _lloyd_numpy(x64, w.astype(np.float64),
+                                     c.astype(np.float64))
+    three = kl.fused_lloyd_step(xb, w, jnp.asarray(c), interpret=True)
+    one = kl.fused_lloyd_step(xb, w, jnp.asarray(c), interpret=True,
+                              pieces=1)
+    np.testing.assert_array_equal(np.asarray(three["counts"]), counts)
+    assert np.abs(np.asarray(one["counts"]) - counts).sum() > 10
+    twin = kl.blocked_lloyd_step(xb, w, jnp.asarray(c))
+    np.testing.assert_array_equal(np.asarray(twin["counts"]), counts)
+    twin1 = kl.blocked_lloyd_step(xb, w, jnp.asarray(c), pieces=1)
+    np.testing.assert_array_equal(np.asarray(twin1["counts"]),
+                                  np.asarray(one["counts"]))
+
+
+def test_twin_takes_any_weights_and_any_storage():
+    n, d, k = 700, 24, 7
+    rng = np.random.RandomState(9)
+    x = rng.randn(n, d)
+    c = rng.randn(k, d)
+    w = rng.uniform(0.0, 2.0, n)
+    sums, counts, cost, _ = _lloyd_numpy(x, w, c)
+    out = kl.blocked_lloyd_step(jnp.asarray(x), jnp.asarray(w),
+                                jnp.asarray(c), chunk=256)
+    np.testing.assert_allclose(np.asarray(out["sums"]), sums, rtol=1e-10)
+    np.testing.assert_allclose(np.asarray(out["counts"]), counts, rtol=1e-12)
+    assert float(out["cost"]) == pytest.approx(cost, rel=1e-10)
+
+
+def test_weights_pick_the_form_inside_the_program():
+    """One live weight value (0 or c) takes the kernel under the mask and
+    scales by c; a second live value takes the twin: same sums either way."""
+    n, d, k = 512, 128, 8
+    xb, x64, mu = _mixture(n, d, k, seed=3)
+    c = mu.astype(np.float32)
+    for w, on_kernel in ((np.where(np.arange(n) % 3, 0.25, 0.0), 1.0),
+                         (np.where(np.arange(n) % 3, 0.25, 0.5), 0.0)):
+        sums, counts, cost, _ = _lloyd_numpy(x64, w, c.astype(np.float64))
+        out = kl.lloyd_step(xb, jnp.asarray(w, jnp.float32), jnp.asarray(c),
+                            fused=True, interpret=True)
+        assert float(out["kernel_shards"]) == on_kernel
+        np.testing.assert_allclose(np.asarray(out["counts"]), counts,
+                                   rtol=1e-6)
+        np.testing.assert_allclose(np.asarray(out["sums"]), sums, rtol=1e-5,
+                                   atol=1e-4)
+        assert float(out["cost"]) == pytest.approx(cost, rel=1e-6)
+
+
+def test_tile_selection():
+    tile, bf16 = kl.lloyd_tile, jnp.bfloat16
+    assert tile(25_000_000, 128, 1000, bf16) == 1024
+    assert tile(512, 128, 24, bf16) == 512
+    assert tile(100, 128, 24, bf16) is None                 # rows < 128
+    assert tile(10 ** 6, 100, 24, bf16) is None             # d % 128
+    assert tile(10 ** 6, 128, 24, jnp.float32) is None      # storage
+    assert tile(10 ** 6, 128, 2048, bf16) == 256
+    assert tile(10 ** 6, 128, 4096, bf16) is None           # VMEM
+
+
+# -- the estimator -------------------------------------------------------------
+
+def _interpreted(monkeypatch):
+    """The package never interprets: the test makes ``pallas_call`` do so."""
+    native_call = kernels.pl.pallas_call
+    monkeypatch.setattr(
+        kernels.pl, "pallas_call",
+        lambda *a, **kw: native_call(*a, **{**kw, "interpret": True}))
+
+
+def _benchmark_points(ctx, rows_per_shard, k, data_seed, order_seed=3):
+    """The benchmark's clustered points as the program's dataset and as the
+    reference sees them: ``(InstanceDataset, (x_raw, y, mesh, axes))``."""
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    from perfbench import kmeans_points
+    rt = ctx.mesh_runtime
+    rng = np.random.RandomState(order_seed)
+    rows = rows_per_shard * rt.n_devices
+    # the generator's part (perfbench/datagen.py runs without x64 only):
+    # stored standard-normal bf16 rows and a label nothing reads
+    x = rt.device_put_sharded_rows(
+        np.asarray(jnp.asarray(rng.randn(rows, 128), jnp.bfloat16)))
+    y = rt.device_put_sharded_rows(np.zeros(rows, np.float32))
+    pts = kmeans_points.points(x, rt.mesh, ROW_AXES, k=k, r=1.0,
+                               data_seed=data_seed)
+    n = x.shape[0]
+    ds = InstanceDataset(
+        ctx, pts, y, rt.device_put_sharded_rows(np.ones(n, np.float32)),
+        n, 128)
+    return ds, (x, y, rt.mesh, ROW_AXES)
+
+
+def _fit(ctx, est, ds, pallas):
+    from cycloneml_tpu.conf import USE_PALLAS_KERNELS
+    ctx.conf.set(USE_PALLAS_KERNELS, pallas)
+    try:
+        return est.fit(ds)
+    finally:
+        ctx.conf.set(USE_PALLAS_KERNELS, "false")
+
+
+@pytest.mark.parametrize("k,pallas", [(24, "true"), (32, "true"),
+                                      (1000, "true"), (24, "false")])
+def test_estimator_against_the_plain_reference(ctx, monkeypatch, k, pallas):
+    """``KMeans(initialModel=...)`` on bf16 mixture points, through the
+    kernel (k padded to 32 and 1,008, and unpadded) and through the twin,
+    lands on the reference's centres after the reference's number of steps
+    and reports the reference's cost at them."""
+    from perfbench import judge, kmeans_points
+    from perfbench.reference import kmeans_lloyd
+    seed = 40 + k
+    ds, data = _benchmark_points(ctx, 512, k, seed)
+    monkeypatch.setattr(kmeans_points, "spec", lambda name: {
+        "k": k, "r": 1.0, "data_seed": seed})
+    _interpreted(monkeypatch)
+    params = {"k": k, "maxIter": 20, "tol": 1e-4}
+    start = kmeans_points.start(seed, k, 128, 1.0)
+    model = _fit(ctx, KMeans(**params, initialModel=start), ds, pallas)
+    s = model.summary
+    assert s.orientation == ("row_major" if pallas == "true" else "xla")
+    assert s.pieces == 3 and s.k == k
+    ref = kmeans_lloyd.fit(data, params)
+    assert s.num_iter == s.total_steps == ref["iterations"]
+    assert s.total_dispatches in (s.total_steps, s.total_steps + 1)
+    answer = {"coef": model.cluster_centers_matrix().to_array().ravel(),
+              "intercept": 0.0, "objective": s.training_cost}
+    got = judge.compare([answer], ref, {"coef_gap": 1e-6,
+                                        "objective_gap": 1e-6})
+    assert got["coef_gap"]["ok"] and got["objective_gap"]["ok"], got
+    assert model.training_cost == s.training_cost
+    assert sum(s.cluster_sizes) == ds.n_rows
+    # the planted lower precision is another result at these limits
+    one = kmeans_lloyd.fit(data, params, centre_bits=7)
+    low = judge.compare([one], ref, {"coef_gap": 1e-6,
+                                     "objective_gap": 1e-6})
+    assert not (low["coef_gap"]["ok"] and low["objective_gap"]["ok"])
+
+
+def test_initial_model_param(ctx, tmp_path):
+    """A ``(k, d)`` array or a ``KMeansModel``; the wrong k raises; it
+    survives ``copy`` and save / load; an empty cluster keeps its centre;
+    ``initMode`` keeps its meaning when it is unset."""
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    rng = np.random.RandomState(2)
+    x = np.vstack([rng.randn(200, 6) + 4.0, rng.randn(200, 6) - 4.0])
+    ds = InstanceDataset.from_numpy(ctx, x)
+    far = np.full(6, 100.0)
+    start = np.vstack([x[0], x[-1], far])
+    est = KMeans(k=3, initialModel=start, maxIter=10)
+    model = est.fit(ds)
+    centres = model.cluster_centers_matrix().to_array()
+    np.testing.assert_array_equal(centres[2], far)       # empty: kept
+    np.testing.assert_allclose(centres[0], x[:200].mean(0), rtol=1e-9)
+    assert model.summary.cluster_sizes == [200.0, 200.0, 0.0]
+    assert model.num_iterations == model.summary.num_iter == 2
+    assert model.summary.training_cost == pytest.approx(
+        ((x[:200] - centres[0]) ** 2).sum() + ((x[200:] - centres[1]) ** 2
+                                               ).sum(), rel=1e-9)
+    # the last step moved nothing: its cost is the returned centres' own
+    assert model.summary.total_dispatches == model.summary.total_steps
+    assert "initialModel" not in model._params
+    again = KMeans(k=3, initialModel=KMeansModel(start)).fit(ds)
+    np.testing.assert_array_equal(
+        again.cluster_centers_matrix().to_array(), centres)
+    np.testing.assert_array_equal(est.copy().get("initialModel"), start)
+    est.save(str(tmp_path / "est"))
+    back = KMeans.load(str(tmp_path / "est"))
+    np.testing.assert_array_equal(
+        np.asarray(back.get("initialModel")), start)
+    np.testing.assert_array_equal(
+        back.fit(ds).cluster_centers_matrix().to_array(), centres)
+    with pytest.raises(ValueError, match=r"initialModel holds centres of shape \(3, 6\); k is 4"):
+        KMeans(k=4, initialModel=start).fit(ds)
+    with pytest.raises(ValueError, match="initialModel"):
+        KMeans(k=3, initialModel=np.zeros(6))
+    for mode in ("random", "k-means||"):
+        m = KMeans(k=2, initMode=mode, seed=4).fit(ds)
+        assert sorted(m.summary.cluster_sizes) == [200.0, 200.0]
+
+
+def test_span_tree_counters_and_a_warm_fit_builds_nothing(ctx, monkeypatch):
+    """One fit under the tracer: ``fit.prepare``, then per step ``phase
+    lloyd.iteration`` ⊃ ``dispatch kmeans.step`` ⊃ ``transfer
+    kmeans.readback`` directly under the job span (no ``fit.optimize``),
+    ``fit.finish`` ⊃ ``dispatch kmeans.cost``; one ``kernel.kmeans_lloyd``
+    instant a program built; ``summary.total_steps`` = the iteration spans
+    = the steps recorded. The aggregator is cached by value, so the second
+    fit compiles nothing and adds no program."""
+    from cycloneml_tpu.ml.clustering import kmeans
+    from cycloneml_tpu.observe import tracing
+    from cycloneml_tpu.parallel import collectives
+    k = 24
+    ds, _ = _benchmark_points(ctx, 512 + 40, k, 77)
+    from perfbench import kmeans_points
+    _interpreted(monkeypatch)
+    assert kmeans.lloyd_aggregator(True, True) is \
+        kmeans.lloyd_aggregator(True, True)
+    assert kmeans.lloyd_aggregator(True, True).__name__ \
+        == "kmeans_lloyd_step"
+    assert kmeans.lloyd_aggregator(True, False).__name__ \
+        == "kmeans_lloyd_cost"
+    est = KMeans(k=k, maxIter=3, initialModel=kmeans_points.start(
+        77, k, 128, 1.0))
+    steps = []
+    monkeypatch.setattr(type(ctx), "record_step",
+                        lambda self, m: steps.append(m), raising=False)
+    tracing.disable()
+    tracer = tracing.enable(max_spans=50_000)
+    try:
+        fits = []
+        for _ in range(2):
+            tracer.clear()
+            steps.clear()
+            model = _fit(ctx, est, ds, "true")
+            fits.append((model, tracer.snapshot(), list(steps),
+                         len(collectives._program_cache)))
+    finally:
+        tracing.disable()
+    (cold, built, _, size), (warm, spans, recorded, size_again) = fits
+    notes = [s for s in built if s.name == "kernel.kmeans_lloyd"]
+    assert [s.attrs for s in notes] == [
+        {"k": k, "k_pad": 32, "pieces": 3, "row_tile": 512, "tail_rows": 40,
+         "orientation": "row_major", "update": update}
+        for update in ("onehot", "none")]
+    assert [s for s in built if s.kind == "compile"]     # the cold fit did
+    assert size_again == size
+    assert not [s for s in spans if s.kind == "compile"
+                or s.name in ("kernel.kmeans_lloyd", "cache.miss")]
+    by_id = {s.span_id: s for s in spans}
+    job, = [s for s in spans if s.kind == "job"]
+    assert job.name == "KMeans.fit"
+
+    def path(s):
+        out = []
+        while s is not None and s.span_id != job.span_id:
+            out.append(f"{s.kind} {s.name}")
+            s = by_id.get(s.parent_id)
+        return " < ".join(out)
+
+    tree = [path(s) for s in spans
+            if s.kind in ("phase", "dispatch", "transfer")]
+    turn = ["phase lloyd.iteration",
+            "dispatch kmeans.step < phase lloyd.iteration",
+            "transfer kmeans.readback < dispatch kmeans.step < "
+            "phase lloyd.iteration"]
+    assert sorted(tree) == sorted(
+        ["phase fit.prepare"] + 3 * turn
+        + ["phase fit.finish", "dispatch kmeans.cost < phase fit.finish",
+           "transfer kmeans.readback < dispatch kmeans.cost < "
+           "phase fit.finish"])
+    turns = [s for s in spans if s.name == "lloyd.iteration"]
+    assert [s.attrs["iteration"] for s in turns] == [1, 2, 3]
+    assert all({"moved", "cost"} <= set(s.attrs) for s in turns)
+    s = warm.summary
+    assert s.total_steps == s.num_iter == len(turns) == len(recorded) == 3
+    assert recorded == [{"lloyd_steps": 1.0}] * 3
+    assert s.total_dispatches == 4 and s.orientation == "row_major"
+    # costs fall step by step, and the returned centres' is the lowest
+    costs = [t.attrs["cost"] for t in turns]
+    assert costs == sorted(costs, reverse=True) and s.training_cost < costs[-1]
+    np.testing.assert_array_equal(warm.cluster_centers_matrix().to_array(),
+                                  cold.cluster_centers_matrix().to_array())
