@@ -491,9 +491,37 @@ def kmeans_leg(ctx, n: int, d: int, k: int, devices) -> dict:
           "kmeans leg: assignments differ from float32 nearest centres")
     gap = rel_err(out["sums"], sums)
     check(gap < 1e-6, f"kmeans leg: sums {gap:.3e} off the float64 reduction")
+
+    # the step's two-piece screen against the step without it, at the
+    # fitted centres (three Lloyd steps on: their mid and lo pieces are
+    # non-zero): the same sums and counts to the bit, shard by shard
+    def screen_against_none(x, y, w, c):
+        from cycloneml_tpu.ops.kmeans_lloyd import fused_lloyd_step
+        a, b = (fused_lloyd_step(x, w, c, screen=on) for on in (True, False))
+        return {"differing": jnp.sum(a["sums"] != b["sums"])
+                + jnp.sum(a["counts"] != b["counts"]),
+                "rechecked_groups": a["rechecked_groups"],
+                "screened_groups": a["screened_groups"],
+                "cost_gap": jnp.abs(a["cost"] - b["cost"]) / b["cost"]}
+
+    fitted = jnp.asarray(model.cluster_centers_matrix().to_array(),
+                         jnp.float32)
+    screened = jax.device_get(
+        ds.tree_aggregate_fn(screen_against_none)(fitted))
+    check(int(screened["differing"]) == 0,
+          f"kmeans leg: the screened step differs from the unscreened one "
+          f"in {int(screened['differing'])} sums / counts")
+    check(float(screened["cost_gap"]) < 1e-6 * len(devices),
+          f"kmeans leg: screened cost {float(screened['cost_gap']):.3e} off")
+    print(f"chip_smoke: kmeans leg rechecked_groups "
+          f"{float(screened['rechecked_groups']):.0f} of the step's "
+          f"{float(screened['screened_groups']):.0f}, recheck_share of the "
+          f"fit {s.recheck_share}", file=sys.stderr)
     return {"n": n, "d": d, "k": k, "orientation": s.orientation,
             "steps": s.total_steps, "dispatches": s.total_dispatches,
             "training_cost": s.training_cost, "sums_vs_f64": gap,
+            "recheck_share": s.recheck_share,
+            "rechecked_groups": float(screened["rechecked_groups"]),
             "cold_fit_s": round(cold_s, 3)}
 
 

@@ -13,11 +13,18 @@ with euclidean/cosine). TPU-first formulation:
   shard, ``k·d`` within the kernel's VMEM budget, weights that hold one
   live value), the step is the Mosaic kernel ``kmeans_lloyd``
   (``ops/kmeans_lloyd.py``: scores on the MXU with the float32 centres as
-  three bf16 pieces, argmin with the lowest index on a tie, the one-hot
-  update product, X read once at storage width); everywhere else its
-  row-blocked XLA twin. ``cyclone.ml.usePallasKernels``: ``auto`` takes the
-  kernel where it exists, ``false`` forces the twin. The reference's
-  per-row ``findClosest`` with triangle-inequality pruning
+  bf16 pieces — every row tile from two of them, and from all three
+  wherever a row's two best lie within what the third can move, so the
+  decisions are three-piece decisions at two pieces' cost —, argmin with
+  the lowest index on a tie, the one-hot update product, X read once at
+  storage width); everywhere else its row-blocked XLA twin.
+  ``cyclone.ml.usePallasKernels``: ``auto`` takes the kernel where it
+  exists, ``false`` forces the twin. ``summary.pieces`` names the width the
+  decisions are held to (3), ``summary.recheck_share`` how much of the fit
+  the kernel scored again with the third piece; a fit whose near-ties stay
+  (more than ``SCREEN_BREAK_EVEN`` of the groups re-checked on two steps
+  running) goes on with the unscreened step, the faster one there. The
+  reference's per-row ``findClosest`` with triangle-inequality pruning
   (DistanceMeasure.scala:123) exists to avoid flops on a CPU; here the
   dense product on the MXU is the faster search.
 - the loop stays on the host: one dispatch and one readback a step, the
@@ -90,21 +97,26 @@ def _normalizer():
 
 
 @functools.lru_cache(maxsize=None)
-def lloyd_aggregator(fused: bool, update: bool):
+def lloyd_aggregator(fused: bool, update: bool, screen: bool = True):
     """One Lloyd step over a shard, ``kmeans_lloyd_step(x, y, w, centres) ->
-    {sums (k, d), counts (k,), cost, kernel_shards}`` (``update=False``:
-    ``kmeans_lloyd_cost``, the assignment-only pass: ``cost`` alone) by
-    ``ops/kmeans_lloyd.lloyd_step``. Cached by VALUE, so every fit asks
-    ``tree_aggregate`` for the same function and gets the same program
-    (``jit_tree_aggregate__kmeans_lloyd_step`` in a device capture): a
-    closure built per fit would be re-traced per fit. ``fused`` is the
-    caller's word that the kernel exists for its X."""
+    {sums (k, d), counts (k,), cost, kernel_shards, screened_groups,
+    rechecked_groups}`` (``update=False``: ``kmeans_lloyd_cost``, the
+    assignment-only pass: ``cost`` and ``kernel_shards``; ``screen=False``:
+    ``kmeans_lloyd_step_unscreened``, the kernel's step at three pieces on
+    every tile) by ``ops/kmeans_lloyd.lloyd_step``. Cached by VALUE, so
+    every fit asks ``tree_aggregate`` for the same function and gets the
+    same program (``jit_tree_aggregate__kmeans_lloyd_step`` in a device
+    capture): a closure built per fit would be re-traced per fit. ``fused``
+    is the caller's word that the kernel exists for its X."""
     def kmeans_lloyd_step(x, y, w, centres):
         from cycloneml_tpu.ops.kmeans_lloyd import lloyd_step
-        return lloyd_step(x, w, centres, fused=fused, update=update)
+        return lloyd_step(x, w, centres, fused=fused, update=update,
+                          screen=screen)
 
     if not update:
         kmeans_lloyd_step.__name__ = "kmeans_lloyd_cost"
+    elif not screen:
+        kmeans_lloyd_step.__name__ = "kmeans_lloyd_step_unscreened"
     return kmeans_lloyd_step
 
 
@@ -115,9 +127,18 @@ class KMeansSummary:
     program did: ``total_steps`` (passes over X that updated the centres),
     ``total_dispatches`` (those and the assignment-only pass),
     ``orientation`` (``row_major``: every step ran the Mosaic kernel;
-    ``xla``: the row-blocked twin) and ``pieces`` (bf16 pieces of a centre
-    in the scores: 3 on a bfloat16 X, None where X is wider and the product
-    is ``highest``). ``training_cost`` is the cost AT the returned centres
+    ``xla``: the row-blocked twin), ``pieces`` (the bf16 pieces of a centre
+    the DECISIONS are held to: 3 on a bfloat16 X — float32-faithful scores,
+    whatever the kernel's screen paid for them —, None where X is wider and
+    the product is ``highest``) and ``recheck_share`` (over the fit's
+    screened steps, the share of the 128-row groups the kernel's two-piece
+    screen scored that it sent to all three pieces because a row's two best
+    centres lay within what the third piece can move: near 0 for
+    well-separated data, towards 1 for data full of near-ties — a fit that
+    reads more than ``SCREEN_BREAK_EVEN`` on two steps running takes the
+    unscreened step from there on, the same ``sums`` and ``counts`` at four
+    passes; None where no step screened). ``training_cost`` is the cost AT
+    the returned centres
     (MLlib: at the centres before the last update) and ``cluster_sizes``
     the last step's assignment counts (weighted)."""
 
@@ -129,6 +150,7 @@ class KMeansSummary:
     total_dispatches: int
     orientation: str
     pieces: Optional[int]
+    recheck_share: Optional[float] = None
 
 
 class KMeans(Estimator, _KMeansParams, MLWritable, MLReadable):
@@ -193,7 +215,9 @@ class KMeans(Estimator, _KMeansParams, MLWritable, MLReadable):
         import jax.numpy as jnp
         from cycloneml_tpu.dataset.instance import compute_dtype
         from cycloneml_tpu.ops.kernels import use_fused_kernels
-        from cycloneml_tpu.ops.kmeans_lloyd import CENTRE_PIECES, lloyd_tile
+        from cycloneml_tpu.ops.kmeans_lloyd import (
+            CENTRE_PIECES, SCREEN_BREAK_EVEN, lloyd_tile,
+        )
 
         with tracing.span("phase", "fit.prepare"):
             k = self.get("k")
@@ -212,10 +236,16 @@ class KMeans(Estimator, _KMeansParams, MLWritable, MLReadable):
             fused = use_fused_kernels(ds.ctx) and lloyd_tile(
                 rows, ds.n_features, k, ds.x.dtype) is not None
             step = ds.tree_aggregate_fn(lloyd_aggregator(fused, True))
+            # what a fit full of near-ties goes on with (a program is built
+            # by its first dispatch: none, for most fits)
+            unscreened = ds.tree_aggregate_fn(
+                lloyd_aggregator(fused, True, False))
 
         n_dispatches = 0
         shards = ds.ctx.mesh_runtime.data_parallelism
         kernel_shards = []
+        screened = rechecked = 0.0
+        past = 0        # steps running whose re-checks passed break-even
 
         def dispatch(program, name, at):
             """One launch of ``program`` at the centres ``at`` and its one
@@ -237,8 +267,20 @@ class KMeans(Estimator, _KMeansParams, MLWritable, MLReadable):
             with tracing.span("phase", "lloyd.iteration",
                               iteration=it) as isp:
                 out = dispatch(step, "step", centers)
+                groups = {key: float(out[key]) for key in
+                          ("screened_groups", "rechecked_groups")}
+                screened += groups["screened_groups"]
+                rechecked += groups["rechecked_groups"]
+                # near-ties that stay (duplicate data under k centres, a
+                # lattice): the unscreened step is the faster from here on.
+                # Two steps running, because a start with duplicate centres
+                # is past break-even once and never again
+                past = past + 1 if groups["rechecked_groups"] \
+                    > SCREEN_BREAK_EVEN * groups["screened_groups"] else 0
+                if past == 2:
+                    step = unscreened
                 if hasattr(ds.ctx, "record_step"):
-                    ds.ctx.record_step({"lloyd_steps": 1.0})
+                    ds.ctx.record_step({"lloyd_steps": 1.0, **groups})
                 counts = np.asarray(out["counts"], dtype=np.float64)
                 sums = np.asarray(out["sums"], dtype=np.float64)
                 cost = float(out["cost"])
@@ -252,7 +294,7 @@ class KMeans(Estimator, _KMeansParams, MLWritable, MLReadable):
                 moved = float(
                     np.linalg.norm(new_centers - centers, axis=1).max())
                 centers = new_centers
-                isp.annotate(moved=moved, cost=cost)
+                isp.annotate(moved=moved, cost=cost, **groups)
             if moved < tol:
                 break
 
@@ -273,7 +315,8 @@ class KMeans(Estimator, _KMeansParams, MLWritable, MLReadable):
                 total_steps=it, total_dispatches=n_dispatches,
                 orientation="row_major" if on_kernel else "xla",
                 pieces=CENTRE_PIECES if str(ds.x.dtype) == "bfloat16"
-                else None)
+                else None,
+                recheck_share=rechecked / screened if screened else None)
             return model
 
     # -- initialization --------------------------------------------------------

@@ -299,15 +299,16 @@ def test_irls_programs_at_the_cells_shape_read_x_as_stored(topo, which):
 
 # -- KMeans' Lloyd step ---------------------------------------------------------
 
-def _compile_lloyd(topo, n, d, k, update, n_chips=1):
+def _compile_lloyd(topo, n, d, k, update, n_chips=1, screen=True):
     """The program one Lloyd step of ``KMeans`` dispatches —
     ``kmeans.lloyd_aggregator(fused=True, update)`` under psum — with the
-    replicated float32 centres as its one extra argument."""
+    replicated float32 centres as its one extra argument (``screen=False``:
+    the program a fit full of near-ties goes on with)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from cycloneml_tpu.ml.clustering import kmeans
-    agg = kmeans.lloyd_aggregator(True, update)
+    agg = kmeans.lloyd_aggregator(True, update, screen)
     mesh = Mesh(np.array(topo.devices[:n_chips]), ("data",))
     rows, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
     args = [jax.ShapeDtypeStruct((n, d), jnp.bfloat16, sharding=rows),
@@ -335,9 +336,10 @@ def _score_values(text, rows, k):
                          line)]
 
 
-@pytest.mark.parametrize("update,name", [(True, "kmeans_lloyd"),
-                                         (False, "kmeans_lloyd_cost")])
-def test_lloyd_step_program_at_the_cells_shape(topo, update, name):
+@pytest.mark.parametrize("update,name,screen", [
+    (True, "kmeans_lloyd", True), (True, "kmeans_lloyd", False),
+    (False, "kmeans_lloyd_cost", True)])
+def test_lloyd_step_program_at_the_cells_shape(topo, update, name, screen):
     """``kmeans_k1000_lloyd_fit``: 25,000,000 x 128 bf16 on one chip, k =
     1,000. X arrives ``{1,0}`` (a width of 128: the first row-major cell);
     the step is ONE Mosaic call (the kernel branch of the weights' cond;
@@ -346,7 +348,7 @@ def test_lloyd_step_program_at_the_cells_shape(topo, update, name):
     no pad or copy of X; its temporaries are the ``(1, n)`` row of w and
     one chunk of the twin."""
     n, d, k = 25_000_000, 128, 1000
-    compiled = _compile_lloyd(topo, n, d, k, update)
+    compiled = _compile_lloyd(topo, n, d, k, update, screen=screen)
     text = compiled.as_text()
     mem = compiled.memory_analysis()
     assert _entry_layout_of_x(text) == "1,0"

@@ -9,6 +9,7 @@ summary of a fit. The program compiled for a described v5e is in
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from cycloneml_tpu.ml.clustering import KMeans, KMeansModel
@@ -72,6 +73,13 @@ def test_kernel_against_the_twin_and_float64(n, k, tile):
     only = kl.fused_lloyd_step(xb, w, jnp.asarray(c), interpret=True,
                                tile=tile, update=False)
     assert set(only) == {"cost"}
+    # the screened step sums the same float32 cost in another order: up to
+    # two roundings of the total from the unscreened step's ...
+    screened = float(got["cost"])
+    got = kl.fused_lloyd_step(xb, w, jnp.asarray(c), interpret=True,
+                              tile=tile, screen=False)
+    assert screened == pytest.approx(float(got["cost"]), rel=2.4e-7)
+    # ... which scores as the pass does, every tile at three pieces
     assert float(only["cost"]) == pytest.approx(float(got["cost"]), rel=1e-7)
 
 
@@ -107,6 +115,140 @@ def test_one_piece_centres_move_assignments_three_pieces_keep():
     twin1 = kl.blocked_lloyd_step(xb, w, jnp.asarray(c), pieces=1)
     np.testing.assert_array_equal(np.asarray(twin1["counts"]),
                                   np.asarray(one["counts"]))
+
+
+def _exact_in(c, pieces):
+    """``c`` (float32) cut to its first ``pieces`` bf16 pieces."""
+    parts = kernels._split3_rounded(jnp.asarray(c, jnp.float32))
+    return np.asarray(sum(p.astype(jnp.float32) for p in parts[:pieces]))
+
+
+def _near_tie_cases():
+    """``name -> (xb, w, c, tile, rechecks)``: what the step's two-piece
+    screen has to send to all three pieces (``rechecks``: does it?)."""
+    d = 128
+    cases = {}
+    for name, (n, k, tile, d) in {"tail_and_mask": (1000, 24, 256, 128),
+                                  "whole_tiles": (1024, 32, 512, 128),
+                                  "k1000": (640, 1000, 128, 128),
+                                  "wide_rows": (768, 40, 256, 256)}.items():
+        xb, _, mu = _mixture(n, d, k, seed=k)
+        c = (mu + 0.5 * np.random.RandomState(1).randn(k, d)).astype(
+            np.float32)
+        c[5] = c[3]                      # a tie in every row c[3] wins
+        w = np.ones(n, np.float32)
+        w[::7] = 0.0
+        cases[name] = (xb, w, c, tile, True)
+    d = 128
+    # well-separated clusters scored from their own (float32) centres
+    xb, _, mu = _mixture(1024, d, 16, seed=11, spread=0.05)
+    cases["well_separated"] = (xb, np.ones(1024, np.float32),
+                               mu.astype(np.float32), 256, False)
+    # two centres that differ ONLY in their third pieces (built piece by
+    # piece: 8 bits at 1, 8 bits at 2^-9, 4 bits at 2^-18), the rows on the
+    # HIGHER index's side by some 40 roundings of a float32 score: two
+    # pieces read a tie in the product and the lower norm of index 0
+    rng = np.random.RandomState(12)
+    sign = np.where(rng.rand(d) < 0.5, -1.0, 1.0)
+    hi, mid, lo = (sign * (1.0 + rng.randint(0, n, d) / n) * 2.0 ** e
+                   for n, e in ((128, 0), (128, -9), (8, -18)))
+    c = rng.randn(16, d).astype(np.float32)
+    c[0], c[1] = hi + mid, hi + mid + lo
+    assert (c[1] != c[0]).all() and (_exact_in(c[1], 2) == c[0]).all()
+    xb = jnp.asarray(c[0] + 4.0 * sign + 0.01 * rng.randn(600, d),
+                     jnp.bfloat16)
+    cases["third_pieces_alone"] = (xb, np.ones(600, np.float32), c, 256,
+                                   True)
+    # rows ON the bisector of two centres (x = m, the centres m ± e): equal
+    # distances, and whatever float32 rounding makes of them
+    m = np.asarray(jnp.asarray(rng.randn(8, d), jnp.bfloat16).astype(
+        jnp.float32))
+    e = (0.3 * rng.randn(8, d)).astype(np.float32)
+    c = np.concatenate([m + e, m - e]).astype(np.float32)
+    xb = jnp.asarray(np.repeat(m, 48, axis=0), jnp.bfloat16)
+    cases["on_the_bisector"] = (xb, np.ones(384, np.float32), c, 128, True)
+    return cases
+
+
+@pytest.mark.parametrize("case", ["tail_and_mask", "whole_tiles", "k1000",
+                                  "wide_rows", "well_separated",
+                                  "third_pieces_alone", "on_the_bisector"])
+def test_screen_returns_the_unscreened_bits(case):
+    """The step scores every tile with two pieces and re-checks, with all
+    three, the 128-row groups that hold a row whose two best lie within
+    what the third can move: ``sums`` and ``counts`` are the unscreened
+    step's BITS (tail tile, 0/1 mask, duplicate centres, planted near-ties,
+    d = 256 alike) and the re-checks are counted. The cost is the same
+    float32 sum in another order (every winner's screen score, then the
+    dropped term of a whole tile's winners at once): ISSUE 40 asked for
+    1e-7 relative, which is under one float32 ulp of the total; two sums
+    that each round it can lie two ulps apart, 2.4e-7 (``tail_and_mask``
+    reads one: 0.015625 of 144,427.66 = 1.08e-7; ``whole_tiles`` none), and
+    the screened one is no further from the float64 cost (5.2e-8 for
+    5.6e-8 there)."""
+    xb, w, c, tile, rechecks = _near_tie_cases()[case]
+    # the screen runs on whole tiles, in 128-row groups
+    screened_groups = xb.shape[0] // tile * (tile // 128)
+    got = kl.fused_lloyd_step(xb, w, jnp.asarray(c), interpret=True,
+                              tile=tile)
+    plain = kl.fused_lloyd_step(xb, w, jnp.asarray(c), interpret=True,
+                                tile=tile, screen=False)
+    for key in ("sums", "counts"):
+        np.testing.assert_array_equal(np.asarray(got[key]),
+                                      np.asarray(plain[key]))
+    # ... of what a row's float32 terms carry: ‖x‖² (on the mixtures the
+    # cost itself is of that size; on a planted tie it is nearly 0)
+    x2 = float(jnp.sum(xb.astype(jnp.float32) ** 2))
+    assert abs(float(got["cost"]) - float(plain["cost"])) <= 1e-7 * x2
+    if case in ("tail_and_mask", "whole_tiles", "k1000", "wide_rows"):
+        # two float32 roundings of the cost itself
+        assert float(got["cost"]) == pytest.approx(float(plain["cost"]),
+                                                   rel=2.4e-7)
+    assert float(plain["rechecked_groups"]) == 0.0 \
+        == float(plain["screened_groups"])
+    assert float(got["screened_groups"]) == screened_groups
+    redone = float(got["rechecked_groups"])
+    assert 0 < redone <= screened_groups if rechecks else redone == 0.0
+    if case == "third_pieces_alone":
+        # the three-piece answer is index 1; two pieces alone say index 0
+        assert np.asarray(got["counts"])[:2].tolist() == [0.0, 600.0]
+        two = kl.fused_lloyd_step(xb, w, jnp.asarray(c), interpret=True,
+                                  tile=tile, pieces=2)
+        assert np.asarray(two["counts"])[:2].tolist() == [600.0, 0.0]
+        # every group of the two whole tiles; the last tile, with rows
+        # past n, takes the unscreened form and counts nothing
+        assert redone == 4 == screened_groups
+    if case == "on_the_bisector":
+        # every row is a tie of its pair in exact arithmetic: all flagged
+        assert redone == screened_groups == 3
+        pair = np.asarray(got["counts"]).reshape(2, 8).sum(0)
+        assert pair.tolist() == [48.0] * 8
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_the_dropped_piece_is_bounded_by_the_norms(seed):
+    """The screen's bound as a property: ``|x·lo_j| <= sqrt(x2)·L`` for
+    every row and every centre (Cauchy–Schwarz with ``L`` the largest norm
+    of a third piece), and bf16-exact centres give ``L == 0``."""
+    rng = np.random.RandomState(seed)
+    k, d = 40, 128
+    c = (rng.randn(k, d) * 10.0 ** rng.uniform(-2, 2, (k, 1))).astype(
+        np.float32)
+    p, cn, bound = kl._centre_operands(jnp.asarray(c), 48, 3)
+    assert bound.shape == (1, 1) and cn.shape == (48, 1)
+    lo = np.asarray(p[:, 2 * d:].astype(jnp.float32), np.float64)
+    x = np.asarray(jnp.asarray(
+        rng.randn(500, d) * 10.0 ** rng.uniform(-1, 1, (500, 1)),
+        jnp.bfloat16).astype(jnp.float32), np.float64)
+    limit = np.sqrt((x * x).sum(1))[:, None] * float(bound[0, 0])
+    assert float(bound[0, 0]) > 0.0
+    assert (np.abs(x @ lo.T) <= limit * (1 + 1e-6)).all()
+    # the three pieces are the centre to its last bit
+    np.testing.assert_array_equal(_exact_in(c, 3), c)
+    exact = kl._centre_operands(jnp.asarray(_exact_in(c, 1)), 48, 3)
+    assert float(exact[2][0, 0]) == 0.0
+    assert float(kl._centre_operands(jnp.asarray(_exact_in(c, 2)), 48,
+                                     3)[2][0, 0]) == 0.0
 
 
 def test_twin_takes_any_weights_and_any_storage():
@@ -214,6 +356,8 @@ def test_estimator_against_the_plain_reference(ctx, monkeypatch, k, pallas):
     s = model.summary
     assert s.orientation == ("row_major" if pallas == "true" else "xla")
     assert s.pieces == 3 and s.k == k
+    assert (0.0 <= s.recheck_share <= 1.0) if pallas == "true" \
+        else s.recheck_share is None
     ref = kmeans_lloyd.fit(data, params)
     assert s.num_iter == s.total_steps == ref["iterations"]
     assert s.total_dispatches in (s.total_steps, s.total_steps + 1)
@@ -229,6 +373,59 @@ def test_estimator_against_the_plain_reference(ctx, monkeypatch, k, pallas):
     low = judge.compare([one], ref, {"coef_gap": 1e-6,
                                      "objective_gap": 1e-6})
     assert not (low["coef_gap"]["ok"] and low["objective_gap"]["ok"])
+
+
+@pytest.mark.parametrize("ties", ["stay", "once"])
+def test_a_fit_whose_near_ties_stay_goes_on_unscreened(ctx, monkeypatch,
+                                                       ties):
+    """The fit reads every step's re-checks: past ``SCREEN_BREAK_EVEN`` on
+    two steps running it takes the unscreened program for the rest (``stay``:
+    one point stored in every 128-row group under TWO centres — the empty
+    twin keeps its place, so the tie is there at every step), and lands on
+    the bits of the fit that screened to the end. A start with a duplicate
+    of a centre that MOVES is past it once (``once``) and keeps the screen."""
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    from cycloneml_tpu.ml.clustering import kmeans
+    from perfbench import kmeans_points
+    k, rt = 24, ctx.mesh_runtime
+    ds, _ = _benchmark_points(ctx, 256, k, 61)
+    start = kmeans_points.start(61, k, 128, 1.0)
+    x = np.array(jax.device_get(ds.x))
+    if ties == "stay":
+        x[::16] = 10.0
+        start = np.vstack([start, np.full((2, 128), 10.0)])
+    else:
+        start = np.vstack([start, start[:1]])
+    n = x.shape[0]
+    ds = InstanceDataset(ctx, rt.device_put_sharded_rows(x), ds.y, ds.w, n,
+                         128)
+    _interpreted(monkeypatch)
+    assert kmeans.lloyd_aggregator(True, True, False).__name__ \
+        == "kmeans_lloyd_step_unscreened"
+    steps = []
+    monkeypatch.setattr(type(ctx), "record_step",
+                        lambda self, m: steps.append(m), raising=False)
+    est = KMeans(k=len(start), maxIter=5, tol=0.0, initialModel=start)
+    model = _fit(ctx, est, ds, "true")
+    groups = 8 * 2.0            # eight shards of one 256-row tile
+    ran = [(m["screened_groups"], m["rechecked_groups"]) for m in steps]
+    assert len(ran) == 5 and ran[0] == (groups, groups)
+    if ties == "stay":
+        assert ran == [(groups, groups)] * 2 + [(0.0, 0.0)] * 3
+        assert model.summary.recheck_share == 1.0
+        assert model.summary.cluster_sizes[-2:] == [n / 16, 0.0]
+    else:
+        assert [r[0] for r in ran] == [groups] * 5
+        assert all(r[1] <= kl.SCREEN_BREAK_EVEN * groups for r in ran[1:])
+    assert model.summary.orientation == "row_major"
+    steps.clear()
+    monkeypatch.setattr(kl, "SCREEN_BREAK_EVEN", 2.0)
+    kept = _fit(ctx, est, ds, "true")
+    assert [m["screened_groups"] for m in steps] == [groups] * 5
+    np.testing.assert_array_equal(
+        kept.cluster_centers_matrix().to_array(),
+        model.cluster_centers_matrix().to_array())
+    assert kept.summary.cluster_sizes == model.summary.cluster_sizes
 
 
 def test_initial_model_param(ctx, tmp_path):
@@ -279,8 +476,11 @@ def test_span_tree_counters_and_a_warm_fit_builds_nothing(ctx, monkeypatch):
     kmeans.readback`` directly under the job span (no ``fit.optimize``),
     ``fit.finish`` ⊃ ``dispatch kmeans.cost``; one ``kernel.kmeans_lloyd``
     instant a program built; ``summary.total_steps`` = the iteration spans
-    = the steps recorded. The aggregator is cached by value, so the second
-    fit compiles nothing and adds no program."""
+    = the steps recorded, and every step's span and record carry the
+    128-row groups the kernel's screen scored (``screened_groups``) and
+    those it scored again (``rechecked_groups``): ``summary.recheck_share``
+    is their ratio over the fit. The aggregator is cached by value, so the second fit
+    compiles nothing and adds no program."""
     from cycloneml_tpu.ml.clustering import kmeans
     from cycloneml_tpu.observe import tracing
     from cycloneml_tpu.parallel import collectives
@@ -315,8 +515,9 @@ def test_span_tree_counters_and_a_warm_fit_builds_nothing(ctx, monkeypatch):
     notes = [s for s in built if s.name == "kernel.kmeans_lloyd"]
     assert [s.attrs for s in notes] == [
         {"k": k, "k_pad": 32, "pieces": 3, "row_tile": 512, "tail_rows": 40,
-         "orientation": "row_major", "update": update}
-        for update in ("onehot", "none")]
+         "orientation": "row_major", "update": update,
+         "screen_pieces": screen}
+        for update, screen in (("onehot", 2), ("none", None))]
     assert [s for s in built if s.kind == "compile"]     # the cold fit did
     assert size_again == size
     assert not [s for s in spans if s.kind == "compile"
@@ -348,7 +549,19 @@ def test_span_tree_counters_and_a_warm_fit_builds_nothing(ctx, monkeypatch):
     assert all({"moved", "cost"} <= set(s.attrs) for s in turns)
     s = warm.summary
     assert s.total_steps == s.num_iter == len(turns) == len(recorded) == 3
-    assert recorded == [{"lloyd_steps": 1.0}] * 3
+    # eight shards of 552 rows: one whole 512-row tile each (four groups)
+    # and a tail tile of 40 rows, which never screens
+    groups = 8 * 1 * 4
+    assert [set(r) for r in recorded] == [
+        {"lloyd_steps", "screened_groups", "rechecked_groups"}] * 3
+    assert [(r["lloyd_steps"], r["screened_groups"]) for r in recorded] \
+        == [(1.0, float(groups))] * 3
+    redone = [r["rechecked_groups"] for r in recorded]
+    assert all(0.0 <= r <= groups and r == int(r) for r in redone)
+    assert [(t.attrs["screened_groups"], t.attrs["rechecked_groups"])
+            for t in turns] == [(float(groups), r) for r in redone]
+    assert s.recheck_share == pytest.approx(sum(redone) / (3 * groups))
+    assert s.recheck_share == cold.summary.recheck_share
     assert s.total_dispatches == 4 and s.orientation == "row_major"
     # costs fall step by step, and the returned centres' is the lowest
     costs = [t.attrs["cost"] for t in turns]
