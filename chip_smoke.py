@@ -9,7 +9,8 @@ environment. It drives the main path through the public entry points —
 both forms of the moment Gramian: one MXU pass, then three), a small ten-class
 ``LogisticRegression`` fit (the fused multinomial sweep under the
 device-resident L-BFGS), a ``KMeans`` fit from a stated starting set (the fused
-Lloyd step), then compiles and checks every
+Lloyd step), the stacked binomial sweep (K binary models a read of X) against K
+serial binomial sweeps at mnist8m's width and at a lane-aligned one, then compiles and checks every
 Pallas kernel natively at small n. Every leg asserts WHICH path ran (platform, data dtype, Mosaic custom call,
 one in-core dispatch) and that what came out is right (finite non-increasing
 objective, agreement with the XLA twin and with a float64 reference).
@@ -525,6 +526,102 @@ def kmeans_leg(ctx, n: int, d: int, k: int, devices) -> dict:
             "cold_fit_s": round(cold_s, 3)}
 
 
+def stacked_leg(ctx, devices) -> dict:
+    """Leg 6: the stacked binomial sweep — K binary models from ONE read of
+    a bf16 X, the K-class sweep's body under K sigmoids — against K serial
+    binomial sweeps of the same stored rows (model j on ``1[y == j]``), in
+    the tiling each array's layout dictates: the largest loss and gradient
+    gap over the models, relative to the serial sweep's. Then one stacked
+    fit twice, in core and streamed (``cyclone.oocore.mode=force``: the
+    same sweep a staged shard at a time, no label stack staged)."""
+    import jax
+    import jax.numpy as jnp
+    from cycloneml_tpu.ops import kernels
+
+    out = {"pieces": kernels.SOFTMAX_PIECES}
+    rng = np.random.default_rng(41)
+    for n, d, k in ((131_072, 784, 10), (131_072, 784, 3), (4_096, 1_280, 10)):
+        x = jnp.asarray(rng.standard_normal((n, d), dtype=np.float32),
+                        jnp.bfloat16)
+        y = jnp.asarray(rng.integers(0, k, n), jnp.float32)
+        w = jnp.ones(n, jnp.float32)
+        inv_std = jnp.asarray(1.0 + rng.random(d), jnp.float32)
+        mean = jnp.asarray(0.1 * rng.standard_normal(d), jnp.float32)
+        coef = jnp.asarray(0.05 * rng.standard_normal((k, d + 1)),
+                           jnp.float32)
+        feature_major = kernels.stored_feature_major(x)
+        check(feature_major == (d % kernels.LANE != 0),
+              f"stacked leg: a {n} x {d} bf16 array is stored "
+              f"{'feature' if feature_major else 'row'}-major")
+        stacked = jax.jit(lambda x, y, c, fm=feature_major, d=d, k=k:
+                          kernels.fused_stacked_binomial_scaled(
+                              x, y, w, inv_std, mean, c, d, k, True,
+                              feature_major=fm))
+        text = stacked.lower(x, y, coef).compile().as_text()
+        check(text.count("tpu_custom_call") == 1
+              and "glm_sweep_stacked_binomial" in text,
+              f"stacked leg: {text.count('tpu_custom_call')} Mosaic calls "
+              f"for {k} models at d={d}")
+        got = jax.device_get(stacked(x, y, coef))
+        serial = jax.jit(lambda x, yj, c, fm=feature_major, d=d:
+                         kernels.fused_binary_logistic_scaled(
+                             x, yj, w, inv_std, mean, c, d, True,
+                             feature_major=fm))
+        loss_gap = grad_gap = 0.0
+        for j in range(k):
+            one = jax.device_get(serial(x, (y == j).astype(jnp.float32),
+                                        coef[j]))
+            loss_gap = max(loss_gap, abs(got["loss"][j] - one["loss"])
+                           / abs(one["loss"]))
+            grad_gap = max(grad_gap, rel_err(got["grad"][j], one["grad"]))
+        check(loss_gap < 2e-6 and grad_gap < 2e-5,
+              f"stacked leg: n={n}, d={d}, K={k}: loss gap {loss_gap:.3e}, "
+              f"gradient gap {grad_gap:.3e} against {k} serial sweeps")
+        out[f"n={n},d={d},K={k}"] = {
+            "orientation": "feature_major" if feature_major else "row_major",
+            "loss_gap": float(loss_gap), "grad_gap": float(grad_gap)}
+
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    from cycloneml_tpu.dataset.random import generate_classification
+    from cycloneml_tpu.ml.classification import LogisticRegression
+    n, d, k = N_SOFTMAX_ROWS, N_SOFTMAX_COLS, 3
+    base = generate_classification(ctx, n, d, seed=6)
+    score = jnp.asarray(rng.standard_normal((d, k)) / np.sqrt(d),
+                        jnp.bfloat16)
+    labels = jax.jit(lambda x, e: jnp.argmax(
+        jnp.dot(x, score, preferred_element_type=jnp.float32) + e,
+        axis=1).astype(jnp.float32))(
+        base.x, ctx.mesh_runtime.device_put_sharded_rows(
+            (0.5 * rng.standard_normal((n, k))).astype(np.float32)))
+    ds = InstanceDataset(ctx, base.x, labels, base.w, n, d)
+    lr = LogisticRegression(maxIter=100, regParam=REG)
+    incore = lr.fit_stacked(ds, num_classes=k)
+    ctx.conf.set("cyclone.oocore.mode", "force")
+    try:
+        streamed = lr.fit_stacked(ds, num_classes=k)
+    finally:
+        ctx.conf.remove("cyclone.oocore.mode")
+    si, ss = incore[0].summary, streamed[0].summary
+    check(not si.streamed and ss.streamed
+          and si.orientation == ss.orientation == "feature_major",
+          f"stacked leg: in core {si.orientation!r} (streamed "
+          f"{si.streamed}), forced {ss.orientation!r} (streamed "
+          f"{ss.streamed})")
+    coef_gap = max(rel_err(a._coef, b._coef)
+                   for a, b in zip(streamed, incore))
+    obj_gap = max(abs(a.summary.objective_history[-1]
+                      - b.summary.objective_history[-1])
+                  for a, b in zip(streamed, incore))
+    check(coef_gap < 10 * COEF_RTOL and obj_gap < LOSS_RTOL,
+          f"stacked leg: streamed against in-core fit: coefficients "
+          f"{coef_gap:.3e}, objective {obj_gap:.3e}")
+    out["streamed_vs_incore"] = {
+        "orientation": ss.orientation, "coef_gap": float(coef_gap),
+        "objective_gap": float(obj_gap),
+        "epochs": int(ss.stacked_evals), "sweeps": int(si.stacked_evals)}
+    return out
+
+
 def rel_err(got, want) -> float:
     got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
     return float(np.max(np.abs(got - want)) / max(np.max(np.abs(want)), 1e-30))
@@ -670,29 +767,6 @@ def kernel_matrix() -> dict:
                        (xs,), dict(moment_reference(xv, yr, w),
                                    mxu_passes=3.0))
 
-    # stacked fits vmap the GLM kernel (labels on axis 1 of an (n, K) bf16
-    # stack, coefficients on axis 0)
-    n, k_models = 4096, 8
-    agg = aggregators.stack_scaled_aggregator(
-        aggregators.binary_logistic_pallas_scaled(d, True))
-    for tier in ("float32", "bfloat16"):
-        x32 = rng.standard_normal((n, d), dtype=np.float32)
-        xs, _, xv = stored(x32, tier)
-        ys = (rng.random((n, k_models)) > 0.5)
-        w = np.ones(n, np.float32)
-        inv_std = np.ones(d, np.float32)
-        mean = np.zeros(d, np.float32)
-        coefs = (0.05 * rng.standard_normal((k_models, d + 1))
-                 ).astype(np.float32)
-        m = xv @ coefs[:, :d].T.astype(np.float64) + coefs[:, d]
-        mult = 1.0 / (1.0 + np.exp(-m)) - ys
-        record(f"logistic_vmap_K8/{tier}/n={n}",
-               agg, (xs, jnp.asarray(ys, jnp.bfloat16), w, inv_std, mean,
-                     coefs),
-               {"loss": np.sum(np.logaddexp(0.0, m) - ys * m, axis=0),
-                "grad": np.concatenate([mult.T @ xv,
-                                        mult.sum(0)[:, None]], axis=1)})
-
     # KMeans assignment (opt-in path; recorded for ROADMAP D3)
     n, d_k, k = 8192, 128, 1000
     centers = rng.standard_normal((k, d_k), dtype=np.float32)
@@ -765,6 +839,8 @@ def main() -> int:
     print(f"chip_smoke: softmax leg ok {softmax}", file=sys.stderr)
     lloyd = kmeans_leg(ctx, N_KMEANS_ROWS, N_KMEANS_COLS, N_CENTRES, devices)
     print(f"chip_smoke: kmeans leg ok {lloyd}", file=sys.stderr)
+    stacked = stacked_leg(ctx, devices)
+    print(f"chip_smoke: stacked leg ok {stacked}", file=sys.stderr)
     kernels_ok = kernel_matrix()
     print(f"chip_smoke: kernel matrix ok {kernels_ok}", file=sys.stderr)
     ctx.stop()
@@ -783,6 +859,7 @@ def main() -> int:
         "glr_fit": glr,
         "softmax_fit": softmax,
         "kmeans_fit": lloyd,
+        "stacked_sweep_vs_serial": stacked,
         "kernel_matrix_max_rel_err": kernels_ok,
         "compile_cache": {
             "dir": cache_dir, "entries_before": entries_before,

@@ -459,6 +459,7 @@ class InstanceDataset:
         self._yw_host: Optional[Tuple[np.ndarray, np.ndarray]] = None
         # weighted class histogram of the labels (label_histogram), once read
         self._label_histogram: Optional[np.ndarray] = None
+        self._labels_whole: Optional[bool] = None
         # real-row mask when padding is interleaved per shard (chunked
         # loaders); None means padding sits at the global tail ([:n_rows])
         self._valid_mask: Optional[np.ndarray] = valid_mask
@@ -572,9 +573,22 @@ class InstanceDataset:
         over ``n`` labels (98 ms at 8,100,000 rows: the v5e's host, PR 35)
         is paid by the first fit that asks, not by every one."""
         if self._label_histogram is None:
-            self._label_histogram = np.bincount(
-                self.y_host().astype(np.int64), weights=self.w_host())
+            y = self.y_host()
+            index = y.astype(np.int64)
+            self._label_histogram = np.bincount(index, weights=self.w_host())
+            # the same pass answers whether the histogram lost anything:
+            # a label that is no whole number was counted under its floor
+            self._labels_whole = bool(np.array_equal(index, y))
         return self._label_histogram.copy()
+
+    def labels_are_class_indices(self) -> bool:
+        """Whether every label is a whole number >= 0, so that
+        :meth:`label_histogram` counts the labels themselves — found in the
+        histogram's own pass and cached with it: what a stacked fit checks
+        once a dataset where it used to validate K relabelled float64
+        copies a fit."""
+        self.label_histogram()
+        return self._labels_whole
 
     def _restore_device(self) -> None:
         restored = False

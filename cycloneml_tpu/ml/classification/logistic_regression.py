@@ -236,34 +236,43 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
                 and not self._has_bounds()
                 and not self.get("checkpointDir"))
 
-    def fit_stacked(self, frame, y_stack=None, reg_params=None):
+    def fit_stacked(self, frame, reg_params=None,
+                    num_classes: Optional[int] = None):
         """Fit K binomial models over ONE shared design matrix as ONE
         gang-scheduled SPMD program (the sanctioned parallel path — see
         ``mesh.safe_fit_parallelism`` and docs/multi-model.md).
 
-        ``vmap`` pushes a model axis through the staged optimizer step
-        mechanically (Frostig et al. 2018; GSPMD, Xu et al. 2021): the K
-        fits share one trace + XLA compile, every ``tree_aggregate`` psum
-        carries all K gradients, and per-model convergence masks freeze
+        The K objectives are ONE aggregator with a leading model axis
+        (``aggregators.stacked_binary_logistic_*``): an evaluation reads X
+        once for all K models, every ``tree_aggregate`` psum carries all K
+        gradients, the K L-BFGS lanes share one compiled chunk program
+        (``StackedDeviceLBFGS``) and per-model convergence masks freeze
         early-converged models on device. No cross-program collective
         rendezvous exists, so — unlike thread-pool fan-out (the PR-2
         deadlock) — full model-parallelism is safe on any mesh.
 
-        ``y_stack``: (K, n) per-model {0, 1} label vectors (OneVsRest's
-        relabelings); default is the frame's own label column tiled K
-        times. ``reg_params``: per-model L2 strength (CrossValidator's
-        regParam grid); default is this estimator's ``regParam`` tiled.
-        At least one of the two must be given. Returns a list of K
-        :class:`LogisticRegressionModel` (summaries carry ``n_models``).
+        Which K models, for a dataset (a frame builds one):
+
+        - ``num_classes=K``: the dataset's labels are class indices and
+          model j's label is ``1[y == j]`` (OneVsRest's relabelling), made
+          inside the sweep from the label vector the dataset already holds:
+          no ``(n, K)`` array exists on the host or the device;
+        - ``reg_params``: per-model L2 strength over the dataset's own 0/1
+          labels (CrossValidator's regParam grid); with ``num_classes`` it
+          is model j's strength, default this estimator's ``regParam``.
+
+        A ``StreamingDataset`` (or ``cyclone.oocore.mode=force``) takes the
+        out-of-core leg: the same aggregator a staged shard at a time, the
+        labels each shard's own. Returns a list of K
+        :class:`LogisticRegressionModel` (summaries carry ``n_models`` and
+        ``stacked_evals``).
         """
         import jax.numpy as jnp
 
         from cycloneml_tpu.dataset.sparse import SparseInstanceDataset
         from cycloneml_tpu.ml.optim.device_lbfgs import StackedDeviceLBFGS
         from cycloneml_tpu.ml.optim.loss import (
-            StackedDistributedLossFunction, inv_std_vector,
-            stacked_l2_scale, validate_binary_labels,
-        )
+            StackedDistributedLossFunction, stacked_l2_scale)
 
         if not self.can_fit_stacked():
             raise ValueError(
@@ -271,6 +280,15 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
                 "non-checkpointed configuration (can_fit_stacked)")
         if isinstance(frame, SparseInstanceDataset):
             raise ValueError("stacked fits are dense-tier only")
+        if num_classes is None and reg_params is None:
+            raise ValueError("fit_stacked needs num_classes or reg_params")
+        shared_labels = num_classes is None
+        n_models = len(reg_params) if shared_labels else int(num_classes)
+        if reg_params is None:
+            reg_params = np.full(n_models, float(self.get("regParam")))
+        reg_params = np.asarray(reg_params, dtype=np.float64)
+        if len(reg_params) != n_models:
+            raise ValueError("reg_params length != number of stacked models")
         ds = frame.to_instance_dataset(
             self.get("featuresCol"), self.get("labelCol"),
             self.get("weightCol") or None, fp8_capable=True)
@@ -280,121 +298,137 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
         from cycloneml_tpu.oocore import (StreamingDataset, shard_dataset,
                                           streaming_mode)
         if isinstance(ds, StreamingDataset):
-            return self._fit_stacked_streamed(ds, y_stack, reg_params)
+            return self._fit_stacked_streamed(ds, reg_params, shared_labels)
         if streaming_mode(getattr(ds.ctx, "conf", None)) == "force":
             sds = shard_dataset(ds)
             try:
-                return self._fit_stacked_streamed(sds, y_stack, reg_params)
+                return self._fit_stacked_streamed(sds, reg_params,
+                                                  shared_labels)
             finally:
                 sds.close()
-        if y_stack is None and reg_params is None:
-            raise ValueError("fit_stacked needs y_stack or reg_params")
-        if y_stack is None:
-            y = np.asarray(ds.unpad(ds.y_host()), dtype=np.float64)
-            y_stack = np.broadcast_to(y, (len(reg_params), len(y)))
-        # keep the caller's storage (OvR hands a data-tier bf16 stack — at
-        # target scale a full (K, n) f64 clone would be 4x the stack it
-        # was narrowed to save); host-side math below converts ONE (n,)
-        # model row at a time, which is lossless for {0, 1} labels
-        y_stack = np.asarray(y_stack)
-        n_models = y_stack.shape[0]
-        if y_stack.shape[1] != ds.n_rows:
-            raise ValueError(
-                f"y_stack has {y_stack.shape[1]} rows per model; dataset "
-                f"has {ds.n_rows}")
-        for kk in range(n_models):
-            validate_binary_labels(
-                np.asarray(y_stack[kk], dtype=np.float64), "fit_stacked")
-        reg = self.get("regParam")
-        if reg_params is None:
-            reg_params = np.full(n_models, float(reg))
-        reg_params = np.asarray(reg_params, dtype=np.float64)
-        if len(reg_params) != n_models:
-            raise ValueError("reg_params length != number of stacked models")
 
         d = ds.n_features
-        stats = Summarizer.summarize(ds)
-        from cycloneml_tpu.dataset.dataset import resolve_fp8_fit
-        ds = resolve_fp8_fit(ds, stats, "LogisticRegression(stacked)")
-        fp8_scale = ds.x_scale
-        features_std = stats.std
-        weight_sum = stats.weight_sum
-        fit_intercept = self.get("fitIntercept")
-        standardize = self.get("standardization")
-        fit_with_mean = fit_intercept  # bounds are excluded by eligibility
-        inv_std = inv_std_vector(features_std)
-        scaled_mean = stats.mean * inv_std if fit_with_mean else np.zeros(d)
-        # fp8: dequant folds into the aggregator-side inv_std (see
-        # _fit_dataset); unscaling below keeps the original
-        inv_std_agg = inv_std * fp8_scale if fp8_scale is not None \
-            else inv_std
+        with tracing.span("phase", "fit.stats",
+                          cached=Summarizer.is_cached(ds)):
+            stats = Summarizer.summarize(ds)
+            # weighted class masses and the labels' check, one host pass a
+            # DATASET (cached on it), not K float64 relabellings a fit
+            hist = ds.label_histogram()
+            if not ds.labels_are_class_indices():
+                raise ValueError("fit_stacked requires class-index labels "
+                                 "(whole numbers >= 0)")
+        with tracing.span("phase", "fit.prepare"):
+            from cycloneml_tpu.dataset.dataset import resolve_fp8_fit
+            ds = resolve_fp8_fit(ds, stats, "LogisticRegression(stacked)")
+            fp8_scale = ds.x_scale
+            std = self._stacked_standardization(stats, fp8_scale)
+            x0 = self._stacked_start(hist, n_models, shared_labels, stats)
 
-        n_coef = d + (1 if fit_intercept else 0)
-        x0 = np.zeros((n_models, n_coef))
-        if fit_intercept:
-            w_real = np.asarray(ds.unpad(ds.w_host()), dtype=np.float64)
-            # per-model weighted positive mass, one f64 row at a time
-            pos = np.array([np.asarray(y_stack[kk], dtype=np.float64)
-                            @ w_real for kk in range(n_models)])
-            ok = (pos > 0) & (pos < weight_sum)
-            p1 = np.where(ok, pos / weight_sum, 0.5)
-            x0[:, d] = np.where(ok, np.log(p1 / (1.0 - p1)), 0.0)
+            # the fused stacked sweep where X's storage admits one (a
+            # resident bf16 X: kernels.multinomial_sweep_tile), in the
+            # tiling its layout dictates; the row-blocked XLA twin
+            # otherwise. Either reads X once an evaluation for all K models
+            # and makes model j's label from the dataset's own label vector
+            from cycloneml_tpu.ops import kernels
+            orientation = None
+            if kernels.use_fused_kernels(ds.ctx):
+                orientation = kernels.multinomial_sweep_orientation(
+                    ds.x, n_models)
+            loss_fn = StackedDistributedLossFunction(
+                ds, self._stacked_aggregator(d, n_models, shared_labels,
+                                             orientation),
+                n_models, reg=reg_params,
+                l2_scale=stacked_l2_scale(d, x0.shape[1], stats.std,
+                                          self.get("standardization")),
+                weight_sum=stats.weight_sum,
+                extra_args=tuple(jnp.asarray(v) for v in std.extra_args))
 
-        # the stacked (n_pad, K) label matrix rides the dataset's row
-        # sharding in the data-tier dtype ({0, 1} is exact in bf16, and at
-        # large K the stack is a real per-sweep byte cost); X itself is
-        # SHARED via derive — no second feature copy exists. Under the
-        # fp8 tier the stack stays at the bf16 rung: labels mix
-        # elementwise with f32 margins, and jax (deliberately) refuses
-        # implicit 8-bit float promotion
-        xdt = np.dtype(str(ds.x.dtype))
-        if fp8_scale is not None:
-            import ml_dtypes
-            xdt = np.dtype(ml_dtypes.bfloat16)
-        y_pad = np.zeros((len(ds.y_host()), n_models), dtype=xdt)
-        valid = ds.valid_indices()
-        for kk in range(n_models):
-            y_pad[valid, kk] = np.asarray(y_stack[kk], dtype=xdt)
-        rt = ds.ctx.mesh_runtime
-        ds_stacked = ds.derive(y=rt.device_put_sharded_rows(y_pad))
-
-        # stacked fits ride the fused Pallas kernel wherever the serial
-        # path would (vmap batches the kernel's row pass mechanically);
-        # the vmapped jnp aggregator is the fallback
-        from cycloneml_tpu.dataset.instance import compute_dtype
-        from cycloneml_tpu.ops.kernels import use_fused_kernels
-        # row-major tiling whatever X's layout: the feature-major sweep
-        # is not proved under vmap yet
-        base_agg = (aggregators.binary_logistic_pallas_scaled(
-                        d, fit_intercept, feature_major=False)
-                    if use_fused_kernels(ds.ctx)
-                    else aggregators.binary_logistic_scaled(d, fit_intercept))
-        agg = aggregators.stack_scaled_aggregator(base_agg)
-        l2s = stacked_l2_scale(d, n_coef, features_std, standardize)
-        adt = compute_dtype()  # standardization vectors: accumulator tier
-        loss_fn = StackedDistributedLossFunction(
-            ds_stacked, agg, n_models, reg=reg_params, l2_scale=l2s,
-            weight_sum=weight_sum,
-            extra_args=(jnp.asarray(inv_std_agg.astype(adt)),
-                        jnp.asarray(scaled_mean.astype(adt))))
-
-        from cycloneml_tpu.conf import LBFGS_DEVICE_CHUNK
-        chunk = int(ds.ctx.conf.get(LBFGS_DEVICE_CHUNK)) \
-            if hasattr(ds.ctx, "conf") else 0
-        # deviceChunk=0 means "one dispatch per iteration"; the stacked
-        # engine has no host loop, so honor it as chunk=1 (per-iteration
-        # dispatches) rather than silently running the default chunk
-        opt = StackedDeviceLBFGS(max_iter=self.get("maxIter"),
-                                 tol=self.get("tol"),
-                                 chunk=max(chunk, 1))
-        res = opt.minimize(loss_fn, x0)
+            from cycloneml_tpu.conf import LBFGS_DEVICE_CHUNK
+            chunk = int(ds.ctx.conf.get(LBFGS_DEVICE_CHUNK)) \
+                if hasattr(ds.ctx, "conf") else 0
+            # deviceChunk=0 means "one dispatch per iteration"; the stacked
+            # engine has no host loop, so honor it as chunk=1 (per-iteration
+            # dispatches) rather than silently running the default chunk
+            opt = StackedDeviceLBFGS(max_iter=self.get("maxIter"),
+                                     tol=self.get("tol"),
+                                     chunk=max(chunk, 1))
+        with tracing.span("phase", "fit.optimize",
+                          optimizer=type(opt).__name__, n_models=n_models):
+            res = opt.minimize(loss_fn, x0)
         if fp8_scale is not None \
                 and not np.all(np.isfinite(np.asarray(res.x))):
             from cycloneml_tpu.dataset.dataset import fp8_fallback
             return self.fit_stacked(
                 fp8_fallback(ds, "LogisticRegression(stacked)",
                              "non-finite fp8 solution"),
-                y_stack=y_stack, reg_params=reg_params)
+                reg_params=reg_params, num_classes=num_classes)
+        with tracing.span("phase", "fit.finish"):
+            return self._stacked_models(
+                res, loss_fn, std, orientation=orientation,
+                pieces=kernels.SOFTMAX_PIECES
+                if orientation is not None else None)
+
+    # what the two legs of a stacked fit (in-core above, streamed below)
+    # share: standardization vectors, starting point, sweep, models
+    def _stacked_standardization(self, stats, fp8_scale):
+        """``inv_std`` / ``scaled_mean`` of a stacked fit, and as
+        ``extra_args`` what its aggregator reads at the accumulator tier
+        (an fp8 X's dequant scale folded into ``inv_std``, as in
+        ``_fit_dataset``; the models are unscaled by the plain one)."""
+        from types import SimpleNamespace
+
+        from cycloneml_tpu.dataset.instance import compute_dtype
+        from cycloneml_tpu.ml.optim.loss import inv_std_vector
+        inv_std = inv_std_vector(stats.std)
+        # bounds are excluded by eligibility: the mean is folded exactly
+        # where an intercept is fitted
+        scaled_mean = stats.mean * inv_std if self.get("fitIntercept") \
+            else np.zeros(len(inv_std))
+        inv_std_agg = inv_std * fp8_scale if fp8_scale is not None \
+            else inv_std
+        adt = compute_dtype()
+        return SimpleNamespace(
+            inv_std=inv_std, scaled_mean=scaled_mean,
+            extra_args=(inv_std_agg.astype(adt), scaled_mean.astype(adt)))
+
+    def _stacked_start(self, hist, n_models: int, shared_labels: bool,
+                       stats) -> np.ndarray:
+        """The ``(K, n_coef)`` starting point — zeros, each intercept at
+        its model's log-odds, the positive mass a label-histogram entry —
+        after checking that the histogram holds no more classes than the
+        fit has labels for."""
+        if len(hist) > (2 if shared_labels else n_models):
+            raise ValueError(
+                "fit_stacked requires "
+                + ("binary {0, 1} labels" if shared_labels else
+                   f"class-index labels below {n_models}")
+                + f"; the dataset's histogram has {len(hist)} entries")
+        d = len(stats.std)
+        fit_intercept = self.get("fitIntercept")
+        x0 = np.zeros((n_models, d + (1 if fit_intercept else 0)))
+        if fit_intercept:
+            mass = np.append(hist, np.zeros(max(n_models, 2) - len(hist)))
+            pos = np.full(n_models, mass[1:].sum()) if shared_labels \
+                else mass[:n_models]
+            ok = (pos > 0) & (pos < stats.weight_sum)
+            p1 = np.where(ok, pos / stats.weight_sum, 0.5)
+            x0[:, d] = np.where(ok, np.log(p1 / (1.0 - p1)), 0.0)
+        return x0
+
+    def _stacked_aggregator(self, d: int, n_models: int, shared_labels: bool,
+                            orientation: Optional[str]):
+        """The fused stacked sweep in the tiling ``orientation`` names, or
+        (None) its row-blocked XLA twin."""
+        if orientation is None:
+            return aggregators.stacked_binary_logistic_scaled(
+                d, n_models, self.get("fitIntercept"), shared_labels)
+        return aggregators.stacked_binary_logistic_pallas_scaled(
+            d, n_models, self.get("fitIntercept"), shared_labels,
+            feature_major=orientation == "feature_major")
+
+    def _stacked_models(self, res, loss_fn, std, **summary):
+        """The K models of a finished stacked run, in original space."""
+        n_models = len(res.x)
         n_unconverged = sum(
             1 for r in res.converged_reasons if r == "max iterations reached")
         if n_unconverged:
@@ -402,14 +436,14 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
                 "stacked LogisticRegression: %d of %d models did not "
                 "converge in %d iterations", n_unconverged, n_models,
                 self.get("maxIter"))
-
+        d = len(std.inv_std)
         models = []
         for kk in range(n_models):
             sol = res.x[kk]
-            beta = sol[:d] * inv_std
-            icpt = float(sol[d]) if fit_intercept else 0.0
-            if fit_with_mean:
-                icpt -= float(sol[:d] @ scaled_mean)
+            beta = sol[:d] * std.inv_std
+            icpt = 0.0
+            if self.get("fitIntercept"):
+                icpt = float(sol[d]) - float(sol[:d] @ std.scaled_mean)
             model = LogisticRegressionModel(
                 coefficient_matrix=beta[None, :],
                 intercept_vector=np.array([icpt]),
@@ -421,116 +455,61 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
                 total_iterations=int(res.iterations[kk]),
                 total_evals=int(res.evals[kk]),
                 total_dispatches=loss_fn.n_dispatches,
-                n_models=n_models)
+                n_models=n_models, stacked_evals=loss_fn.n_evals, **summary)
             models.append(model)
         return models
 
-    def _fit_stacked_streamed(self, sds, y_stack=None, reg_params=None):
+    def _fit_stacked_streamed(self, sds, reg_params: np.ndarray,
+                              shared_labels: bool):
         """The out-of-core leg of :meth:`fit_stacked`: K binomial models
         over ONE shard set, each optimizer round ONE streamed epoch whose
-        per-shard program is the vmapped scaled aggregator
-        (``StackedStreamingLossFunction``) — so the spill is read once
-        per round, not once per model. The optimizer is
+        per-shard program is the stacked aggregator the in-core leg runs
+        (``StackedStreamingLossFunction``; model j's label made from the
+        shard's own label vector) — so the spill is read once per round,
+        not once per model, and no label stack is staged. The optimizer is
         :class:`StackedHostLBFGS`: K serial L-BFGS coroutines whose
         pending trial points batch into each epoch, every model making
         exactly the decisions its serial streamed fit would (the parity
         test pins rtol 1e-9 under the f64 config)."""
         import jax.numpy as jnp
 
-        from cycloneml_tpu.dataset.instance import compute_dtype
         from cycloneml_tpu.ml.optim.device_lbfgs import StackedHostLBFGS
-        from cycloneml_tpu.ml.optim.loss import (inv_std_vector,
-                                                 stacked_l2_scale,
-                                                 validate_binary_labels)
+        from cycloneml_tpu.ml.optim.loss import stacked_l2_scale
         from cycloneml_tpu.oocore import StackedStreamingLossFunction
+        from cycloneml_tpu.ops import kernels
 
-        if y_stack is None and reg_params is None:
-            raise ValueError("fit_stacked needs y_stack or reg_params")
+        n_models = len(reg_params)
         d = sds.n_features
         stats = sds.summary()   # write-pass moments: no stats epoch
-        weight_sum = stats.weight_sum
         # the fp8 decision already ran at spill time (the
         # materialization-time envelope probe in shards._finalize_fp8);
         # the dequant scale folds into inv_std exactly like in-core
         fp8_scale = getattr(sds, "x_scale", None)
+        std = self._stacked_standardization(stats, fp8_scale)
+        # the write pass's histogram (it raises on labels that are no class
+        # indices): zero label epochs
+        x0 = self._stacked_start(sds.label_histogram(), n_models,
+                                 shared_labels, stats)
 
-        if y_stack is None:
-            # tiled grid fit over the shard set's own labels: binary-ness
-            # comes from the write-pass histogram, positives from the
-            # label moments — zero label epochs
-            hist = sds.label_histogram()
-            if len(hist) > 2:
-                raise ValueError(
-                    f"fit_stacked requires binary {{0, 1}} labels; the "
-                    f"shard set carries {len(hist)} classes")
-            n_models = len(reg_params)
-            pos = np.full(n_models, stats.label_sum)
-        else:
-            y_stack = np.asarray(y_stack)
-            n_models = y_stack.shape[0]
-            if y_stack.shape[1] != sds.n_rows:
-                raise ValueError(
-                    f"y_stack has {y_stack.shape[1]} rows per model; the "
-                    f"shard set has {sds.n_rows}")
-            for kk in range(n_models):
-                validate_binary_labels(
-                    np.asarray(y_stack[kk], dtype=np.float64),
-                    "fit_stacked")
-            # per-model weighted positive mass from the shards' w members
-            # only (npz members load lazily: the packed X bytes stay on
-            # disk) — one O(n) host vector, matching the caller's own
-            # O(K·n) stack
-            w_all = np.concatenate([
-                np.asarray(np.load(s.path)["w"], dtype=np.float64)
-                for s in sds._shards])
-            pos = np.array([
-                np.asarray(y_stack[kk], dtype=np.float64) @ w_all
-                for kk in range(n_models)])
-        reg = self.get("regParam")
-        if reg_params is None:
-            reg_params = np.full(n_models, float(reg))
-        reg_params = np.asarray(reg_params, dtype=np.float64)
-        if len(reg_params) != n_models:
-            raise ValueError("reg_params length != number of stacked models")
-
-        features_std = stats.std
-        fit_intercept = self.get("fitIntercept")
-        standardize = self.get("standardization")
-        fit_with_mean = fit_intercept  # bounds are excluded by eligibility
-        inv_std = inv_std_vector(features_std)
-        scaled_mean = stats.mean * inv_std if fit_with_mean else np.zeros(d)
-        inv_std_agg = inv_std * fp8_scale if fp8_scale is not None \
-            else inv_std
-
-        n_coef = d + (1 if fit_intercept else 0)
-        x0 = np.zeros((n_models, n_coef))
-        if fit_intercept:
-            ok = (pos > 0) & (pos < weight_sum)
-            p1 = np.where(ok, pos / weight_sum, 0.5)
-            x0[:, d] = np.where(ok, np.log(p1 / (1.0 - p1)), 0.0)
-
-        from cycloneml_tpu.ops.kernels import use_fused_kernels
-        base_agg = (aggregators.binary_logistic_pallas_scaled(
-                        d, fit_intercept, feature_major=False)
-                    if use_fused_kernels(sds.ctx)
-                    else aggregators.binary_logistic_scaled(d, fit_intercept))
-        agg = aggregators.stack_scaled_aggregator(base_agg)
-        l2s = stacked_l2_scale(d, n_coef, features_std, standardize)
-        adt = compute_dtype()
-        # the staged (rows, K) label stack: {0, 1} is exact in bf16, and
-        # f64 under the x64 parity config keeps streamed-vs-serial
-        # summation identical; never fp8 — labels mix with f32 margins
-        if adt is np.float64:
-            ydt = np.float64
-        else:
-            import ml_dtypes
-            ydt = ml_dtypes.bfloat16
+        # a staged shard lies as the device's default layout puts it
+        orientation = None
+        if kernels.use_fused_kernels(sds.ctx):
+            feature_major = kernels.default_feature_major(d)
+            rows = sds.pad_rows // sds.ctx.mesh_runtime.data_parallelism
+            if kernels.multinomial_sweep_tile(
+                    rows, d, n_models,
+                    getattr(sds, "x_dtype", np.float64),
+                    feature_major) is not None:
+                orientation = "feature_major" if feature_major \
+                    else "row_major"
         loss_fn = StackedStreamingLossFunction(
-            sds, agg, n_models, reg=reg_params, l2_scale=l2s,
-            weight_sum=weight_sum,
-            extra_args=(jnp.asarray(inv_std_agg.astype(adt)),
-                        jnp.asarray(scaled_mean.astype(adt))),
-            y_stack=y_stack, y_dtype=ydt)
+            sds, self._stacked_aggregator(d, n_models, shared_labels,
+                                          orientation),
+            n_models, reg=reg_params,
+            l2_scale=stacked_l2_scale(d, x0.shape[1], stats.std,
+                                      self.get("standardization")),
+            weight_sum=stats.weight_sum,
+            extra_args=tuple(jnp.asarray(v) for v in std.extra_args))
 
         opt = StackedHostLBFGS(max_iter=self.get("maxIter"),
                                tol=self.get("tol"))
@@ -542,39 +521,12 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
             # refit
             bf16 = sds.to_instance_dataset(fp8_capable=False)
             try:
-                return self._fit_stacked_streamed(
-                    bf16, y_stack=y_stack, reg_params=reg_params)
+                return self._fit_stacked_streamed(bf16, reg_params,
+                                                  shared_labels)
             finally:
                 bf16.close()
-        n_unconverged = sum(
-            1 for r in res.converged_reasons if r == "max iterations reached")
-        if n_unconverged:
-            logger.warning(
-                "stacked LogisticRegression (streamed): %d of %d models did "
-                "not converge in %d iterations", n_unconverged, n_models,
-                self.get("maxIter"))
-
-        models = []
-        for kk in range(n_models):
-            sol = res.x[kk]
-            beta = sol[:d] * inv_std
-            icpt = float(sol[d]) if fit_intercept else 0.0
-            if fit_with_mean:
-                icpt -= float(sol[:d] @ scaled_mean)
-            model = LogisticRegressionModel(
-                coefficient_matrix=beta[None, :],
-                intercept_vector=np.array([icpt]),
-                num_classes=2, is_multinomial=False)
-            self._copy_values(model)
-            model._set_parent(self)
-            model.summary = LogisticRegressionTrainingSummary(
-                objective_history=list(res.loss_histories[kk]),
-                total_iterations=int(res.iterations[kk]),
-                total_evals=int(res.evals[kk]),
-                total_dispatches=loss_fn.n_dispatches,
-                n_models=n_models, streamed=True)
-            models.append(model)
-        return models
+        return self._stacked_models(res, loss_fn, std, streamed=True,
+                                    orientation=orientation)
 
     def _fit_sparse(self, ds) -> "LogisticRegressionModel":
         """Binomial logistic regression over the sparse (ELL / ELL+COO
@@ -1077,7 +1029,7 @@ class LogisticRegressionTrainingSummary:
     def __init__(self, objective_history, total_iterations,
                  total_evals=None, total_dispatches=None, n_models=1,
                  streamed=False, orientation=None, search_evals=None,
-                 num_classes=2):
+                 num_classes=2, stacked_evals=None, pieces=None):
         self.objective_history = objective_history
         self.total_iterations = total_iterations
         # optimizer-path telemetry: loss/grad evaluations and host->device
@@ -1103,6 +1055,13 @@ class LogisticRegressionTrainingSummary:
         # classes the fit's objective ran over (2: a binomial fit): with
         # ``orientation`` it says which fused sweep that was
         self.num_classes = num_classes
+        # a stacked fit's shared evaluations: sweeps of X, each of which
+        # served all n_models lanes (``total_evals`` is this model's own:
+        # the ones it was still live for); None outside a stacked fit
+        self.stacked_evals = stacked_evals
+        # bf16 pieces an f32 operand of the fused class sweep's products
+        # rode the MXU in (ops/kernels.SOFTMAX_PIECES); None elsewhere
+        self.pieces = pieces
 
 
 class BinaryLogisticRegressionSummary:

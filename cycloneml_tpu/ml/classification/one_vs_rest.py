@@ -3,13 +3,17 @@
 Re-design of the reference (ref: ml/classification/OneVsRest.scala — fits
 one binary copy of the base classifier per class over relabeled data, with
 a ``parallelism`` thread pool; the model picks the class whose binary
-margin is largest). The relabel is a host-side column swap.
+margin is largest).
 
 ``parallelism > 1`` routes through the STACKED fit engine when the base
 classifier supports it (``fit_stacked``): the K binary fits share one
-design matrix, so ``vmap`` runs them as ONE gang-scheduled SPMD program —
-one trace + compile amortized over all K models, one psum per step
-carrying K gradients, per-model convergence masks. The reference's thread
+design matrix, so they run as ONE gang-scheduled SPMD program — one read
+of X an evaluation for all K models, model j's label ``1[y == j]`` made
+inside the sweep from the dataset's own label vector (no relabelled copy
+exists), one trace + compile amortized over all K models, one psum per step
+carrying K gradients, per-model convergence masks. The serial loop's
+relabel is a host-side column swap (a frame) or a derived label vector (a
+device-resident dataset). The reference's thread
 pool (and this repo's pre-stacking port of it) dispatched K concurrent
 SPMD programs onto the shared mesh and deadlocked XLA's collective
 rendezvous (graftlint JX007 now mechanizes that hazard); the serial loop
@@ -67,25 +71,21 @@ class OneVsRest(Estimator, _OVRParams, MLWritable, MLReadable):
     def set_parallelism(self, v):
         return self.set("parallelism", v)
 
-    def _fit(self, frame: MLFrame) -> "OneVsRestModel":
+    def _fit(self, frame) -> "OneVsRestModel":
+        """``frame`` is an ``MLFrame`` or a device-resident
+        ``InstanceDataset`` whose labels are class indices; a frame builds
+        (and caches) its dataset and takes the same path."""
         if self.classifier is None:
             raise ValueError("classifier must be set")
-        label_col = self.get("labelCol")
-        y = np.asarray(frame[label_col])
-        num_classes = int(y.max()) + 1
-
-        from cycloneml_tpu.dataset.instance import compute_dtype, data_dtype
-
-        def _configure(clf):
-            clf.set("featuresCol", self.get("featuresCol"))
-            wc = self.get("weightCol")
-            if wc and "weightCol" in clf._params:
-                clf.set("weightCol", wc)
-            return clf
-
         from cycloneml_tpu.mesh import safe_fit_parallelism
         requested = self.get("parallelism")
-        clf = _configure(self.classifier.copy())
+        clf = self._configured(self.classifier.copy())
+        if hasattr(frame, "label_histogram"):
+            # a dataset: the class count is its cached label histogram's,
+            # no pass over the labels a fit
+            num_classes = len(frame.label_histogram())
+        else:
+            num_classes = int(np.asarray(frame[self.get("labelCol")]).max()) + 1
         stackable = (requested > 1 and num_classes > 1
                      and hasattr(clf, "fit_stacked")
                      and clf.can_fit_stacked()
@@ -96,33 +96,52 @@ class OneVsRest(Estimator, _OVRParams, MLWritable, MLReadable):
             logger.info(
                 "OneVsRest: fitting %d binary models as ONE stacked SPMD "
                 "program (effective parallelism %d)", num_classes, effective)
-            clf.set("labelCol", label_col)
-            # ONE (K, n) binary label matrix in the DATA-tier dtype ({0, 1}
-            # is exact in bf16) — not K fp64 host vectors (JX004 data-tier
-            # discipline); the stacked engine consumes all K rows at once
-            y_stack = (np.arange(num_classes)[:, None]
-                       == y[None, :]).astype(
-                           data_dtype(getattr(frame.ctx, "conf", None)))
-            models = clf.fit_stacked(frame, y_stack)
+            clf.set("labelCol", self.get("labelCol"))
+            ds = frame.to_instance_dataset(
+                clf.get("featuresCol"), clf.get("labelCol"),
+                clf.get("weightCol") or None, fp8_capable=True)
+            # model j fits 1[y == j], made inside the stacked sweep from
+            # the label vector the dataset (or, streamed, each staged shard)
+            # holds: no label matrix, no upload a fit
+            models = clf.fit_stacked(ds, num_classes=num_classes)
         else:
             # serial fallback: SPMD fits stay on this thread (a >1 thread
             # pool deadlocks the shared mesh — mesh.safe_fit_parallelism);
-            # relabels are one TRANSIENT data-tier-dtype vector per class
-            # (a full (n, K) matrix would sit in host memory for all K
-            # sequential fits for no reader)
+            # relabels are one TRANSIENT vector per class (a full (n, K)
+            # matrix would sit in memory for all K sequential fits for no
+            # reader)
             safe_fit_parallelism(requested)
             models = []
             for c in range(num_classes):
-                binary = (y == c).astype(compute_dtype())
-                sub = frame.with_column("_ovr_label", binary)
-                one = _configure(self.classifier.copy())
+                one = self._configured(self.classifier.copy())
                 one.set("labelCol", "_ovr_label")
-                models.append(one.fit(sub))
+                models.append(one.fit(self._relabelled(frame, c)))
 
         model = OneVsRestModel(models, uid=self.uid)
         self._copy_values(model)
         model._set_parent(self)
+        model.summary = OneVsRestSummary.of(models)
         return model
+
+    def _configured(self, clf):
+        clf.set("featuresCol", self.get("featuresCol"))
+        wc = self.get("weightCol")
+        if wc and "weightCol" in clf._params:
+            clf.set("weightCol", wc)
+        return clf
+
+    def _relabelled(self, frame, c: int):
+        """``frame`` with the binary label ``1[label == c]``: a column
+        ``_ovr_label`` of a frame, the label vector of a dataset (X and the
+        weights shared, not copied)."""
+        from cycloneml_tpu.dataset.instance import compute_dtype
+        if hasattr(frame, "with_column"):
+            y = np.asarray(frame[self.get("labelCol")])
+            return frame.with_column("_ovr_label",
+                                     (y == c).astype(compute_dtype()))
+        sub = frame.derive(y=(frame.y == c).astype(frame.y.dtype))
+        return sub.attach_host_labels(
+            (frame.y_host() == c).astype(np.float64), frame.w_host())
 
     def copy(self, extra=None) -> "OneVsRest":
         that = super().copy(extra)
@@ -136,12 +155,54 @@ class OneVsRest(Estimator, _OVRParams, MLWritable, MLReadable):
         self.classifier = load_pipeline_stages(path)[0]
 
 
+class OneVsRestSummary:
+    """What a ``OneVsRest`` fit cost, from its binary models' training
+    summaries (MLlib's ``OneVsRestModel`` has no summary: an addition).
+
+    ``iterations`` / ``evals`` are per model. ``total_evals`` counts sweeps
+    of X: a stacked fit's shared evaluations, each of which served every
+    model (so ``sum(evals) <= num_classes * total_evals``, the difference
+    the lane-evaluations computed for models that had already stopped), a
+    serial fit's sum over its models. ``orientation`` / ``pieces`` are the
+    fused stacked sweep's tiling and bf16 pieces a product (None where the
+    XLA aggregator ran, as on the host platform)."""
+
+    @classmethod
+    def of(cls, models) -> Optional["OneVsRestSummary"]:
+        """The summary of ``models``, or None where a base classifier's
+        models carry no optimiser counts to make one from."""
+        summaries = [getattr(m, "summary", None) for m in models]
+        if not summaries or not all(
+                hasattr(s, "total_evals") and hasattr(s, "stacked_evals")
+                for s in summaries):
+            return None
+        return cls(summaries)
+
+    def __init__(self, summaries):
+        first = summaries[0]
+        self.num_classes = len(summaries)
+        self.iterations = [int(s.total_iterations) for s in summaries]
+        self.evals = [int(s.total_evals) for s in summaries]
+        self.n_models = int(first.n_models)
+        stacked = self.n_models > 1
+        self.total_evals = int(first.stacked_evals) if stacked \
+            else sum(self.evals)
+        self.total_dispatches = int(first.total_dispatches) if stacked \
+            else sum(int(s.total_dispatches) for s in summaries)
+        self.orientation = first.orientation
+        self.pieces = first.pieces
+        self.objectives = [float(s.objective_history[-1])
+                           for s in summaries]
+
+
 class OneVsRestModel(Model, _OVRParams, MLWritable, MLReadable):
     def __init__(self, models: Optional[List[ClassificationModel]] = None,
                  uid=None):
         super().__init__(uid)
         self._declare_ovr_params()
         self.models = list(models or [])
+        #: set by ``OneVsRest.fit`` (a loaded model has none)
+        self.summary: Optional[OneVsRestSummary] = None
 
     @property
     def num_classes(self) -> int:

@@ -408,13 +408,80 @@ def stack_aggregator(agg: Agg) -> Agg:
     return jax.vmap(agg, in_axes=(None, 1, None, 0))
 
 
+#: bytes of ``(rows, K)`` float32 margins one chunk of the row-blocked
+#: stacked twin may hold (its multipliers are as large again)
+STACKED_CHUNK_BYTES = 4 << 20
+
+
+def stacked_binary_logistic_scaled(d: int, k: int, fit_intercept: bool = True,
+                                   shared_labels: bool = False) -> Agg:
+    """``k`` independent binomial models over ONE X as one aggregator:
+    ``agg(x, y, w, inv_std, scaled_mean, coef (k, d [+ 1]))`` →
+    ``{loss (k,), grad (k, d [+ 1]), count}``, model j's objective
+    :func:`binary_logistic_scaled`'s on the labels ``1[y == j]`` — ``y`` the
+    row's class index, OneVsRest's relabelling — or, with ``shared_labels``,
+    on ``y`` itself (models that differ in their penalty alone: a regParam
+    grid). The labels are made a chunk of rows at a time inside the pass:
+    no ``(n, k)`` array exists, on the host or the device. The XLA twin of
+    ``kernels.fused_stacked_binomial_scaled`` — what the CPU runs, and a
+    TPU for the shapes ``kernels.multinomial_sweep_tile`` refuses; a narrow
+    X rounds the coefficients to its tier here (``_tier_dot``), which the
+    kernel does not."""
+    return _stacked_binary_logistic_scaled(d, k, fit_intercept,
+                                           bool(shared_labels),
+                                           matmul_precision())
+
+
 @functools.lru_cache(maxsize=None)
-def stack_scaled_aggregator(agg: Agg) -> Agg:
-    """Model-axis twin of a scaled aggregator
-    ``(x, y, w, inv_std, scaled_mean, coef)`` (standardization folded into
-    the read): labels vmap over axis 1, coefficients over axis 0, everything
-    else — including the shared standardization vectors — broadcasts."""
-    return jax.vmap(agg, in_axes=(None, 1, None, None, None, 0))
+def _stacked_binary_logistic_scaled(d: int, k: int, fit_intercept: bool,
+                                    shared_labels: bool, prec) -> Agg:
+
+    @_named("stacked_binary_logistic_scaled")
+    def agg(x, y, w, inv_std, scaled_mean, coef):
+        n = x.shape[0]
+        wmat = coef[:, :d]
+        scaled = wmat * inv_std[None, :]
+        bias = -jnp.dot(wmat, scaled_mean, precision=prec)        # (k,)
+        if fit_intercept:
+            bias = bias + coef[:, d]
+        chunk = min(n, max(8, STACKED_CHUNK_BYTES // (4 * k) // 8 * 8))
+
+        def chunk_sums(xb, yb, wb):
+            margins = _tier_dot(xb, scaled.T, prec) + bias[None, :]
+            hit = (yb == 1)[:, None] if shared_labels else \
+                yb.astype(jnp.int32)[:, None] == jnp.arange(k)[None, :]
+            loss = wb[:, None] * (jax.nn.softplus(margins)
+                                  - jnp.where(hit, margins, 0.0))
+            mult = wb[:, None] * (jax.nn.sigmoid(margins)
+                                  - hit.astype(margins.dtype))
+            return {"loss": jnp.sum(loss, axis=0),
+                    "raw": _tier_dot(mult.T, xb, prec),           # (k, d)
+                    "msum": jnp.sum(mult, axis=0),
+                    "count": jnp.sum(wb)}
+
+        def body(carry, i):
+            part = chunk_sums(*(jax.lax.dynamic_slice_in_dim(a, i * chunk,
+                                                             chunk)
+                                for a in (x, y, w)))
+            return jax.tree.map(jnp.add, carry, part), None
+
+        # chunk <= n: at least one whole chunk, then the rows left over
+        zero = jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype),
+            jax.eval_shape(chunk_sums, x[:chunk], y[:chunk], w[:chunk]))
+        total, _ = jax.lax.scan(body, zero, jnp.arange(n // chunk))
+        if n % chunk:
+            lo = n - n % chunk
+            total = jax.tree.map(jnp.add, total,
+                                 chunk_sums(x[lo:], y[lo:], w[lo:]))
+        msum = total["msum"]
+        grad = (total["raw"] * inv_std[None, :]
+                - msum[:, None] * scaled_mean[None, :])
+        if fit_intercept:
+            grad = jnp.concatenate([grad, msum[:, None]], axis=1)
+        return {"loss": total["loss"], "grad": grad, "count": total["count"]}
+
+    return agg
 
 
 def autodiff_check(agg_loss_only: Callable, d: int):
@@ -487,6 +554,37 @@ def _multinomial_logistic_pallas_scaled(d: int, k: int, fit_intercept: bool,
         return fused_multinomial_logistic_scaled(
             x, y, w, inv_std, scaled_mean, coef, d, k, fit_intercept,
             feature_major=feature_major)
+
+    return agg
+
+
+def stacked_binary_logistic_pallas_scaled(d: int, k: int,
+                                          fit_intercept: bool = True,
+                                          shared_labels: bool = False,
+                                          feature_major=None) -> Agg:
+    """Pallas twin of :func:`stacked_binary_logistic_scaled`
+    (ops/kernels.fused_stacked_binomial_scaled): the K-class sweep's body
+    under K sigmoids — one read of a bf16 X an evaluation for all k models,
+    both products on the MXU in three bf16 pieces. For the shapes
+    ``kernels.multinomial_sweep_tile`` admits; the caller asks it first.
+    ``feature_major``: as :func:`binary_logistic_pallas_scaled`."""
+    return _stacked_binary_logistic_pallas_scaled(
+        d, k, fit_intercept, bool(shared_labels),
+        _feature_major(d, feature_major))
+
+
+@functools.lru_cache(maxsize=None)
+def _stacked_binary_logistic_pallas_scaled(d: int, k: int,
+                                           fit_intercept: bool,
+                                           shared_labels: bool,
+                                           feature_major: bool) -> Agg:
+    from cycloneml_tpu.ops.kernels import fused_stacked_binomial_scaled
+
+    @_named("stacked_binary_logistic_pallas_scaled")
+    def agg(x, y, w, inv_std, scaled_mean, coef):
+        return fused_stacked_binomial_scaled(
+            x, y, w, inv_std, scaled_mean, coef, d, k, fit_intercept,
+            shared_labels=shared_labels, feature_major=feature_major)
 
     return agg
 

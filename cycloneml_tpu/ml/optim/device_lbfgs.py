@@ -27,12 +27,14 @@ Two chunk programs, one set of parts. The parts of an iteration
 (``_two_loop``, ``_descent_or_reset``, ``_init_alpha``, ``_push_pair``,
 ``_convergence_code``) are written once, for one model; ``_build_chunk``
 calls them and ``_build_stacked_chunk`` calls ``jax.vmap`` of them. The
-builders and their ``while_loop`` bodies stay two: the serial program is
-NOT the stacked one at K = 1. Under ``vmap`` the objective's sweep cannot
-take the feature-major tiling (``logistic_regression.py`` passes
-``feature_major=False`` to the stacked aggregator), so a serial fit routed
-through the stacked program would pay the lane pad and the layout copy of X
-again — 3.3× of ``lr_epsilon_fit``'s ``fit_s`` (ledger, PR 28).
+builders and their ``while_loop`` bodies stay two. The stacked objective is
+ONE aggregator with the model axis inside (``aggregators.
+stacked_binary_logistic_*``: nothing is ``vmap``ped over a sweep, and it
+reads X once for all K lanes in the tiling X's layout dictates), so the
+tiling no longer keeps them apart; what does is what each driver must
+support — the serial one yields a resumable ``OptimState`` a turn and
+inlines a traced penalty, the stacked one returns once and carries the
+penalty's strength a lane as data (``ROADMAP.md`` D2).
 
 ``StackedHostLBFGS`` (the streamed regime) is host code: it drives K of
 ``lbfgs.LBFGS``'s coroutines and lives here only beside its device twin.
@@ -272,8 +274,7 @@ def _build_chunk(compiled, l2_t, m: int, K: int, c1: float, c2: float,
                 return v, grad, jnp.dot(d, grad)
 
             alpha, f_new, g_new, ev = wolfe_search(
-                phi, jnp.zeros_like(g), f, dg0, init_alpha,
-                c1, c2, max_ls, cdt)
+                phi, g, f, dg0, init_alpha, c1, c2, max_ls, cdt)
             s = alpha * d
             S, Y, k = _push_pair(S, Y, k, s, g_new - g, m)
             code = _convergence_code(f, f_new, g_new, coef + s,
@@ -633,8 +634,8 @@ def _build_stacked_chunk(compiled, m: int, K_iters: int, c1: float, c2: float,
                 return v, grad, row_dot(d, grad)
 
             alpha, f_new, g_new, ev = wolfe_search(
-                phi, jnp.zeros_like(g), f, dg0, init_alpha,
-                c1, c2, max_ls, cdt, active=live)
+                phi, g, f, dg0, init_alpha, c1, c2, max_ls, cdt,
+                active=live)
             s_vec = alpha[:, None] * d
             x_new = coef + s_vec
             S_new, Y_new, k_new = push_pair(S, Y, k, s_vec, g_new - g)
@@ -725,9 +726,6 @@ class StackedDeviceLBFGS:
     def minimize(self, f, x0: np.ndarray) -> StackedOptimResult:
         """``f`` is a ``StackedDistributedLossFunction``; ``x0`` is the
         (K, n_coef) stacked start point."""
-        import jax
-        import jax.numpy as jnp
-
         x0 = np.asarray(x0, dtype=np.float64)
         K, n = x0.shape
         if K != f.n_models:
@@ -750,15 +748,18 @@ class StackedDeviceLBFGS:
 
         key, prog, fresh = build(chunk)
 
-        coef = jnp.asarray(x0.astype(cdt))
-        S_d = jnp.zeros((K, self.m, n), cdt)
-        Y_d = jnp.zeros((K, self.m, n), cdt)
-        k_d = jnp.zeros((K,), jnp.int32)
-        f_d = jnp.zeros((K,), cdt)
-        g_d = jnp.zeros((K, n), cdt)
-        reg_d = jnp.asarray(f.reg.astype(cdt))
+        # the first chunk's launch uploads its own host operands (as
+        # DeviceLBFGS's does): a jnp.asarray / jnp.zeros here would be a
+        # device operation apiece, issued from Python before the chunk
+        coef = x0.astype(cdt)
+        S_d = np.zeros((K, self.m, n), cdt)
+        Y_d = np.zeros((K, self.m, n), cdt)
+        k_d = np.zeros((K,), np.int32)
+        f_d = np.zeros((K,), cdt)
+        g_d = np.zeros((K, n), cdt)
+        reg_d = f.reg.astype(cdt)
         l2s = (f.l2_scale if f.l2_scale is not None else np.zeros(n))
-        l2s_d = jnp.asarray(l2s.astype(cdt))
+        l2s_d = l2s.astype(cdt)
         first = True  # this chunk evaluates f(x0) and scales its first step
         total_iter = 0
         iters_total = np.zeros(K, dtype=np.int64)
@@ -766,44 +767,50 @@ class StackedDeviceLBFGS:
         histories: List[List[float]] = [[] for _ in range(K)]
         code_h = np.zeros(K, dtype=np.int64)
         while True:
-            args = (*arrays, coef, S_d, Y_d, k_d, f_d, g_d,
-                    np.bool_(first), cdt.type(f.weight_sum), reg_d, l2s_d,
-                    cdt.type(self.tol), cdt.type(self.grad_tol),
-                    np.int32(max(self.max_iter - total_iter, 0)),
-                    np.bool_(first), code_h.astype(np.int32))
-            if first:
-                chunk, key, prog, new_fresh = _budget_guarded_chunk(
-                    "lbfgs.stacked_chunk", key, prog, args, chunk,
-                    getattr(f, "_ctx", None), build)
-                if new_fresh is not None:
-                    fresh = new_fresh
-                    self.effective_chunk = chunk
-            # the (K, ·) state stays on the device; the rest comes back
-            ((coef, S_d, Y_d, k_d, f_d, g_d, *_),
-             (losses, steps, iters, ev_pm, ev_g, code_h, f0_h)) = \
-                dispatch_fused(
-                    "lbfgs.stacked_chunk", key, prog, args, fresh=fresh,
-                    transfer_name="lbfgs.readback", evals_at=4,
-                    readback=lambda out: out[6:], n_models=K)
-            fresh = False
-            f.n_evals += int(ev_g)
-            f.n_dispatches += 1
-            if first:
+            # one chunk turn is one `optim.iteration` span, as the serial
+            # driver's: argument tuple, dispatch, readback, bookkeeping
+            with tracing.span("phase", "optim.iteration",
+                              iteration=total_iter, n_models=K,
+                              active_models=int((code_h == 0).sum())):
+                args = (*arrays, coef, S_d, Y_d, k_d, f_d, g_d,
+                        np.bool_(first), cdt.type(f.weight_sum), reg_d,
+                        l2s_d, cdt.type(self.tol), cdt.type(self.grad_tol),
+                        np.int32(max(self.max_iter - total_iter, 0)),
+                        np.bool_(first), code_h.astype(np.int32))
+                if first:
+                    chunk, key, prog, new_fresh = _budget_guarded_chunk(
+                        "lbfgs.stacked_chunk", key, prog, args, chunk,
+                        getattr(f, "_ctx", None), build)
+                    if new_fresh is not None:
+                        fresh = new_fresh
+                        self.effective_chunk = chunk
+                # the (K, ·) state stays on the device; the rest comes back
+                ((coef, S_d, Y_d, k_d, f_d, g_d, *_),
+                 (losses, steps, iters, ev_pm, ev_g, code_h, f0_h)) = \
+                    dispatch_fused(
+                        "lbfgs.stacked_chunk", key, prog, args, fresh=fresh,
+                        transfer_name="lbfgs.readback", evals_at=4,
+                        readback=lambda out: out[6:], n_models=K)
+                fresh = False
+                f.n_evals += int(ev_g)
+                f.n_dispatches += 1
+                if first:
+                    for kk in range(K):
+                        histories[kk].append(float(f0_h[kk]))
+                    first = False
                 for kk in range(K):
-                    histories[kk].append(float(f0_h[kk]))
-                first = False
-            for kk in range(K):
-                for v in losses[kk, :int(steps)]:
-                    if not np.isnan(v):
-                        histories[kk].append(float(v))
-            iters_total += np.asarray(iters, dtype=np.int64)
-            evals_total += np.asarray(ev_pm, dtype=np.int64)
-            total_iter += int(steps)
-            if hasattr(f, "_ctx") and hasattr(f._ctx, "record_step"):
-                f._ctx.record_step({
-                    "loss": float(np.nanmean(losses[:, :max(int(steps), 1)]))
-                    if int(steps) else float(np.mean(f0_h)),
-                    "chunk_iterations": int(steps), "n_models": K})
+                    for v in losses[kk, :int(steps)]:
+                        if not np.isnan(v):
+                            histories[kk].append(float(v))
+                iters_total += np.asarray(iters, dtype=np.int64)
+                evals_total += np.asarray(ev_pm, dtype=np.int64)
+                total_iter += int(steps)
+                if hasattr(f, "_ctx") and hasattr(f._ctx, "record_step"):
+                    f._ctx.record_step({
+                        "loss": float(np.nanmean(
+                            losses[:, :max(int(steps), 1)]))
+                        if int(steps) else float(np.mean(f0_h)),
+                        "chunk_iterations": int(steps), "n_models": K})
             if (code_h != 0).all() or total_iter >= self.max_iter:
                 break
         # a model still live when the budget ran out stopped on the budget

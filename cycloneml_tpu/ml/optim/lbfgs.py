@@ -221,7 +221,7 @@ def _strong_wolfe(f: LossGrad, x: np.ndarray, value: float, grad: np.ndarray,
     # search in ONE device dispatch (vs one dispatch per phi eval here)
     fused = getattr(f, "device_line_search", None)
     if fused is not None:
-        out = fused(x, direction, value, slope, init_alpha,
+        out = fused(x, direction, value, grad, slope, init_alpha,
                     c1, c2, max_evals)
         if out is not None:
             return out

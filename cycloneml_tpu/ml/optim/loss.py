@@ -100,8 +100,9 @@ class DistributedLossFunction:
 
     # -- device-resident line search ------------------------------------------
     def device_line_search(self, x: np.ndarray, direction: np.ndarray,
-                           value: float, dg0: float, init_alpha: float,
-                           c1: float, c2: float, max_evals: int):
+                           value: float, grad: np.ndarray, dg0: float,
+                           init_alpha: float, c1: float, c2: float,
+                           max_evals: int):
         """Run the ENTIRE strong-Wolfe search in one XLA dispatch.
 
         The host path pays one dispatch plus readbacks per φ(α) evaluation
@@ -110,7 +111,10 @@ class DistributedLossFunction:
         inlined psum aggregation, so a whole iteration is one dispatch and
         one small readback. The reference pays one full Spark *job* per
         evaluation (ref RDDLossFunction.scala:56) — this is the structure we
-        beat, not emulate. Returns ``(alpha, value_new, grad_new)`` with the
+        beat, not emulate. ``value`` / ``grad`` / ``dg0`` are the objective,
+        its gradient and the slope AT ``x``: the gradient rides along
+        because ``wolfe_search`` hands it back with the empty step when no
+        trial lowered the value. Returns ``(alpha, value_new, grad_new)`` with the
         host-f64 types the optimizer expects, or ``None`` when regularization
         has no traceable twin (caller falls back to the host search).
         """
@@ -153,8 +157,8 @@ class DistributedLossFunction:
         args = (*arrays,
                 np.asarray(x, dtype=cdt),
                 np.asarray(direction, dtype=cdt),
-                cdt.type(value), cdt.type(dg0),
-                cdt.type(init_alpha),
+                cdt.type(value), np.asarray(grad, dtype=cdt),
+                cdt.type(dg0), cdt.type(init_alpha),
                 cdt.type(self.weight_sum))
         if fresh and tracing.full_active() is not None:
             # a raise-mode budget guard must fire before the oversized
@@ -209,14 +213,15 @@ def stacked_host_l2(loss: np.ndarray, grad: np.ndarray,
 
 
 class StackedDistributedLossFunction:
-    """Model-axis (vmapped) twin of :class:`DistributedLossFunction`.
+    """Model-axis twin of :class:`DistributedLossFunction`.
 
     Callable ``(coef_stack (K, n_coef)) -> (loss (K,), grad (K, n_coef))``
-    in host float64. ``dataset`` must carry the stacked ``(n_pad, K)`` label
-    matrix as its ``y`` (see ``InstanceDataset.derive``) and ``agg`` the
-    vmapped aggregator twin (``aggregators.stack_scaled_aggregator``), so K
+    in host float64. ``agg`` is an aggregator whose coefficients and sums
+    carry the model axis — ``aggregators.stacked_binary_logistic_scaled`` /
+    ``…_pallas_scaled`` over the dataset as it is (model j's label made
+    inside the pass from the dataset's own ``(n,)`` label vector) — so K
     independent binomial objectives over ONE shared design matrix evaluate
-    as a single SPMD program — one psum with a leading model axis, never K
+    as a single SPMD program: one psum with a leading model axis, never K
     rendezvous-prone concurrent programs (the PR-2 deadlock).
 
     The L2 term is carried as runtime data — per-model ``reg`` ``(K,)`` plus
@@ -285,8 +290,8 @@ def _build_line_search(compiled, l2_t, c1: float, c2: float, max_evals: int,
     import jax.numpy as jnp
 
     def lbfgs_line_search(*args):
-        arrays = args[:-6]
-        x0, dirn, value0, dg0, init_alpha, ws = args[-6:]
+        arrays = args[:-7]
+        x0, dirn, value0, grad0, dg0, init_alpha, ws = args[-7:]
         # divide by ws, matching the host path's `loss / weight_sum`
         # bit-for-bit (a reciprocal-multiply drifts in the last ulp,
         # which 40 unregularized iterations amplify)
@@ -302,8 +307,7 @@ def _build_line_search(compiled, l2_t, c1: float, c2: float, max_evals: int,
                 grad = grad + rg
             return loss, grad, jnp.dot(dirn, grad)
 
-        g_zero = jnp.zeros((x0.shape[0],), cdt)
-        return wolfe_search(phi, g_zero, value0, dg0, init_alpha,
+        return wolfe_search(phi, grad0, value0, dg0, init_alpha,
                             c1, c2, max_evals, cdt)
 
     return jax.jit(lbfgs_line_search)
@@ -321,19 +325,20 @@ def _select_bcast(mask, a, b):
     return jnp.where(mask, a, b)
 
 
-def wolfe_search(phi, g_zero, value0, dg0, init_alpha,
+def wolfe_search(phi, g0, value0, dg0, init_alpha,
                  c1: float, c2: float, max_evals: int, cdt, active=None):
     """Traced strong-Wolfe bracket+zoom (Nocedal-Wright alg 3.5/3.6) as a
     ``lax.while_loop`` state machine — the device-resident twin of the host
     search, ``lbfgs._wolfe_search``.
 
-    ``phi(alpha) -> (value, grad_pytree, dg)``; ``g_zero`` is a zero pytree
-    matching the gradient structure (any sharding — the feature-sharded
+    ``phi(alpha) -> (value, grad_pytree, dg)``; ``g0`` is the gradient
+    pytree AT THE START, φ's at α = 0 (any sharding — the feature-sharded
     path threads a (beta_sharded, b0_scalar) pair through unchanged).
-    Returns ``(alpha, value, grad_pytree, evals)``.
+    Returns ``(alpha, value, grad_pytree, evals)``: a point whose value is
+    NOT above ``value0``, or the empty step ``(0, value0, g0)``.
 
     Batched (model-axis) form: when ``value0``/``dg0``/``init_alpha`` carry a
-    leading ``(K,)`` axis (and ``g_zero`` leaves a leading ``K``), each model
+    leading ``(K,)`` axis (and ``g0``'s leaves a leading ``K``), each model
     runs its OWN bracket+zoom trajectory in lockstep evaluation steps — one
     batched ``phi`` per step — and models whose search terminates freeze
     (state selected through, no further effect) instead of forcing the rest
@@ -341,11 +346,30 @@ def wolfe_search(phi, g_zero, value0, dg0, init_alpha,
     search at all (already-converged models in a stacked fit): they start in
     the done phase with zero evals. Per-model ``evals`` counts only live
     steps, so the batched search's global step count is ``evals.max()``.
+
+    The search also ends — on the trial in hand — once it would go on to
+    SMALLER steps while the first-order decrease on offer, ``α·|dg0|``, is
+    already below what the objective resolves: ``eps·|value0|``, ``eps`` of
+    the accumulator ``cdt`` (the rule ``lbfgs.OWLQN._search`` has had since
+    PR 30). A trial that fails Armijo there fails by rounding, every α the
+    zoom would bisect to offers less, and without the exit it ran out all
+    ``max_evals`` of them — at the last iteration of a float32 fit, where
+    the caller's ``|Δf|`` test then stops the run anyway; in a stacked fit
+    one such lane held every other lane's sweep for 30 evaluations (PR 41,
+    on the v5e: 42 shared sweeps for 12).
+
+    A strong-Wolfe point lowers the value. A search that ended otherwise —
+    on the resolution exit, or on its budget — may hold a trial that reads
+    ABOVE ``value0``; it then returns the empty step instead, for every
+    caller alike: nothing moves, the curvature pair is ``(0, 0)`` (which
+    ``_push_pair`` / ``_History.update`` refuse) and the caller's ``|Δf|``
+    test reads 0, so no objective history this search feeds ever rises.
     """
     import jax
     import jax.numpy as jnp
 
     value0 = jnp.asarray(value0, cdt)
+    resolution = float(np.finfo(cdt).eps) * jnp.abs(value0)
     zero = jnp.zeros(jnp.shape(value0), cdt)
     izero = jnp.zeros(jnp.shape(value0), jnp.int32)
     phase0 = izero if active is None else \
@@ -359,7 +383,7 @@ def wolfe_search(phi, g_zero, value0, dg0, init_alpha,
         v_lo=zero, d_lo=zero,
         v_hi=zero,
         res_alpha=zero, res_v=value0 + zero,
-        res_g=g_zero,
+        res_g=g0,
     )
 
     def cond(s):
@@ -372,6 +396,8 @@ def wolfe_search(phi, g_zero, value0, dg0, init_alpha,
         v, g, dg = phi(alpha)
         armijo_fail = v > value0 + c1 * alpha * dg0
         wolfe_ok = jnp.abs(dg) <= -c2 * dg0
+        # what this α still offers is under the accumulator's resolution
+        unresolved = alpha * jnp.abs(dg0) <= resolution
 
         # -- bracket phase (Nocedal-Wright alg 3.5) --
         b_zoom_a = armijo_fail | ((s["bi"] > 0) & (v >= s["v_prev"]))
@@ -383,6 +409,8 @@ def wolfe_search(phi, g_zero, value0, dg0, init_alpha,
         # this branch is unreachable in practice — 30 doublings)
         b_exhaust = b_cont & (s["bi"] + 1 >= max_evals)
         enter_zoom = b_zoom_a | b_zoom_b
+        # a zoom from here only goes to smaller steps: end on this trial
+        b_unresolved = enter_zoom & unresolved
 
         # -- zoom phase (alg 3.6) --
         z_hi_a = armijo_fail | (v >= s["v_lo"])
@@ -398,9 +426,10 @@ def wolfe_search(phi, g_zero, value0, dg0, init_alpha,
 
         phase = jnp.where(
             in_bracket,
-            jnp.where(b_done | b_exhaust, 2,
+            jnp.where(b_done | b_exhaust | b_unresolved, 2,
                       jnp.where(enter_zoom, 1, 0)),
-            jnp.where(z_done | z_exhaust, 2, 1)).astype(jnp.int32)
+            jnp.where(z_done | z_exhaust | unresolved, 2, 1)).astype(
+                jnp.int32)
 
         # zoom bracket: freshly entered from bracket phase, or updated
         lo = jnp.where(in_bracket,
@@ -418,7 +447,8 @@ def wolfe_search(phi, g_zero, value0, dg0, init_alpha,
 
         # result: bracket records only on termination; zoom records
         # every eval (the host zoom's running ``best``)
-        set_res = jnp.where(in_bracket, b_done | b_exhaust, True)
+        set_res = jnp.where(in_bracket, b_done | b_exhaust | b_unresolved,
+                            True)
         new = dict(
             phase=phase,
             evals=s["evals"] + 1,
@@ -451,7 +481,12 @@ def wolfe_search(phi, g_zero, value0, dg0, init_alpha,
         }
 
     final = jax.lax.while_loop(cond, body, state)
-    return (final["res_alpha"], final["res_v"], final["res_g"],
+    raised = final["res_v"] > value0
+    return (jnp.where(raised, zero, final["res_alpha"]),
+            jnp.where(raised, value0, final["res_v"]),
+            jax.tree_util.tree_map(
+                lambda gs, gr: _select_bcast(raised, gs, gr),
+                g0, final["res_g"]),
             final["evals"])
 
 

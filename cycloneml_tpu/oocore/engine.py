@@ -191,8 +191,8 @@ class StreamingGradientDescent:
         from cycloneml_tpu.mesh import DATA_AXIS, REPLICA_AXIS
         from cycloneml_tpu.ml.optim import aggregators
         from cycloneml_tpu.observe import tracing
-        from cycloneml_tpu.oocore.objective import \
-            StackedStreamingLossFunction
+        from cycloneml_tpu.oocore.objective import (
+            StackedStreamingLossFunction, _StackedShardView)
 
         frac = self.mini_batch_fraction
         seed = self.seed
@@ -227,11 +227,13 @@ class StreamingGradientDescent:
                                          jax.lax.axis_index(REPLICA_AXIS))
                 w = w * (jax.random.uniform(key, w.shape) < frac)
                 return stacked(x, y, w, coef)
-            loss_fn = StackedStreamingLossFunction(
-                sds, fn, n_models, y_stack=y_stack)
         else:
-            loss_fn = StackedStreamingLossFunction(
-                sds, stacked, n_models, y_stack=y_stack)
+            fn = stacked
+        # the vmapped aggregator reads a (rows, K) label stack: staged a
+        # shard at a time by the view
+        view = _StackedShardView.tiled(sds, n_models) if y_stack is None \
+            else _StackedShardView.from_stack(sds, y_stack)
+        loss_fn = StackedStreamingLossFunction(view, fn, n_models)
 
         histories: list = [[] for _ in range(n_models)]
         regs = np.zeros(n_models)

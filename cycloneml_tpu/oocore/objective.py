@@ -199,41 +199,48 @@ class StreamingLossFunction:
 
 
 class _StackedShardView:
-    """StreamingDataset facade carrying the per-shard ``(rows, K)`` label
-    stack, built host-side at stage time — the stacked streamed fit never
-    materializes the whole ``(n, K)`` matrix anywhere: each shard's stack
-    is O(shard · K), staged once, donated like every other shard operand.
+    """StreamingDataset facade carrying a per-shard ``(rows, K)`` label
+    stack, built host-side at stage time, for a per-shard program that
+    ``vmap``s a one-model aggregator over its label axis (the streamed
+    SGD's ``optimize_stacked``): each shard's stack is O(shard · K), staged
+    once, donated like every other shard operand — the whole ``(n, K)``
+    matrix is never on the device.
 
-    Two label sources, mirroring the in-core ``fit_stacked`` inputs:
+    Two label sources:
 
     - :meth:`tiled` — the shard's own labels broadcast across K models
-      (CV grids: same data, K reg strengths);
+      (same data, K penalties);
     - :meth:`from_stack` — column slices of a caller ``(K, n)`` stack in
-      shard row order (OneVsRest relabelings; ``from_chunks`` preserves
-      row order, so shard offsets index the stack directly).
+      shard row order (``from_chunks`` preserves row order, so shard
+      offsets index the stack directly).
     """
 
-    def __init__(self, sds, n_models: int, y_fn, y_dtype):
+    def __init__(self, sds, n_models: int, y_fn):
         self._sds = sds
         self.n_models = int(n_models)
         self._y_fn = y_fn
-        self.y_dtype = np.dtype(y_dtype)
+        self.y_dtype = self._stack_dtype()
+
+    @staticmethod
+    def _stack_dtype() -> np.dtype:
+        """The stack is staged at the accumulator's width."""
+        from cycloneml_tpu.dataset.instance import compute_dtype
+        return np.dtype(compute_dtype())
 
     @classmethod
-    def tiled(cls, sds, n_models: int, y_dtype) -> "_StackedShardView":
-        ydt = np.dtype(y_dtype)
+    def tiled(cls, sds, n_models: int) -> "_StackedShardView":
+        ydt = cls._stack_dtype()
 
         def y_fn(i, y):
             y = np.asarray(y, dtype=ydt)
             return np.ascontiguousarray(
                 np.broadcast_to(y[:, None], (len(y), n_models)))
 
-        return cls(sds, n_models, y_fn, ydt)
+        return cls(sds, n_models, y_fn)
 
     @classmethod
-    def from_stack(cls, sds, y_stack: np.ndarray,
-                   y_dtype) -> "_StackedShardView":
-        ydt = np.dtype(y_dtype)
+    def from_stack(cls, sds, y_stack: np.ndarray) -> "_StackedShardView":
+        ydt = cls._stack_dtype()
         offsets = np.cumsum([0] + [s.rows for s in sds._shards])
         if y_stack.shape[1] != sds.n_rows:
             raise ValueError(
@@ -245,7 +252,7 @@ class _StackedShardView:
             return np.ascontiguousarray(
                 np.asarray(y_stack[:, lo:hi]).T.astype(ydt))
 
-        return cls(sds, len(y_stack), y_fn, ydt)
+        return cls(sds, len(y_stack), y_fn)
 
     # -- delegated surface (what ShardStream + the objective touch) -----------
     @property
@@ -291,26 +298,24 @@ class StackedStreamingLossFunction(StreamingLossFunction):
 
     Callable ``(coef_stack (K, n_coef)) -> (loss (K,), grad (K, n_coef))``
     in host float64; one evaluation is ONE double-buffered epoch whose
-    per-shard program is the vmapped stacked aggregator — every staged
-    shard serves all K models, so a K-model grid/OvR fit over spilled
-    data reads the data once per iteration instead of K times. Per-model
-    L2 is host-side runtime data (``stacked_host_l2`` — shared with the
-    in-core stacked loss, so penalties are bit-identical).
+    per-shard program is a stacked aggregator — every staged shard serves
+    all K models, so a K-model grid/OvR fit over spilled data reads the
+    data once per iteration instead of K times. ``sds`` is the shard set
+    itself where ``agg`` carries the model axis inside and makes each
+    model's label from the shard's own label vector
+    (``aggregators.stacked_binary_logistic_*``), or a
+    :class:`_StackedShardView` of it where ``agg`` is a ``vmap`` over a
+    staged ``(rows, K)`` label stack. Per-model L2 is host-side runtime
+    data (``stacked_host_l2`` — shared with the in-core stacked loss, so
+    penalties are bit-identical).
     """
 
     def __init__(self, sds, agg, n_models: int,
                  reg: Optional[np.ndarray] = None,
                  l2_scale: Optional[np.ndarray] = None,
                  weight_sum: Optional[float] = None,
-                 extra_args: tuple = (), y_stack: Optional[np.ndarray] = None,
-                 y_dtype=None):
-        if y_dtype is None:
-            from cycloneml_tpu.dataset.instance import compute_dtype
-            y_dtype = compute_dtype()
-        view = (_StackedShardView.tiled(sds, n_models, y_dtype)
-                if y_stack is None
-                else _StackedShardView.from_stack(sds, y_stack, y_dtype))
-        super().__init__(view, agg, l2_reg_fn=None, weight_sum=weight_sum,
+                 extra_args: tuple = ()):
+        super().__init__(sds, agg, l2_reg_fn=None, weight_sum=weight_sum,
                          extra_args=extra_args)
         self.n_models = int(n_models)
         self.reg = (np.zeros(self.n_models) if reg is None
@@ -334,26 +339,18 @@ class StackedStreamingLossFunction(StreamingLossFunction):
         return loss, grad
 
     def _shard_avals(self, n_coef: int, concrete: bool = False) -> tuple:
+        """The base class's operands with the model axis on the
+        coefficients — and on the labels, where a view stages a stack."""
         import jax
-        from cycloneml_tpu.dataset.instance import compute_dtype
-        view = self._sds
-        xdt = np.dtype(view.x_dtype)
-        ydt = np.dtype(view.y_dtype)
-        adt = np.dtype(compute_dtype())
-        rt = view.ctx.mesh_runtime
+        x, y, w, *rest = super()._shard_avals(n_coef, concrete)
         K = self.n_models
-        if concrete:
-            x = rt.device_put_sharded_rows(
-                np.zeros((view.pad_rows, view.n_features), dtype=xdt))
-            y = rt.device_put_sharded_rows(
-                np.zeros((view.pad_rows, K), dtype=ydt))
-            w = rt.device_put_sharded_rows(np.zeros(view.pad_rows, dtype=adt))
-        else:
-            x = jax.ShapeDtypeStruct((view.pad_rows, view.n_features), xdt,
-                                     sharding=rt.data_sharding(1))
-            y = jax.ShapeDtypeStruct((view.pad_rows, K), ydt,
-                                     sharding=rt.data_sharding(1))
-            w = jax.ShapeDtypeStruct((view.pad_rows,), adt,
-                                     sharding=rt.data_sharding(0))
-        return (x, y, w, *self._extras,
-                np.zeros((K, n_coef), dtype=np.float64))
+        if isinstance(self._sds, _StackedShardView):
+            view = self._sds
+            rt = view.ctx.mesh_runtime
+            if concrete:
+                y = rt.device_put_sharded_rows(
+                    np.zeros((view.pad_rows, K), dtype=view.y_dtype))
+            else:
+                y = jax.ShapeDtypeStruct((view.pad_rows, K), view.y_dtype,
+                                         sharding=rt.data_sharding(1))
+        return (x, y, w, *rest[:-1], np.zeros((K, n_coef), dtype=np.float64))

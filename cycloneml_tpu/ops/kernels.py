@@ -13,6 +13,10 @@ output blocks — the standard Pallas reduction pattern):
   MultinomialLogisticBlockAggregator) — margins, softmax, loss and gradient
   from one read of a bfloat16 X, both products on the MXU with the f32
   operand of each as three bf16 pieces; tiled as the GLM sweep is.
+- ``fused_stacked_binomial_scaled``: K independent binomial models over one
+  X (OneVsRest's relabellings, a regParam grid) — the K-class sweep's body
+  with the link changed from a softmax to K sigmoids: one read of X an
+  evaluation for all K, the lane's 0/1 label made in the kernel.
 - ``fused_kmeans_assign``: the KMeans distance+argmin inner loop (ref:
   DistanceMeasure.findClosest:123) as ‖x‖²−2x·c+‖c‖² with a fused argmin.
 - ``fused_moment_gramian``: the augmented Gramian ``[1|y|X]'W[1|y|X]`` —
@@ -716,58 +720,120 @@ def fused_multinomial_logistic_scaled(x, y, w, inv_std, scaled_mean, coef,
     stored and picks the tiling, never the result; ``tile`` overrides the
     rows a grid step takes (tests). Which ``(d, k)`` fit:
     :func:`multinomial_sweep_tile`, which the caller asks first."""
-    n = x.shape[0]
+    coef = jnp.asarray(coef, jnp.float32)
+    wmat = coef[: d * k].reshape(k, d)
+    b = coef[d * k:] if fit_intercept else None
+    loss, gw, msum, count = _class_sweep(
+        x, y, w, inv_std, scaled_mean, wmat, b, link="softmax",
+        interpret=interpret, feature_major=feature_major, tile=tile)
+    grad = jnp.concatenate([gw.reshape(-1), msum]) if fit_intercept \
+        else gw.reshape(-1)
+    return {"loss": jnp.sum(loss), "grad": grad, "count": count}
+
+
+def fused_stacked_binomial_scaled(x, y, w, inv_std, scaled_mean, coef,
+                                  d: int, k: int,
+                                  fit_intercept: bool = True,
+                                  shared_labels: bool = False,
+                                  interpret: bool = False,
+                                  feature_major: bool = False,
+                                  tile: int = None
+                                  ) -> Dict[str, jnp.ndarray]:
+    """``k`` INDEPENDENT binomial losses and their gradients from ONE read
+    of a bfloat16 X — the K-class sweep (:func:`fused_multinomial_logistic_
+    scaled`: same tiling, same three-piece MXU products, same folded
+    standardization) with the link a sigmoid a model in place of one
+    softmax a row. ``coef`` is the ``(k, d [+ 1])`` stack, one model a row.
+
+    The label of model ``j`` on a row is made in the kernel and exists
+    nowhere else: ``1[y == j]`` for ``y`` the row's class index
+    (OneVsRest's relabelling), or ``y`` itself for every model where
+    ``shared_labels`` (models that differ in their penalty alone: a
+    regParam grid). Returns ``{"loss": (k,), "grad": (k, d [+ 1]),
+    "count"}``. Which ``(d, k)`` fit: :func:`multinomial_sweep_tile`."""
+    coef = jnp.asarray(coef, jnp.float32)
+    loss, gw, msum, count = _class_sweep(
+        x, y, w, inv_std, scaled_mean, coef[:, :d],
+        coef[:, d] if fit_intercept else None,
+        link="shared_sigmoid" if shared_labels else "sigmoid",
+        interpret=interpret, feature_major=feature_major, tile=tile)
+    grad = jnp.concatenate([gw, msum[:, None]], axis=1) if fit_intercept \
+        else gw
+    return {"loss": loss, "grad": grad, "count": count}
+
+
+#: the links of the class sweep — what a row's k margins turn into — with
+#: the ``kind`` of the sweep's instant (and the Mosaic call's name after
+#: ``glm_sweep_``) and what the instant calls the k rows
+_CLASS_LINKS = {
+    "softmax": ("multinomial", "classes", "class_pad"),
+    "sigmoid": ("stacked_binomial", "models", "model_pad"),
+    "shared_sigmoid": ("stacked_binomial", "models", "model_pad")}
+
+
+def _class_sweep(x, y, w, inv_std, scaled_mean, wmat, b, *, link: str,
+                 interpret: bool, feature_major: bool, tile):
+    """What the two class-sweep wrappers share: the ``(k, d)`` coefficient
+    rows folded with the standardization and split into pieces, the kernel,
+    and the gradient's un-folding. Returns ``(loss, grad_W (k, d),
+    Σ mult (k,), Σ w)`` — ``loss`` the lane sums' total, one number under
+    the softmax and ``(k,)`` under the sigmoids."""
+    n, d = x.shape
+    k = wmat.shape[0]
     if tile is None:
         tile = multinomial_sweep_tile(n, d, k, x.dtype, feature_major)
     if tile is None:
         raise ValueError(
-            f"no multinomial sweep for a {x.dtype} X of {n} x {d}, "
+            f"no class sweep for a {x.dtype} X of {n} x {d}, "
             f"{k} classes, feature_major={feature_major}: ask "
             f"multinomial_sweep_tile first and take the XLA aggregator")
     f32 = jnp.float32
     kp = _pad_to(k, CLASS_GROUP)
-    coef = jnp.asarray(coef, f32)
     inv_std = jnp.asarray(inv_std, f32)
     scaled_mean = jnp.asarray(scaled_mean, f32)
-    wmat = coef[: d * k].reshape(k, d)
-    b = coef[d * k:] if fit_intercept else jnp.zeros((k,), f32)
-    bias = b - jnp.dot(wmat, scaled_mean,
-                       precision=jax.lax.Precision.HIGHEST)
+    shift = jnp.dot(wmat, scaled_mean, precision=jax.lax.Precision.HIGHEST)
+    bias = -shift if b is None else b - shift
     scaled = jnp.pad(wmat * inv_std[None, :], ((0, kp - k), (0, 0)))
     pieces = jnp.concatenate(_split3_rounded(scaled), axis=0)
     bias = jnp.pad(bias, (0, kp - k)).reshape(kp, 1)
-    _note_sweep("multinomial",
-                "feature_major" if feature_major else "row_major",
-                classes=k, class_pad=kp, pieces=SOFTMAX_PIECES,
-                pad_cols=0, tail_rows=n % tile,
-                **{"lane_tile" if feature_major else "row_tile": tile})
+    kind, count_attr, pad_attr = _CLASS_LINKS[link]
+    _note_sweep(kind, "feature_major" if feature_major else "row_major",
+                pieces=SOFTMAX_PIECES, pad_cols=0, tail_rows=n % tile,
+                **{count_attr: k, pad_attr: kp,
+                   "lane_tile" if feature_major else "row_tile": tile})
     loss, raw, msum, count = _run_multinomial(
         x.T if feature_major else x, jnp.asarray(y, f32), jnp.asarray(w, f32),
         pieces, bias, k=k, tile=tile, feature_major=feature_major,
-        interpret=interpret)
+        link=link, interpret=interpret)
     msum = jnp.sum(msum[:k], axis=1)
     gw = raw[:k] * inv_std[None, :] - msum[:, None] * scaled_mean[None, :]
-    grad = jnp.concatenate([gw.reshape(-1), msum]) if fit_intercept \
-        else gw.reshape(-1)
-    return {"loss": jnp.sum(loss), "grad": grad, "count": jnp.sum(count)}
+    loss = jnp.sum(loss) if link == "softmax" else jnp.sum(loss[:k], axis=1)
+    return loss, gw, msum, jnp.sum(count)
 
 
 def _run_multinomial(x, y, w, pieces, bias, *, k, tile, feature_major,
-                     interpret):
+                     link, interpret):
     """The K-class GLM sweep: per grid step the margins of ``tile`` rows
-    (MXU), their softmax, loss and multipliers with the classes on the
+    (MXU), their link, loss and multipliers with the classes on the
     sublanes and the rows on the lanes (VPU, a ``(class_pad, tile)``
     block), and the multipliers' product with the same X tile (MXU), Kahan-
     added across the sequential grid as :func:`_run_glm`'s sums are.
+
+    ``link`` (static) is what the k margins of a row are: ``"softmax"`` —
+    one model, the row's loss one number — or k models of their own,
+    ``"sigmoid"`` (model j's label is ``1[y == j]``) / ``"shared_sigmoid"``
+    (every model's label is ``y``), the row's loss one number a model. The
+    products, the tiling, the tail and the sums are the same code.
 
     ``x`` is the ``(d, n)`` view (``feature_major``: blocks ``(d, tile)``)
     or the ``(n, d)`` array (blocks ``(tile, d)``); either way X meets the
     MXU as it is stored, y and w ride as lane-dense ``(1, n)`` rows, and
     nothing is padded: the rows of the last tile past n are selected out in
     that grid step alone (Pallas leaves them undefined, and 0 · NaN is NaN).
-    Returns ``(loss (1, 128), Σ mult·x (class_pad, d), Σ mult (class_pad,
-    128), Σ w (1, 128))`` with the lane-wise partial sums left to the
-    caller."""
+    Returns ``(loss (1, 128) — (class_pad, 128) under the sigmoids, whose
+    rows past k are the padding's and the caller's to drop, as the other
+    sums' are —, Σ mult·x (class_pad, d), Σ mult (class_pad, 128), Σ w
+    (1, 128))`` with the lane-wise partial sums left to the caller."""
     kp = pieces.shape[0] // SOFTMAX_PIECES
     d = pieces.shape[1]
     n = x.shape[1] if feature_major else x.shape[0]
@@ -798,17 +864,28 @@ def _run_multinomial(x, y, w, pieces, bias, *, k, tile, feature_major,
             margins = b_ref[:] + sum(
                 stacked[j * kp:(j + 1) * kp] for j in range(SOFTMAX_PIECES))
             klass = jax.lax.broadcasted_iota(jnp.int32, (kp, tile), 0)
-            if k < kp:
-                margins = jnp.where(klass < k, margins, -jnp.inf)
             yv, wv = y_ref[:], w_ref[:]
-            top = jnp.max(margins, axis=0, keepdims=True)
-            e = jnp.exp(margins - top)
-            z = jnp.sum(e, axis=0, keepdims=True)
-            hit = klass == yv.astype(jnp.int32)
-            picked = jnp.sum(jnp.where(hit, margins, 0.0), axis=0,
-                             keepdims=True)
-            v_loss = wv * (top + jnp.log(z) - picked)
-            mult = wv * (e / z - hit.astype(jnp.float32))
+            if link == "softmax":
+                if k < kp:
+                    margins = jnp.where(klass < k, margins, -jnp.inf)
+                top = jnp.max(margins, axis=0, keepdims=True)
+                e = jnp.exp(margins - top)
+                z = jnp.sum(e, axis=0, keepdims=True)
+                hit = klass == yv.astype(jnp.int32)
+                picked = jnp.sum(jnp.where(hit, margins, 0.0), axis=0,
+                                 keepdims=True)
+                v_loss = wv * (top + jnp.log(z) - picked)
+                mult = wv * (e / z - hit.astype(jnp.float32))
+            else:
+                # a model a sublane: rows past k are the padding's (zero
+                # coefficients: finite, and dropped by the caller)
+                hit = yv == 1.0 if link == "shared_sigmoid" \
+                    else klass == yv.astype(jnp.int32)
+                e = jnp.exp(-jnp.abs(margins))
+                v_loss = wv * (jnp.maximum(margins, 0.0) + jnp.log1p(e)
+                               - jnp.where(hit, margins, 0.0))
+                mult = wv * (jnp.where(margins >= 0.0, 1.0, e) / (1.0 + e)
+                             - hit.astype(jnp.float32))
             if live is not None:
                 v_loss = jnp.where(live, v_loss, 0.0)
                 mult = jnp.where(live, mult, 0.0)
@@ -838,11 +915,12 @@ def _run_multinomial(x, y, w, pieces, bias, *, k, tile, feature_major,
     x_spec = pl.BlockSpec((d, tile), lambda i: (0, i)) if feature_major \
         else pl.BlockSpec((tile, d), lambda i: (i, 0))
     vec_spec = pl.BlockSpec((1, tile), lambda i: (0, i))
-    shapes = [(1, LANE), (kp, d), (kp, LANE), (1, LANE)]
+    shapes = [(1 if link == "softmax" else kp, LANE), (kp, d), (kp, LANE),
+              (1, LANE)]
     args = (x, y.reshape(1, n), w.reshape(1, n), pieces, bias)
     sweep = pl.pallas_call(
         glm_sweep_multinomial,
-        name="glm_sweep_multinomial",
+        name="glm_sweep_" + _CLASS_LINKS[link][0],
         grid=(steps,),
         in_specs=[x_spec, vec_spec, vec_spec,
                   pl.BlockSpec(pieces.shape, lambda i: (0, 0)),

@@ -240,8 +240,9 @@ class FeatureShardedLossFunction:
         return loss, grad
 
     def device_line_search(self, x: np.ndarray, direction: np.ndarray,
-                           value: float, dg0: float, init_alpha: float,
-                           c1: float, c2: float, max_evals: int):
+                           value: float, grad: np.ndarray, dg0: float,
+                           init_alpha: float, c1: float, c2: float,
+                           max_evals: int):
         """Whole strong-Wolfe search in one dispatch, beta kept sharded.
 
         The penalty is re-derived on the sharded beta slice
@@ -261,7 +262,9 @@ class FeatureShardedLossFunction:
                                                cdt))
         beta0, b0 = self._split(x, cdt)
         dbeta, db0 = self._split(direction, cdt)
+        gbeta, gb0 = self._split(grad, cdt)
         args = (self._x, self._y, self._w, beta0, b0, dbeta, db0,
+                gbeta, gb0,
                 cdt.type(value), cdt.type(dg0), cdt.type(init_alpha),
                 cdt.type(self.weight_sum), cdt.type(reg),
                 self._inv_std, self._scaled_mean)
@@ -291,7 +294,7 @@ def _build_tp_line_search(runtime: MeshRuntime, c1: float, c2: float,
 
     tp_prog = binary_logistic_tp_program(runtime)
 
-    def program(x, y, w, beta0, b0, dbeta, db0,
+    def program(x, y, w, beta0, b0, dbeta, db0, gbeta0, gb00,
                 value0, dg0, init_alpha, ws, reg, inv_std, scaled_mean):
         def phi(alpha):
             beta = beta0 + alpha * dbeta
@@ -308,9 +311,9 @@ def _build_tp_line_search(runtime: MeshRuntime, c1: float, c2: float,
             dg = jnp.dot(dbeta, gbn) + db0 * gb0n
             return loss, (gbn, gb0n), dg
 
-        g_zero = (jnp.zeros_like(beta0), cdt.type(0.0))
         alpha, v, (gb, gb0), evals = wolfe_search(
-            phi, g_zero, value0, dg0, init_alpha, c1, c2, max_evals, cdt)
+            phi, (gbeta0, gb00), value0, dg0, init_alpha, c1, c2,
+            max_evals, cdt)
         return alpha, v, gb, gb0, evals
 
     return jax.jit(program)

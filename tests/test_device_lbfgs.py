@@ -206,3 +206,176 @@ def test_lr_estimator_uses_device_chunk(ctx):
     np.testing.assert_allclose(m1.coefficients.to_array(),
                                m0.coefficients.to_array(),
                                rtol=1e-6, atol=1e-9)
+
+
+class TestResolutionRule:
+    """``loss.wolfe_search`` ends where float32 cannot resolve the decrease
+    on offer (``lbfgs.OWLQN._search``'s rule, PR 30) instead of bisecting
+    its whole budget away."""
+
+    @staticmethod
+    def _search(phi, value0, dg0, dtype, active=None):
+        import jax.numpy as jnp
+        from cycloneml_tpu.ml.optim.loss import wolfe_search
+        cdt = np.dtype(dtype)
+        value0 = jnp.asarray(value0, cdt)
+        return wolfe_search(phi, jnp.zeros(jnp.shape(value0) + (2,), cdt),
+                            value0, jnp.asarray(dg0, cdt),
+                            jnp.ones(jnp.shape(value0), cdt), 1e-4, 0.9, 30,
+                            cdt, active=active)
+
+    @staticmethod
+    def _flat(slope):
+        """φ at the bottom of a float32 objective: every trial reads an ulp
+        ABOVE the start."""
+        import jax.numpy as jnp
+
+        def phi(alpha):
+            return (jnp.float32(1.0) + jnp.float32(2.0 ** -23),
+                    jnp.full((2,), alpha, jnp.float32), jnp.float32(slope))
+        return phi
+
+    def test_a_trial_that_fails_armijo_by_rounding_ends_the_search(self):
+        """Armijo fails by an ulp, and would for every smaller step: the
+        search ends after that one trial. The trial read ABOVE the start,
+        so what comes back is the empty step — α = 0, the start's own value
+        and the start's gradient (``g0``, zeros here; φ's is ones)."""
+        alpha, v, g, evals = self._search(self._flat(-1e-9), 1.0, -1e-9,
+                                          np.float32)
+        assert int(evals) == 1 and float(alpha) == 0.0
+        assert float(v) == 1.0
+        assert g.tolist() == [0.0, 0.0]
+
+    def test_a_trial_that_lowers_the_value_is_kept(self):
+        """The resolution exit on a trial BELOW the start (it fails the
+        curvature condition with a positive slope, and a zoom would only go
+        to smaller steps): that trial is the result, gradient and all."""
+        import jax.numpy as jnp
+
+        def phi(alpha):
+            return (jnp.float32(1.0) - jnp.float32(2.0 ** -23),
+                    jnp.full((2,), 7.0, jnp.float32), jnp.float32(1.0))
+
+        alpha, v, g, evals = self._search(phi, 1.0, -1e-9, np.float32)
+        assert int(evals) == 1 and float(alpha) == 1.0
+        assert float(v) == np.float32(1.0) - np.float32(2.0 ** -23)
+        assert g.tolist() == [7.0, 7.0]
+
+    def test_the_zoom_stops_at_the_resolution_not_at_its_budget(self):
+        """A slope the accumulator resolves at α = 1 but not a few
+        bisections later: the zoom ends there."""
+        _, _, _, evals = self._search(self._flat(-1e-6), 1.0, -1e-6,
+                                      np.float32)
+        # eps·|F| = 1.2e-7: α = 1, 1/2, 1/4, 1/8, then 1/16 offers 6e-8
+        assert int(evals) == 5
+
+    def test_lanes_keep_their_own_searches(self):
+        """Batched: the unresolved lane stops at once, the sound lane runs
+        its own bracket to the end and neither holds the other."""
+        import jax.numpy as jnp
+
+        def phi(alpha):
+            # lane 0: flat to rounding; lane 1: (α - 30)^2 / 2
+            v = jnp.stack([jnp.float32(1.0) + jnp.float32(2.0 ** -23),
+                           0.5 * (alpha[1] - 30.0) ** 2])
+            dg = jnp.stack([jnp.float32(-1e-9), alpha[1] - 30.0])
+            return v.astype(jnp.float32), jnp.zeros((2, 2), jnp.float32), \
+                dg.astype(jnp.float32)
+
+        alpha, v, _, evals = self._search(
+            phi, np.array([1.0, 450.0]), np.array([-1e-9, -30.0]),
+            np.float32)
+        # lane 0 takes the empty step; lane 1 doubles its step until the
+        # curvature condition holds
+        assert evals.tolist() == [1, 3] and alpha.tolist() == [0.0, 4.0]
+        assert v.tolist() == [1.0, 338.0]
+
+    @pytest.mark.parametrize("stacked", [False, True])
+    def test_a_chunk_drops_the_step_that_raised_its_objective(self, stacked):
+        """A float32 chunk over an objective whose value stops resolving
+        (a quadratic riding on 1e4): the run ends on the value test after a
+        handful of evaluations, and its history never rises — a search that
+        ended on rounding above its start hands back the empty step."""
+        import jax.numpy as jnp
+        from cycloneml_tpu.ml.optim import device_lbfgs
+        cdt = np.dtype(np.float32)
+        target = jnp.asarray([0.3, -0.2, 0.1, 0.7], jnp.float32)
+        curv = jnp.asarray([1.0, 7.0, 30.0, 100.0], jnp.float32)
+
+        def compiled(coef):
+            r = coef - target
+            return {"loss": 1e4 + 0.5 * jnp.sum(curv * r * r, axis=-1),
+                    "grad": curv * r}
+
+        n, m, iters = 4, 10, 40
+        if stacked:
+            prog = device_lbfgs._build_stacked_chunk(
+                compiled, m, iters, 1e-4, 0.9, 30, cdt, n_arrays=0)
+            out = prog(np.zeros((2, n), cdt), np.zeros((2, m, n), cdt),
+                       np.zeros((2, m, n), cdt), np.zeros(2, np.int32),
+                       np.zeros(2, cdt), np.zeros((2, n), cdt),
+                       np.bool_(True), cdt.type(1.0), np.zeros(2, cdt),
+                       np.zeros(n, cdt), cdt.type(0.0), cdt.type(0.0),
+                       np.int32(iters), np.bool_(True),
+                       np.zeros(2, np.int32))
+            losses, steps, evals = out[6][0], int(out[7]), int(out[10])
+            codes = out[11].tolist()
+        else:
+            prog = device_lbfgs._build_chunk(
+                compiled, None, m, iters, 1e-4, 0.9, 30, cdt, n_arrays=0)
+            out = prog(np.zeros(n, cdt), np.zeros((m, n), cdt),
+                       np.zeros((m, n), cdt), np.int32(0), cdt.type(0.0),
+                       np.zeros(n, cdt), np.bool_(True), cdt.type(1.0),
+                       cdt.type(0.0), cdt.type(0.0), np.int32(iters),
+                       np.bool_(True))
+            losses, steps, evals = out[6], int(out[7]), int(out[8])
+            codes = [int(out[9])]
+        hist = [float(v) for v in np.asarray(losses)[:steps]]
+        assert all(b <= a for a, b in zip(hist, hist[1:])), hist
+        # tol = 0: what stops the run is a step that no longer moves the
+        # float32 value (or a gradient of exactly zero) — reached without
+        # bisecting a budget away
+        assert all(c != 0 for c in codes) and steps < iters, (codes, steps)
+        assert evals <= 2 * steps + 6, (evals, steps)
+
+    def test_float64_searches_are_what_they_were(self):
+        """The rule is ``eps`` of the accumulator: in float64 a slope of
+        1e-9 is far above it, and the search bisects on as before."""
+        import jax.numpy as jnp
+
+        def phi(alpha):
+            return (jnp.float64(1.0) + 1e-12, jnp.zeros((2,), jnp.float64),
+                    jnp.float64(-1e-9))
+
+        _, _, _, evals = self._search(phi, 1.0, -1e-9, np.float64)
+        assert int(evals) > 10
+
+    def test_the_host_driver_never_takes_a_raised_step_in_float32(
+            self, ctx, monkeypatch):
+        """``DistributedLossFunction.device_line_search`` under the host
+        ``LBFGS`` with a float32 accumulator (the chip's tier): run to the
+        floor (tol = 0), the history never rises, the run ends on the empty
+        step's |Δf| = 0 and no search bisects a budget away."""
+        from cycloneml_tpu.dataset import instance
+        from cycloneml_tpu.dataset.dataset import InstanceDataset
+        from cycloneml_tpu.ml.optim import aggregators
+        from cycloneml_tpu.ml.optim.lbfgs import LBFGS
+        from cycloneml_tpu.ml.optim.loss import (DistributedLossFunction,
+                                                 l2_regularization)
+        monkeypatch.setattr(instance, "compute_dtype", lambda: np.float32)
+        rng = np.random.RandomState(2)
+        n, d = 600, 32
+        x = rng.randn(n, d)
+        y = (x @ rng.randn(d) + rng.randn(n) > 0).astype(np.float64)
+        ds = InstanceDataset.from_numpy(ctx, x, y)
+        loss = DistributedLossFunction(
+            ds, aggregators.binary_logistic(d, fit_intercept=True),
+            l2_regularization(0.01, d, True, standardize=True))
+        assert loss.accumulator_dtype == np.float32
+        st = LBFGS(max_iter=100, tol=0.0).minimize(loss, np.zeros(d + 1))
+        hist = st.loss_history
+        assert all(b <= a for a, b in zip(hist, hist[1:])), hist
+        assert st.converged_reason == "function value converged", st
+        assert st.iteration < 100
+        assert loss.n_evals <= 2 * st.iteration + 6, \
+            (loss.n_evals, st.iteration)

@@ -222,3 +222,29 @@ def test_pallas_scaled_kernel_matches_scaled_aggregator(ctx):
                                atol=2e-4)
     np.testing.assert_allclose(float(got["count"]), float(exp["count"]),
                                rtol=1e-6)
+
+
+@pytest.mark.parametrize("n,d,seed,reg", [(600, 32, 2, 0.01),
+                                          (256, 24, 0, 0.01),
+                                          (600, 32, 7, 0.001)])
+def test_tp_line_search_never_takes_a_raised_step_in_float32(
+        tp_ctx, n, d, seed, reg):
+    """The feature-sharded twin of ``wolfe_search``'s callers, on float32
+    rows (its search runs at X's width): run to the floor (tol = 0), the
+    history never rises — on each of these problems the last search ends on
+    a trial an ulp above its start, and hands back the empty step —, the
+    run ends on that step's |Δf| = 0 and no search bisects its budget
+    away."""
+    x, y = _problem(n=n, d=d, seed=seed)
+    l2 = l2_regularization(reg, d, True, standardize=True)
+    rt = tp_ctx.mesh_runtime
+    ds_tp = InstanceDataset.from_numpy(tp_ctx, x, y, dtype=np.float32)
+    x_tp = fs.feature_sharded_put(rt, ds_tp.x)
+    assert x_tp.dtype == np.float32
+    tp = fs.FeatureShardedLossFunction(rt, x_tp, ds_tp.y, ds_tp.w, d, True, l2)
+    st = LBFGS(max_iter=100, tol=0.0).minimize(tp, np.zeros(d + 1))
+    hist = st.loss_history
+    assert all(b <= a for a, b in zip(hist, hist[1:])), hist
+    assert st.converged_reason == "function value converged", st
+    assert st.iteration < 100 and tp.n_fused_searches == st.iteration
+    assert tp.n_evals <= 2 * st.iteration + 6, (tp.n_evals, st.iteration)

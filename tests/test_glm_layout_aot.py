@@ -41,6 +41,10 @@ def _aggregator(kind, d, feature_major):
         return (aggregators.multinomial_logistic_pallas_scaled(
             d, N_CLASSES, True, feature_major=feature_major),
             [v, v, ((d * N_CLASSES + N_CLASSES,), jnp.float32)])
+    if kind == "stacked":
+        return (aggregators.stacked_binary_logistic_pallas_scaled(
+            d, N_CLASSES, True, feature_major=feature_major),
+            [v, v, ((N_CLASSES, d + 1), jnp.float32)])
     return (aggregators.least_squares_pallas_scaled(
         d, feature_major=feature_major), [v, v, ((2,), jnp.float32), v])
 
@@ -148,6 +152,39 @@ def test_multinomial_program_reads_x_as_stored(topo, n, d, feature_major,
     assert n * d * 2 <= mem.argument_size_in_bytes <= n * d * 2 + (1 << 27)
     assert mem.temp_size_in_bytes < 1 << 30
     assert _x_ops(text, n) == [] and _wide_x(text, n, d) == []
+
+
+def _label_matrices(text, n, k):
+    """Instructions that hold a value of one entry a (row, model) pair in a
+    storage type: the OneVsRest label matrix in any of its shapes."""
+    shapes = "|".join(f"{a},{b}" for a, b in (
+        (n, k), (n, 16), (k, n), (16, n)))
+    return [line.strip() for line in text.splitlines()
+            if re.search(rf"= (?:bf16|f16|f32|s8|u8|pred|s32)\[(?:{shapes})\]",
+                         line)]
+
+
+@pytest.mark.parametrize("n,d,feature_major,layout", [
+    (8_100_000, 784, True, "0,1"), (2_000_000, 1280, False, "1,0")])
+def test_stacked_binomial_program_reads_x_once_as_stored(topo, n, d,
+                                                         feature_major,
+                                                         layout):
+    """``ovr_lr_mnist8m_fit``'s shard and the row-major side at a
+    lane-aligned width: the K = 10 binary models' evaluation is ONE Mosaic
+    call (``glm_sweep_stacked_binomial``: X is read once for all ten) with
+    no f32 value of X's shape, no pad or copy of X, and no ``(rows, K)``
+    label matrix in any orientation — the labels are made in the kernel
+    from the class-index row."""
+    compiled = _compile(topo, "stacked", n, d, feature_major)
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert _entry_layout_of_x(text) == layout
+    assert text.count("tpu_custom_call") == 1
+    assert "glm_sweep_stacked_binomial" in text
+    assert n * d * 2 <= mem.argument_size_in_bytes <= n * d * 2 + (1 << 27)
+    assert mem.temp_size_in_bytes < 1 << 30
+    assert _x_ops(text, n) == [] and _wide_x(text, n, d) == []
+    assert _label_matrices(text, n, N_CLASSES) == []
 
 
 # -- the normal equations' moment program (WeightedLeastSquares) ---------------

@@ -104,8 +104,8 @@ def test_bf16_npz_spill_and_checkpoint_roundtrip(ctx, tier, tmp_path):
     ds3 = InstanceDataset.restore(ctx, path)
     assert str(ds3.x.dtype) == "bfloat16"
     np.testing.assert_array_equal(np.asarray(ds3.x), x_before)
-    # y can ride the data tier too (fit_stacked derives a bf16 label
-    # matrix) — the pack must cover it, not just x
+    # y can ride the data tier too (a derived bf16 label matrix) — the
+    # pack must cover it, not just x
     import ml_dtypes
     rt = ctx.mesh_runtime
     y_stackish = rng.rand(64, 2) > 0.5

@@ -793,6 +793,68 @@ def test_streamed_stacked_fit_matches_serial_streamed(ctx):
         sds.close()
 
 
+def test_streamed_ovr_fit_stages_no_label_stack(ctx):
+    """``fit_stacked(sds, num_classes=K)`` — OneVsRest's relabelling over a
+    shard set: model j's label is made inside the per-shard program from
+    the shard's own label vector, so nothing of shape ``(rows, K)`` is ever
+    staged; each model matches its serial streamed fit on ``1[y == j]`` to
+    1e-9, and ``OneVsRest`` under ``cyclone.oocore.mode=force`` takes this
+    leg and lands on the in-core stacked fit's models."""
+    from cycloneml_tpu.dataset.frame import MLFrame
+    from cycloneml_tpu.ml.classification import LogisticRegression, OneVsRest
+    rng = np.random.RandomState(44)
+    k, n, d = 3, 2000, 8
+    centers = rng.randn(k, d) * 1.5
+    y = rng.randint(0, k, n).astype(float)
+    x = centers[y.astype(int)] + rng.randn(n, d)
+    lr = LogisticRegression(maxIter=40, tol=1e-9, regParam=0.05)
+    sds = _streaming_ds(ctx, x, y)
+    rt = ctx.mesh_runtime
+    staged, real_put = [], rt.device_put_sharded_rows
+    rt.device_put_sharded_rows = \
+        lambda a, *p, **kw: staged.append(np.shape(a)) or real_put(a, *p, **kw)
+    try:
+        models = lr.fit_stacked(sds, num_classes=k)
+    finally:
+        rt.device_put_sharded_rows = real_put
+    try:
+        assert staged and all(len(shape) == 1 or shape[1] == d
+                              for shape in staged), set(staged)
+        serial_evals = []
+        for j in range(k):
+            one = _streaming_ds(ctx, x, (y == j).astype(float))
+            try:
+                ref = lr.fit(one)
+            finally:
+                one.close()
+            np.testing.assert_allclose(models[j]._coef, ref._coef,
+                                       rtol=1e-9, atol=1e-12)
+            np.testing.assert_allclose(models[j]._icpt, ref._icpt,
+                                       rtol=1e-9, atol=1e-12)
+            serial_evals.append(ref.summary.total_evals)
+        s = models[0].summary
+        assert s.streamed and s.n_models == k
+        assert s.stacked_evals <= max(serial_evals) < sum(serial_evals)
+        with pytest.raises(ValueError, match="class-index labels below 2"):
+            lr.fit_stacked(sds, num_classes=2)
+    finally:
+        sds.close()
+
+    frame = MLFrame(ctx, {"features": x, "label": y})
+    ovr = OneVsRest(classifier=lr, parallelism=k)
+    incore = ovr.fit(frame)
+    ctx.conf.set("cyclone.oocore.mode", "force")
+    try:
+        forced = ovr.fit(MLFrame(ctx, {"features": x, "label": y}))
+    finally:
+        ctx.conf.remove("cyclone.oocore.mode")
+    assert all(m.summary.streamed for m in forced.models)
+    assert forced.summary.total_evals == forced.models[0].summary.stacked_evals
+    for a, b in zip(forced.models, incore.models):
+        np.testing.assert_allclose(a._coef, b._coef, rtol=1e-5, atol=1e-7)
+        np.testing.assert_allclose(a._icpt, b._icpt, rtol=1e-5, atol=1e-7)
+
+
 def test_streamed_stacked_sgd_matches_serial(ctx):
     """``optimize_stacked`` is the model-axis twin of the streamed SGD:
     per-model labels via ``y_stack`` (OvR relabelings), a shared

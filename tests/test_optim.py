@@ -497,7 +497,11 @@ def test_fused_line_search_dispatch_count(ctx):
     loss = DistributedLossFunction(
         ds, aggregators.binary_logistic(d, fit_intercept=True),
         l2_regularization(0.01, d, True, standardize=True))
-    st = LBFGS(max_iter=20, tol=0.0).minimize(loss, np.zeros(d + 1))
+    # a start far from the optimum: the first searches must double their
+    # first trial before the curvature condition holds (from zeros every
+    # search of this problem accepts its first trial, and at the float64
+    # floor `wolfe_search` ends a search at once since PR 41)
+    st = LBFGS(max_iter=20, tol=0.0).minimize(loss, 3.0 * rng.randn(d + 1))
     assert st.iteration >= 5
     # initial eval = 1 dispatch; each iteration = 1 fused line-search dispatch
     assert loss.n_dispatches <= st.iteration + 2, \

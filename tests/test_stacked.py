@@ -222,21 +222,17 @@ class TestConvergenceMasks:
 
         frame = _binary_frame(ctx, seed=40)
         ds = frame.to_instance_dataset("features", "label", None)
-        y = np.asarray(ds.unpad(ds.y_host()))
         stats = Summarizer.summarize(ds)
         inv_std = inv_std_vector(stats.std)
         scaled_mean = stats.mean * inv_std
         d = ds.n_features
         K = len(regs)
         xdt = np.dtype(str(ds.x.dtype))
-        y_pad = np.zeros((len(ds.y_host()), K), dtype=xdt)
-        y_pad[ds.valid_indices()] = np.tile(y[:, None], (1, K)).astype(xdt)
-        ds_st = ds.derive(
-            y=ctx.mesh_runtime.device_put_sharded_rows(y_pad))
-        agg = aggregators.stack_scaled_aggregator(
-            aggregators.binary_logistic_scaled(d, True))
+        # K models over the dataset's own 0/1 labels (a regParam grid)
+        agg = aggregators.stacked_binary_logistic_scaled(
+            d, K, True, shared_labels=True)
         loss = StackedDistributedLossFunction(
-            ds_st, agg, K, reg=np.asarray(regs),
+            ds, agg, K, reg=np.asarray(regs),
             l2_scale=stacked_l2_scale(d, d + 1),
             weight_sum=stats.weight_sum,
             extra_args=(jnp.asarray(inv_std.astype(xdt)),

@@ -843,7 +843,8 @@ def test_chunk_and_line_search_programs_are_named(ctx):
                        np.bool_(True)).as_text(debug_info=True)
     assert "jit_lbfgs_chunk" in text
     search = _build_line_search(call.compiled, None, 1e-4, 0.9, 5, cdt)
-    text = search.lower(*arrays, c, c, one, one, one, one).as_text()
+    # x0, direction, f(x0), ∇f(x0), the slope, the first step, Σw
+    text = search.lower(*arrays, c, c, one, c, one, one, one).as_text()
     assert "jit_lbfgs_line_search" in text
 
 
