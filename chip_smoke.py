@@ -611,10 +611,11 @@ def stacked_leg(ctx, devices) -> dict:
         ctx.conf.remove("cyclone.oocore.mode")
     si, ss = incore[0].summary, streamed[0].summary
     check(not si.streamed and ss.streamed
-          and si.orientation == ss.orientation == "feature_major",
-          f"stacked leg: in core {si.orientation!r} (streamed "
-          f"{si.streamed}), forced {ss.orientation!r} (streamed "
-          f"{ss.streamed})")
+          and si.orientation == ss.orientation == "feature_major"
+          and si.pieces == ss.pieces == kernels.SOFTMAX_PIECES,
+          f"stacked leg: in core {si.orientation!r}, {si.pieces} pieces "
+          f"(streamed {si.streamed}), forced {ss.orientation!r}, "
+          f"{ss.pieces} pieces (streamed {ss.streamed})")
     coef_gap = max(rel_err(a._coef, b._coef)
                    for a, b in zip(streamed, incore))
     obj_gap = max(abs(a.summary.objective_history[-1]
@@ -791,27 +792,6 @@ def kernel_matrix() -> dict:
                        (xs,), dict(moment_reference(xv, yr, w),
                                    mxu_passes=3.0))
 
-    # KMeans assignment (opt-in path; recorded for ROADMAP D3)
-    n, d_k, k = 8192, 128, 1000
-    centers = rng.standard_normal((k, d_k), dtype=np.float32)
-    for tier in ("float32", "bfloat16", "float8"):
-        x32 = (centers[rng.integers(0, k, n)]
-               + 0.05 * rng.standard_normal((n, d_k), dtype=np.float32))
-        xs, scale, xv = stored(x32, tier)
-        d2 = ((xv * xv).sum(1)[:, None] - 2.0 * xv @ centers.T.astype(
-            np.float64) + (centers.astype(np.float64) ** 2).sum(1)[None])
-        fn = jax.jit(lambda x, s=scale: kernels.fused_kmeans_assign(
-            x, centers, x_scale=s))
-        check("tpu_custom_call" in fn.lower(xs).as_text(),
-              f"kmeans/{tier}: not lowered to Mosaic")
-        best, dist = fn(xs)
-        best = np.asarray(best)
-        # near-ties may resolve either way: judge by the distance reached
-        reached = d2[np.arange(n), best]
-        check(np.all(reached <= d2.min(1) + 1e-3 * (1.0 + d2.min(1))),
-              f"kmeans/{tier}: assignments are not nearest centers")
-        out[f"kmeans_assign/{tier}/n={n},k={k},d={d_k}"] = rel_err(
-            np.asarray(dist), np.maximum(d2.min(1), 0.0))
     return out
 
 

@@ -324,20 +324,15 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
             std = self._stacked_standardization(stats, fp8_scale)
             x0 = self._stacked_start(hist, n_models, shared_labels, stats)
 
-            # the fused stacked sweep where X's storage admits one (a
-            # resident bf16 X: kernels.multinomial_sweep_tile), in the
-            # tiling its layout dictates; the row-blocked XLA twin
-            # otherwise. Either reads X once an evaluation for all K models
-            # and makes model j's label from the dataset's own label vector
-            from cycloneml_tpu.ops import kernels
-            orientation = None
-            if kernels.use_fused_kernels(ds.ctx):
-                orientation = kernels.multinomial_sweep_orientation(
-                    ds.x, n_models)
+            # the fused stacked sweep or its row-blocked XLA twin: either
+            # reads X once an evaluation for all K models and makes model
+            # j's label from the dataset's own label vector
+            sweep = aggregators.dense_sweep(
+                ds, "stacked_binomial", d, k=n_models,
+                fit_intercept=self.get("fitIntercept"),
+                shared_labels=shared_labels)
             loss_fn = StackedDistributedLossFunction(
-                ds, self._stacked_aggregator(d, n_models, shared_labels,
-                                             orientation),
-                n_models, reg=reg_params,
+                ds, sweep.agg, n_models, reg=reg_params,
                 l2_scale=stacked_l2_scale(d, x0.shape[1], stats.std,
                                           self.get("standardization")),
                 weight_sum=stats.weight_sum,
@@ -364,9 +359,8 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
                 reg_params=reg_params, num_classes=num_classes)
         with tracing.span("phase", "fit.finish"):
             return self._stacked_models(
-                res, loss_fn, std, orientation=orientation,
-                pieces=kernels.SOFTMAX_PIECES
-                if orientation is not None else None)
+                res, loss_fn, std, orientation=sweep.orientation,
+                pieces=sweep.pieces)
 
     # what the two legs of a stacked fit (in-core above, streamed below)
     # share: standardization vectors, starting point, sweep, models
@@ -414,17 +408,6 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
             p1 = np.where(ok, pos / stats.weight_sum, 0.5)
             x0[:, d] = np.where(ok, np.log(p1 / (1.0 - p1)), 0.0)
         return x0
-
-    def _stacked_aggregator(self, d: int, n_models: int, shared_labels: bool,
-                            orientation: Optional[str]):
-        """The fused stacked sweep in the tiling ``orientation`` names, or
-        (None) its row-blocked XLA twin."""
-        if orientation is None:
-            return aggregators.stacked_binary_logistic_scaled(
-                d, n_models, self.get("fitIntercept"), shared_labels)
-        return aggregators.stacked_binary_logistic_pallas_scaled(
-            d, n_models, self.get("fitIntercept"), shared_labels,
-            feature_major=orientation == "feature_major")
 
     def _stacked_models(self, res, loss_fn, std, **summary):
         """The K models of a finished stacked run, in original space."""
@@ -476,7 +459,6 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
         from cycloneml_tpu.ml.optim.device_lbfgs import StackedHostLBFGS
         from cycloneml_tpu.ml.optim.loss import stacked_l2_scale
         from cycloneml_tpu.oocore import StackedStreamingLossFunction
-        from cycloneml_tpu.ops import kernels
 
         n_models = len(reg_params)
         d = sds.n_features
@@ -491,22 +473,12 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
         x0 = self._stacked_start(sds.label_histogram(), n_models,
                                  shared_labels, stats)
 
-        # a staged shard lies as the device's default layout puts it
-        orientation = None
-        # (one model: the XLA twin, as kernels.multinomial_sweep_orientation)
-        if kernels.use_fused_kernels(sds.ctx) and n_models > 1:
-            feature_major = kernels.default_feature_major(d)
-            rows = sds.pad_rows // sds.ctx.mesh_runtime.data_parallelism
-            if kernels.multinomial_sweep_tile(
-                    rows, d, n_models,
-                    getattr(sds, "x_dtype", np.float64),
-                    feature_major) is not None:
-                orientation = "feature_major" if feature_major \
-                    else "row_major"
+        sweep = aggregators.dense_sweep(
+            sds, "stacked_binomial", d, k=n_models,
+            fit_intercept=self.get("fitIntercept"),
+            shared_labels=shared_labels)
         loss_fn = StackedStreamingLossFunction(
-            sds, self._stacked_aggregator(d, n_models, shared_labels,
-                                          orientation),
-            n_models, reg=reg_params,
+            sds, sweep.agg, n_models, reg=reg_params,
             l2_scale=stacked_l2_scale(d, x0.shape[1], stats.std,
                                       self.get("standardization")),
             weight_sum=stats.weight_sum,
@@ -527,7 +499,8 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
             finally:
                 bf16.close()
         return self._stacked_models(res, loss_fn, std, streamed=True,
-                                    orientation=orientation)
+                                    orientation=sweep.orientation,
+                                    pieces=sweep.pieces)
 
     def _fit_sparse(self, ds) -> "LogisticRegressionModel":
         """Binomial logistic regression over the sparse (ELL / ELL+COO
@@ -684,19 +657,18 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
                                                "upperBoundsOnIntercepts"))
 
             rt = ds.ctx.mesh_runtime
-            from cycloneml_tpu.ops.kernels import use_fused_kernels
             from cycloneml_tpu.parallel import feature_sharding as fs
             m = fs.model_parallelism(rt)
             tp_active = (not is_multinomial) and m > 1 and d % m == 0 \
                 and not streamed
-            # fused Pallas kernels are the DEFAULT sweep on natively-lowered
-            # backends (usePallasKernels=auto): one VMEM-resident row pass per
-            # evaluation, bf16 blocks read at storage width with fp32 in-kernel
-            # accumulation; the XLA-fused jnp aggregator stays as the fallback
-            # (and the only path on CPU, where the interpreter is for tests)
-            use_pallas = use_fused_kernels(ds.ctx)
-            orientation = None  # tiling of the fused sweep, if it runs
-            pieces = None       # and its bf16 pieces a product (0: VPU)
+            # the dense sweep: a fused Pallas kernel (the default on
+            # natively-lowered backends) or its XLA-fused twin, its tiling
+            # and form — aggregators.dense_sweep decides; the feature-
+            # sharded TP program has a sweep of its own
+            agg, orientation, pieces = (None, None, None) if tp_active \
+                else aggregators.dense_sweep(
+                    ds, "multinomial" if is_multinomial else "binomial", d,
+                    k=num_classes, fit_intercept=fit_intercept)
             # EVERY fit path folds standardization (and fitWithMean centering)
             # INTO the aggregator read — no standardized copy exists anywhere:
             # replicated binomial/multinomial since r4; the feature-sharded TP
@@ -716,24 +688,6 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
                 else inv_std
 
             if is_multinomial:
-                # the fused K-class sweep where X's storage admits one (a
-                # resident bf16 X: kernels.multinomial_sweep_tile), in the
-                # tiling its layout dictates; the XLA aggregator otherwise
-                # (always scaled: the TP alternative is binomial-only)
-                if use_pallas and not streamed:
-                    from cycloneml_tpu.ops.kernels import \
-                        multinomial_sweep_orientation
-                    orientation = multinomial_sweep_orientation(
-                        ds.x, num_classes)
-                if orientation is not None:
-                    from cycloneml_tpu.ops.kernels import SOFTMAX_PIECES
-                    pieces = SOFTMAX_PIECES
-                    agg = aggregators.multinomial_logistic_pallas_scaled(
-                        d, num_classes, fit_intercept,
-                        feature_major=orientation == "feature_major")
-                else:
-                    agg = aggregators.multinomial_logistic_scaled(
-                        d, num_classes, fit_intercept)
                 n_coef = d * num_classes + (num_classes if fit_intercept else 0)
                 x0 = np.zeros(n_coef)
                 if fit_intercept and histogram.min() > 0:
@@ -744,24 +698,6 @@ class LogisticRegression(Predictor, _LogisticRegressionParams,
                     features_std=np.tile(features_std, num_classes),
                     standardize=standardize) if l2 > 0 else None
             else:
-                if use_pallas:
-                    # the sweep's tiling follows the way X is stored (a
-                    # streamed fit's shards are staged per dispatch: no
-                    # resident array to observe, row-major as before)
-                    from cycloneml_tpu.ops.kernels import (
-                        glm_sweep_orientation, glm_sweep_pieces)
-                    tiling = "row_major" if streamed else \
-                        glm_sweep_orientation(ds.x, fp8_scale is not None)
-                    agg = aggregators.binary_logistic_pallas_scaled(
-                        d, fit_intercept,
-                        feature_major=tiling == "feature_major")
-                    if not tp_active:   # the TP program has its own sweep
-                        orientation = tiling
-                        pieces = glm_sweep_pieces(
-                            ds, tiling == "feature_major",
-                            fp8_scale is not None)
-                else:
-                    agg = aggregators.binary_logistic_scaled(d, fit_intercept)
                 n_coef = d + (1 if fit_intercept else 0)
                 x0 = np.zeros(n_coef)
                 if fit_intercept and 0 < histogram[1:].sum() < weight_sum:
@@ -1056,7 +992,7 @@ class LogisticRegressionTrainingSummary:
         # budget-guard degradation; dispatches then count SHARD dispatches
         self.streamed = streamed
         # tiling of the fused GLM sweep the fit ran ("feature_major" /
-        # "row_major", ops/kernels.glm_sweep_orientation); None when the
+        # "row_major", ml/optim/aggregators.dense_sweep); None when the
         # sweep was not the fused kernel
         self.orientation = orientation
         # classes the fit's objective ran over (2: a binomial fit): with
@@ -1067,9 +1003,9 @@ class LogisticRegressionTrainingSummary:
         # the ones it was still live for); None outside a stacked fit
         self.stacked_evals = stacked_evals
         # bf16 pieces an f32 operand of the fused sweep's products rode the
-        # MXU in (ops/kernels.SOFTMAX_PIECES; a binomial fit's sweep: 0 on
-        # the VPU body, ops/kernels.glm_sweep_pieces); None when the sweep
-        # was not a fused kernel
+        # MXU in (3; a binomial fit's sweep: 0 on the VPU body,
+        # ml/optim/aggregators.dense_sweep); None when the sweep was not a
+        # fused kernel
         self.pieces = pieces
 
 

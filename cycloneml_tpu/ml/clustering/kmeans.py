@@ -214,7 +214,7 @@ class KMeans(Estimator, _KMeansParams, MLWritable, MLReadable):
         import jax
         import jax.numpy as jnp
         from cycloneml_tpu.dataset.instance import compute_dtype
-        from cycloneml_tpu.ops.kernels import use_fused_kernels
+        from cycloneml_tpu.ml.optim.aggregators import use_fused_kernels
         from cycloneml_tpu.ops.kmeans_lloyd import (
             CENTRE_PIECES, SCREEN_BREAK_EVEN, lloyd_tile,
         )

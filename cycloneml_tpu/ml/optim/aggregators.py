@@ -25,7 +25,7 @@ Layout conventions (match the reference's flat coefficient layout):
 from __future__ import annotations
 
 import functools
-from typing import Callable, Dict
+from typing import Callable, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -499,8 +499,8 @@ def binary_logistic_pallas_scaled(d: int, fit_intercept: bool = True,
     needs the standardized copy either.
 
     ``feature_major`` is the tiling of the sweep (ops/kernels: rows on the
-    lanes, for an X stored that way). An estimator passes what it observed
-    on its X (``kernels.glm_sweep_orientation``); ``None`` — a caller with
+    lanes, for an X stored that way). :func:`dense_sweep` passes what it
+    observed on a fit's X; ``None`` — a caller with
     no array in hand — means what the device's default layout gives an
     ``(n, d)`` array, so asking again with the same ``d`` returns the very
     aggregator a fit of a default-layout X used (one program per
@@ -610,3 +610,110 @@ def _least_squares_pallas_scaled(d: int, feature_major: bool) -> Agg:
             feature_major=feature_major)
 
     return agg
+
+
+# -- which dense sweep a fit runs ----------------------------------------------
+
+def use_fused_kernels(ctx) -> bool:
+    """Whether the eligible dense sweeps route through the fused Pallas
+    kernels: ``cyclone.ml.usePallasKernels`` 'auto' (default) says yes on
+    natively-lowered backends (TPU) — the fused kernels ARE the default
+    sweep there — and no elsewhere; 'true'/'false' force one path
+    everywhere ('true' off a TPU raises at lowering: the package never
+    interprets). A ``ctx`` without a ``conf`` (bare runtime namespaces)
+    reads as 'auto'."""
+    from cycloneml_tpu.conf import USE_PALLAS_KERNELS
+    from cycloneml_tpu.ops import kernels
+    conf = getattr(ctx, "conf", None)
+    mode = (str(conf.get(USE_PALLAS_KERNELS)).lower()
+            if conf is not None else "auto")
+    if mode == "true":
+        return True
+    if mode == "false":
+        return False
+    return kernels.pallas_available()
+
+
+class Sweep(NamedTuple):
+    """What :func:`dense_sweep` chose for a fit: the aggregator, the
+    tiling of the fused kernel it runs (``"feature_major"`` /
+    ``"row_major"``; None where the XLA twin runs) and the bf16 pieces an
+    f32 operand of that kernel's products rides the MXU in (3; 0 for the
+    one-model sweep's VPU body; None with ``orientation``) — what the
+    estimators write on their summaries."""
+    agg: Agg
+    orientation: Optional[str]
+    pieces: Optional[int]
+
+
+def dense_sweep(ds, kind: str, d: int, *, k: int = 1,
+                fit_intercept: bool = True,
+                shared_labels: bool = False) -> Sweep:
+    """The dense sweep a fit of ``ds`` runs — the one place that decides
+    it. ``kind`` is the objective: ``"binomial"`` / ``"least_squares"``
+    (one model), ``"multinomial"`` (``k`` classes) or
+    ``"stacked_binomial"`` (``k`` models, labels as in
+    :func:`stacked_binary_logistic_scaled`). The fused kernel runs where
+    :func:`use_fused_kernels` says so and a shard of X admits it, in the
+    tiling X's layout dictates; the XLA twin everywhere else. The factories
+    are cached by value, so the same question returns the same aggregator
+    object, and the program cache is keyed by that identity."""
+    from cycloneml_tpu.oocore import StreamingDataset
+    from cycloneml_tpu.ops import kernels
+    if kind not in ("binomial", "least_squares", "multinomial",
+                    "stacked_binomial"):
+        raise ValueError(f"no dense sweep of kind {kind!r}")
+    streamed = isinstance(ds, StreamingDataset)
+    orientation = pieces = None
+    if use_fused_kernels(ds.ctx):
+        if streamed:
+            # a staged shard: the (pad_rows, d) block split over the data
+            # axis, in no resident layout that could be read
+            rows = ds.pad_rows // ds.ctx.mesh_runtime.data_parallelism
+            dtype = ds.x_dtype
+        else:
+            rows = ds.x.sharding.shard_shape(ds.x.shape)[0]
+            dtype = ds.x.dtype
+        has_scale = getattr(ds, "x_scale", None) is not None
+        # the two streamed rules disagree on how a staged shard lies: the
+        # one-model sweeps take it row-major, the stacked one as the
+        # device's default layout puts an (n, d) array (ROADMAP D3(g))
+        if kind in ("binomial", "least_squares"):
+            orientation = "row_major" if streamed else \
+                kernels.glm_sweep_orientation(ds.x, has_scale)
+            pieces = 0 if kernels.mxu_sweep_tile(
+                rows, d, dtype, has_scale,
+                orientation == "feature_major") is None \
+                else kernels.SOFTMAX_PIECES
+        else:
+            if not streamed:
+                orientation = kernels.multinomial_sweep_orientation(ds.x, k)
+            elif kind == "stacked_binomial" and k > 1:
+                feature_major = kernels.default_feature_major(d)
+                if kernels.multinomial_sweep_tile(
+                        rows, d, k, dtype, feature_major) is not None:
+                    orientation = "feature_major" if feature_major \
+                        else "row_major"
+            if orientation is not None:
+                pieces = kernels.SOFTMAX_PIECES
+    fused = orientation is not None
+    feature_major = orientation == "feature_major"
+    if kind == "binomial":
+        agg = binary_logistic_pallas_scaled(
+            d, fit_intercept, feature_major=feature_major) if fused \
+            else binary_logistic_scaled(d, fit_intercept)
+    elif kind == "least_squares":
+        agg = least_squares_pallas_scaled(
+            d, feature_major=feature_major) if fused \
+            else least_squares_scaled(d)
+    elif kind == "multinomial":
+        agg = multinomial_logistic_pallas_scaled(
+            d, k, fit_intercept, feature_major=feature_major) if fused \
+            else multinomial_logistic_scaled(d, k, fit_intercept)
+    else:
+        agg = stacked_binary_logistic_pallas_scaled(
+            d, k, fit_intercept, shared_labels,
+            feature_major=feature_major) if fused \
+            else stacked_binary_logistic_scaled(d, k, fit_intercept,
+                                                shared_labels)
+    return Sweep(agg, orientation, pieces)

@@ -249,7 +249,6 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
             # storage width with fp32 accumulation inside the kernel; the
             # fused Pallas kernel is the default sweep on native backends.
             from cycloneml_tpu.dataset.instance import compute_dtype
-            from cycloneml_tpu.ops.kernels import use_fused_kernels
             adt = compute_dtype()
             scaled_mean = (x_mean * inv_std) if fit_intercept else np.zeros(d)
             y_mean_std = (y_mean / y_std) if fit_intercept else 0.0
@@ -262,21 +261,8 @@ class LinearRegression(Predictor, _LinearRegressionParams, MLWritable, MLReadabl
                 else inv_std
             from cycloneml_tpu.oocore import StreamingDataset
             streamed = isinstance(ds, StreamingDataset)
-            orientation = pieces = None
-            if use_fused_kernels(ds.ctx):
-                # the sweep's tiling follows the way X is stored (a
-                # streamed fit's shards are staged per dispatch: no
-                # resident array to observe, row-major as before)
-                from cycloneml_tpu.ops.kernels import (glm_sweep_orientation,
-                                                       glm_sweep_pieces)
-                orientation = "row_major" if streamed else \
-                    glm_sweep_orientation(ds.x, fp8_scale is not None)
-                pieces = glm_sweep_pieces(ds, orientation == "feature_major",
-                                          fp8_scale is not None)
-                agg = aggregators.least_squares_pallas_scaled(
-                    d, feature_major=orientation == "feature_major")
-            else:
-                agg = aggregators.least_squares_scaled(d)
+            agg, orientation, pieces = aggregators.dense_sweep(
+                ds, "least_squares", d)
 
             l2 = (1.0 - alpha) * reg
             l1 = alpha * reg
@@ -399,10 +385,10 @@ class LinearRegressionTrainingSummary:
         # True when the fit ran on the out-of-core streaming engine
         self.streamed = streamed
         # tiling of the fused GLM sweep the fit ran ("feature_major" /
-        # "row_major", ops/kernels.glm_sweep_orientation); None when the
+        # "row_major", ml/optim/aggregators.dense_sweep); None when the
         # sweep was not the fused kernel
         self.orientation = orientation
         # bf16 pieces an f32 operand of that sweep's products rode the MXU
-        # in (3), or 0 for the VPU body (ops/kernels.glm_sweep_pieces);
-        # None with ``orientation``
+        # in (3), or 0 for the VPU body (the same function's answer); None
+        # with ``orientation``
         self.pieces = pieces

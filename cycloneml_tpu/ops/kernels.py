@@ -4,15 +4,15 @@ Each kernel fuses one estimator hot loop into a single VMEM-resident pass
 over row tiles (grid over the instance-block rows, accumulating into revisited
 output blocks — the standard Pallas reduction pattern):
 
-- ``fused_binary_logistic``: the north-star hot loop (ref:
+- ``fused_binary_logistic_scaled``: the north-star hot loop (ref:
   BinaryLogisticBlockAggregator.scala:41 — forward gemv :97, multiplier :112,
   transpose gemv :130) as margin→softplus-loss→multiplier→grad in one kernel.
 - ``fused_least_squares_scaled``: the LinearRegression l-bfgs residual sweep
   on the same row pass. It and ``fused_binary_logistic_scaled`` take one of
-  two forms by the X they are handed: a bfloat16 X whose width the class
-  sweep's tile admits (feature-major from 240 features) meets the MXU as
-  stored (the class sweep below with one model); every other X the VPU
-  body (``_run_glm``).
+  two forms by the X they are handed (``mxu_sweep_tile``): a bfloat16 X
+  whose width the class sweep's tile admits (feature-major from 240
+  features) meets the MXU as stored (the class sweep below with one
+  model); every other X the VPU body (``_run_glm``).
 - ``fused_multinomial_logistic_scaled``: the K-class sweep (ref:
   MultinomialLogisticBlockAggregator) — margins, softmax, loss and gradient
   from one read of a bfloat16 X, both products on the MXU with the f32
@@ -21,22 +21,20 @@ output blocks — the standard Pallas reduction pattern):
   X (OneVsRest's relabellings, a regParam grid) — the K-class sweep's body
   with the link changed from a softmax to K sigmoids: one read of X an
   evaluation for all K, the lane's 0/1 label made in the kernel.
-- ``fused_kmeans_assign``: the KMeans distance+argmin inner loop (ref:
-  DistanceMeasure.findClosest:123) as ‖x‖²−2x·c+‖c‖² with a fused argmin.
 - ``fused_moment_gramian``: the augmented Gramian ``[1|y|X]'W[1|y|X]`` —
   WeightedLeastSquares' moment pass and RowMatrix.computeGramianMatrix:130
   (the treeAggregate of spr:147 rank-1 updates) as upper-triangle MXU
   products of bf16 tiles; ``moment_sums`` is its front door.
 
-Under ``cyclone.ml.usePallasKernels=auto`` (the default) the GLM kernels
-ARE the dense sweep on a TPU backend, and the XLA-fused ``jnp`` aggregators
-are the sweep everywhere else; the KMeans kernel is opt-in (``true``) only.
-The Gramian reads no conf key: ``moment_sums`` takes the kernel wherever the
-array it is handed allows one (PERF.md §6, PR 29: 54 ms against 105 ms for
-XLA's contraction at 2,000,000 x 2,000 on the v5e).
+Kernels take arrays and shapes; ``ml/optim/aggregators.dense_sweep``
+chooses which GLM sweep a fit runs (a kernel here or the XLA-fused ``jnp``
+aggregator, its tiling, its form) from the array rules here and
+``cyclone.ml.usePallasKernels``. The Gramian has no such key:
+``moment_sums`` takes the kernel wherever the array it is handed allows one
+(PERF.md §6: 54 ms against 105 ms for XLA's contraction at
+2,000,000 x 2,000 on the v5e).
 
-The KMeans wrapper pads rows to the tile size and features to the 128-lane
-boundary; the Gramian pads nothing (it tiles like the feature-major sweep
+The Gramian pads nothing (it tiles like the feature-major sweep
 below, or transposes a row-major tile in VMEM). The GLM sweep has two tilings of one body, chosen by
 the way X is stored (``stored_feature_major``): *row-major* — ``(row_tile,
 d_pad)`` blocks, d on the lanes, padded to the 128-lane boundary only when
@@ -80,25 +78,6 @@ _VMEM_BUDGET = 24 << 20
 def pallas_available() -> bool:
     """True when the default backend lowers Pallas natively (TPU)."""
     return jax.default_backend() == "tpu"
-
-
-def use_fused_kernels(ctx) -> bool:
-    """Whether the eligible dense sweeps route through the fused Pallas
-    kernels: ``cyclone.ml.usePallasKernels`` 'auto' (default) says yes on
-    natively-lowered backends (TPU) — the fused kernels ARE the default
-    sweep there — and no elsewhere; 'true'/'false' force one path
-    everywhere ('true' off a TPU raises at lowering: the package never
-    interprets). A ``ctx`` without a ``conf`` (bare runtime namespaces)
-    reads as 'auto'."""
-    from cycloneml_tpu.conf import USE_PALLAS_KERNELS
-    conf = getattr(ctx, "conf", None)
-    mode = (str(conf.get(USE_PALLAS_KERNELS)).lower()
-            if conf is not None else "auto")
-    if mode == "true":
-        return True
-    if mode == "false":
-        return False
-    return pallas_available()
 
 
 def _compiler_params(semantics: str):
@@ -229,11 +208,13 @@ def glm_sweep_orientation(x, has_scale: bool = False) -> str:
 MXU_SWEEP_MIN_FEATURE_MAJOR_WIDTH = 240
 
 
-def _mxu_sweep_tile(rows: int, d: int, dtype, has_scale: bool,
-                    feature_major: bool):
-    """Rows a grid step of the one-model sweep's MXU form takes — the class
-    sweep (:func:`_class_sweep`) with one model — or None where the VPU
-    body (:func:`_run_glm`) is the form: an fp8 X with its scale, a
+def mxu_sweep_tile(rows: int, d: int, dtype, has_scale: bool,
+                   feature_major: bool):
+    """The one-model sweep's FORM, the one rule the wrappers below apply
+    to the shard they are handed and ``aggregators.dense_sweep`` applies
+    to a fit's shard geometry: rows a grid step of the MXU form takes —
+    the class sweep (:func:`_class_sweep`) with one model — or None where
+    the VPU body (:func:`_run_glm`) is the form: an fp8 X with its scale, a
     feature-major width under ``MXU_SWEEP_MIN_FEATURE_MAJOR_WIDTH``, and
     whatever :func:`multinomial_sweep_tile` refuses (storage other than
     bfloat16, feature-major widths off 16, row-major widths off 128, fewer
@@ -242,25 +223,6 @@ def _mxu_sweep_tile(rows: int, d: int, dtype, has_scale: bool,
                      and d < MXU_SWEEP_MIN_FEATURE_MAJOR_WIDTH):
         return None
     return multinomial_sweep_tile(rows, d, 1, dtype, feature_major)
-
-
-def glm_sweep_pieces(ds, feature_major: bool, has_scale: bool) -> int:
-    """bf16 pieces an f32 operand of the one-model sweep's products rides
-    the MXU in for a fit of ``ds`` in the given tiling: ``SOFTMAX_PIECES``
-    where the wrappers will take the MXU form, 0 where the VPU body — the
-    rule :func:`_mxu_sweep_tile` applies to a shard, asked by the
-    estimators for their summary. A streamed dataset's shard is its staged
-    ``(pad_rows, d)`` block split over the data axis."""
-    x = getattr(ds, "x", None)
-    if x is None:
-        rows = ds.pad_rows // ds.ctx.mesh_runtime.data_parallelism
-        dtype = getattr(ds, "x_dtype", np.float64)
-    else:
-        rows = x.sharding.shard_shape(x.shape)[0]
-        dtype = x.dtype
-    tile = _mxu_sweep_tile(rows, ds.n_features, _storage_dtype(dtype),
-                           has_scale, feature_major)
-    return 0 if tile is None else SOFTMAX_PIECES
 
 
 def _auto_lane_tile(n: int, d: int, dtype, has_scale: bool):
@@ -368,40 +330,6 @@ def _note_sweep(kind: str, orientation: str, **attrs) -> None:
 
 # -- fused binary logistic loss + gradient -------------------------------------
 
-def fused_binary_logistic(x, y, w, coef, d: int, fit_intercept: bool = True,
-                          interpret: bool = False,
-                          row_tile: int = ROW_TILE,
-                          x_scale=None,
-                          feature_major: bool = False
-                          ) -> Dict[str, jnp.ndarray]:
-    """Drop-in for the ``aggregators.binary_logistic`` block math: one pass
-    over HBM computing {loss, grad, count} sums for the shard. Narrow
-    (bf16/fp8) data-tier blocks are read at storage width and upcast to
-    the f32 accumulator per VMEM tile — half (bf16) or a quarter (fp8) of
-    the HBM traffic of an f32 sweep, no wide X copy anywhere. ``x_scale``
-    is the fp8 tier's per-column dequantization vector, applied in-kernel
-    per VMEM block. ``feature_major`` (static) is the caller's observation
-    that X is stored rows-on-lanes (:func:`stored_feature_major`); it
-    picks the tiling, never the result."""
-    dtype = jnp.float32
-    x = _storage_width(x)
-    y = jnp.asarray(y, dtype)
-    w = jnp.asarray(w, dtype)
-    coef = jnp.asarray(coef, dtype)
-    beta = coef[:d] if fit_intercept else coef
-    b0 = coef[d] if fit_intercept else jnp.zeros((), dtype)
-
-    loss, g, msum, count = _glm_sums(
-        x, y, w, beta, b0, jnp.zeros((), dtype), kind="logistic", d=d,
-        row_tile=row_tile, interpret=interpret, x_scale=x_scale,
-        feature_major=feature_major)
-    if fit_intercept:
-        grad = jnp.concatenate([g, msum[None]])
-    else:
-        grad = g
-    return {"loss": loss, "grad": grad, "count": count}
-
-
 def fused_binary_logistic_scaled(x, y, w, inv_std, scaled_mean, coef,
                                  d: int, fit_intercept: bool = True,
                                  interpret: bool = False,
@@ -409,9 +337,17 @@ def fused_binary_logistic_scaled(x, y, w, inv_std, scaled_mean, coef,
                                  x_scale=None,
                                  feature_major: bool = False
                                  ) -> Dict[str, jnp.ndarray]:
-    """Folded-standardization twin of :func:`fused_binary_logistic`: the
-    kernel reads RAW feature rows — no standardized copy — because the
-    scaling is algebra OUTSIDE the row pass:
+    """The ``aggregators.binary_logistic_scaled`` block math as one pass
+    over HBM computing {loss, grad, count} sums for the shard. Narrow
+    (bf16/fp8) data-tier blocks are read at storage width and upcast to
+    the f32 accumulator per VMEM tile — half (bf16) or a quarter (fp8) of
+    the HBM traffic of an f32 sweep, no wide X copy anywhere. ``x_scale``
+    is the fp8 tier's per-column dequantization vector, applied in-kernel
+    per VMEM block. ``feature_major`` (static) is the caller's observation
+    that X is stored rows-on-lanes (:func:`stored_feature_major`); it
+    picks the tiling, never the result. The kernel reads RAW feature rows
+    — no standardized copy — because the scaling is algebra OUTSIDE the
+    row pass:
 
       margin = x·(inv_std∘β) + (β₀ − scaled_mean·β)   (scaled vector +
                                                        offset fold into the
@@ -420,11 +356,12 @@ def fused_binary_logistic_scaled(x, y, w, inv_std, scaled_mean, coef,
                                                        the kernel's raw sums)
 
     Same contract as ``aggregators.binary_logistic_scaled``. The form is
-    read off X (:func:`_mxu_sweep_tile`): a bfloat16 X the class sweep can
+    read off X (:func:`mxu_sweep_tile`): a bfloat16 X the class sweep can
     tile is the one-model class sweep under the ``shared_sigmoid`` link —
     both products on the MXU, ``inv_std∘β`` and the multipliers as three
-    bf16 pieces, X never upcast —, every other X the VPU body, which is the
-    unscaled kernel's byte for byte.
+    bf16 pieces, X never upcast —, every other X the VPU body
+    (:func:`_glm_sums`) with ``inv_std∘β`` and the offset in its β / β₀
+    slots.
     """
     dtype = jnp.float32
     x = _storage_width(x)
@@ -434,8 +371,8 @@ def fused_binary_logistic_scaled(x, y, w, inv_std, scaled_mean, coef,
     inv_std = jnp.asarray(inv_std, dtype)
     scaled_mean = jnp.asarray(scaled_mean, dtype)
     beta = coef[:d] if fit_intercept else coef
-    tile = _mxu_sweep_tile(x.shape[0], d, x.dtype, x_scale is not None,
-                           feature_major)
+    tile = mxu_sweep_tile(x.shape[0], d, x.dtype, x_scale is not None,
+                          feature_major)
     if tile is not None:
         loss, g, msum, count = _class_sweep(
             x, y, w, inv_std, scaled_mean, beta[None, :],
@@ -489,8 +426,8 @@ def fused_least_squares_scaled(x, y, w, inv_std, scaled_mean, y_pars, coef,
     inv_std = jnp.asarray(inv_std, dtype)
     scaled_mean = jnp.asarray(scaled_mean, dtype)
     y_pars = jnp.asarray(y_pars, dtype)
-    tile = _mxu_sweep_tile(x.shape[0], d, x.dtype, x_scale is not None,
-                           feature_major)
+    tile = mxu_sweep_tile(x.shape[0], d, x.dtype, x_scale is not None,
+                          feature_major)
     if tile is not None:
         loss, g, _, count = _class_sweep(
             x, y, w, inv_std, scaled_mean, coef[None, :], y_pars[1:],
@@ -731,7 +668,7 @@ def multinomial_sweep_tile(rows: int, d: int, k: int, dtype,
     the VMEM budget. At k ≤ 16 that admits d ≤ 5,600 or so; the class pad
     grows in sixteens and the budget holds k·d ≲ 1.0e5 (k = 100 at d = 784,
     k = 40 at d = 2,000). k = 1 is the one-model sweep's MXU form
-    (:func:`_mxu_sweep_tile`): 1,024 rows at d = 2,000."""
+    (:func:`mxu_sweep_tile`): 1,024 rows at d = 2,000."""
     if np.dtype(dtype) != np.dtype(jnp.bfloat16) or rows < LANE or k < 1:
         return None
     if d % (CLASS_GROUP if feature_major else LANE):
@@ -761,7 +698,7 @@ def multinomial_sweep_orientation(x, k: int):
     tile (:func:`multinomial_sweep_tile`) and the fit is the XLA
     aggregator's. A stacked fit of ONE model is the XLA aggregator's too:
     the K-row sweep serves k ≥ 2 (one model's MXU form is the serial
-    wrappers', :func:`_mxu_sweep_tile`)."""
+    wrappers', :func:`mxu_sweep_tile`)."""
     feature_major = stored_feature_major(x)
     rows, d = x.sharding.shard_shape(x.shape)
     if k < 2 or multinomial_sweep_tile(rows, d, k, x.dtype,
@@ -1045,90 +982,6 @@ def _run_multinomial(x, y, w, pieces, bias, *, k, tile, feature_major,
         interpret=interpret,
     )
     return sweep(*args)
-
-
-# -- fused KMeans assignment ----------------------------------------------------
-
-def fused_kmeans_assign(x, centers, interpret: bool = False,
-                        row_tile: int = ROW_TILE, x_scale=None):
-    """Nearest-center assignment: returns (best_idx (n,), min_dist² (n,)).
-    Fuses ‖x‖² − 2x·cᵀ + ‖c‖² with the argmin so the (T, k) distance tile
-    never leaves VMEM (ref: DistanceMeasure.findClosest:123). bf16 point
-    blocks stay at storage width in HBM — the tile upcasts to f32 in VMEM
-    for the distance accumulation, so narrowing the tier no longer costs a
-    full-X fp32 materialization per Lloyd step. fp8 point blocks pass
-    their per-column dequant vector as ``x_scale``, applied to every
-    upcast VMEM block before the distance math (centers stay f32 in
-    original space)."""
-    x = _storage_width(x)
-    centers = jnp.asarray(centers, jnp.float32)
-    n, d = x.shape
-    k = centers.shape[0]
-    d_pad = _pad_to(d, LANE)
-    k_pad = _pad_to(k, 8)
-    # per tile row: the double-buffered x block and its f32 upcast, plus
-    # the (T, k_pad) product and distance tiles (lane-padded to 128);
-    # fixed: the resident (k_pad, d_pad) centers and their transpose
-    row_tile = _auto_row_tile(
-        n, row_tile, x.dtype,
-        d_pad * (2 * x.dtype.itemsize + 8) + 3 * 4 * _pad_to(k_pad, LANE)
-        + 8 * 512,
-        fixed_bytes=3 * 4 * k_pad * d_pad)
-    n_pad = _pad_to(max(n, row_tile), row_tile)
-    x_p = jnp.pad(x, ((0, n_pad - n), (0, d_pad - d)))
-    c_p = jnp.pad(centers, ((0, k_pad - k), (0, d_pad - d)))
-    # padded centers must never win the argmin
-    c_norm = jnp.concatenate(
-        [jnp.sum(c_p[:k] * c_p[:k], axis=1),
-         jnp.full((k_pad - k,), jnp.inf, jnp.float32)]).reshape(1, k_pad)
-    has_scale = x_scale is not None
-    s_p = _pad_scale(x_scale, d, d_pad) if has_scale else None
-
-    def kmeans_assign(*refs):
-        if has_scale:
-            x_ref, c_ref, cn_ref, s_ref, best_ref, dist_ref = refs
-        else:
-            x_ref, c_ref, cn_ref, best_ref, dist_ref = refs
-            s_ref = None
-        xv = x_ref[:].astype(jnp.float32)                      # (T, d_pad)
-        if s_ref is not None:
-            xv = xv * s_ref[:]          # fp8 dequant per VMEM block
-        # HIGHEST = multi-pass f32 on the MXU; default bf16 multiplies lose
-        # near-tie argmins at ~1e-4 relative distance (ref computes in f64)
-        prod = jnp.dot(xv, c_ref[:].T,
-                       preferred_element_type=jnp.float32,
-                       precision=jax.lax.Precision.HIGHEST)    # (T, k_pad)
-        x2 = jnp.sum(xv * xv, axis=1, keepdims=True)           # (T, 1)
-        d2 = x2 - 2.0 * prod + cn_ref[:]                       # (T, k_pad)
-        best_ref[:] = jnp.argmin(d2, axis=1).astype(jnp.int32).reshape(-1, 1)
-        dist_ref[:] = jnp.min(d2, axis=1).reshape(-1, 1)
-
-    in_specs = [
-        pl.BlockSpec((row_tile, d_pad), lambda i: (i, 0)),
-        pl.BlockSpec((k_pad, d_pad), lambda i: (0, 0)),
-        pl.BlockSpec((1, k_pad), lambda i: (0, 0)),
-    ]
-    args = [x_p, c_p, c_norm]
-    if has_scale:
-        in_specs.append(pl.BlockSpec((1, d_pad), lambda i: (0, 0)))
-        args.append(s_p)
-    best, dist = pl.pallas_call(
-        kmeans_assign,
-        name="kmeans_assign",
-        grid=(n_pad // row_tile,),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((row_tile, 1), lambda i: (i, 0)),
-            pl.BlockSpec((row_tile, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
-        ],
-        compiler_params=_compiler_params("parallel"),
-        interpret=interpret,
-    )(*args)
-    return best[:n, 0], jnp.maximum(dist[:n, 0], 0.0)
 
 
 # -- moment Gramian (WeightedLeastSquares, RowMatrix) -----------------------------

@@ -8,9 +8,7 @@ themselves tested against sklearn/scipy golden numbers elsewhere.
 import numpy as np
 import pytest
 
-from cycloneml_tpu.ops import (fused_binary_logistic,
-                               fused_binary_logistic_scaled,
-                               fused_kmeans_assign,
+from cycloneml_tpu.ops import (fused_binary_logistic_scaled,
                                fused_least_squares_scaled)
 from cycloneml_tpu.ml.optim import aggregators
 
@@ -25,6 +23,15 @@ def data():
     return x, y, w
 
 
+def _unscaled(x, y, w, coef, d, fit_intercept, **kw):
+    """The serial logistic sweep with no standardization to fold
+    (``inv_std`` 1, ``scaled_mean`` 0: ``1·β`` and ``β₀ − 0·β`` are exact),
+    against the unscaled XLA aggregator."""
+    return fused_binary_logistic_scaled(x, y, w, np.ones(d), np.zeros(d),
+                                        coef, d, fit_intercept,
+                                        interpret=True, row_tile=128, **kw)
+
+
 @pytest.mark.parametrize("fit_intercept", [True, False])
 def test_fused_logistic_matches_aggregator(data, fit_intercept, ctx):
     x, y, w = data
@@ -35,8 +42,7 @@ def test_fused_logistic_matches_aggregator(data, fit_intercept, ctx):
     ref = aggregators.binary_logistic(d, fit_intercept)(
         np.asarray(x, np.float32), np.asarray(y, np.float32),
         np.asarray(w, np.float32), np.asarray(coef, np.float32))
-    got = fused_binary_logistic(x, y, w, coef, d, fit_intercept,
-                                interpret=True, row_tile=128)
+    got = _unscaled(x, y, w, coef, d, fit_intercept)
 
     np.testing.assert_allclose(float(got["loss"]), float(ref["loss"]),
                                rtol=1e-5)
@@ -52,37 +58,16 @@ def test_fused_logistic_padding_rows_inert(ctx):
     d = 17
     coef = rng.randn(d + 1)
     x, y, w = rng.randn(100, d), (rng.rand(100) > 0.5).astype(float), np.ones(100)
-    small = fused_binary_logistic(x, y, w, coef, d, True,
-                                  interpret=True, row_tile=128)
+    small = _unscaled(x, y, w, coef, d, True)
     # same data with explicit zero-weight junk rows appended
     x2 = np.vstack([x, rng.randn(60, d) * 100])
     y2 = np.concatenate([y, np.ones(60)])
     w2 = np.concatenate([w, np.zeros(60)])
-    big = fused_binary_logistic(x2, y2, w2, coef, d, True,
-                                interpret=True, row_tile=128)
+    big = _unscaled(x2, y2, w2, coef, d, True)
     np.testing.assert_allclose(float(big["loss"]), float(small["loss"]),
                                rtol=1e-6)
     np.testing.assert_allclose(np.asarray(big["grad"]),
                                np.asarray(small["grad"]), rtol=1e-5, atol=1e-5)
-
-
-def test_fused_kmeans_assign(ctx):
-    rng = np.random.RandomState(7)
-    x = rng.randn(500, 23)
-    centers = rng.randn(11, 23)
-    best, dist = fused_kmeans_assign(x, centers, interpret=True, row_tile=128)
-    d2 = ((x[:, None, :] - centers[None, :, :]) ** 2).sum(-1)
-    np.testing.assert_array_equal(np.asarray(best), d2.argmin(1))
-    np.testing.assert_allclose(np.asarray(dist), d2.min(1), rtol=1e-4,
-                               atol=1e-4)
-
-
-def test_fused_kmeans_padded_centers_never_win(ctx):
-    rng = np.random.RandomState(8)
-    x = rng.randn(50, 5) * 1000  # huge distances; padded centers are at 0
-    centers = rng.randn(3, 5) * 1000
-    best, _ = fused_kmeans_assign(x, centers, interpret=True, row_tile=128)
-    assert np.asarray(best).max() < 3
 
 
 # -- bf16 data tier: storage-width reads, fp32 in-kernel accumulation --------
@@ -104,8 +89,7 @@ def test_fused_logistic_bf16_inputs(data, ctx):
     ref = aggregators.binary_logistic(d, True)(
         np.asarray(xbf, np.float32), np.asarray(y, np.float32),
         np.asarray(w, np.float32), np.asarray(coef, np.float32))
-    got = fused_binary_logistic(xbf, y, w, coef, d, True,
-                                interpret=True, row_tile=128)
+    got = _unscaled(xbf, y, w, coef, d, True)
     np.testing.assert_allclose(float(got["loss"]), float(ref["loss"]),
                                rtol=1e-3)
     np.testing.assert_allclose(np.asarray(got["grad"]),
@@ -155,22 +139,6 @@ def test_fused_least_squares_scaled_matches_aggregator(data, narrow, ctx):
                                np.asarray(ref["grad"]), rtol=5e-3, atol=5e-3)
     np.testing.assert_allclose(float(got["count"]), float(ref["count"]),
                                rtol=1e-6)
-
-
-def test_fused_kmeans_assign_bf16_points(ctx):
-    """bf16 points with f32 distance accumulation: assignments match the
-    f64 reference computed over the SAME bf16-rounded values (the tier
-    rounds the data once; the kernel must not round the accumulation)."""
-    rng = np.random.RandomState(9)
-    xbf = _bf16(rng.randn(300, 17))
-    centers = rng.randn(5, 17)
-    best, dist = fused_kmeans_assign(xbf, centers, interpret=True,
-                                     row_tile=128)
-    xf = np.asarray(xbf, np.float64)
-    d2 = ((xf[:, None, :] - centers[None, :, :]) ** 2).sum(-1)
-    np.testing.assert_array_equal(np.asarray(best), d2.argmin(1))
-    np.testing.assert_allclose(np.asarray(dist), d2.min(1), rtol=1e-2,
-                               atol=1e-2)
 
 
 def test_estimators_run_on_pallas_kernels(ctx, monkeypatch):
@@ -239,9 +207,7 @@ def test_fused_logistic_fp8_scale_operand(data, ctx):
     ref = aggregators.binary_logistic(d, True)(
         deq, np.asarray(y, np.float32), np.asarray(w, np.float32),
         np.asarray(coef, np.float32))
-    got = fused_binary_logistic(x8, y, w, coef, d, True,
-                                interpret=True, row_tile=128,
-                                x_scale=scale)
+    got = _unscaled(x8, y, w, coef, d, True, x_scale=scale)
     np.testing.assert_allclose(float(got["loss"]), float(ref["loss"]),
                                rtol=1e-3)
     np.testing.assert_allclose(np.asarray(got["grad"]),
@@ -269,21 +235,6 @@ def test_fused_least_squares_fp8_scale_operand(data, ctx):
                                rtol=1e-3)
     np.testing.assert_allclose(np.asarray(got["grad"]),
                                np.asarray(ref["grad"]), rtol=5e-3, atol=5e-3)
-
-
-def test_fused_kmeans_assign_fp8(ctx):
-    rng = np.random.RandomState(11)
-    centers = rng.randn(5, 8) * 2.0
-    x = centers[rng.randint(0, 5, 200)] + 0.05 * rng.randn(200, 8)
-    x8, scale = _fp8_cols(x)
-    deq = np.asarray(x8, np.float64) * scale[None, :]
-    best, dist = fused_kmeans_assign(x8, centers, interpret=True,
-                                     row_tile=64, x_scale=scale)
-    # reference assignment on the dequantized points
-    d2 = ((deq[:, None, :] - centers[None, :, :]) ** 2).sum(-1)
-    np.testing.assert_array_equal(np.asarray(best), d2.argmin(1))
-    np.testing.assert_allclose(np.asarray(dist), d2.min(1),
-                               rtol=1e-4, atol=1e-4)
 
 
 # -- the feature-major tiling of the GLM sweep (PR 28) ------------------------
@@ -646,7 +597,7 @@ def test_sweep_loss_holds_to_the_ulp_whatever_the_row_order(form, d, ctx):
     from cycloneml_tpu.ops import kernels
     rng = np.random.RandomState(4)
     n = 8192 + 40
-    assert (kernels._mxu_sweep_tile(n, d, jnp.bfloat16, False, True)
+    assert (kernels.mxu_sweep_tile(n, d, jnp.bfloat16, False, True)
             is not None) == (form == "mxu")
     x = rng.randn(n, d)
     y = (rng.rand(n) > 0.5).astype(np.float64)
@@ -664,3 +615,110 @@ def test_sweep_loss_holds_to_the_ulp_whatever_the_row_order(form, d, ctx):
             jnp.asarray(x[order], jnp.bfloat16), y[order], w, np.ones(d),
             np.zeros(d), coef, d, True, interpret=True, feature_major=True)
         assert abs(float(got["loss"]) - exact) <= 1.5 * ulp, p
+
+
+#: (kind, streamed, usePallasKernels) of the seam's paths — what
+#: ``aggregators.dense_sweep`` answers for each: (the factory call that
+#: path's aggregator is, orientation, pieces) for d = 256, k = 3, a bf16 X
+#: whose resident layout reads feature-major
+_SEAM_PATHS = {
+    ("binomial", False, "true"): (
+        lambda d, k: aggregators.binary_logistic_pallas_scaled(
+            d, True, feature_major=True), "feature_major", 3),
+    ("least_squares", False, "true"): (
+        lambda d, k: aggregators.least_squares_pallas_scaled(
+            d, feature_major=True), "feature_major", 3),
+    # a staged shard: row-major, the form read off the staged geometry
+    ("binomial", True, "true"): (
+        lambda d, k: aggregators.binary_logistic_pallas_scaled(
+            d, True, feature_major=False), "row_major", 3),
+    ("least_squares", True, "true"): (
+        lambda d, k: aggregators.least_squares_pallas_scaled(
+            d, feature_major=False), "row_major", 3),
+    ("multinomial", False, "true"): (
+        lambda d, k: aggregators.multinomial_logistic_pallas_scaled(
+            d, k, True, feature_major=True), "feature_major", 3),
+    ("multinomial", True, "true"): (
+        lambda d, k: aggregators.multinomial_logistic_scaled(d, k, True),
+        None, None),
+    ("stacked_binomial", False, "true"): (
+        lambda d, k: aggregators.stacked_binary_logistic_pallas_scaled(
+            d, k, True, False, feature_major=True), "feature_major", 3),
+    # the staged shard as the device's default layout puts it (row-major
+    # off a TPU), and the three pieces its kernel runs
+    ("stacked_binomial", True, "true"): (
+        lambda d, k: aggregators.stacked_binary_logistic_pallas_scaled(
+            d, k, True, False, feature_major=False), "row_major", 3),
+    ("binomial", False, "false"): (
+        lambda d, k: aggregators.binary_logistic_scaled(d, True), None,
+        None),
+}
+
+
+@pytest.mark.parametrize("kind,streamed,pallas", list(_SEAM_PATHS),
+                         ids=lambda v: str(v))
+def test_dense_sweep_answers_each_path(ctx, monkeypatch, kind, streamed,
+                                       pallas):
+    """``aggregators.dense_sweep`` — the one place that picks a fit's dense
+    sweep — returns, for each resident and streamed path, the very
+    aggregator object of that path's factory call (the program cache is
+    keyed by that identity), the tiling and the form; where a fused kernel
+    runs on resident X, ``pieces`` is what the traced sweep's
+    ``kernel.glm_sweep`` instant says."""
+    import jax.numpy as jnp
+    from cycloneml_tpu.conf import OOCORE_STREAM_DTYPE, USE_PALLAS_KERNELS
+    from cycloneml_tpu.dataset.dataset import InstanceDataset
+    from cycloneml_tpu.observe import tracing
+    from cycloneml_tpu.oocore import shard_dataset
+    from cycloneml_tpu.ops import kernels
+
+    n, d, k = 2048, 256, 3
+    rt = ctx.mesh_runtime
+    rng = np.random.RandomState(44)
+    x = rt.device_put_sharded_rows(
+        np.asarray(jnp.asarray(rng.randn(n, d), jnp.bfloat16)))
+    y = rng.randint(0, 2, n).astype(np.float32)
+    ds = InstanceDataset(ctx, x, rt.device_put_sharded_rows(y),
+                         rt.device_put_sharded_rows(np.ones(n, np.float32)),
+                         n, d)
+    native_call = kernels.pl.pallas_call
+    monkeypatch.setattr(
+        kernels.pl, "pallas_call",
+        lambda *a, **kw: native_call(*a, **{**kw, "interpret": True}))
+    monkeypatch.setattr(kernels, "stored_feature_major", lambda a: True)
+    factory, orientation, pieces = _SEAM_PATHS[(kind, streamed, pallas)]
+    ctx.conf.set(USE_PALLAS_KERNELS, pallas)
+    try:
+        if streamed:
+            ctx.conf.set(OOCORE_STREAM_DTYPE, "bfloat16")
+            try:
+                sds = shard_dataset(ds)
+            finally:
+                ctx.conf.set(OOCORE_STREAM_DTYPE, "auto")
+            try:
+                assert np.dtype(sds.x_dtype) == np.dtype(jnp.bfloat16)
+                sweep = aggregators.dense_sweep(sds, kind, d, k=k)
+            finally:
+                sds.close()
+        else:
+            sweep = aggregators.dense_sweep(ds, kind, d, k=k)
+    finally:
+        ctx.conf.set(USE_PALLAS_KERNELS, "false")
+    assert sweep.agg is factory(d, k)
+    assert (sweep.orientation, sweep.pieces) == (orientation, pieces)
+    if streamed or orientation is None:
+        return
+    extras = [np.ones(d, np.float32), np.zeros(d, np.float32)]
+    if kind == "least_squares":
+        extras.append(np.array([1.0, 0.0], np.float32))
+    coef = {"binomial": d + 1, "least_squares": d,
+            "multinomial": k * d + k, "stacked_binomial": (k, d + 1)}[kind]
+    tracer = tracing.enable()
+    try:
+        ds.tree_aggregate_fn(sweep.agg)(
+            *(jnp.asarray(v) for v in extras), jnp.zeros(coef, jnp.float32))
+    finally:
+        tracing.disable()
+    notes = [s.attrs for s in tracer.snapshot() if s.name == "kernel.glm_sweep"]
+    assert notes and {a["pieces"] for a in notes} == {sweep.pieces}
+    assert {a["orientation"] for a in notes} == {sweep.orientation}

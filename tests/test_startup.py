@@ -76,18 +76,21 @@ def test_backend_peaks_exact_device_kind_only():
 
 
 def test_kernel_wrappers_never_interpret_on_their_own():
-    from cycloneml_tpu.ops import (fused_binary_logistic,
-                                   fused_kmeans_assign,
+    from cycloneml_tpu.ops import (fused_binary_logistic_scaled,
                                    fused_moment_gramian)
+    from cycloneml_tpu.ops.kmeans_lloyd import fused_lloyd_step
     rng = np.random.RandomState(0)
     x = rng.randn(64, 8).astype(np.float32)
     y = (x[:, 0] > 0).astype(np.float32)
     w = np.ones(64, np.float32)
-    for call in (lambda: fused_binary_logistic(x, y, w, np.zeros(9), 8),
+    xb = rng.randn(128, 128).astype("bfloat16")
+    for call in (lambda: fused_binary_logistic_scaled(
+                     x, y, w, np.ones(8), np.zeros(8), np.zeros(9), 8),
                  lambda: fused_moment_gramian(
                      x.astype("bfloat16"), y, w, feature_major=True,
                      lane_tile=64, weighted=False),
-                 lambda: fused_kmeans_assign(x, x[:4])):
+                 lambda: fused_lloyd_step(xb, np.ones(128, np.float32),
+                                          xb[:4].astype(np.float32))):
         with pytest.raises(ValueError, match="[Oo]nly interpret mode"):
             call()
 
